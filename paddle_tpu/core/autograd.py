@@ -201,6 +201,7 @@ def backward(tensors, grad_tensors=None, retain_graph: bool = False,
     Paddle semantics: leaf tensors with stop_gradient=False receive ``.grad``
     (accumulated across calls); non-leaf grads are not retained.
     """
+    from .dispatch import settle_cpu_collectives
     from .tensor import Tensor
 
     if isinstance(tensors, Tensor):
@@ -271,6 +272,7 @@ def backward(tensors, grad_tensors=None, retain_graph: bool = False,
             in_cots = node.vjp_fn(full[0])
         else:
             in_cots = node.vjp_fn(full)
+        settle_cpu_collectives(in_cots)
         visited.append(node)
         for t, ng, ic in zip(node.inputs, node.needs_grad, in_cots):
             if not ng or ic is None:

@@ -476,11 +476,45 @@ def _lazy_record(name, impl, args, attrs, tensor_idx, tensors, arrays,
                  node=node)
 
 
+_cpu_mesh = None  # resolved once: CPU backend with several devices
+
+
+def settle_cpu_collectives(outs):
+    """Wait for eager results that span several CPU devices.
+
+    jaxlib 0.9's CPU client runs the partitions of an SPMD program on a
+    pool of max(cores, devices) threads, and a partition of the NEXT
+    program can hold a thread while it waits for its inputs.  With as
+    many virtual devices as cores, the last partition of a program with
+    an all-reduce then never gets a thread: the rendezvous deadlocks
+    and XLA aborts the process after 40 s ("Expected 8 threads to join
+    the rendezvous, but only 7 of them arrived").  Two jitted functions
+    dispatched back to back in a loop reproduce it with no paddle code;
+    waiting on the first one's result makes it go away.  Eager per-op
+    dispatch is exactly that pattern, so on the CPU backend a
+    multi-device result is waited for before the next op goes out.  A
+    chip runs each device's programs in order on its own stream and is
+    not affected: there this returns at once.
+    """
+    global _cpu_mesh
+    if _cpu_mesh is None:
+        _cpu_mesh = (jax.default_backend() == "cpu"
+                     and jax.device_count() > 1)
+    if not _cpu_mesh:
+        return
+    for o in outs:
+        if (isinstance(o, jax.Array)
+                and not isinstance(o, jax.core.Tracer)
+                and len(o.sharding.device_set) > 1):
+            o.block_until_ready()
+
+
 def _wrap(outs, name, node):
     from .tensor import Tensor
 
     is_multi = isinstance(outs, (tuple, list))
     outs_t = tuple(outs) if is_multi else (outs,)
+    settle_cpu_collectives(outs_t)
     wrapped = []
     for i, o in enumerate(outs_t):
         if o is None:

@@ -14,16 +14,14 @@ import jax
 from ..core.place import (set_device, get_device, device_count,
                           is_compiled_with_cuda, current_place, CPUPlace,
                           TPUPlace, CUDAPlace)
-from .compile_cache import (ENV_COMPILE_CACHE_DIR, compile_cache_dir,
-                            compile_cache_enabled, ensure_compile_cache)
+from .compile_cache import ensure_compile_cache
 
 __all__ = ["set_device", "get_device", "get_available_device",
            "get_available_custom_device", "is_compiled_with_cuda",
            "device_count", "synchronize", "Stream", "Event",
            "current_stream", "stream_guard", "get_all_device_type",
            "get_all_custom_device_type", "XPUPlace", "cuda", "tpu", "Place",
-           "ENV_COMPILE_CACHE_DIR", "ensure_compile_cache",
-           "compile_cache_dir", "compile_cache_enabled"]
+           "ensure_compile_cache"]
 
 Place = TPUPlace
 XPUPlace = TPUPlace

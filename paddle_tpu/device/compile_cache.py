@@ -1,11 +1,18 @@
 """Persistent XLA compilation cache wiring.
 
-``PADDLE_TPU_COMPILE_CACHE_DIR`` points JAX's on-disk compilation cache
-at a directory; every ``jax.jit(...).lower(...).compile()`` in the
-process (the static Executor, ``run_steps`` fused loops, ``jit.
-to_static``, eager segment compiles) then writes its executable there
-and warm-process compiles are served from disk — measured ~3.5x faster
-on CPU, far larger on TPU where Mosaic/XLA compiles are minutes-class.
+Every ``jax.jit(...).lower(...).compile()`` in the process (the static
+Executor, ``run_steps`` fused loops, ``jit.to_static``, eager segment
+compiles) writes its executable to JAX's on-disk compilation cache and
+warm-process compiles are served from disk — a BERT-base train step is
+a minutes-class compile on the TPU.
+
+Where the cache lives is decided outside the program: JAX itself reads
+``JAX_COMPILATION_CACHE_DIR`` at import, and when that variable is set
+this module sets no directory.  Otherwise the cache goes to one fixed,
+git-ignored directory in the checkout (``DEFAULT_CACHE_DIR``) — the
+path is part of the cache key, so it never carries a pid, a time or a
+temp name.  ``jax_enable_compilation_cache=False`` (the test suite sets
+it) keeps the cache off altogether.
 
 The in-process layer above it is the Executor's program-fingerprint
 -keyed executable cache (``static/executor.py``): a structurally
@@ -16,91 +23,62 @@ entry across Executor instances without even re-lowering.
 compile; it is idempotent and near-free after the first call.  Every
 compile site records ``compile.count`` / ``compile.ms`` in the
 observability metrics registry so cold vs warm compile cost is
-measurable (bench.py reports both).
+measurable.
 """
 from __future__ import annotations
 
 import os
 import threading
 
-__all__ = ["ENV_COMPILE_CACHE_DIR", "ensure_compile_cache",
-           "compile_cache_dir", "compile_cache_enabled",
+__all__ = ["DEFAULT_CACHE_DIR", "ensure_compile_cache",
            "record_compile_metrics"]
 
-ENV_COMPILE_CACHE_DIR = "PADDLE_TPU_COMPILE_CACHE_DIR"
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _lock = threading.Lock()
-_configured_dir = None  # the dir last applied (None = not applied)
-_probed = False
-
-
-def compile_cache_dir():
-    """The configured cache directory, or None when disabled."""
-    d = os.environ.get(ENV_COMPILE_CACHE_DIR, "").strip()
-    return d or None
-
-
-def compile_cache_enabled():
-    return _configured_dir is not None
+_applied = False
 
 
 def ensure_compile_cache():
-    """Apply ``PADDLE_TPU_COMPILE_CACHE_DIR`` to JAX's persistent
-    compilation cache (idempotent; re-applies if the env var changed).
+    """Turn JAX's persistent compilation cache on (idempotent) and
+    return its directory, or None when the cache is disabled.
 
-    Thresholds are zeroed so even fast CPU-test compiles persist —
-    the default min-compile-time gate would skip exactly the programs
-    the test suite and bench CPU path exercise.  Returns the active
-    cache dir or None.
+    Thresholds are zeroed so even fast compiles persist — the default
+    min-compile-time gate would skip the many small programs the eager
+    tiers compile.
     """
-    global _configured_dir, _probed
-    d = compile_cache_dir()
-    if d == _configured_dir and _probed:
-        return _configured_dir
+    global _applied
+    import jax
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    if _applied:
+        return jax.config.jax_compilation_cache_dir
     with _lock:
-        d = compile_cache_dir()
-        if d == _configured_dir and _probed:
-            return _configured_dir
-        _probed = True
-        if d is None:
-            if _configured_dir is not None:
-                try:
-                    import jax
-                    jax.config.update("jax_compilation_cache_dir", None)
-                    from jax._src import compilation_cache as _jcc
-                    _jcc.reset_cache()
-                except Exception:
-                    pass
-            _configured_dir = None
-            return None
-        try:
-            import jax
-            os.makedirs(d, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", d)
+        if not _applied:
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs", 0.0)
             jax.config.update(
                 "jax_persistent_cache_min_entry_size_bytes", -1)
-            try:
+            if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+                from jax.experimental.compilation_cache import (
+                    compilation_cache)
+                os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+                jax.config.update("jax_compilation_cache_dir",
+                                  DEFAULT_CACHE_DIR)
                 # jax's disk cache is initialized once, on the first
                 # compile — a compile that ran before the dir was set
                 # latches it off, so force re-initialization
-                from jax._src import compilation_cache as _jcc
-                _jcc.reset_cache()
-            except Exception:
-                pass
-            _configured_dir = d
-        except Exception:
-            # an old jaxlib without the knobs must not break compiles
-            _configured_dir = None
-    return _configured_dir
+                compilation_cache.reset_cache()
+            _applied = True
+    return jax.config.jax_compilation_cache_dir
 
 
 def record_compile_metrics(ms, kind="compile"):
     """Land one compile's wall time in the metrics registry
     (``compile.count`` counter + ``compile.ms`` histogram, plus a
-    per-kind histogram) — bench.py snapshots these for the cold/warm
-    compile report."""
+    per-kind histogram)."""
     from .. import observability as obs
     reg = obs.get_registry()
     reg.counter("compile.count").inc()

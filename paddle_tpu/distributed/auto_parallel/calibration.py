@@ -63,8 +63,7 @@ def _collective_fn(kind, axis):
             return jax.lax.psum_scatter(x, axis, tiled=True)
     elif kind == "permute":
         def f(x):
-            from ..jax_compat import axis_size as _axis_size
-            n = _axis_size(axis)
+            n = jax.lax.axis_size(axis)
             perm = [(i, (i + 1) % n) for i in range(n)]
             return jax.lax.ppermute(x, axis, perm)
     else:
@@ -97,9 +96,8 @@ def measure_collectives(mesh, axis, sizes=None, kinds=None, reps=5):
             xs = jnp.zeros((n * elems,), jnp.float32) + 1.0
             sharded = jax.device_put(
                 xs, NamedSharding(mesh, P(axis)))
-            from ..jax_compat import shard_map as _shard_map
-            g = jax.jit(_shard_map(
-                f, mesh=mesh, in_specs=P(axis),
+            g = jax.jit(jax.shard_map(
+                f, mesh=mesh, in_specs=P(axis), check_vma=False,
                 out_specs=P(axis) if kind in ("reduce_scatter",
                                               "permute", "all_reduce")
                 else P()))

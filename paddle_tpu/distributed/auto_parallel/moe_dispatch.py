@@ -168,7 +168,6 @@ _rot_cache: dict = {}
 
 
 def _rot_fn(plan, axis, x):
-    from ..jax_compat import shard_map as _shard_map
     from jax.sharding import PartitionSpec as P
     key = (plan.cache_token(), axis, x.shape, str(x.dtype))
     fn = _rot_cache.get(key)
@@ -177,8 +176,9 @@ def _rot_fn(plan, axis, x):
     size = plan.axis_size(axis)
     perm = [(i, (i + 1) % size) for i in range(size)]
     spec = P(*((axis,) + (None,) * (x.ndim - 1)))
-    rot = _shard_map(lambda v: jax.lax.ppermute(v, axis, perm),
-                     mesh=plan.mesh, in_specs=spec, out_specs=spec)
+    rot = jax.shard_map(lambda v: jax.lax.ppermute(v, axis, perm),
+                        mesh=plan.mesh, in_specs=spec, out_specs=spec,
+                        check_vma=False)
     fn = jax.jit(rot).lower(x).compile()
     _rot_cache[key] = fn
     return fn

@@ -369,7 +369,6 @@ def _pad_to(x, dim, multiple):
 
 def _compiled(plan, axis, direction, mode, a, b):
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     key = (plan.cache_token(), axis, direction, mode,
            a.shape, str(a.dtype), b.shape, str(b.dtype))
@@ -387,8 +386,8 @@ def _compiled(plan, axis, direction, mode, a, b):
             al, bl, axis=axis, axis_size=size, mode=mode)
         in_specs = (P(None, axis), P(axis, None))
         out_specs = P(axis, None)
-    mapped = shard_map(local, mesh=plan.mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+    mapped = jax.shard_map(local, mesh=plan.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     with obs.span(f"compile:sharded_matmul[{direction}/{mode}]",
                   cat="compile", mesh=plan.describe(), axis=axis):
         fn = jax.jit(mapped).lower(a, b).compile()
@@ -439,7 +438,6 @@ def sharded_matmul(a, b, *, direction, plan=None, axis="tp", mode=None):
 
 def _measured_fns(plan, axis, a, b):
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     key = ("measured", plan.cache_token(), axis,
            a.shape, str(a.dtype), b.shape, str(b.dtype))
@@ -448,13 +446,13 @@ def _measured_fns(plan, axis, a, b):
         return fns
     size = plan.axis_size(axis)
     perm = [(i, (i + 1) % size) for i in range(size)]
-    rot = shard_map(lambda x: jax.lax.ppermute(x, axis, perm),
-                    mesh=plan.mesh, in_specs=P(axis, None),
-                    out_specs=P(axis, None), check_rep=False)
-    dot = shard_map(
+    rot = jax.shard_map(lambda x: jax.lax.ppermute(x, axis, perm),
+                        mesh=plan.mesh, in_specs=P(axis, None),
+                        out_specs=P(axis, None), check_vma=False)
+    dot = jax.shard_map(
         lambda al, bl: _dot(al, bl).astype(_out_dtype(al, bl)),
         mesh=plan.mesh, in_specs=(P(axis, None), P(None, None)),
-        out_specs=P(axis, None), check_rep=False)
+        out_specs=P(axis, None), check_vma=False)
     fns = (jax.jit(rot).lower(a).compile(),
            jax.jit(dot).lower(a, b).compile())
     _jit_cache[key] = fns
@@ -554,7 +552,6 @@ def executor_linear_override(plan, mode, routed=None):
             or plan.axis_size("tp") <= 1:
         return None
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from . import sharding as spmd
     from ...nn.functional.common import _apply_act
@@ -605,9 +602,9 @@ def executor_linear_override(plan, mode, routed=None):
             full = jax.lax.all_gather(part, "tp", axis=0, tiled=True)
             return full.reshape(xl.shape[:-1] + (wl.shape[-1],))
 
-        mapped = shard_map(island, mesh=plan.mesh,
-                           in_specs=(x_spec, P("tp", None)),
-                           out_specs=out_spec, check_rep=False)
+        mapped = jax.shard_map(island, mesh=plan.mesh,
+                               in_specs=(x_spec, P("tp", None)),
+                               out_specs=out_spec, check_vma=False)
         z = mapped(x, w)
         if bias is not None:
             z = z + bias

@@ -573,9 +573,8 @@ class MeshPlan:
                 raise ValueError(
                     "pass either in_/out_shardings (jit) or "
                     "in_/out_specs (shard_map), not both")
-            from jax.experimental.shard_map import shard_map
-            mapped = shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                               out_specs=out_specs)
+            mapped = jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                                   out_specs=out_specs)
             return jax.jit(mapped, donate_argnums=donate_argnums,
                            static_argnums=static_argnums, **jit_kwargs)
         is_leaf = lambda x: isinstance(x, (P, NamedSharding))  # noqa: E731

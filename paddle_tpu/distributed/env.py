@@ -68,8 +68,7 @@ def init_parallel_env(strategy=None):
     # (= nnodes * nproc_per_node); one per host on TPU
     nprocs = int(os.environ.get(
         "PADDLE_TRAINERS_NUM", os.environ.get("PADDLE_NNODES", "1")))
-    from .jax_compat import distributed_initialized
-    if nprocs > 1 and coord and not distributed_initialized():
+    if nprocs > 1 and coord and not jax.distributed.is_initialized():
         port = os.environ.get("MASTER_PORT", "8476")
         jax.distributed.initialize(
             coordinator_address=f"{coord.split(':')[0]}:{port}",

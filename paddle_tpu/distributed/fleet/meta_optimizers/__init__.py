@@ -367,10 +367,9 @@ class LocalSGDOptimizer(_InnerDelegate):
         mesh = Mesh(np.array(_jax.devices()), ("lsgd",))
         nd = _jax.device_count()
         nl = _jax.local_device_count()
-        from ...jax_compat import shard_map as _shard_map
-        avg = _jax.jit(_shard_map(
+        avg = _jax.jit(_jax.shard_map(
             lambda x: jax.lax.pmean(x, "lsgd"), mesh=mesh,
-            in_specs=P("lsgd"), out_specs=P("lsgd")))
+            in_specs=P("lsgd"), out_specs=P("lsgd"), check_vma=False))
         for p in self.inner._parameter_list:
             local = np.broadcast_to(
                 np.asarray(p._value)[None],

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ...env import global_mesh
-from ...jax_compat import shard_map as _shard_map
 
 __all__ = ["global_scatter_local", "global_gather_local",
            "moe_ep_forward_local", "ExpertParallelEngine"]
@@ -198,9 +197,9 @@ class ExpertParallelEngine:
         tok_spec = P(self.tok_axes)
         p_specs = tuple(P(axis, *([None] * (a.ndim - 1)))
                         for a in stacked)
-        fn = _shard_map(
+        fn = jax.shard_map(
             device_fn, mesh=mesh,
             in_specs=(p_specs, tok_spec, tok_spec, tok_spec, tok_spec),
-            out_specs=tok_spec)
+            out_specs=tok_spec, check_vma=False)
         y = fn(tuple(stacked), x_val, probs, topk_idx, topk_val)
         return y, aux

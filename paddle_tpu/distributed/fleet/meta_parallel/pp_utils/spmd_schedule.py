@@ -47,7 +47,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ....communication.group import Group  # noqa: F401  (API surface)
-from ....jax_compat import shard_map as _shard_map
 from .....core.tensor import Tensor
 
 logger = logging.getLogger("paddle_tpu.pipeline")
@@ -258,11 +257,12 @@ class SpmdPipelineEngine:
         data_spec_y = P(None, batch_axes if batch_axes else None,
                         *([None] * (len(y_aval.shape) - 2)))
 
-        smapped = _shard_map(
+        smapped = jax.shard_map(
             device_fn, mesh=mesh,
             in_specs=(tuple(p_specs), tuple(o_specs), rep, rep,
                       data_spec_x, data_spec_y),
-            out_specs=(rep, tuple(p_specs), tuple(o_specs)))
+            out_specs=(rep, tuple(p_specs), tuple(o_specs)),
+            check_vma=False)
 
         jitted = jax.jit(smapped, donate_argnums=(0, 1))
         return jitted

@@ -346,7 +346,7 @@ class TracedFunction:
         flow = obs.next_flow_id()
         from ..device.compile_cache import (ensure_compile_cache,
                                             record_compile_metrics)
-        ensure_compile_cache()  # PADDLE_TPU_COMPILE_CACHE_DIR
+        ensure_compile_cache()
         import time as _time
         t0 = _time.perf_counter()
         with obs.span("compile:" + label, cat="compile", flow_out=flow,

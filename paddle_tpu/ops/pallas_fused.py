@@ -52,13 +52,25 @@ _GELU_C = 0.7978845608028654           # sqrt(2/pi)
 _GELU_A = 0.044715
 
 
+def _erf_f32(x):
+    """erf for kernel bodies: Mosaic has no lowering for the erf
+    primitive (jax 0.9), so exact gelu could not compile for the chip.
+    Abramowitz & Stegun 7.1.26, |error| < 1.5e-7."""
+    a = jnp.abs(x)
+    t = 1.0 / (1.0 + 0.3275911 * a)
+    poly = t * (0.254829592 + t * (-0.284496736 + t * (
+        1.421413741 + t * (-1.453152027 + t * 1.061405429))))
+    y = 1.0 - poly * jnp.exp(-a * a)
+    return jnp.where(x < 0.0, -y, y)
+
+
 def _act_f32(z, act):
     if act == "none":
         return z
     if act == "relu":
         return jnp.maximum(z, 0.0)
     if act == "gelu":
-        return 0.5 * z * (1.0 + jax.lax.erf(z / _SQRT_2))
+        return 0.5 * z * (1.0 + _erf_f32(z / _SQRT_2))
     if act == "gelu_tanh":
         t = jnp.tanh(_GELU_C * (z + _GELU_A * z * z * z))
         return 0.5 * z * (1.0 + t)
@@ -75,7 +87,7 @@ def _act_grad_f32(z, act):
     if act == "gelu":
         # d/dz [z*Phi(z)] = Phi(z) + z*phi(z)
         phi = _INV_SQRT_2PI * jnp.exp(-0.5 * z * z)
-        return 0.5 * (1.0 + jax.lax.erf(z / _SQRT_2)) + z * phi
+        return 0.5 * (1.0 + _erf_f32(z / _SQRT_2)) + z * phi
     if act == "gelu_tanh":
         u = _GELU_C * (z + _GELU_A * z * z * z)
         t = jnp.tanh(u)

@@ -309,13 +309,12 @@ def _run_probe(kernel: str) -> ProbeResult:
     from ..analysis.diagnostics import Diagnostic, record
     try:
         # Probe under x32.  The kernels trace their pallas_calls under
-        # disable_x64 (pallas_kernels._x32), but interpret-mode lowering
+        # x32 (pallas_tiles._x32), but interpret-mode lowering
         # of the grid loop happens at *call* time, where the framework's
         # global x64 flag leaks i64 loop carries into the i32 kernel
         # body and StableHLO rejects the mixed compare.  x32 at call
         # time matches what the kernels actually compute.
-        from jax.experimental import disable_x64
-        with disable_x64():
+        with jax.enable_x64(False):
             _PROBES[kernel]()
         result = ProbeResult(kernel, True)
         _logger.info("pallas kernel %s: probe compile OK", kernel)

@@ -306,39 +306,6 @@ def _flash_bwd(q, k, v, do, out, lse, scale, causal, sq_real, sk_real,
     return dq, dk, dv
 
 
-_autotune_table = None
-
-
-def autotune_cache_path():
-    import os
-    return os.path.join(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))),
-        ".bench_cache", "flash_blocks.json")
-
-
-def _load_autotune():
-    """Flash block-size autotune cache (the reference's
-    phi/kernels/autotune role): scripts/flash_block_sweep.py measures
-    the (block_q, block_k) grid on the real chip in a healthy window and
-    persists the winners; runtime consults them by sequence length.
-    TPU only — interpret-mode tests must not change tiling based on a
-    local tuning file."""
-    global _autotune_table
-    if _autotune_table is None:
-        if _interpret():
-            _autotune_table = {}
-            return _autotune_table
-        import json
-        try:
-            _autotune_table = {
-                int(k): (int(v[0]), int(v[1]))
-                for k, v in json.load(
-                    open(autotune_cache_path())).items()}
-        except Exception:
-            _autotune_table = {}
-    return _autotune_table
-
-
 def set_flash_block_sizes(block_q=None, block_k=None):
     """Process-wide override for the sweep harness."""
     global _block_override
@@ -351,18 +318,13 @@ _block_override = (None, None)
 def _pick_block(seq: int, which: int = 0, dtype=jnp.float32) -> int:
     """Q/K block rows for `seq`: legal by construction for `dtype`
     (sublane multiple of _min_rows), covering `seq` after _round_up
-    padding.  Overrides and autotuned values are clamped to legality
+    padding.  The sweep harness's override is clamped to legality
     rather than trusted — an illegal sweep value degrades to the
     default instead of crashing Mosaic."""
     mr = _min_rows(dtype)
     ov = _sane_block(_block_override[which], seq, mr)
     if ov:
         return ov
-    tuned = _load_autotune().get(seq)
-    if tuned:
-        t = _sane_block(tuned[which], seq, mr)
-        if t:
-            return t
     return 128 if seq >= 128 else _round_up(max(seq, mr), mr)
 
 

@@ -52,21 +52,6 @@ __all__ = [
 _NEG_INF = -1e30
 _STAT_LANES = 8  # trailing lane dim for per-row stat arrays
 
-try:
-    from jax._src.config import enable_x64 as _enable_x64_ctx
-except ImportError:  # pragma: no cover - fallback for jax API moves
-    import contextlib
-
-    @contextlib.contextmanager
-    def _enable_x64_ctx(value):
-        old = jax.config.jax_enable_x64
-        jax.config.update("jax_enable_x64", value)
-        try:
-            yield
-        finally:
-            jax.config.update("jax_enable_x64", old)
-
-
 def _x32(fn):
     """Trace the wrapped pallas_call builder under x32 semantics.
 
@@ -81,7 +66,7 @@ def _x32(fn):
     """
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):
-        with _enable_x64_ctx(False):
+        with jax.enable_x64(False):
             return fn(*args, **kwargs)
     return wrapper
 

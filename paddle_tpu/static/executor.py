@@ -636,7 +636,7 @@ class Executor:
             # not pay the single-step XLA compile it never invokes
             from ..device.compile_cache import (ensure_compile_cache,
                                                 record_compile_metrics)
-            ensure_compile_cache()  # PADDLE_TPU_COMPILE_CACHE_DIR
+            ensure_compile_cache()
             t0 = time.perf_counter()
             with obs.span("compile:" + entry["program_label"],
                           cat="compile", flow_out=entry["flow"],

@@ -316,7 +316,7 @@ class TestDtypeAudit:
         assert audit_jaxpr(jaxpr, amp="auto") == []
 
     def test_f64_flagged(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             jaxpr = jax.make_jaxpr(
                 lambda x: x.astype(jnp.float64).sum())(
                     jnp.ones((4,), jnp.float32))

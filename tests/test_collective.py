@@ -6,7 +6,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 import paddle_tpu as paddle
 import paddle_tpu.distributed as dist
@@ -32,8 +31,8 @@ def _grp():
 
 
 def _run(mesh, fn, arr, in_spec, out_spec):
-    return shard_map(fn, mesh=mesh, in_specs=in_spec, out_specs=out_spec,
-                     check_rep=False)(arr)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_spec,
+                         out_specs=out_spec, check_vma=False)(arr)
 
 
 def test_all_reduce_shard_map(mesh):
@@ -134,8 +133,7 @@ def test_ppermute_send_recv_shard_map(mesh):
         t = Tensor(v, _internal=True)
 
         def impl(val, *, axis):
-            from paddle_tpu.distributed.jax_compat import axis_size
-            n = axis_size(axis)
+            n = jax.lax.axis_size(axis)
             perm = [(i, (i + 1) % n) for i in range(n)]
             return jax.lax.ppermute(val, axis, perm)
 

@@ -49,10 +49,9 @@ WORKER = textwrap.dedent("""
         local = np.ones((jax.local_device_count(), 1), np.float32)
         arr = jax.make_array_from_process_local_data(
             NamedSharding(mesh, P("dp")), local, (nd, 1))
-        from paddle_tpu.distributed.jax_compat import shard_map
-        out = jax.jit(shard_map(
+        out = jax.jit(jax.shard_map(
             lambda x: jax.lax.psum(x, "dp"), mesh=mesh,
-            in_specs=P("dp"), out_specs=P()))(arr)
+            in_specs=P("dp"), out_specs=P(), check_vma=False))(arr)
         assert float(np.asarray(jax.device_get(out))[0, 0]) == nd, tag
 
     paddle.seed(0)

@@ -6,7 +6,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 import paddle_tpu as paddle
 import paddle_tpu.distributed as dist
@@ -165,8 +164,8 @@ def test_parallel_cross_entropy_shard_map():
         out = pce(t, l)
         return out._value
 
-    got = shard_map(f, mesh=mesh, in_specs=(P(None, "mp"), P(None)),
-                    out_specs=P(None), check_rep=False)(
+    got = jax.shard_map(f, mesh=mesh, in_specs=(P(None, "mp"), P(None)),
+                        out_specs=P(None), check_vma=False)(
         jnp.asarray(logits), jnp.asarray(labels))
 
     ref = paddle.nn.functional.cross_entropy(

@@ -276,9 +276,17 @@ def test_lazy_to_static_with_pending_state():
 # whole-step capture + fingerprinted executable reuse
 # ---------------------------------------------------------------------
 def test_lazy_lenet_full_state_bit_parity():
-    """The whole-step segment must be BIT-identical to per-op eager:
-    losses, every parameter, and every Adam accumulator, after 3 full
-    train steps (fwd + bwd + fused update)."""
+    """The whole-step segment must track per-op eager after 3 full
+    train steps (fwd + bwd + fused update): losses BIT-identical, every
+    parameter and Adam accumulator within a few ulp of its array's
+    largest element.
+
+    State is not held to bit equality: XLA's CPU backend (jaxlib 0.9)
+    contracts a multiply that feeds an add into one FMA when both sit
+    in one program, so the fused step rounds Adam's
+    ``b1*m + (1-b1)*g`` once where per-op programs round twice —
+    ``jax.jit(whole)`` vs split jits reproduces it with no paddle code,
+    the caveat the BN test below and to_static already carry."""
     import contextlib
     from paddle_tpu.vision.models import LeNet
 
@@ -303,17 +311,21 @@ def test_lazy_lenet_full_state_bit_parity():
                 opt.clear_grad()
                 losses.append(float(loss))
             params = [np.asarray(p.numpy()) for p in m.parameters()]
-            accs = [np.asarray(t.numpy())
+            # in parameter order: generated names differ between the
+            # two runs, so sorting by name would pair unlike arrays
+            accs = [np.asarray(d[p.name].numpy())
                     for _, d in sorted(opt._accumulators.items())
-                    for _, t in sorted(d.items())]
+                    for p in m.parameters() if p.name in d]
         return losses, params, accs
 
     l_ref, p_ref, a_ref = train(False)
     l_got, p_got, a_got = train(True)
     assert l_got == l_ref                     # exact, not allclose
     assert len(a_got) == len(a_ref) > 0
+    eps = np.finfo(np.float32).eps
     for got, ref in zip(p_got + a_got, p_ref + a_ref):
-        np.testing.assert_array_equal(got, ref)
+        np.testing.assert_allclose(got, ref, rtol=0,
+                                   atol=8 * eps * np.abs(ref).max())
 
 
 def test_lazy_fused_bn_segment_close_parity():

@@ -311,14 +311,34 @@ def test_preflight_accounts_for_depth(monkeypatch):
 
 
 # -- persistent compile cache --------------------------------------------
-def test_compile_cache_persists_to_dir(tmp_path, monkeypatch):
-    from paddle_tpu.device import ensure_compile_cache
-    from paddle_tpu.device.compile_cache import compile_cache_enabled
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_persists_to_dir(tmp_path, monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR set: the program sets no directory and
+    writes where JAX was told; unset: one fixed path in the checkout."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+    from paddle_tpu.device import compile_cache as cc
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    assert cc.ensure_compile_cache() is None      # conftest: cache off
     cache = tmp_path / "xla_cache"
-    monkeypatch.setenv("PADDLE_TPU_COMPILE_CACHE_DIR", str(cache))
-    assert ensure_compile_cache() == str(cache)
-    assert compile_cache_enabled()
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_enable_compilation_cache", "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    monkeypatch.setattr(cc, "_applied", False)
+    if env_dir:
+        # what `JAX_COMPILATION_CACHE_DIR=... python` gives: JAX reads
+        # the variable into its config at import
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache))
+        jax.config.update("jax_compilation_cache_dir", str(cache))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setattr(cc, "DEFAULT_CACHE_DIR", str(cache))
+    jax.config.update("jax_enable_compilation_cache", True)
+    jcc.reset_cache()
     try:
+        assert cc.ensure_compile_cache() == str(cache)
         paddle.enable_static()
         main, loss = _linreg_program()
         static.Executor().run(main, feed=_feeds(1)[0], fetch_list=[loss],
@@ -326,9 +346,10 @@ def test_compile_cache_persists_to_dir(tmp_path, monkeypatch):
         files = [p for p in cache.rglob("*") if p.is_file()]
         assert files, "compile did not persist to the cache dir"
     finally:
-        monkeypatch.delenv("PADDLE_TPU_COMPILE_CACHE_DIR")
-        assert ensure_compile_cache() is None
-        assert not compile_cache_enabled()
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        jcc.reset_cache()
+    assert cc.ensure_compile_cache() is None
 
 
 # -- pipeline_stats ------------------------------------------------------

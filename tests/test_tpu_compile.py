@@ -1,0 +1,235 @@
+"""Compile the Pallas kernels for a described (not attached) TPU v5e.
+
+Interpret mode skips Mosaic's block-mapping, tiling and VMEM checks, so
+a kernel can pass every CPU test and still be refused by the chip's
+compiler.  Each case below runs the full XLA + Mosaic pipeline through
+a compile-only TPU client (`jax.experimental.topologies`) — the third
+rehearsal of the `on-chip-measurement` guide, kept as tests so it
+guards every later change at no chip time.
+
+`SMOKE_CASES` are the kernels `chip_smoke.py` drives, at its real
+widths: BERT-base at batch 16 x sequence 512 (8192 rows, hidden 768,
+FFN 3072, vocab 30522, 12 heads of 64) and the GPT engine's unified
+step (bf16: 8 rows, 256-token prefill chunk, 1,024-token contexts).
+`OTHER_CASES` keep the remaining gated kernels compiling at
+transformer widths.  Nothing runs and nothing is timed here.
+
+The topology, the shardings and every shape are built inside
+module-scoped fixtures: only the pytest worker that is handed this file
+loads libtpu (see the guide on why nothing here may run at import).
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import paddle_tpu.ops.pallas_fused as pf
+import paddle_tpu.ops.pallas_grouped as pgm
+import paddle_tpu.ops.pallas_kernels as pk
+import paddle_tpu.ops.pallas_ragged as pr
+import paddle_tpu.ops.pallas_tiles as pt
+
+bf16, f32, i32, i8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no logs in /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Sharding on the first described chip, with the kernels lowering
+    through Mosaic (the CPU backend would pick interpret mode) and the
+    persistent cache off (a compile-only entry cannot be read back)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    mp = pytest.MonkeyPatch()
+    # each kernel module binds _interpret by name at import
+    for mod in (pk, pf, pr, pgm, pt):
+        mp.setattr(mod, "_interpret", lambda: False)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+    mp.undo()
+
+
+def _sum_grad(fn, argnums):
+    return jax.grad(lambda *a: fn(*a).astype(f32).sum(), argnums=argnums)
+
+
+# -- chip_smoke.py widths ------------------------------------------------
+B, S, H, D, HID, FFN, VOCAB = 16, 512, 12, 64, 768, 3072, 30522
+ROWS = B * S
+
+
+def _flash(direction):
+    qkv = [((B, S, H, D), bf16)] * 3
+    fwd = functools.partial(pk.flash_attention, causal=False)
+    return (fwd if direction == "fwd" else _sum_grad(fwd, (0, 1, 2))), qkv
+
+
+def _layer_norm():
+    return (_sum_grad(pk.fused_layer_norm, (0, 1, 2)),
+            [((ROWS, HID), bf16), ((HID,), bf16), ((HID,), bf16)])
+
+
+def _ln_residual():
+    return (_sum_grad(pf.fused_layer_norm_residual, (0, 1, 2, 3)),
+            [((ROWS, HID), bf16)] * 2 + [((HID,), bf16)] * 2)
+
+
+def _matmul_epilogue(k, n, act="gelu_tanh", rows=ROWS):
+    return (_sum_grad(lambda x, w, b: pf.fused_linear_act(
+                x, w, b, act), (0, 1, 2)),
+            [((rows, k), bf16), ((k, n), bf16), ((n,), bf16)])
+
+
+def _xent():
+    return (jax.grad(lambda x, lbl: pk.fused_softmax_cross_entropy(
+                x, lbl).sum()),
+            [((ROWS, VOCAB), f32), ((ROWS,), i32)])
+
+
+def _ragged(kv_dtype):
+    """The GPT engine's unified step in bf16 (engine.py: token_budget =
+    chunk_pad + (max_batch-1)*block_q, table_width = context/block)."""
+    from paddle_tpu.inference.serving.kv_cache import kv_block_size
+    from paddle_tpu.inference.serving.scheduler import (
+        max_batch_size, prefill_chunk_size)
+    bq = pr.ragged_q_block(bf16)
+    seqs, bs = max_batch_size(), kv_block_size()
+    chunk_pad = -(-prefill_chunk_size() // bq) * bq
+    tokens = chunk_pad + (seqs - 1) * bq
+    nqb, width = tokens // bq, 1024 // bs
+    # 0.3 of a 16 GB chip over 12 layers of 12x64 bf16 K+V blocks
+    nb = int(0.3 * 16e9) // (2 * 12 * H * bs * D * 2) + 1
+    pool = ((nb, H, bs, D), kv_dtype)
+    args = [((tokens, H, D), bf16), pool, pool, ((seqs, width), i32),
+            ((seqs,), i32), ((nqb,), i32), ((nqb,), i32), ((nqb,), i32)]
+    if kv_dtype == i8:
+        sc = ((nb, bs, pr.KV_SCALE_LANES), f32)
+        return (lambda q, kp, vp, bt, cl, sid, qs, qv, ks, vs:
+                pr.ragged_paged_attention(q, kp, vp, bt, cl, sid, qs, qv,
+                                          k_scales=ks, v_scales=vs),
+                args + [sc, sc])
+    return pr.ragged_paged_attention, args
+
+
+SMOKE_CASES = {
+    "flash_fwd_16x512x12x64": lambda: _flash("fwd"),
+    "flash_bwd_16x512x12x64": lambda: _flash("bwd"),
+    "layer_norm_8192x768": _layer_norm,
+    "ln_residual_8192x768": _ln_residual,
+    "matmul_epilogue_8192x768x3072": lambda: _matmul_epilogue(HID, FFN),
+    "matmul_epilogue_8192x3072x768": lambda: _matmul_epilogue(FFN, HID),
+    "softmax_xent_8192x30522": _xent,
+    "ragged_attention_gpt_bf16": lambda: _ragged(bf16),
+    "ragged_attention_gpt_int8kv": lambda: _ragged(i8),
+}
+
+
+# -- the gated kernels chip_smoke.py does not reach ----------------------
+def _rms_norm():
+    return (_sum_grad(pk.fused_rms_norm, (0, 1)),
+            [((4096, 4096), bf16), ((4096,), bf16)])
+
+
+def _matmul_epilogue_int8():
+    return (_sum_grad(lambda x, w, s, b: pf.fused_linear_act_int8(
+                x, w, s, b, "gelu_tanh"), (0, 2, 3)),
+            [((768, HID), bf16), ((HID, FFN), i8), ((FFN,), f32),
+             ((FFN,), bf16)])
+
+
+def _grouped_matmul():
+    experts, tokens = 8, 1024
+    _, nb, rows = pgm.grouped_layout(tokens, experts, bf16)
+    gid = jnp.zeros((nb,), i32)
+    return (_sum_grad(lambda x, w, b: pgm.grouped_linear_act(
+                x, w, b, block_group=gid, act="gelu_tanh"), (0, 1, 2)),
+            [((rows, HID), bf16), ((experts, HID, FFN), bf16),
+             ((experts, FFN), bf16)])
+
+
+def _lora_sgmv():
+    adapters, tokens = 64, 1024
+    _, nb, rows = pgm.grouped_layout(tokens, adapters, bf16)
+    r = pgm.lora_rank_pad(16, bf16)
+    aid = jnp.zeros((nb,), i32)
+    return (_sum_grad(lambda z, x, a, b: pgm.lora_segment_epilogue(
+                z, x, a, b, block_adapter=aid, act="gelu_tanh"),
+                (0, 1, 2, 3)),
+            [((rows, FFN), bf16), ((rows, HID), bf16),
+             ((adapters, HID, r), bf16), ((adapters, r, FFN), bf16)])
+
+
+def _paged_attention():
+    pool = ((128, 8, 16, 64), bf16)
+    return (pk.paged_attention,
+            [((4, 1, 8, 64), bf16), pool, pool, ((4, 8), i32),
+             ((4,), i32)])
+
+
+OTHER_CASES = {
+    # every epilogue the kernels offer ("gelu" needs an in-kernel erf:
+    # Mosaic lowers no erf primitive)
+    **{f"matmul_epilogue_act_{act}":
+       functools.partial(_matmul_epilogue, HID, FFN, act, 256)
+       for act in pf.ACTIVATIONS if act != "gelu_tanh"},
+    "rms_norm_4096x4096": _rms_norm,
+    "matmul_epilogue_int8_768x768x3072": _matmul_epilogue_int8,
+    "grouped_matmul_8x768x3072": _grouped_matmul,
+    "lora_sgmv_64x768x3072_r16": _lora_sgmv,
+    "paged_attention_bf16": _paged_attention,
+}
+
+CASES = {**SMOKE_CASES, **OTHER_CASES}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_kernel_compiles_for_v5e(one_chip, case):
+    fn, args = CASES[case]()
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in args]
+    compiled = jax.jit(fn).lower(*avals).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < 16e9
+
+
+@pytest.mark.parametrize("direction", ["fwd", "bwd"])
+def test_ring_flash_compiles_for_v5e_2x2(topo, one_chip, direction):
+    """Mosaic kernels inside shard_map over the four described chips
+    (the sep-axis long-context path)."""
+    from paddle_tpu.ops.ring_flash_attention import (
+        ring_flash_attention_local)
+    n_dev = len(topo.devices)
+    mesh = Mesh(np.array(topo.devices).reshape(n_dev), ("sep",))
+    spec = P(None, "sep", None, None)
+    fn = jax.shard_map(
+        functools.partial(ring_flash_attention_local, axis="sep",
+                          axis_size=n_dev, causal=True, scale=0.125),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
+    if direction == "bwd":
+        fn = _sum_grad(fn, (0, 1, 2))
+    qa = jax.ShapeDtypeStruct((2, 512, 4, 64), bf16,
+                              sharding=NamedSharding(mesh, spec))
+    text = jax.jit(fn).lower(qa, qa, qa).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "collective-permute" in text
