@@ -1,0 +1,494 @@
+"""Prove that the trainer and the serving engine start on the chip.
+
+    python chip_smoke.py            # one TPU chip: three phases
+    python chip_smoke.py --chips 4  # four chips: the sharded phase only
+
+One process, JAX imported once, no platform forced in code: the script
+refuses to start unless ``jax.devices()[0].platform == "tpu"``, so no
+kernel can run interpreted and no phase can quietly compute on the
+host.  Each phase drives a main path through the entry points a user
+calls, at the published width of a model the repo ships, with weights
+and data made from a seed, and checks the result by the repo's own
+means.  Phases print one JSON line each; any failed check raises.  The
+last line of stdout is ``{"ok": true, "device": {...}}``.
+
+Every phase is a function of the model configuration and sizes:
+``tests/test_chip_smoke.py`` runs the same code on the CPU at a
+2-layer width-128 model, and a rehearsal compiles it for a described
+chip before chip time is spent.  The command line takes no size.
+
+This is a start-up proof, not a benchmark: the seconds it prints are
+wall time of whole phases, compilation included.
+"""
+import argparse
+import base64
+import gc
+import json
+import math
+import re
+import sys
+import time
+from collections import Counter
+
+import jax
+import numpy as np
+
+import paddle_tpu as paddle
+from paddle_tpu import observability as obs
+from paddle_tpu import optimizer, static
+from paddle_tpu.core import dispatch as eager_dispatch
+from paddle_tpu.core import lazy
+from paddle_tpu.distributed.auto_parallel.sharding import (
+    BERT_RULES, MeshPlan, annotate_params, clear_mesh_plan, set_mesh_plan)
+from paddle_tpu.inference.serving import GenerationEngine
+from paddle_tpu.models import (BertConfig, BertForMaskedLM, GPTConfig,
+                               GPTForCausalLM)
+from paddle_tpu.ops.pallas_gate import probe_report
+
+SEED = 0
+# Two runs of one program that differ only in kernel vs XLA composite
+# round bf16 operands at different points: 2^-8 per rounding, a few
+# roundings deep.  Held to 3% of the reference's norm (1% for a loss,
+# which averages thousands of tokens).
+BF16_REL_L2 = 3e-2
+BF16_LOSS_RTOL = 1e-2
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_compiles = {"n": 0, "seconds": 0.0}
+
+
+def _on_compile(event, duration, **_):
+    if event == _BACKEND_COMPILE:
+        _compiles["n"] += 1
+        _compiles["seconds"] += duration
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_compile)
+
+
+def check(ok, what):
+    if not ok:
+        raise AssertionError(what)
+
+
+def mosaic_kernels(hlo_text):
+    """Names of the Pallas kernel bodies behind the ``tpu_custom_call``s
+    of a compiled program, with their counts.  The call carries its
+    Mosaic module as base64 MLIR bytecode, whose string table holds the
+    kernel function's name (every body in ``paddle_tpu/ops`` is
+    ``_<name>_kernel``)."""
+    found = Counter()
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        body = re.search(r'"body":"([A-Za-z0-9+/=]+)"', line)
+        names = sorted(set(re.findall(
+            rb"_[a-z0-9_]+_kernel\b",
+            base64.b64decode(body.group(1))))) if body else []
+        found[b"+".join(names).decode() or "unnamed"] += 1
+    return dict(found)
+
+
+def _rel_l2(got, ref):
+    got = np.asarray(got, np.float64)
+    ref = np.asarray(ref, np.float64)
+    return float(np.linalg.norm(got - ref) / np.linalg.norm(ref))
+
+
+def _pallas_flag(on):
+    paddle.set_flags({"FLAGS_use_pallas_kernels": bool(on)})
+
+
+def _probed(path_kernels):
+    """Gate outcome of every kernel the phase asked for: on a TPU each
+    was probe-compiled before its first use, and a failed probe raises
+    in the gate, so anything listed here is ``ok``."""
+    report = probe_report()
+    return {k: report[k].get("ok") for k in path_kernels
+            if report[k].get("probed")}
+
+
+# ---------------------------------------------------------------------
+# training, static graph
+# ---------------------------------------------------------------------
+def _bert_batch(cfg, batch, seq):
+    ids = np.random.default_rng(SEED).integers(
+        0, cfg.vocab_size, (batch, seq)).astype(np.int64)
+    return {"ids": ids, "labels": ids}
+
+
+def bert_program(cfg, batch, seq):
+    """The BERT MLM train program: bf16 O1 ``auto_cast``, AdamW, built
+    under ``program_guard`` (static mode and any mesh plan are the
+    caller's).  Returns ``(program, loss, model)``."""
+    paddle.seed(SEED)
+    main_prog, startup = static.Program(), static.Program()
+    with static.program_guard(main_prog, startup):
+        ids = static.data("ids", [batch, seq], "int64")
+        labels = static.data("labels", [batch, seq], "int64")
+        model = BertForMaskedLM(cfg)
+        annotate_params(model)
+        with paddle.amp.auto_cast(dtype="bfloat16", level="O1"):
+            loss, _ = model(ids, labels=labels)
+        opt = optimizer.AdamW(learning_rate=1e-4,
+                              parameters=model.parameters())
+        opt.minimize(loss)
+    return main_prog, loss, model
+
+
+def _bert_static_run(cfg, batch, seq, steps, fused_steps=0, plan=None):
+    """Run the train program: ``steps`` x ``Executor.run`` on one fixed
+    batch, then ``run_steps(fused_steps)``.  Returns the losses and
+    what the compiled step holds."""
+    paddle.enable_static()
+    set_mesh_plan(plan)
+    try:
+        main_prog, loss, model = bert_program(cfg, batch, seq)
+        exe = static.Executor()
+        feed = _bert_batch(cfg, batch, seq)
+        losses, compiles_after_first = [], 0
+        for i in range(steps):
+            before = _compiles["n"]
+            (lv,) = exe.run(main_prog, feed=feed, fetch_list=[loss])
+            losses.append(float(lv))
+            if i:
+                compiles_after_first += _compiles["n"] - before
+        out = {"losses": losses,
+               "compiles_after_first": compiles_after_first}
+        (entry,) = exe._cache.values()
+        text = entry["compiled"].as_text()
+        out["kernels"] = mosaic_kernels(text)
+        out["collectives"] = {
+            op: len(re.findall(rf"\b{op}(?:-start)?\(", text))
+            for op in ("all-reduce", "all-gather", "reduce-scatter",
+                       "collective-permute", "all-to-all")}
+        if fused_steps:
+            (lv,) = exe.run_steps(fused_steps, main_prog, feed=feed,
+                                  fetch_list=[loss])
+            out["fused_loss"] = float(lv)
+        per_dev = Counter()
+        for p in model.parameters():
+            for sh in p._value.addressable_shards:
+                per_dev[sh.device.id] += sh.data.nbytes
+        out["param_bytes_per_device"] = dict(sorted(per_dev.items()))
+        out["param_bytes"] = sum(
+            p._value.nbytes for p in model.parameters())
+        return out
+    finally:
+        clear_mesh_plan()
+        paddle.disable_static()
+
+
+BERT_PATH_KERNELS = ("layer_norm", "layer_norm_residual",
+                     "matmul_epilogue", "softmax_cross_entropy",
+                     "flash_attention")
+
+
+def train_static(cfg, batch, seq, steps=8, fused_steps=4):
+    on = _bert_static_run(cfg, batch, seq, steps, fused_steps)
+    losses, ln_v = on["losses"], math.log(cfg.vocab_size)
+    check(abs(losses[0] - ln_v) <= 0.05 * ln_v,
+          f"step-0 loss {losses[0]} not within 5% of ln(vocab) {ln_v}")
+    check(all(math.isfinite(x) for x in losses)
+          and losses[-1] < losses[0],
+          f"loss not finite and falling over {steps} steps: {losses}")
+    check(math.isfinite(on["fused_loss"]) and on["fused_loss"] < losses[0],
+          f"run_steps loss {on['fused_loss']} vs step 0 {losses[0]}")
+    check(on["compiles_after_first"] == 0,
+          f"{on['compiles_after_first']} compilations after step 0")
+    _pallas_flag(False)
+    try:
+        off = _bert_static_run(cfg, batch, seq, 1)
+    finally:
+        _pallas_flag(True)
+    check(not off["kernels"], f"flag off, kernels ran: {off['kernels']}")
+    check(abs(losses[0] - off["losses"][0])
+          <= BF16_LOSS_RTOL * abs(off["losses"][0]),
+          f"step-0 loss {losses[0]} vs composites {off['losses'][0]}")
+    return {"kernels": on["kernels"],
+            "checked": {
+                "loss_step0": losses[0], "ln_vocab": ln_v,
+                "loss_last": losses[-1], "loss_run_steps": on["fused_loss"],
+                "loss_step0_composites": off["losses"][0],
+                "loss_rtol": BF16_LOSS_RTOL,
+                "compiles_after_first_step": 0,
+                "probe_ok": _probed(BERT_PATH_KERNELS)}}
+
+
+# ---------------------------------------------------------------------
+# training, dygraph
+# ---------------------------------------------------------------------
+def _eager_kernels():
+    """Kernels in the per-op executables of the eager tier.  A jitted
+    op keeps no text, so each cached op that took a kernel is lowered
+    again from the shapes in its cache key."""
+    found = Counter()
+    for cache in (eager_dispatch._eager_fwd_cache,
+                  eager_dispatch._eager_vjp_cache):
+        for key, fn in list(cache.items()):
+            attrs, avals = dict(key[3]), key[4]
+            if attrs.get("use_pallas") != ("bool", True):
+                continue
+            args = [jax.ShapeDtypeStruct(s, np.dtype(d)) for s, d in avals]
+            found.update(mosaic_kernels(
+                fn.lower(*args).compile().as_text()))
+    return dict(found)
+
+
+def train_eager(cfg, batch, seq, steps=4):
+    """The same model and batch in dygraph, in whatever tier
+    ``import paddle_tpu`` selected (per-op dispatch unless
+    ``PADDLE_TPU_LAZY=1`` turned the auto-trace tier on)."""
+    paddle.seed(SEED)
+    model = BertForMaskedLM(cfg)
+    opt = optimizer.AdamW(learning_rate=1e-4,
+                          parameters=model.parameters())
+    feed = _bert_batch(cfg, batch, seq)
+    ids = paddle.to_tensor(feed["ids"])
+    labels = paddle.to_tensor(feed["labels"])
+    tier = "lazy" if lazy.lazy_enabled() else "per-op"
+    dispatches = obs.get_registry().histogram("eager.dispatch_us")
+    obs_was = obs.enabled()
+    obs.enable(True)          # eager.dispatch_us counts op dispatches
+    losses, launches = [], []
+    try:
+        for _ in range(steps):
+            n0, f0 = dispatches.count, lazy.stats["flushes"]
+            with paddle.amp.auto_cast(dtype="bfloat16", level="O1"):
+                loss, _ = model(ids, labels=labels)
+            loss.backward()
+            opt.step()
+            opt.clear_grad()
+            losses.append(float(loss))
+            launches.append(lazy.stats["flushes"] - f0 if tier == "lazy"
+                            else dispatches.count - n0)
+    finally:
+        obs.enable(obs_was)
+    check(all(math.isfinite(x) for x in losses)
+          and losses[-1] < losses[0],
+          f"loss not finite and falling over {steps} steps: {losses}")
+    if tier == "lazy":
+        kernels = Counter()
+        for exe in lazy._segment_cache.values():
+            kernels.update(mosaic_kernels(exe.as_text()))
+        kernels = dict(kernels)
+    else:
+        kernels = _eager_kernels()
+    return {"kernels": kernels,
+            "checked": {
+                "tier": tier, "losses": losses,
+                # per-op: forward + optimizer op dispatches (backward
+                # adds one launch per grad node); lazy: segment flushes
+                "launches_per_steady_step": launches[-1],
+                "probe_ok": _probed(BERT_PATH_KERNELS)}}
+
+
+# ---------------------------------------------------------------------
+# serving
+# ---------------------------------------------------------------------
+def _serve_run(model, prompts, new_tokens, arrivals):
+    """Drain ``prompts`` through a fresh ``GenerationEngine``
+    (``add_request`` + ``step``), request ``i`` arriving before step
+    ``arrivals[i]``.  Returns the generated tokens, the logits of the
+    first decode row, and step counts.
+
+    The engine samples on the device and hands back tokens only.  To
+    compare logits, the step's device dispatch is wrapped: on the first
+    step that carries a decode row, the same model call the step makes
+    is repeated on the inputs the engine just staged, returning logits
+    instead of samples (its K/V writes land on the slots the step
+    itself wrote, with the same values)."""
+    engine = GenerationEngine(model)
+    logits_fn = paddle.jit.to_static(
+        lambda ids: model(ids, cache=engine._view, use_cache=False))
+    seen = {"mixed": 0, "decode_logits": None, "tap_compiles": 0,
+            "shape": None}
+    dispatch_step = engine._checked_dispatch
+
+    def tapped(ids_t, args, chunk, decodes, appended):
+        tok = dispatch_step(ids_t, args, chunk, decodes, appended)
+        seen["mixed"] += bool(decodes and chunk is not None)
+        seen["shape"] = (chunk is not None, len(decodes))
+        if decodes and seen["decode_logits"] is None:
+            before = _compiles["n"]
+            with paddle.no_grad():
+                # decode rows are packed first: row 0 of the flat
+                # buffer is the first decoding request's new token
+                seen["decode_logits"] = np.asarray(
+                    logits_fn(ids_t).numpy()[0, 0], np.float32)
+            seen["tap_compiles"] = _compiles["n"] - before
+        return tok
+
+    engine._checked_dispatch = tapped
+    try:
+        # The step program has one shape and must compile once.  The
+        # host side of a step also runs small jnp programs (feeding the
+        # previous tokens in, draining) whose shapes follow the number
+        # of decode rows, so a step may compile only the first time its
+        # (prefill chunk?, decode rows) shape is seen.
+        ids, step, shapes, host_compiles, repeat_compiles = {}, 0, set(), 0, 0
+        while engine.has_unfinished() or len(ids) < len(prompts):
+            for i, at in enumerate(arrivals):
+                if at == step:
+                    ids[i] = engine.add_request(
+                        prompts[i], max_new_tokens=new_tokens)
+            before, seen["shape"], seen["tap_compiles"] = \
+                _compiles["n"], None, 0
+            engine.step()
+            n = _compiles["n"] - before - seen["tap_compiles"]
+            if step:
+                host_compiles += n
+            if seen["shape"] in shapes:
+                repeat_compiles += n
+            shapes.add(seen["shape"])
+            step += 1
+        (entry,) = engine._step_fn._cache.values()
+        return {
+            "tokens": [engine.result(ids[i])[len(prompts[i]):]
+                       for i in range(len(prompts))],
+            "decode_logits": seen["decode_logits"],
+            "mixed_steps": seen["mixed"], "steps": step,
+            "host_compiles_after_first_step": host_compiles,
+            "repeat_shape_compiles": repeat_compiles,
+            "token_budget": engine.token_budget,
+            "table_width": engine.cache.table_width,
+            "kv_blocks": engine.cache.num_blocks,
+            "kernels": mosaic_kernels(entry["compiled"].as_text())}
+    finally:
+        engine.close()
+
+
+SERVE_PATH_KERNELS = ("ragged_attention", "matmul_epilogue",
+                      "layer_norm", "layer_norm_residual")
+
+
+def serve(cfg, prompt_lens, new_tokens=32, arrivals=None):
+    """Greedy requests through the paged-KV engine, kernels on and then
+    off; later requests arrive while earlier ones decode, so prefill
+    chunks and decode rows share steps."""
+    arrivals = arrivals or [0, 0, 2, 4][:len(prompt_lens)]
+    paddle.seed(SEED)
+    model = GPTForCausalLM(cfg).bfloat16()
+    model.eval()
+    rng = np.random.default_rng(SEED)
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
+               for n in prompt_lens]
+    on = _serve_run(model, prompts, new_tokens, arrivals)
+    _pallas_flag(False)
+    try:
+        off = _serve_run(model, prompts, new_tokens, arrivals)
+    finally:
+        _pallas_flag(True)
+    for run in (on, off):
+        check([len(t) for t in run["tokens"]] == [new_tokens] * len(prompts),
+              f"not every request finished with {new_tokens} tokens: "
+              f"{[len(t) for t in run['tokens']]}")
+    check(on["mixed_steps"] > 0, "no step mixed a prefill chunk with "
+          "decode rows")
+    check(on["repeat_shape_compiles"] == 0,
+          f"{on['repeat_shape_compiles']} compilations in steps whose "
+          "shape had run before")
+    check(not off["kernels"], f"flag off, kernels ran: {off['kernels']}")
+    rel = _rel_l2(on["decode_logits"], off["decode_logits"])
+    check(np.isfinite(on["decode_logits"]).all() and rel <= BF16_REL_L2,
+          f"first-decode logits differ from composites by {rel} (rel L2)")
+    diverge = [next((j for j, (a, b) in enumerate(zip(x, y)) if a != b),
+                    None)
+               for x, y in zip(on["tokens"], off["tokens"])]
+    return {"kernels": on["kernels"],
+            "checked": {
+                "requests": len(prompts), "new_tokens_each": new_tokens,
+                "steps": on["steps"], "mixed_steps": on["mixed_steps"],
+                "token_budget": on["token_budget"],
+                "table_width": on["table_width"],
+                "kv_blocks": on["kv_blocks"],
+                "step_program_compiles": 1,
+                "compiles_in_repeated_step_shapes": 0,
+                "host_path_compiles_after_first_step":
+                    on["host_compiles_after_first_step"],
+                "decode_logits_rel_l2_vs_composites": rel,
+                "rel_l2_tolerance": BF16_REL_L2,
+                "first_diverging_greedy_position": diverge,
+                "probe_ok": _probed(SERVE_PATH_KERNELS)}}
+
+
+# ---------------------------------------------------------------------
+# training across four chips
+# ---------------------------------------------------------------------
+def train_static_mesh(cfg, batch, seq, mesh="dp=2,tp=2", steps=4):
+    """The ``train_static`` program under a ``MeshPlan`` with the
+    repo's BERT rules, against the same program, seed and batch on
+    device 0 alone, in this process."""
+    one = _bert_static_run(cfg, batch, seq, steps)
+    plan = MeshPlan(mesh, rules=BERT_RULES())
+    many = _bert_static_run(cfg, batch, seq, steps, plan=plan)
+    for i, (a, b) in enumerate(zip(many["losses"], one["losses"])):
+        check(math.isfinite(a) and abs(a - b) <= BF16_LOSS_RTOL * abs(b),
+              f"step {i}: loss {a} on {mesh} vs {b} on one device")
+    check(many["collectives"]["all-reduce"] > 0,
+          f"no all-reduce in the sharded step: {many['collectives']}")
+    per_dev = many["param_bytes_per_device"]
+    check(len(per_dev) == plan.size and min(per_dev.values()) > 0,
+          f"parameters live on devices {sorted(per_dev)} of {plan.size}")
+    check(max(per_dev.values()) < many["param_bytes"],
+          "no parameter is sharded: every device holds all "
+          f"{many['param_bytes']} bytes")
+    check(max(per_dev.values()) <= 1.01 * min(per_dev.values()),
+          f"uneven parameter bytes across devices: {per_dev}")
+    return {"kernels": many["kernels"],
+            "checked": {
+                "mesh": mesh, "losses": many["losses"],
+                "losses_one_device": one["losses"],
+                "loss_rtol": BF16_LOSS_RTOL,
+                "collectives": many["collectives"],
+                "param_bytes_per_device": per_dev,
+                "param_bytes_total": many["param_bytes"],
+                "kernels_one_device": one["kernels"]}}
+
+
+# ---------------------------------------------------------------------
+def run_phase(name, fn, *args, **kwargs):
+    """Run one phase; print its JSON line; free what it held."""
+    n0, s0, t0 = _compiles["n"], _compiles["seconds"], time.perf_counter()
+    result = fn(*args, **kwargs)
+    line = {"phase": name,
+            "seconds": round(time.perf_counter() - t0, 2),
+            "compile_seconds": round(_compiles["seconds"] - s0, 2),
+            "compiles": _compiles["n"] - n0, **result}
+    print(json.dumps(line), flush=True)
+    static.Executor.clear_shared_cache()
+    gc.collect()
+    jax.clear_caches()
+    return line
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    args = ap.parse_args(argv)
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        sys.exit(f"chip_smoke needs a TPU: JAX found {dev.platform!r} "
+                 f"({dev.device_kind})")
+    if jax.device_count() != args.chips:
+        sys.exit(f"--chips {args.chips} but JAX sees "
+                 f"{jax.device_count()} device(s)")
+    bert, batch, seq = BertConfig(), 16, 512
+    if args.chips == 4:
+        lines = [run_phase("train_static_mesh", train_static_mesh,
+                           bert, batch, seq)]
+    else:
+        lines = [
+            run_phase("train_static", train_static, bert, batch, seq),
+            run_phase("train_eager", train_eager, bert, batch, seq),
+            run_phase("serve", serve, GPTConfig(), [37, 200, 513, 900])]
+    for line in lines:
+        check(line["kernels"], f"phase {line['phase']}: no Pallas "
+              "tpu_custom_call in its compiled program")
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": jax.device_count()}}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

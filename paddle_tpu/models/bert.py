@@ -10,6 +10,7 @@ from dataclasses import dataclass
 import paddle_tpu as paddle
 from .. import nn
 from ..nn import functional as F
+from ..nn import initializer as I
 
 
 @dataclass
@@ -22,6 +23,11 @@ class BertConfig:
     max_position_embeddings: int = 512
     type_vocab_size: int = 2
     layer_norm_eps: float = 1e-12
+    # published init (bert-base-uncased config.json): every weight
+    # matrix ~ N(0, 0.02).  With the tied output head, the framework's
+    # N(0, 1) embedding default put the loss at init far above
+    # ln(vocab).
+    initializer_range: float = 0.02
     # Paddle-parity defaults (paddlenlp BertConfig): dropout on the
     # embeddings, each sublayer output, and the attention probs.  The
     # static Executor threads the generator state per step, so dropout
@@ -35,15 +41,22 @@ class BertConfig:
     use_scan_layers: bool = False
 
 
+def _weight_attr(cfg):
+    return paddle.ParamAttr(
+        initializer=I.Normal(0.0, cfg.initializer_range))
+
+
 class BertEmbeddings(nn.Layer):
     def __init__(self, cfg: BertConfig):
         super().__init__()
-        self.word_embeddings = nn.Embedding(cfg.vocab_size,
-                                            cfg.hidden_size)
+        self.word_embeddings = nn.Embedding(
+            cfg.vocab_size, cfg.hidden_size, weight_attr=_weight_attr(cfg))
         self.position_embeddings = nn.Embedding(
-            cfg.max_position_embeddings, cfg.hidden_size)
-        self.token_type_embeddings = nn.Embedding(cfg.type_vocab_size,
-                                                  cfg.hidden_size)
+            cfg.max_position_embeddings, cfg.hidden_size,
+            weight_attr=_weight_attr(cfg))
+        self.token_type_embeddings = nn.Embedding(
+            cfg.type_vocab_size, cfg.hidden_size,
+            weight_attr=_weight_attr(cfg))
         self.layer_norm = nn.LayerNorm(cfg.hidden_size,
                                        epsilon=cfg.layer_norm_eps)
         self.dropout = nn.Dropout(cfg.hidden_dropout_prob)
@@ -63,8 +76,10 @@ class BertSelfAttention(nn.Layer):
         self.num_heads = cfg.num_attention_heads
         self.head_dim = cfg.hidden_size // cfg.num_attention_heads
         self.attn_drop_p = cfg.attention_probs_dropout_prob
-        self.qkv = nn.Linear(cfg.hidden_size, 3 * cfg.hidden_size)
-        self.out = nn.Linear(cfg.hidden_size, cfg.hidden_size)
+        self.qkv = nn.Linear(cfg.hidden_size, 3 * cfg.hidden_size,
+                             weight_attr=_weight_attr(cfg))
+        self.out = nn.Linear(cfg.hidden_size, cfg.hidden_size,
+                             weight_attr=_weight_attr(cfg))
 
     def forward(self, x, attn_mask=None):
         b, s, h = x.shape
@@ -82,8 +97,10 @@ class BertLayer(nn.Layer):
         super().__init__()
         self.attention = BertSelfAttention(cfg)
         self.ln1 = nn.LayerNorm(cfg.hidden_size, epsilon=cfg.layer_norm_eps)
-        self.fc1 = nn.Linear(cfg.hidden_size, cfg.intermediate_size)
-        self.fc2 = nn.Linear(cfg.intermediate_size, cfg.hidden_size)
+        self.fc1 = nn.Linear(cfg.hidden_size, cfg.intermediate_size,
+                             weight_attr=_weight_attr(cfg))
+        self.fc2 = nn.Linear(cfg.intermediate_size, cfg.hidden_size,
+                             weight_attr=_weight_attr(cfg))
         self.ln2 = nn.LayerNorm(cfg.hidden_size, epsilon=cfg.layer_norm_eps)
         self.dropout = nn.Dropout(cfg.hidden_dropout_prob)
 
@@ -136,7 +153,8 @@ class TiedMLMHead(nn.Layer):
 
     def __init__(self, cfg: BertConfig):
         super().__init__()
-        self.transform = nn.Linear(cfg.hidden_size, cfg.hidden_size)
+        self.transform = nn.Linear(cfg.hidden_size, cfg.hidden_size,
+                                   weight_attr=_weight_attr(cfg))
         self.ln = nn.LayerNorm(cfg.hidden_size,
                                epsilon=cfg.layer_norm_eps)
 

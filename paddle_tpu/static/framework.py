@@ -214,12 +214,21 @@ def in_static_mode():
 
 
 def _static_dispatch_hook(name, impl, args, attrs):
-    """Installed on dispatch when static mode is on: append an OpDesc if any
-    input is a symbolic Variable, else execute eagerly (e.g. initializers)."""
+    """Installed on dispatch when static mode is on: append an OpDesc if
+    any input is a symbolic Variable or a trainable tensor, else execute
+    eagerly (e.g. initializers).
+
+    An op on a parameter alone belongs to the program too: run eagerly,
+    its result is a build-time constant that takes the parameter's
+    place, and the parameter is never trained.  That is what AMP O1's
+    ``weight.astype(bf16)`` and an embedding lookup of constant
+    positions did."""
     from ..core.dispatch import dispatch, _state
 
-    has_var = any(isinstance(a, Variable) for a in args)
-    if not has_var:
+    symbolic = any(isinstance(a, Variable)
+                   or (isinstance(a, Tensor) and not a.stop_gradient)
+                   for a in args)
+    if not symbolic:
         prev = _state.static_hook
         _state.static_hook = None
         try:

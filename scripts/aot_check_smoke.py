@@ -1,0 +1,186 @@
+"""Compile `chip_smoke.py`'s step programs, at its real sizes, for a
+described (not attached) TPU v5e:2x2 — the third rehearsal of the
+`on-chip-measurement` guide.  Nothing runs; no chip time is spent.
+
+    JAX_PLATFORMS=cpu python scripts/aot_check_smoke.py [name ...]
+
+Programs: ``static`` (BERT-base train step, one chip), ``loop`` (its
+``run_steps`` loop), ``mesh`` (the same step under dp=2,tp=2 over the
+four described chips), ``serve`` (the GPT engine's unified step).
+Each prints its compile seconds, the Mosaic kernels and collectives in
+the compiled text, and ``memory_analysis()`` against the chip's 16 GB.
+
+`tests/test_tpu_compile.py` keeps the kernels alone compiling in
+tier-1; this is the whole-program version, minutes long, run by hand
+before a chip call.  A compile that passes here is not a chip run.
+
+The program under test asks ``jax.default_backend()`` and would take
+its CPU branches, so this script steers it from outside: the kernel
+gate is opened and the kernels lower through Mosaic, not interpreted.
+"""
+import os
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+import chip_smoke as cs  # noqa: E402
+import paddle_tpu as paddle  # noqa: E402
+from paddle_tpu import static  # noqa: E402
+from paddle_tpu.distributed.auto_parallel.sharding import (  # noqa: E402
+    BERT_RULES, MeshPlan, clear_mesh_plan, set_mesh_plan)
+from paddle_tpu.models import BertConfig, GPTConfig  # noqa: E402
+from paddle_tpu.ops import (pallas_fused, pallas_gate,  # noqa: E402
+                            pallas_grouped, pallas_kernels,
+                            pallas_ragged, pallas_tiles)
+
+HBM_BYTES = 16e9
+BATCH, SEQ = 16, 512
+
+
+def open_gate():
+    """Lower the Mosaic path: every call site asks the gate by name at
+    trace time, and each kernel module binds ``_interpret`` by name."""
+    pallas_gate.pallas_enabled = lambda name: True
+    for mod in (pallas_kernels, pallas_fused, pallas_ragged,
+                pallas_grouped, pallas_tiles):
+        mod._interpret = lambda: False
+
+
+def report(name, lowered):
+    t0 = time.perf_counter()
+    compiled = lowered.compile()
+    secs = time.perf_counter() - t0
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"{name}: compiled in {secs:.1f}s; per-device bytes: "
+          f"args {mem.argument_size_in_bytes/1e9:.2f}G "
+          f"out {mem.output_size_in_bytes/1e9:.2f}G "
+          f"temp {mem.temp_size_in_bytes/1e9:.2f}G "
+          f"aliased {mem.alias_size_in_bytes/1e9:.2f}G "
+          f"= {total/1e9:.2f}G of {HBM_BYTES/1e9:.0f}G", flush=True)
+    print(f"{name}: kernels {cs.mosaic_kernels(text)}", flush=True)
+    print(f"{name}: collectives "
+          f"{ {op: text.count(op + '(') + text.count(op + '-start(') for op in ('all-reduce', 'all-gather', 'reduce-scatter', 'collective-permute', 'all-to-all')} }",
+          flush=True)
+    assert total < HBM_BYTES, f"{name} does not fit one chip"
+    assert "tpu_custom_call" in text, f"{name}: no Mosaic kernel"
+    return compiled
+
+
+def _on(sharding, avals):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=sharding), avals)
+
+
+def static_entry(plan=None):
+    paddle.enable_static()
+    set_mesh_plan(plan)
+    try:
+        prog, loss, _ = cs.bert_program(BertConfig(), BATCH, SEQ)
+        feed = cs._bert_batch(BertConfig(), BATCH, SEQ)
+        return static.Executor()._build(prog, feed, [loss])
+    finally:
+        clear_mesh_plan()
+        paddle.disable_static()
+
+
+def check_static(topo, loop=False):
+    entry = static_entry()
+    chip = SingleDeviceSharding(topo.devices[0])
+    avals = _on(chip, entry["avals"])
+    fn, name = entry["pure"], "static"
+    if loop:
+        pure, name = entry["pure"], "loop"
+
+        # the body of Executor.run_steps
+        def fn(feed, params, opts, rngs, lr, step0, n):
+            def body(i, carry):
+                params, opts, rngs = carry
+                _, params, opts, rngs = pure(feed, params, opts, rngs,
+                                             lr, step0 + i)
+                return params, opts, rngs
+            params, opts, rngs = jax.lax.fori_loop(
+                0, n - 1, body, (params, opts, rngs))
+            return pure(feed, params, opts, rngs, lr, step0 + n - 1)
+        avals += (jax.ShapeDtypeStruct((), jnp.int32, sharding=chip),)
+    report(name, jax.jit(fn, donate_argnums=(1, 2)).lower(*avals))
+
+
+def check_mesh(topo):
+    plan = MeshPlan("dp=2,tp=2", rules=BERT_RULES(),
+                    devices=topo.devices)
+    entry = static_entry(plan)
+    report("mesh", jax.jit(
+        entry["pure"], donate_argnums=(1, 2),
+        in_shardings=entry["in_shardings"],
+        out_shardings=entry["out_shardings"]).lower(*entry["avals"]))
+
+
+def check_serve(topo):
+    """Trace the engine's step once on the CPU at the real size (that
+    is how ``to_static`` discovers its state), then re-trace the same
+    pure function with the gate open and lower it for the chip."""
+    import paddle_tpu.ops.pallas_gate as gate
+    saved = gate.pallas_enabled
+    gate.pallas_enabled = lambda name: False
+    cfg = GPTConfig()
+    paddle.seed(cs.SEED)
+    from paddle_tpu.inference.serving import GenerationEngine
+    from paddle_tpu.models import GPTForCausalLM
+    model = GPTForCausalLM(cfg).bfloat16()
+    model.eval()
+    # 0.3 of the chip's memory, as the engine sizes its pool there
+    blocks = int(0.3 * HBM_BYTES) // (
+        2 * cfg.num_hidden_layers * cfg.hidden_size * 16 * 2)
+    engine = GenerationEngine(model, num_blocks=blocks)
+    engine.add_request(list(range(1, 301)), max_new_tokens=2)
+    engine.step()
+    (entry,) = engine._step_fn._cache.values()
+    print(f"serve: token_budget {engine.token_budget} table_width "
+          f"{engine.cache.table_width} kv_blocks {engine.cache.num_blocks}",
+          flush=True)
+    gate.pallas_enabled = saved
+    open_gate()
+    chip = SingleDeviceSharding(topo.devices[0])
+    report("serve", jax.jit(entry["pure_fn"]).lower(
+        *_on(chip, entry["avals"])))
+    engine.close()
+
+
+def main(names):
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    # a compile-only entry cannot be read back without a chip
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    names = names or ["serve", "static", "loop", "mesh"]
+    if "serve" in names:        # before the gate opens for good
+        check_serve(topo)
+    open_gate()
+    for name in names:
+        if name == "static":
+            check_static(topo)
+        elif name == "loop":
+            check_static(topo, loop=True)
+        elif name == "mesh":
+            check_mesh(topo)
+        elif name != "serve":
+            raise SystemExit(f"unknown program {name!r}")
+    print("AOT_SMOKE_OK", flush=True)
+
+
+if __name__ == "__main__":
+    main(sys.argv[1:])

@@ -146,11 +146,15 @@ def test_static_auto_cast_records_bf16_casts():
             jax.ShapeDtypeStruct((), np.float32),
             jax.ShapeDtypeStruct((), np.int32)).as_text()
         assert "bf16" in txt, "static auto_cast(bfloat16) produced no bf16"
-        # and the compiled step still trains
+        # and the compiled step still trains — the weight itself, not
+        # a bf16 copy cast once at build time in its place
+        w0 = np.asarray(lin.weight.numpy()).copy()
         (l0,) = exe.run(main, feed=fd, fetch_list=[loss])
         for _ in range(5):
             (l1,) = exe.run(main, feed=fd, fetch_list=[loss])
         assert float(l1) < float(l0)
+        assert any(p is lin.weight for p in entry["params"])
+        assert not np.array_equal(w0, np.asarray(lin.weight.numpy()))
     finally:
         paddle.disable_static()
 

@@ -1,0 +1,73 @@
+"""`chip_smoke.py`'s phases at a tiny size on the CPU (the first
+rehearsal of the `on-chip-measurement` guide), and its refusal to
+start without a chip.  Off the chip every kernel call site takes the
+XLA composite, so the kernel lists are empty and kernels-on equals
+kernels-off; what is tested here is the control flow and the checks."""
+import math
+import os
+import sys
+
+import jax
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+import chip_smoke  # noqa: E402
+from paddle_tpu.models import BertConfig, GPTConfig  # noqa: E402
+
+BERT = BertConfig(vocab_size=512, hidden_size=128, num_hidden_layers=2,
+                  num_attention_heads=2, intermediate_size=256,
+                  max_position_embeddings=64)
+GPT = GPTConfig(vocab_size=512, hidden_size=128, num_hidden_layers=2,
+                num_attention_heads=2, max_position_embeddings=128)
+
+
+def test_train_static_tiny():
+    out = chip_smoke.train_static(BERT, 4, 32, steps=4, fused_steps=2)
+    c = out["checked"]
+    assert abs(c["loss_step0"] - math.log(512)) < 0.05 * math.log(512)
+    assert c["loss_run_steps"] < c["loss_last"] < c["loss_step0"]
+    assert c["loss_step0_composites"] == c["loss_step0"]
+    assert out["kernels"] == {}
+
+
+def test_train_eager_tiny():
+    out = chip_smoke.train_eager(BERT, 4, 32, steps=3)
+    c = out["checked"]
+    assert c["tier"] == "per-op" and c["launches_per_steady_step"] > 10
+    assert c["losses"][-1] < c["losses"][0]
+    assert out["kernels"] == {}
+
+
+def test_serve_tiny():
+    out = chip_smoke.serve(GPT, [5, 40, 70, 90], new_tokens=6)
+    c = out["checked"]
+    assert c["requests"] == 4 and c["mixed_steps"] > 0
+    assert c["decode_logits_rel_l2_vs_composites"] == 0.0
+    assert c["first_diverging_greedy_position"] == [None] * 4
+    assert out["kernels"] == {}
+
+
+def test_train_static_mesh_tiny():
+    out = chip_smoke.train_static_mesh(BERT, 4, 32, steps=2)
+    c = out["checked"]
+    assert c["collectives"]["all-reduce"] > 0
+    assert sorted(c["param_bytes_per_device"]) == [
+        d.id for d in jax.devices()[:4]]
+
+
+def test_mosaic_kernel_names_from_hlo():
+    import base64
+    body = base64.b64encode(b"\x00func\x00_me_fwd_kernel\x00_act_f32")
+    line = ('custom-call(), custom_call_target="tpu_custom_call", '
+            'backend_config={"custom_call_config":{"body":"%s"}}'
+            % body.decode())
+    assert chip_smoke.mosaic_kernels(line + "\n" + line + "\nadd()") == {
+        "_me_fwd_kernel": 2}
+
+
+def test_refuses_to_start_without_a_chip(capsys):
+    with pytest.raises(SystemExit) as e:
+        chip_smoke.main([])
+    assert e.value.code not in (0, None)
+    assert capsys.readouterr().out == ""        # no phase, no result
