@@ -5,13 +5,16 @@ buffer bookkeeping) is native C++ (SURVEY.md §2.1/§2.2) [UNVERIFIED —
 empty reference mount].  Here the device runtime is PJRT/XLA; the
 host-side batch assembly is the piece that benefits from native code,
 implemented in collate.c and compiled on first use with the system cc
-(`cc -O3 -shared -fPIC`), cached under ~/.cache/paddle_tpu.  Everything
+(`cc -O3 -shared -fPIC`) into the git-ignored ``_native/build/`` of the
+checkout, under a name made from the source's content — so a library
+is only ever loaded for the source it was built from.  Everything
 degrades to numpy when no compiler is available — `available()` tells
 you which path is live.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -31,17 +34,21 @@ _store_tried = False
 
 
 def _compile_native(src_name, so_name, compilers, flags):
-    """Shared compile-with-mtime-cache-then-load step for every native
-    component (collate, tcp_store, shm_ring)."""
-    src = os.path.join(os.path.dirname(__file__), src_name)
-    cache = os.path.join(
-        os.path.expanduser(os.environ.get("PADDLE_TPU_CACHE",
-                                          "~/.cache/paddle_tpu")),
-        "native")
+    """Shared compile-once-then-load step for every native component
+    (collate, tcp_store, shm_ring).  The library's name carries a hash
+    of the source and the flags: a copy of the tree whose files all
+    have fresh mtimes can never pick up a library built from other
+    sources."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    src = os.path.join(here, src_name)
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(
+            f.read() + " ".join(flags).encode()).hexdigest()[:16]
+    cache = os.path.join(here, "build")
     os.makedirs(cache, exist_ok=True)
-    so = os.path.join(cache, so_name)
-    if not os.path.exists(so) or (os.path.getmtime(so)
-                                  < os.path.getmtime(src)):
+    stem, ext = os.path.splitext(so_name)
+    so = os.path.join(cache, f"{stem}-{digest}{ext}")
+    if not os.path.exists(so):
         tmp = f"{so}.{os.getpid()}.tmp"  # per-pid: N ranks may race here
         for cc in compilers:
             try:

@@ -22,10 +22,7 @@ __all__ = [
 
 @functools.lru_cache(maxsize=None)
 def _backend_devices(backend=None):
-    try:
-        return tuple(jax.devices(backend) if backend else jax.devices())
-    except RuntimeError:
-        return ()
+    return tuple(jax.devices(backend) if backend else jax.devices())
 
 
 def _accel_backend() -> str:
@@ -58,13 +55,19 @@ class Place:
         return self.device_id
 
     def jax_device(self):
-        backend = "cpu" if self.device_type == "cpu" else None
+        """The jax device behind this place.  An accelerator place
+        resolves on the default backend — on the CPU backend that is
+        the host's (virtual) devices, which is what lets scripts
+        written for a chip run in the CPU test suite.  An index past
+        the device count is an error, never another device."""
         devs = _backend_devices(None)
         if self.device_type == "cpu" and jax.default_backend() != "cpu":
             devs = _backend_devices("cpu")
-        if not devs:
-            raise RuntimeError(f"No devices for place {self}")
-        return devs[self.device_id % len(devs)]
+        if not 0 <= self.device_id < len(devs):
+            raise ValueError(
+                f"{self}: no such device, the {devs[0].platform} backend "
+                f"has {len(devs)}")
+        return devs[self.device_id]
 
     def __repr__(self):
         return f"Place({self.device_type}:{self.device_id})"

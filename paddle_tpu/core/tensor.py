@@ -293,8 +293,8 @@ class Tensor:
         return self.to_tpu(device_id)
 
     def to_tpu(self, device_id=None):
-        devs = jax.devices()
-        dev = devs[(device_id or 0) % len(devs)]
+        from .place import TPUPlace
+        dev = TPUPlace(device_id or 0).jax_device()
         return Tensor(jax.device_put(self._value, dev), _internal=True,
                       stop_gradient=self.stop_gradient)
 

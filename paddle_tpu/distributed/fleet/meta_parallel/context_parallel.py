@@ -59,7 +59,7 @@ def ring_attention_local(q, k, v, *, axis="sep", axis_size, causal=False,
         scale = 1.0 / (q.shape[-1] ** 0.5)
     if use_pallas is None:
         from ....ops.pallas_gate import pallas_enabled
-        use_pallas = pallas_enabled("flash_attention")
+        use_pallas = pallas_enabled("flash_attention", manual=True)
     if use_pallas:
         from ....ops.ring_flash_attention import ring_flash_attention_local
         return ring_flash_attention_local(

@@ -332,6 +332,10 @@ def _sweep_stale_shm_rings():
 
 def _mp_worker_init(dataset, init_fn, counter, ring_names=None):
     global _mp_dataset, _mp_ring, _mp_wid
+    # the chip belongs to the parent: whatever a worker does with jax
+    # (a dataset that builds Tensors) stays on the host
+    import jax
+    jax.config.update("jax_platforms", "cpu")
     _mp_dataset = dataset
     # explicit 0..num_workers-1 id from a shared counter; the process
     # _identity is a parent-global counter that drifts out of range on
@@ -440,13 +444,8 @@ class DataLoader:
         # forking after the XLA runtime started its thread pools can
         # deadlock children; spawn (dataset pickled once into workers)
         # is the safe method then
-        method = "fork"
-        try:
-            from jax._src import xla_bridge as _xb
-            if _xb.backends_are_initialized():
-                method = "spawn"
-        except Exception:
-            pass
+        from jax._src import xla_bridge as _xb
+        method = "spawn" if _xb.backends_are_initialized() else "fork"
         try:
             ctx = mp.get_context(method)
         except ValueError as e:  # pragma: no cover - non-POSIX
@@ -478,6 +477,11 @@ class DataLoader:
                             r.close()
                     rings = []
 
+        # spawned workers read JAX_PLATFORMS when they import jax,
+        # before the initializer runs (unpickling the dataset may
+        # already build arrays): start them pinned to the host
+        platforms = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = "cpu"
         try:
             counter = ctx.Value("i", 0)
             pool = ctx.Pool(
@@ -497,6 +501,11 @@ class DataLoader:
             for r in rings:
                 r.close()
             raise _MPUnavailable(str(e))
+        finally:
+            if platforms is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = platforms
         return pool, rings
 
     def _mp_teardown(self, pool=None, rings=None):

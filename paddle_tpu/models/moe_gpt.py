@@ -148,7 +148,8 @@ def _make_ep_impl(mesh, axis):
         gid = jnp.stack(gids)                   # [P, nb]
         rows_stack = jnp.stack(row_maps)        # [P, T]
 
-        gmm = pg.grouped_linear_act if pallas_enabled("grouped_matmul") \
+        gmm = pg.grouped_linear_act \
+            if pallas_enabled("grouped_matmul", manual=True) \
             else pg.grouped_linear_act_ref
 
         def island(xd_l, gid_l, w1_l, b1_l, w2_l, b2_l):

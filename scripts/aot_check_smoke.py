@@ -47,14 +47,17 @@ BATCH, SEQ = 16, 512
 
 def open_gate():
     """Lower the Mosaic path: every call site asks the gate by name at
-    trace time, and each kernel module binds ``_interpret`` by name."""
-    pallas_gate.pallas_enabled = lambda name: True
+    trace time, and each kernel module binds ``_interpret`` by name.
+    The gate's own rule stays: under a MeshPlan only ``shard_map``
+    bodies keep their kernels."""
+    pallas_gate.pallas_enabled = lambda name, manual=False: (
+        manual or not pallas_gate._auto_partitioned())
     for mod in (pallas_kernels, pallas_fused, pallas_ragged,
                 pallas_grouped, pallas_tiles):
         mod._interpret = lambda: False
 
 
-def report(name, lowered):
+def report(name, lowered, kernels=True):
     t0 = time.perf_counter()
     compiled = lowered.compile()
     secs = time.perf_counter() - t0
@@ -73,8 +76,9 @@ def report(name, lowered):
           f"{ {op: text.count(op + '(') + text.count(op + '-start(') for op in ('all-reduce', 'all-gather', 'reduce-scatter', 'collective-permute', 'all-to-all')} }",
           flush=True)
     assert total < HBM_BYTES, f"{name} does not fit one chip"
-    assert "tpu_custom_call" in text, f"{name}: no Mosaic kernel"
-    return compiled
+    assert ("tpu_custom_call" in text) == kernels, (
+        f"{name}: Mosaic kernels {'missing' if kernels else 'present'}")
+    return text
 
 
 def _on(sharding, avals):
@@ -121,10 +125,12 @@ def check_mesh(topo):
     plan = MeshPlan("dp=2,tp=2", rules=BERT_RULES(),
                     devices=topo.devices)
     entry = static_entry(plan)
-    report("mesh", jax.jit(
+    text = report("mesh", jax.jit(
         entry["pure"], donate_argnums=(1, 2),
         in_shardings=entry["in_shardings"],
-        out_shardings=entry["out_shardings"]).lower(*entry["avals"]))
+        out_shardings=entry["out_shardings"]).lower(*entry["avals"]),
+        kernels=False)   # XLA partitions this program: composites
+    assert "all-reduce" in text, "mesh: no all-reduce"
 
 
 def check_serve(topo):
@@ -133,7 +139,7 @@ def check_serve(topo):
     pure function with the gate open and lower it for the chip."""
     import paddle_tpu.ops.pallas_gate as gate
     saved = gate.pallas_enabled
-    gate.pallas_enabled = lambda name: False
+    gate.pallas_enabled = lambda name, manual=False: False
     cfg = GPTConfig()
     paddle.seed(cs.SEED)
     from paddle_tpu.inference.serving import GenerationEngine
@@ -153,7 +159,10 @@ def check_serve(topo):
     gate.pallas_enabled = saved
     open_gate()
     chip = SingleDeviceSharding(topo.devices[0])
-    report("serve", jax.jit(entry["pure_fn"]).lower(
+    # a fresh function object: jax keeps the first trace of `pure_fn`
+    # for these shapes and would hand back the CPU-branch jaxpr
+    report("serve", jax.jit(
+        lambda *a: entry["pure_fn"](*a), donate_argnums=(2,)).lower(
         *_on(chip, entry["avals"])))
     engine.close()
 

@@ -195,6 +195,29 @@ class TestProbeGate:
         from paddle_tpu.ops import pallas_gate as pg
         assert pg.pallas_enabled("flash_attention") is False
 
+    def test_broken_probe_raises_on_tpu(self, monkeypatch):
+        """On a TPU backend a failed probe stops the run with its
+        diagnosis — first call and every later one — and only the flag
+        selects the composites."""
+        from paddle_tpu.ops import pallas_gate as pg
+
+        def boom():
+            raise ImportError("cannot import name 'disable_x64'")
+
+        pg.reset_probe_cache()
+        monkeypatch.setattr(pg.jax, "default_backend", lambda: "tpu")
+        monkeypatch.setitem(pg._PROBES, "layer_norm", boom)
+        try:
+            for _ in range(2):
+                with pytest.raises(RuntimeError,
+                                   match="(?s)disable_x64.*TPU110"):
+                    pg.pallas_enabled("layer_norm")
+            paddle.set_flags({"FLAGS_use_pallas_kernels": False})
+            assert pg.pallas_enabled("layer_norm") is False
+        finally:
+            paddle.set_flags({"FLAGS_use_pallas_kernels": True})
+            pg.reset_probe_cache()
+
 
 # ---------------------------------------------------------------------
 # Recompile risk (TPU2xx)

@@ -207,3 +207,30 @@ def test_dataloader_shm_oversized_batch_falls_back():
     x = np.asarray(out[0][0]._value)
     assert x.shape == (8, 64, 1024)
     np.testing.assert_allclose(x[3, 0, 0], 3.0)
+
+
+class _PlatformDS(Dataset):
+    """Reports, from inside a worker, where jax would run."""
+
+    def __getitem__(self, i):
+        import os
+        import jax
+        plat = {"cpu": 0}.get(jax.config.jax_platforms, 1)
+        env = {"cpu": 0}.get(os.environ.get("JAX_PLATFORMS"), 1)
+        return np.array([plat, env, os.getpid()], np.int64)
+
+    def __len__(self):
+        return 8
+
+
+def test_dataloader_workers_never_take_the_chip(monkeypatch):
+    """A worker process is pinned to the host before it can initialise
+    a backend — the chip belongs to the parent — whatever the parent's
+    environment says; the parent's environment is left as it was."""
+    import os
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    rows = np.concatenate([np.asarray(b) for b in DataLoader(
+        _PlatformDS(), batch_size=4, num_workers=2)])
+    assert (rows[:, :2] == 0).all()
+    assert (rows[:, 2] != os.getpid()).all()      # ran in workers
+    assert "JAX_PLATFORMS" not in os.environ

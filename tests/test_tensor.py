@@ -126,3 +126,18 @@ def test_round3_method_fills():
     np.testing.assert_allclose(a.numpy(), 0.25)
     nz = paddle.to_tensor(np.array([0.0, 1.0, 0.0, 2.0])).nonzero()
     np.testing.assert_array_equal(np.asarray(nz.numpy()).ravel(), [1, 3])
+
+
+def test_device_index_past_the_count_is_an_error():
+    """`TPUPlace(n)` / `.tpu(n)` used to wrap (`n % count`): device 3
+    of 1 was device 0.  On the CPU backend the accelerator alias still
+    resolves to the host's devices, by index."""
+    import jax
+    n = len(jax.devices())
+    x = paddle.to_tensor([1.0, 2.0])
+    assert x.tpu(n - 1).value().devices() == {jax.devices()[n - 1]}
+    assert paddle.TPUPlace(0).jax_device() == jax.devices()[0]
+    with pytest.raises(ValueError, match="no such device"):
+        paddle.TPUPlace(n).jax_device()
+    with pytest.raises(ValueError, match="no such device"):
+        x.tpu(n)
