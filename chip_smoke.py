@@ -22,6 +22,7 @@ wall time of whole phases, compilation included.
 """
 import argparse
 import base64
+import contextlib
 import gc
 import json
 import math
@@ -95,17 +96,23 @@ def _rel_l2(got, ref):
     return float(np.linalg.norm(got - ref) / np.linalg.norm(ref))
 
 
-def _pallas_flag(on):
-    paddle.set_flags({"FLAGS_use_pallas_kernels": bool(on)})
+@contextlib.contextmanager
+def composites():
+    """``FLAGS_use_pallas_kernels`` off: every call site that is
+    recorded or dispatched inside takes the XLA composite."""
+    paddle.set_flags({"FLAGS_use_pallas_kernels": False})
+    try:
+        yield
+    finally:
+        paddle.set_flags({"FLAGS_use_pallas_kernels": True})
 
 
-def _probed(path_kernels):
-    """Gate outcome of every kernel the phase asked for: on a TPU each
-    was probe-compiled before its first use, and a failed probe raises
-    in the gate, so anything listed here is ``ok``."""
-    report = probe_report()
-    return {k: report[k].get("ok") for k in path_kernels
-            if report[k].get("probed")}
+def _probed():
+    """Gate outcome of every kernel asked for so far in this process:
+    on a TPU each was probe-compiled before its first use, and a failed
+    probe raises in the gate, so anything listed here is ``ok``."""
+    return {k: r["ok"] for k, r in probe_report().items()
+            if r.get("probed")}
 
 
 # ---------------------------------------------------------------------
@@ -179,11 +186,6 @@ def _bert_static_run(cfg, batch, seq, steps, fused_steps=0, plan=None):
         paddle.disable_static()
 
 
-BERT_PATH_KERNELS = ("layer_norm", "layer_norm_residual",
-                     "matmul_epilogue", "softmax_cross_entropy",
-                     "flash_attention")
-
-
 def train_static(cfg, batch, seq, steps=8, fused_steps=4):
     on = _bert_static_run(cfg, batch, seq, steps, fused_steps)
     losses, ln_v = on["losses"], math.log(cfg.vocab_size)
@@ -196,11 +198,8 @@ def train_static(cfg, batch, seq, steps=8, fused_steps=4):
           f"run_steps loss {on['fused_loss']} vs step 0 {losses[0]}")
     check(on["compiles_after_first"] == 0,
           f"{on['compiles_after_first']} compilations after step 0")
-    _pallas_flag(False)
-    try:
+    with composites():
         off = _bert_static_run(cfg, batch, seq, 1)
-    finally:
-        _pallas_flag(True)
     check(not off["kernels"], f"flag off, kernels ran: {off['kernels']}")
     check(abs(losses[0] - off["losses"][0])
           <= BF16_LOSS_RTOL * abs(off["losses"][0]),
@@ -212,7 +211,7 @@ def train_static(cfg, batch, seq, steps=8, fused_steps=4):
                 "loss_step0_composites": off["losses"][0],
                 "loss_rtol": BF16_LOSS_RTOL,
                 "compiles_after_first_step": 0,
-                "probe_ok": _probed(BERT_PATH_KERNELS)}}
+                "probe_ok": _probed()}}
 
 
 # ---------------------------------------------------------------------
@@ -236,7 +235,7 @@ def _eager_kernels():
 
 
 def train_eager(cfg, batch, seq, steps=4):
-    """The same model and batch in dygraph, in whatever tier
+    """The same model family and batch in dygraph, in whatever tier
     ``import paddle_tpu`` selected (per-op dispatch unless
     ``PADDLE_TPU_LAZY=1`` turned the auto-trace tier on)."""
     paddle.seed(SEED)
@@ -280,7 +279,7 @@ def train_eager(cfg, batch, seq, steps=4):
                 # per-op: forward + optimizer op dispatches (backward
                 # adds one launch per grad node); lazy: segment flushes
                 "launches_per_steady_step": launches[-1],
-                "probe_ok": _probed(BERT_PATH_KERNELS)}}
+                "probe_ok": _probed()}}
 
 
 # ---------------------------------------------------------------------
@@ -298,6 +297,7 @@ def _serve_run(model, prompts, new_tokens, arrivals):
     is repeated on the inputs the engine just staged, returning logits
     instead of samples (its K/V writes land on the slots the step
     itself wrote, with the same values)."""
+    gc.collect()          # an earlier engine's KV pool, held by cycles
     engine = GenerationEngine(model)
     logits_fn = paddle.jit.to_static(
         lambda ids: model(ids, cache=engine._view, use_cache=False))
@@ -358,10 +358,6 @@ def _serve_run(model, prompts, new_tokens, arrivals):
         engine.close()
 
 
-SERVE_PATH_KERNELS = ("ragged_attention", "matmul_epilogue",
-                      "layer_norm", "layer_norm_residual")
-
-
 def serve(cfg, prompt_lens, new_tokens=32, arrivals=None):
     """Greedy requests through the paged-KV engine, kernels on and then
     off; later requests arrive while earlier ones decode, so prefill
@@ -374,11 +370,8 @@ def serve(cfg, prompt_lens, new_tokens=32, arrivals=None):
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
                for n in prompt_lens]
     on = _serve_run(model, prompts, new_tokens, arrivals)
-    _pallas_flag(False)
-    try:
+    with composites():
         off = _serve_run(model, prompts, new_tokens, arrivals)
-    finally:
-        _pallas_flag(True)
     for run in (on, off):
         check([len(t) for t in run["tokens"]] == [new_tokens] * len(prompts),
               f"not every request finished with {new_tokens} tokens: "
@@ -409,7 +402,7 @@ def serve(cfg, prompt_lens, new_tokens=32, arrivals=None):
                 "decode_logits_rel_l2_vs_composites": rel,
                 "rel_l2_tolerance": BF16_REL_L2,
                 "first_diverging_greedy_position": diverge,
-                "probe_ok": _probed(SERVE_PATH_KERNELS)}}
+                "probe_ok": _probed()}}
 
 
 # ---------------------------------------------------------------------
@@ -484,13 +477,18 @@ def main(argv=None):
         sys.exit(f"--chips {args.chips} but JAX sees "
                  f"{jax.device_count()} device(s)")
     bert, batch, seq = BertConfig(), 16, 512
+    # The per-op tier keeps every op's residuals until backward: at 12
+    # layers this batch ran out of the chip's 16 GB at the loss op
+    # (PERF.md, PR 21).  Depth is what a smoke may cut; width and
+    # batch stay.
+    bert_eager = BertConfig(num_hidden_layers=6)
     if args.chips == 4:
         lines = [run_phase("train_static_mesh", train_static_mesh,
                            bert, batch, seq)]
     else:
         lines = [
             run_phase("train_static", train_static, bert, batch, seq),
-            run_phase("train_eager", train_eager, bert, batch, seq),
+            run_phase("train_eager", train_eager, bert_eager, batch, seq),
             run_phase("serve", serve, GPTConfig(), [37, 200, 513, 900])]
     for line in lines:
         check(line["kernels"], f"phase {line['phase']}: no Pallas "

@@ -38,7 +38,7 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-__all__ = ["pallas_enabled", "probe_all", "probe_kernel", "probe_report",
+__all__ = ["pallas_enabled", "probe_kernel", "probe_report",
            "reset_probe_cache", "ProbeResult"]
 
 _logger = logging.getLogger("paddle_tpu.pallas")
@@ -433,13 +433,6 @@ def probe_report(kernel: str = None) -> dict:
         res = _probe_results.get(name)
         out[name] = res.to_dict() if res else {"probed": False}
     return out[kernel] if kernel else out
-
-
-def probe_all() -> dict:
-    """Probe every kernel now; returns {name: ok}.  On a TPU the first
-    failing probe raises (see ``pallas_enabled``); off it, or with the
-    flag off, every entry is False."""
-    return {name: pallas_enabled(name) for name in _PROBES}
 
 
 def reset_probe_cache() -> None:

@@ -27,8 +27,8 @@ shape/layout/tracing policy — no kernel bodies:
 Every kernel module binds these by `from .pallas_tiles import ...`, so
 a helper is ONE object process-wide — the bit-identity guarantee of the
 refactor is that the kernels call the same code they inlined before.
-Tooling that monkeypatches `_interpret` (scripts/aot_check_kernels.py)
-must patch each kernel module's own global, as before.
+Tooling that monkeypatches `_interpret` (tests/test_tpu_compile.py,
+scripts/aot_check_smoke.py) must patch each kernel module's own global.
 """
 from __future__ import annotations
 
@@ -51,6 +51,7 @@ __all__ = [
 
 _NEG_INF = -1e30
 _STAT_LANES = 8  # trailing lane dim for per-row stat arrays
+
 
 def _x32(fn):
     """Trace the wrapped pallas_call builder under x32 semantics.

@@ -222,11 +222,14 @@ def _static_dispatch_hook(name, impl, args, attrs):
     its result is a build-time constant that takes the parameter's
     place, and the parameter is never trained.  That is what AMP O1's
     ``weight.astype(bf16)`` and an embedding lookup of constant
-    positions did."""
+    positions did.  A tensor that holds a tracer is not build time: it
+    is an op's own body running inside the compiled step (``recompute``
+    re-runs its function there), and executes as before."""
     from ..core.dispatch import dispatch, _state
 
     symbolic = any(isinstance(a, Variable)
-                   or (isinstance(a, Tensor) and not a.stop_gradient)
+                   or (isinstance(a, Tensor) and not a.stop_gradient
+                       and not isinstance(a._value, jax.core.Tracer))
                    for a in args)
     if not symbolic:
         prev = _state.static_hook

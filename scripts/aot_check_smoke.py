@@ -28,7 +28,6 @@ sys.path.insert(0, ROOT)
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
 import chip_smoke as cs  # noqa: E402
@@ -137,9 +136,7 @@ def check_serve(topo):
     """Trace the engine's step once on the CPU at the real size (that
     is how ``to_static`` discovers its state), then re-trace the same
     pure function with the gate open and lower it for the chip."""
-    import paddle_tpu.ops.pallas_gate as gate
-    saved = gate.pallas_enabled
-    gate.pallas_enabled = lambda name, manual=False: False
+    pallas_gate.pallas_enabled = lambda name, manual=False: False
     cfg = GPTConfig()
     paddle.seed(cs.SEED)
     from paddle_tpu.inference.serving import GenerationEngine
@@ -156,7 +153,6 @@ def check_serve(topo):
     print(f"serve: token_budget {engine.token_budget} table_width "
           f"{engine.cache.table_width} kv_blocks {engine.cache.num_blocks}",
           flush=True)
-    gate.pallas_enabled = saved
     open_gate()
     chip = SingleDeviceSharding(topo.devices[0])
     # a fresh function object: jax keeps the first trace of `pure_fn`

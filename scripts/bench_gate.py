@@ -23,7 +23,6 @@ yet, the fresh capture SEEDs it (exit 0).
 """
 import argparse
 import json
-import os
 import subprocess
 import sys
 from pathlib import Path

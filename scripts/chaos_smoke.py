@@ -467,8 +467,7 @@ def _cluster_kill_preempt(args, report):
     import subprocess
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS",
-                        "PADDLE_TPU_COMPILE_CACHE_DIR")}
+           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
     src = (_CLUSTER_DRILL_SUB
            .replace("%ROOT%", repr(root))
            .replace("%SEED%", str(args.seed)))
@@ -549,8 +548,7 @@ def _elastic_device_lost(args, report):
     import json
     import subprocess
     env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS",
-                        "PADDLE_TPU_COMPILE_CACHE_DIR")}
+           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
     p = subprocess.run(
         [sys.executable, "-c",
          _ELASTIC_DRILL_SUB.replace("%SEED%", str(args.seed))],
