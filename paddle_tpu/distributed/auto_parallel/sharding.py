@@ -653,12 +653,28 @@ _override_set = False
 _env_cache = {}           # env string -> MeshPlan
 
 
+def _rehome_rng_state():
+    """A step compiled under a plan hands the default generator's state
+    back replicated over the plan's mesh.  Left there, every later
+    eager random op — parameter init — is born committed to that mesh,
+    and a program compiled without the plan refuses such arguments.
+    When the plan changes, the state goes back to an uncommitted copy
+    (the next plan's first dispatch places it again)."""
+    import jax
+    from ...framework.random import default_generator
+    state = default_generator().state_tensor
+    value = state._value
+    if isinstance(value, jax.Array) and len(value.sharding.device_set) > 1:
+        state._value = jax.numpy.asarray(np.asarray(value))
+
+
 def set_mesh_plan(plan):
     """Set (or with ``None`` clear back to env-driven) the active plan."""
     global _override, _override_set
     with _lock:
         _override = plan
         _override_set = plan is not None
+    _rehome_rng_state()
 
 
 def clear_mesh_plan():
@@ -667,6 +683,7 @@ def clear_mesh_plan():
         _override = None
         _override_set = False
         _env_cache.clear()
+    _rehome_rng_state()
 
 
 def get_mesh_plan():

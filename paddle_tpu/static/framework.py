@@ -215,20 +215,24 @@ def in_static_mode():
 
 def _static_dispatch_hook(name, impl, args, attrs):
     """Installed on dispatch when static mode is on: append an OpDesc if
-    any input is a symbolic Variable or a trainable tensor, else execute
-    eagerly (e.g. initializers).
+    any input is a symbolic Variable — or, while a ``program_guard`` is
+    building a program, a trainable tensor — else execute eagerly (e.g.
+    initializers).
 
-    An op on a parameter alone belongs to the program too: run eagerly,
-    its result is a build-time constant that takes the parameter's
-    place, and the parameter is never trained.  That is what AMP O1's
-    ``weight.astype(bf16)`` and an embedding lookup of constant
-    positions did.  A tensor that holds a tracer is not build time: it
-    is an op's own body running inside the compiled step (``recompute``
-    re-runs its function there), and executes as before."""
+    Under the guard an op on a parameter alone belongs to the program
+    too: run eagerly, its result is a build-time constant that takes
+    the parameter's place, and the parameter is never trained.  That is
+    what AMP O1's ``weight.astype(bf16)`` and an embedding lookup of
+    constant positions did.  Outside a guard, arithmetic on parameters
+    stays host-side surgery (loading, rescaling).  A tensor that holds
+    a tracer is not build time either: it is an op's own body running
+    inside the compiled step (``recompute`` re-runs its function
+    there)."""
     from ..core.dispatch import dispatch, _state
 
     symbolic = any(isinstance(a, Variable)
-                   or (isinstance(a, Tensor) and not a.stop_gradient
+                   or (_guard_depth and isinstance(a, Tensor)
+                       and not a.stop_gradient
                        and not isinstance(a._value, jax.core.Tracer))
                    for a in args)
     if not symbolic:
@@ -295,16 +299,21 @@ def disable_static():
     get_dispatch_state().static_hook = None
 
 
+_guard_depth = 0  # program_guard nesting: a program is being built
+
+
 @contextlib.contextmanager
 def program_guard(main_program, startup_program=None):
-    global _main_program, _startup_program
+    global _main_program, _startup_program, _guard_depth
     prev_main, prev_startup = _main_program, _startup_program
     _main_program = main_program
     if startup_program is not None:
         _startup_program = startup_program
+    _guard_depth += 1
     try:
         yield
     finally:
+        _guard_depth -= 1
         _main_program = prev_main
         _startup_program = prev_startup
 
