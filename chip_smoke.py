@@ -1,6 +1,6 @@
 """Prove that the trainer and the serving engine start on the chip.
 
-    python chip_smoke.py            # one TPU chip: three phases
+    python chip_smoke.py            # one TPU chip: four phases
     python chip_smoke.py --chips 4  # four chips: the sharded phase only
 
 One process, JAX imported once, no platform forced in code: the script
@@ -234,10 +234,18 @@ def _eager_kernels():
     return dict(found)
 
 
-def train_eager(cfg, batch, seq, steps=4):
-    """The same model family and batch in dygraph, in whatever tier
+def train_eager(cfg, batch, seq, steps=4, lazy_tier=False):
+    """The same model family and batch in dygraph: in whatever tier
     ``import paddle_tpu`` selected (per-op dispatch unless
-    ``PADDLE_TPU_LAZY=1`` turned the auto-trace tier on)."""
+    ``PADDLE_TPU_LAZY=1``), or with ``lazy_tier`` under
+    ``paddle.incubate.lazy_eager()``, the auto-trace tier that flushes
+    a whole step as one or two compiled segments."""
+    with (paddle.incubate.lazy_eager() if lazy_tier
+          else contextlib.nullcontext()):
+        return _train_eager(cfg, batch, seq, steps)
+
+
+def _train_eager(cfg, batch, seq, steps):
     paddle.seed(SEED)
     model = BertForMaskedLM(cfg)
     opt = optimizer.AdamW(learning_rate=1e-4,
@@ -268,8 +276,8 @@ def train_eager(cfg, batch, seq, steps=4):
           f"loss not finite and falling over {steps} steps: {losses}")
     if tier == "lazy":
         kernels = Counter()
-        for exe in lazy._segment_cache.values():
-            kernels.update(mosaic_kernels(exe.as_text()))
+        for segment in lazy._segment_cache.values():
+            kernels.update(mosaic_kernels(segment.compiled.as_text()))
         kernels = dict(kernels)
     else:
         kernels = _eager_kernels()
@@ -489,6 +497,8 @@ def main(argv=None):
         lines = [
             run_phase("train_static", train_static, bert, batch, seq),
             run_phase("train_eager", train_eager, bert_eager, batch, seq),
+            run_phase("train_lazy", train_eager, bert, batch, seq,
+                      lazy_tier=True),
             run_phase("serve", serve, GPTConfig(), [37, 200, 513, 900])]
     for line in lines:
         check(line["kernels"], f"phase {line['phase']}: no Pallas "

@@ -31,10 +31,16 @@ def test_train_static_tiny():
     assert out["kernels"] == {}
 
 
-def test_train_eager_tiny():
-    out = chip_smoke.train_eager(BERT, 4, 32, steps=3)
+@pytest.mark.parametrize("lazy_tier", [False, True])
+def test_train_eager_tiny(lazy_tier):
+    out = chip_smoke.train_eager(BERT, 4, 32, steps=3,
+                                 lazy_tier=lazy_tier)
     c = out["checked"]
-    assert c["tier"] == "per-op" and c["launches_per_steady_step"] > 10
+    if lazy_tier:      # the whole step flushes as a segment or two
+        assert c["tier"] == "lazy" and c["launches_per_steady_step"] <= 2
+    else:
+        assert c["tier"] == "per-op"
+        assert c["launches_per_steady_step"] > 10
     assert c["losses"][-1] < c["losses"][0]
     assert out["kernels"] == {}
 
