@@ -21,7 +21,6 @@ This is a start-up proof, not a benchmark: the seconds it prints are
 wall time of whole phases, compilation included.
 """
 import argparse
-import base64
 import contextlib
 import gc
 import json
@@ -73,20 +72,17 @@ def check(ok, what):
 
 
 def mosaic_kernels(hlo_text):
-    """Names of the Pallas kernel bodies behind the ``tpu_custom_call``s
-    of a compiled program, with their counts.  The call carries its
-    Mosaic module as base64 MLIR bytecode, whose string table holds the
-    kernel function's name (every body in ``paddle_tpu/ops`` is
-    ``_<name>_kernel``)."""
+    """Names of the Pallas kernels behind the ``tpu_custom_call``s of a
+    compiled program, with their counts.  Every ``pallas_call`` of
+    ``paddle_tpu/ops`` is named ``<kernel>_<direction>``
+    (``pallas_tiles._kernel_span``), and XLA names the call's HLO
+    instruction after it: ``%layer_norm_fwd.3 = ... custom-call(...)``."""
     found = Counter()
     for line in hlo_text.splitlines():
         if 'custom_call_target="tpu_custom_call"' not in line:
             continue
-        body = re.search(r'"body":"([A-Za-z0-9+/=]+)"', line)
-        names = sorted(set(re.findall(
-            rb"_[a-z0-9_]+_kernel\b",
-            base64.b64decode(body.group(1))))) if body else []
-        found[b"+".join(names).decode() or "unnamed"] += 1
+        name = re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)* = ", line)
+        found[name.group(1) if name else "unnamed"] += 1
     return dict(found)
 
 

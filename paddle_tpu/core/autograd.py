@@ -20,6 +20,7 @@ from typing import Any, Callable, List, Optional, Sequence
 
 import jax.numpy as jnp
 
+from ..observability.timeline import span as _span
 from .lazy import concrete as _lazy_concrete, lazy_add
 
 __all__ = [
@@ -200,7 +201,14 @@ def backward(tensors, grad_tensors=None, retain_graph: bool = False,
 
     Paddle semantics: leaf tensors with stop_gradient=False receive ``.grad``
     (accumulated across calls); non-leaf grads are not retained.
+
+    The tape walk is one ``autograd:backward`` boundary span.
     """
+    with _span("autograd:backward", boundary=True):
+        _backward(tensors, grad_tensors, retain_graph, grad_sink)
+
+
+def _backward(tensors, grad_tensors, retain_graph, grad_sink):
     from .dispatch import settle_cpu_collectives
     from .tensor import Tensor
 

@@ -44,9 +44,12 @@ argument, so params/opt-state cost 1x HBM in the replayed step (gated
 on ``FLAGS_buffer_donation``; the donation mask is part of the
 fingerprint).
 
-Observability: each flush runs under a ``lazy:flush`` span
-(``cat="dispatch"``, attrs: nodes, cache_hit, fingerprint), segment
-compiles under ``compile:lazy:segment``; the metrics registry carries
+Observability: a flush is three boundary spans (timeline behind the
+gate, and always the profiler's clock): ``lazy:wire`` (wiring, masks,
+the key's hash), ``lazy:flush`` (``cat="dispatch"``, attrs: nodes,
+cache_hit, fingerprint — the replay's dispatch) and ``lazy:writeback``;
+segment compiles run under ``compile:lazy:segment``.  ``record_node``
+has none: per-node paths stay free.  The metrics registry carries
 ``eager.segment_cache_hit_rate`` / ``eager.segment_cache_evictions``,
 and ``phase_breakdown()`` exposes the lazy lane.  Fresh executables go
 through the memory-guard preflight before their first dispatch, so
@@ -610,7 +613,7 @@ def _compile_segment(seg_key, pending, wiring, masks, leaves,
         jit_kwargs["donate_argnums"] = (0,)
     donated = tuple(leaves[i] for i in d_idx)
     kept = tuple(leaves[i] for i in k_idx)
-    with _span("compile:lazy:segment", cat="compile",
+    with _span("compile:lazy:segment", cat="compile", boundary=True,
                nodes=len(pending), fingerprint=fp):
         compiled = jax.jit(replay, **jit_kwargs) \
             .lower(donated, kept).compile()
@@ -623,6 +626,61 @@ def _compile_segment(seg_key, pending, wiring, masks, leaves,
 
 
 def _flush_nodes(pending, donate=None):
+    with _span("lazy:wire", boundary=True, nodes=len(pending)):
+        seg_key, masks, leaves, leaf_sig, donate_idx, kept_idx, wiring \
+            = _wire(pending, donate)
+        seg = _segment_cache.get(seg_key)     # hashes the whole key
+    stats["flushes"] += 1
+    stats["nodes"] += len(pending)
+    hit = seg is not None
+    if hit:
+        stats["cache_hits"] += 1
+        _segment_cache.move_to_end(seg_key)
+    else:
+        stats["compiles"] += 1
+        fp = _intern_key(seg_key)
+        seg = _compile_segment(seg_key, pending, wiring, masks, leaves,
+                               donate_idx, kept_idx, fp)
+        _segment_cache[seg_key] = seg
+        if len(_segment_cache) > _SEGMENT_CACHE_MAX:
+            _segment_cache.popitem(last=False)
+            stats["evictions"] += 1
+            if _obs_enabled():
+                from ..observability.registry import get_registry
+                get_registry().counter(
+                    "eager.segment_cache_evictions").inc()
+        _note_segment_compile(fp, pending, leaf_sig)
+    stats["donated"] += len(donate_idx)
+    if _obs_enabled():
+        _metrics_flush_update(hit)
+    donated = tuple(leaves[i] for i in donate_idx)
+    kept = tuple(leaves[i] for i in kept_idx)
+    del leaves
+    from ..device import hbm_oom_context
+    with _span("lazy:flush", cat="dispatch", boundary=True,
+               nodes=len(pending), cache_hit=hit,
+               fingerprint=seg.fingerprint, donated=len(donated)):
+        with hbm_oom_context():  # dygraph OOMs surface here
+            out = seg.compiled(donated, kept)
+    with _span("lazy:writeback", boundary=True):
+        for n, vals, mask in zip(pending, out, masks):
+            it = iter(vals)
+            for lv, keep in zip(n.outs, mask):
+                if keep:
+                    lv._concrete = next(it)
+                    # break the lv -> node -> sibling-outs chain: a
+                    # rebound tensor must free (and donate) last step's
+                    # buffers, not keep the whole flushed segment alive
+                    # transitively
+                    lv.node = None
+            n.run = None
+            n.inputs = []
+            n.buffer = None
+
+
+def _wire(pending, donate):
+    """The flush's host work before the segment-cache lookup: operand
+    wiring, liveness masks, the donation mask and the segment key."""
     leaves = []
     leaf_pos: dict = {}          # id(array) -> leaf index
     wiring = []
@@ -671,51 +729,7 @@ def _flush_nodes(pending, donate=None):
         (jnp.shape(v), str(jnp.result_type(v)), _weak_of(v))
         for v in leaves)
     seg_key = (tuple(wiring), tuple(masks), leaf_sig, donate_idx)
-    stats["flushes"] += 1
-    stats["nodes"] += len(pending)
-    seg = _segment_cache.get(seg_key)
-    hit = seg is not None
-    if hit:
-        stats["cache_hits"] += 1
-        _segment_cache.move_to_end(seg_key)
-    else:
-        stats["compiles"] += 1
-        fp = _intern_key(seg_key)
-        seg = _compile_segment(seg_key, pending, wiring, masks, leaves,
-                               donate_idx, kept_idx, fp)
-        _segment_cache[seg_key] = seg
-        if len(_segment_cache) > _SEGMENT_CACHE_MAX:
-            _segment_cache.popitem(last=False)
-            stats["evictions"] += 1
-            if _obs_enabled():
-                from ..observability.registry import get_registry
-                get_registry().counter(
-                    "eager.segment_cache_evictions").inc()
-        _note_segment_compile(fp, pending, leaf_sig)
-    stats["donated"] += len(donate_idx)
-    if _obs_enabled():
-        _metrics_flush_update(hit)
-    donated = tuple(leaves[i] for i in donate_idx)
-    kept = tuple(leaves[i] for i in kept_idx)
-    del leaves
-    from ..device import hbm_oom_context
-    with _span("lazy:flush", cat="dispatch", nodes=len(pending),
-               cache_hit=hit, fingerprint=seg.fingerprint,
-               donated=len(donated)):
-        with hbm_oom_context():  # dygraph OOMs surface here
-            out = seg.compiled(donated, kept)
-    for n, vals, mask in zip(pending, out, masks):
-        it = iter(vals)
-        for lv, keep in zip(n.outs, mask):
-            if keep:
-                lv._concrete = next(it)
-                # break the lv -> node -> sibling-outs chain: a rebound
-                # tensor must free (and donate) last step's buffers, not
-                # keep the whole flushed segment alive transitively
-                lv.node = None
-        n.run = None
-        n.inputs = []
-        n.buffer = None
+    return seg_key, masks, leaves, leaf_sig, donate_idx, kept_idx, wiring
 
 
 # ---------------------------------------------------------------------

@@ -20,6 +20,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..observability.timeline import span as _span
 from . import autograd
 from . import lazy as _lazy
 from .dtypes import DType, convert_dtype, to_jax_dtype, to_paddle_dtype, default_dtype
@@ -212,7 +213,10 @@ class Tensor:
 
     # ---- host interop ----
     def numpy(self):
-        return np.asarray(self._value)
+        # every host read: forces a lazy value, waits for the device
+        # and copies (the ``sync:read`` boundary span)
+        with _span("sync:read", cat="d2h", boundary=True):
+            return np.asarray(self._value)
 
     def __array__(self, dtype=None):
         a = np.asarray(self._value)

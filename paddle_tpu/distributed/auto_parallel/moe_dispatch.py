@@ -190,8 +190,7 @@ def measured_ep_dispatch(xd, expert_fn, *, plan, axis="ep", mode=None):
 
     ``xd``: the global grouped token buffer, dim 0 sharded over
     ``axis`` (each of the P ring positions holds one chunk);
-    ``expert_fn(xd)`` is the expert compute over the whole buffer (its
-    Pallas path emits ``cat="kernel"`` spans).  Each of the P-1 ring
+    ``expert_fn(xd)`` is the expert compute over the whole buffer.  Each of the P-1 ring
     hops is a compiled one-hop ``ppermute`` over the plan's mesh
     running inside a ``cat="collective"`` span carrying the ``ep`` axis
     attr; overlapped mode dispatches the resident chunks' expert

@@ -454,30 +454,14 @@ class GenerationEngine:
         chunk + every decode row) plus a lazy drain.  Returns the
         requests that finished this step."""
         self._step_idx += 1
+        with obs.span("engine:step", boundary=True, step=self._step_idx):
+            return self._step()
+
+    def _step(self):
         self._step_finished = []
         self._step_tenant_tokens = {}
-        allow_admission = True
-        while True:
-            action, payload = self.scheduler.next_action(allow_admission)
-            if action == "admit":
-                try:
-                    self._admit(payload)
-                except Exception as e:
-                    # allocation failed (e.g. injected serve.alloc_fail):
-                    # allocate() raises before any pool mutation and
-                    # begin_prefill before any queue mutation, so the
-                    # request simply stays at the queue head and retries
-                    # NEXT step — admission closes for the rest of THIS
-                    # step so one fault cannot retry-loop it.
-                    allow_admission = False
-                    self._alloc_fails += 1
-                    obs.get_registry().counter(
-                        "serving.alloc_fails").inc()
-                    obs.instant("serving.alloc_fail", cat="fault",
-                                request=payload.id,
-                                error=f"{type(e).__name__}: {e}"[:200])
-                continue
-            break
+        with obs.span("engine:schedule", boundary=True):
+            action, payload = self._schedule()
         if action == "step":
             if self.proposer is not None:
                 self._run_spec_step(payload)
@@ -490,14 +474,41 @@ class GenerationEngine:
         lag = 0 if self.role == "prefill" \
             else max(0, pipeline_depth() - 1)
         self._drain(lag)
-        self._collect_finished()
-        reg = obs.get_registry()
-        reg.gauge("serving.queue_depth").set(self.scheduler.queue_depth)
-        for t, n in self._step_tenant_tokens.items():
-            reg.counter(f"serving.tenant.{t}.tokens").inc(n)
-            obs.instant("serving.tenant.tokens", cat="decode",
-                        step=self._step_idx, tenant=t, n=n)
+        with obs.span("engine:collect", boundary=True):
+            self._collect_finished()
+            reg = obs.get_registry()
+            reg.gauge("serving.queue_depth").set(
+                self.scheduler.queue_depth)
+            for t, n in self._step_tenant_tokens.items():
+                reg.counter(f"serving.tenant.{t}.tokens").inc(n)
+                obs.instant("serving.tenant.tokens", cat="decode",
+                            step=self._step_idx, tenant=t, n=n)
         return list(self._step_finished)
+
+    def _schedule(self):
+        """Ask the scheduler for this step's action, admitting queued
+        requests on the way."""
+        allow_admission = True
+        while True:
+            action, payload = self.scheduler.next_action(allow_admission)
+            if action != "admit":
+                return action, payload
+            try:
+                self._admit(payload)
+            except Exception as e:
+                # allocation failed (e.g. injected serve.alloc_fail):
+                # allocate() raises before any pool mutation and
+                # begin_prefill before any queue mutation, so the
+                # request simply stays at the queue head and retries
+                # NEXT step — admission closes for the rest of THIS
+                # step so one fault cannot retry-loop it.
+                allow_admission = False
+                self._alloc_fails += 1
+                obs.get_registry().counter(
+                    "serving.alloc_fails").inc()
+                obs.instant("serving.alloc_fail", cat="fault",
+                            request=payload.id,
+                            error=f"{type(e).__name__}: {e}"[:200])
 
     # -- disaggregated handoff (disagg.py) -------------------------------
     def handoff_ready(self):
@@ -809,6 +820,49 @@ class GenerationEngine:
     def _dispatch_step(self, chunk, decodes, appended):
         """Pack the chunk + decode rows into the flat ragged buffer and
         dispatch the ONE compiled step."""
+        with obs.span("engine:pack", boundary=True):
+            ids_t, args, rows_reqs = self._pack_step(chunk, decodes)
+        tok = self._spanned_dispatch(ids_t, args, chunk, decodes,
+                                     appended)
+        self._last_tokens = tok._value
+        for _, req in rows_reqs:
+            req.n_scheduled += 1
+        if rows_reqs:
+            self._pending.append((rows_reqs, tok._value))
+        if chunk is not None:
+            req = chunk.request
+            req.num_computed = chunk.start + chunk.length
+            # landed blocks join the prefix index for future sharers
+            self.cache.commit_prefix(
+                req.id, req.prompt[:req.num_computed])
+
+    def _spanned_dispatch(self, ids_t, args, chunk, decodes, appended,
+                          **decode_attrs):
+        """`_checked_dispatch` under its spans: ``engine:dispatch`` on
+        the profiler's clock, and the timeline's ``decode`` /
+        ``prefill:chunk`` (what the step carried) inside it."""
+        with contextlib.ExitStack() as stack:
+            stack.enter_context(obs.span(
+                "engine:dispatch", boundary=True, step=self._step_idx,
+                decode_rows=len(decodes),
+                chunk_tokens=chunk.length if chunk is not None else 0))
+            if decodes:
+                stack.enter_context(obs.span(
+                    "decode", cat="decode", step=self._step_idx,
+                    batch=len(decodes), **decode_attrs))
+            if chunk is not None:
+                stack.enter_context(obs.span(
+                    "prefill:chunk", cat="prefill", step=self._step_idx,
+                    request=chunk.request.id, start=chunk.start,
+                    tokens=chunk.length,
+                    **({"tenant": chunk.request.tenant}
+                       if chunk.request.tenant else {})))
+            return self._checked_dispatch(ids_t, args, chunk, decodes,
+                                          appended)
+
+    def _pack_step(self, chunk, decodes):
+        """The host's part of a step: the flat ragged buffer, the
+        kernel's segment descriptors and the control tensors."""
         T, S, BQ = self.token_budget, self.max_batch, self.block_q
         W = self.cache.table_width
         NQB = self.num_q_blocks
@@ -889,32 +943,7 @@ class GenerationEngine:
             ids_dev = ids_dev.at[0, flat_idx].set(
                 self._last_tokens[rows])
         ids_t = Tensor(ids_dev, _internal=True, stop_gradient=True)
-
-        with contextlib.ExitStack() as stack:
-            if decodes:
-                stack.enter_context(obs.span(
-                    "decode", cat="decode", step=self._step_idx,
-                    batch=len(decodes)))
-            if chunk is not None:
-                stack.enter_context(obs.span(
-                    "prefill:chunk", cat="prefill", step=self._step_idx,
-                    request=chunk.request.id, start=chunk.start,
-                    tokens=chunk.length,
-                    **({"tenant": chunk.request.tenant}
-                       if chunk.request.tenant else {})))
-            tok = self._checked_dispatch(ids_t, args, chunk, decodes,
-                                         appended)
-        self._last_tokens = tok._value
-        for _, req in rows_reqs:
-            req.n_scheduled += 1
-        if rows_reqs:
-            self._pending.append((rows_reqs, tok._value))
-        if chunk is not None:
-            req = chunk.request
-            req.num_computed = chunk.start + chunk.length
-            # landed blocks join the prefix index for future sharers
-            self.cache.commit_prefix(
-                req.id, req.prompt[:req.num_computed])
+        return ids_t, args, rows_reqs
 
     # -- the speculative step -------------------------------------------
     def _run_spec_step(self, plan):
@@ -967,6 +996,50 @@ class GenerationEngine:
         samples back and accept the longest draft prefix that matches
         the target's own tokens.  Rejected positions roll back with one
         refcount-aware ``truncate()`` — the preemption-rollback path."""
+        with obs.span("engine:pack", boundary=True):
+            ids_t, args, spec_rows, chunk_row = self._pack_spec_step(
+                chunk, decodes, drafts, appended)
+        tok = self._spanned_dispatch(ids_t, args, chunk, decodes,
+                                     appended, spec=True)
+        # the accept decision gates the next step's feed, so spec steps
+        # drain host-synchronously (no _pending window)
+        with obs.span("engine:drain", boundary=True, lag=0):
+            host = np.asarray(tok._value)
+
+        for req, base, d in spec_rows:
+            if req.done:
+                continue
+            row_tok = host[req.row]
+            # column j is the target's token following draft prefix
+            # d[:j]; accept while the draft agrees with the target
+            a = 0
+            while a < len(d) and int(row_tok[a]) == d[a]:
+                a += 1
+            self._tokens_drafted += len(d)
+            self._tokens_accepted += a
+            committed = 0
+            for j in range(a + 1):       # accepted prefix + bonus token
+                self._commit_token(req, int(row_tok[j]))
+                committed += 1
+                if req.done:
+                    break
+            # positions past the last committed token hold rejected
+            # drafts: roll the paged cache back to the verified length
+            self.cache.truncate(req.id, base + committed)
+            req.n_scheduled = len(req.generated)
+            self.proposer.commit(req.id, base + 1 + a)
+        if chunk_row is not None:
+            r, req = chunk_row
+            if not req.done:
+                self._commit_token(req, int(host[r, 0]))
+                req.n_scheduled = len(req.generated)
+        if chunk is not None:
+            req = chunk.request
+            req.num_computed = chunk.start + chunk.length
+            self.cache.commit_prefix(
+                req.id, req.prompt[:req.num_computed])
+
+    def _pack_spec_step(self, chunk, decodes, drafts, appended):
         T, S, BQ = self.token_budget, self.max_batch, self.block_q
         C = self.spec_cols
         W = self.cache.table_width
@@ -1046,57 +1119,7 @@ class GenerationEngine:
             self._lora.stage(lora_slots)
         args = self._control_tensors(
             [self._rows[r] for r in range(S)], S)
-        ids_t = self._tensor(ids)
-        with contextlib.ExitStack() as stack:
-            if decodes:
-                stack.enter_context(obs.span(
-                    "decode", cat="decode", step=self._step_idx,
-                    batch=len(decodes), spec=True))
-            if chunk is not None:
-                stack.enter_context(obs.span(
-                    "prefill:chunk", cat="prefill", step=self._step_idx,
-                    request=chunk.request.id, start=chunk.start,
-                    tokens=chunk.length,
-                    **({"tenant": chunk.request.tenant}
-                       if chunk.request.tenant else {})))
-            tok = self._checked_dispatch(ids_t, args, chunk, decodes,
-                                         appended)
-        # the accept decision gates the next step's feed, so spec steps
-        # drain host-synchronously (no _pending window)
-        host = np.asarray(tok._value)
-
-        for req, base, d in spec_rows:
-            if req.done:
-                continue
-            row_tok = host[req.row]
-            # column j is the target's token following draft prefix
-            # d[:j]; accept while the draft agrees with the target
-            a = 0
-            while a < len(d) and int(row_tok[a]) == d[a]:
-                a += 1
-            self._tokens_drafted += len(d)
-            self._tokens_accepted += a
-            committed = 0
-            for j in range(a + 1):       # accepted prefix + bonus token
-                self._commit_token(req, int(row_tok[j]))
-                committed += 1
-                if req.done:
-                    break
-            # positions past the last committed token hold rejected
-            # drafts: roll the paged cache back to the verified length
-            self.cache.truncate(req.id, base + committed)
-            req.n_scheduled = len(req.generated)
-            self.proposer.commit(req.id, base + 1 + a)
-        if chunk_row is not None:
-            r, req = chunk_row
-            if not req.done:
-                self._commit_token(req, int(host[r, 0]))
-                req.n_scheduled = len(req.generated)
-        if chunk is not None:
-            req = chunk.request
-            req.num_computed = chunk.start + chunk.length
-            self.cache.commit_prefix(
-                req.id, req.prompt[:req.num_computed])
+        return self._tensor(ids), args, spec_rows, chunk_row
 
     def _control_tensors(self, reqs, n):
         """Per-row sampling controls; None entries are masked rows."""
@@ -1158,13 +1181,16 @@ class GenerationEngine:
     def _drain(self, lag):
         """Read dispatched token arrays older than ``lag`` steps back to
         the host — the only device synchronization in the loop."""
-        while len(self._pending) > lag:
-            rows_reqs, device_toks = self._pending.pop(0)
-            host = np.asarray(device_toks)
-            for idx, req in rows_reqs:
-                if req.done:
-                    continue     # tokens raced past EOS: discard
-                self._commit_token(req, int(host[idx]))
+        if len(self._pending) <= lag:
+            return
+        with obs.span("engine:drain", boundary=True, lag=lag):
+            while len(self._pending) > lag:
+                rows_reqs, device_toks = self._pending.pop(0)
+                host = np.asarray(device_toks)
+                for idx, req in rows_reqs:
+                    if req.done:
+                        continue     # tokens raced past EOS: discard
+                    self._commit_token(req, int(host[idx]))
 
     def _collect_finished(self):
         for req in list(self.scheduler.running):

@@ -30,7 +30,7 @@ __all__ = ["CATEGORY_LANES", "chrome_trace", "collective_overlap_stats",
 CATEGORY_LANES = {"host": 0, "compile": 1, "dispatch": 2, "collective": 3,
                   "memory": 4, "fault": 5, "amp": 6, "h2d": 7, "d2h": 8,
                   "pipeline": 9, "prefill": 10, "decode": 11,
-                  "analysis": 12, "kernel": 13, "dma": 14,
+                  "analysis": 12, "dma": 14,
                   "recovery": 15, "ckpt": 16, "fabric": 17}
 _EXTRA_LANE_BASE = 18
 
@@ -177,11 +177,10 @@ def phase_breakdown(events=None):
     collective milliseconds, collective payload bytes, and the
     host↔device transfer bytes the dispatch spans recorded.
 
-    Pallas kernel dispatch spans (``cat="kernel"``, named
-    ``kernel:<name>.<direction>`` by ``pallas_kernels._kernel_span``)
-    aggregate into ``kernel_ms``/``kernel_count`` plus one
-    ``kernel_<name>_<direction>_ms``/``_count`` pair per kernel+direction
-    so the bench shows exactly where fused-kernel time went.
+    Kernel time is not here: a Pallas kernel inside a compiled step
+    runs on the device, where no host span can time it.  Kernels carry
+    their names into the device trace (``pallas_tiles._kernel_span``)
+    and ``benchmarks/span_reduce.py`` sums their device time by name.
 
     SPMD attribution: dispatch spans emitted under an active
     :class:`~..distributed.auto_parallel.sharding.MeshPlan` carry a
@@ -227,15 +226,12 @@ def phase_breakdown(events=None):
         events = get_timeline().events()
     out = {"compile_ms": 0.0, "dispatch_ms": 0.0, "collective_ms": 0.0,
            "h2d_ms": 0.0, "d2h_ms": 0.0, "pipeline_wait_ms": 0.0,
-           "prefill_ms": 0.0, "decode_ms": 0.0, "kernel_ms": 0.0,
-           "dma_ms": 0.0,
+           "prefill_ms": 0.0, "decode_ms": 0.0, "dma_ms": 0.0,
            "collective_bytes": 0, "h2d_bytes": 0, "d2h_bytes": 0,
            "dma_bytes": 0,
            "compile_count": 0, "dispatch_count": 0, "collective_count": 0,
            "h2d_count": 0, "d2h_count": 0, "pipeline_wait_count": 0,
-           "prefill_count": 0, "decode_count": 0, "kernel_count": 0,
-           "dma_count": 0}
-    kernel_keys = []
+           "prefill_count": 0, "decode_count": 0, "dma_count": 0}
     axis_keys = []
     shards = {}
     tenants = {}
@@ -306,20 +302,7 @@ def phase_breakdown(events=None):
             row = _tenant_row(str(tenant))
             row["prefill_ms"] += ms
             row["prefill_count"] += 1
-        if e.cat == "kernel":
-            out["kernel_ms"] += ms
-            out["kernel_count"] += 1
-            name = e.name
-            if name.startswith("kernel:"):
-                name = name[len("kernel:"):]
-            key = "kernel_" + name.replace(".", "_").replace(":", "_")
-            if key + "_ms" not in out:
-                out[key + "_ms"] = 0.0
-                out[key + "_count"] = 0
-                kernel_keys.append(key + "_ms")
-            out[key + "_ms"] += ms
-            out[key + "_count"] += 1
-        elif e.cat == "compile":
+        if e.cat == "compile":
             out["compile_ms"] += ms
             out["compile_count"] += 1
         elif e.cat == "dispatch":
@@ -401,7 +384,7 @@ def phase_breakdown(events=None):
             out[f"{e.cat}_count"] += 1
     for k in ("compile_ms", "dispatch_ms", "collective_ms", "h2d_ms",
               "d2h_ms", "pipeline_wait_ms", "prefill_ms", "decode_ms",
-              "kernel_ms", "dma_ms", *kernel_keys, *axis_keys):
+              "dma_ms", *axis_keys):
         out[k] = round(out[k], 3)
     # per-axis compute/communication overlap (tile-level overlap win):
     # overlap_ratio_<axis> = fraction of that axis's collective-span
@@ -452,7 +435,7 @@ def phase_breakdown(events=None):
     if any(fabric.values()):
         compute = sorted((e.ts, e.ts + e.dur) for e in events
                          if e.dur is not None
-                         and e.cat in ("dispatch", "kernel", "decode"))
+                         and e.cat in ("dispatch", "decode"))
         merged = []
         for a, b in compute:
             if merged and a <= merged[-1][1]:
@@ -480,7 +463,7 @@ def collective_overlap_stats(events=None):
     For every mesh axis that recorded ``cat="collective"`` spans (the
     eager collectives and the overlapped-matmul measured driver both
     stamp ``axis=...``), measures how much of the collective's span was
-    covered by compute spans (``cat="dispatch"``/``"kernel"``) — the
+    covered by compute spans (``cat="dispatch"``) — the
     tile-level overlap actually achieved, not asserted.  Ratio 1.0
     means every byte of collective time ran under compute; ~0 means the
     MXU sat idle for the transfer (the sequential fallback's
@@ -492,7 +475,7 @@ def collective_overlap_stats(events=None):
         events = get_timeline().events()
     compute = sorted((e.ts, e.ts + e.dur) for e in events
                      if e.dur is not None
-                     and e.cat in ("dispatch", "kernel"))
+                     and e.cat == "dispatch")
     merged = []
     for a, b in compute:
         if merged and a <= merged[-1][1]:

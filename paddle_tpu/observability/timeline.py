@@ -14,11 +14,21 @@ The runtime telemetry substrate every other layer reports into:
   * flow ids — ``flow_out`` on a compile span and ``flow_in`` on its
     dispatch spans link compile→dispatch arrows in the chrome trace.
 
+  * ``span(name, boundary=True)`` — a **boundary span**: the same
+    record, and a ``jax.profiler.TraceAnnotation`` of the same name and
+    attrs entered whether or not the gate is on.  The annotation puts
+    the span into the ``/host:CPU`` plane of a profiler trace, on the
+    device's clock; with no profiler session it is dropped in C++.
+    Boundary spans sit only where a step crosses a layer (Executor,
+    lazy flush, backward, optimizer, host read, engine step), never on
+    a per-op path.
+
 Gating: ``PADDLE_TPU_OBS`` (unset/0/off → disabled).  Disabled, every
-entry point is one module-global read returning a shared no-op object —
-instrumented hot loops pay effectively nothing.  ``enable()`` /
-``disable()`` override the env var at runtime (the Profiler enables for
-the duration of a session).
+timeline entry point is one module-global read returning a shared no-op
+object — instrumented hot loops pay effectively nothing; a boundary
+span then costs its annotation alone.  ``enable()`` / ``disable()``
+override the env var at runtime (the Profiler enables for the duration
+of a session).
 
 This module must import nothing from paddle_tpu: executor, collectives,
 fault plan, and memory guard all import it, and it must never create an
@@ -344,15 +354,92 @@ class _SpanCM:
         self.__exit__(None, None, None)
 
 
+_annotation_cls = None
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` with the span surface (``set``,
+    ``begin``, ``end``).  Built on first use: importing this module
+    imports neither jax nor anything of paddle_tpu."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        class _Annotation(TraceAnnotation):
+            __slots__ = ()
+
+            def set(self, key, value):
+                self.set_metadata(**{key: value})
+                return self
+
+            begin = TraceAnnotation.__enter__
+
+            def end(self):
+                self.__exit__(None, None, None)
+
+        _annotation_cls = _Annotation
+    return _annotation_cls
+
+
+class _BoundarySpan:
+    """A live span with both sinks: the profiler's annotation and the
+    timeline's record."""
+
+    __slots__ = ("_ann", "_span")
+
+    def __init__(self, ann, span):
+        self._ann = ann
+        self._span = span
+
+    def set(self, key, value):
+        self._ann.set(key, value)
+        self._span.set(key, value)
+        return self
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        return False
+
+    begin = __enter__
+
+    def end(self):
+        self.__exit__(None, None, None)
+
+
 def span(name, cat="host", step=None, flow_in=None, flow_out=None,
-         **attrs):
-    """Timed region.  Disabled → the shared no-op singleton."""
-    if not enabled():
+         boundary=False, **attrs):
+    """Timed region.  Disabled → the shared no-op singleton.
+
+    ``boundary=True`` also enters a ``jax.profiler.TraceAnnotation``
+    named ``name`` with the same attrs (and ``step``), gate on or off;
+    a string names the annotation where the timeline's name varies
+    (the Executor's dispatch span is named after its program, and is
+    ``exe:dispatch`` with a ``program`` attr on the profiler's clock).
+    Disabled, a boundary span is that annotation alone."""
+    on = enabled()
+    if not (on or boundary):
         return _NULL_SPAN
     amb = ambient_attrs()
     if amb:
         attrs = {**amb, **attrs}
-    return _SpanCM(name, cat, step, attrs or None, flow_in, flow_out)
+    if not boundary:
+        return _SpanCM(name, cat, step, attrs or None, flow_in, flow_out)
+    cls = _annotation_cls or _annotation()
+    ann_name = name if boundary is True else boundary
+    if step is None:
+        ann = cls(ann_name, **attrs)
+    else:
+        ann = cls(ann_name, step=step, **attrs)
+    if not on:
+        return ann
+    return _BoundarySpan(ann, _SpanCM(name, cat, step, attrs or None,
+                                      flow_in, flow_out))
 
 
 def instant(name, cat="host", step=None, **attrs):

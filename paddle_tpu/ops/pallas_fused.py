@@ -141,7 +141,7 @@ def _fused_ln_residual_2d_fwd(x, r, gamma, beta, eps):
     rows_pad = _round_up(rows, br)
     xp = _pad_dim(x, 0, rows_pad)
     rp = _pad_dim(r, 0, rows_pad)
-    with _kernel_span("layer_norm_residual", "fwd"):
+    with _kernel_span("layer_norm_residual", "fwd") as kernel_name:
         out, s, mu, rstd = pl.pallas_call(
             functools.partial(_ln_res_fwd_kernel, eps=eps),
             grid=(rows_pad // br,),
@@ -164,6 +164,7 @@ def _fused_ln_residual_2d_fwd(x, r, gamma, beta, eps):
                 jax.ShapeDtypeStruct((rows_pad, _STAT_LANES), jnp.float32),
             ],
             interpret=_interpret(),
+            name=kernel_name,
         )(xp, rp, gamma.reshape(1, n), beta.reshape(1, n))
     return out[:rows], (s[:rows], gamma, mu, rstd)
 
@@ -176,7 +177,7 @@ def _fused_ln_residual_2d_bwd(eps, res, do):
     rows_pad = _round_up(rows, br)
     sp = _pad_dim(s, 0, rows_pad)
     dop = _pad_dim(do, 0, rows_pad)
-    with _kernel_span("layer_norm_residual", "bwd"):
+    with _kernel_span("layer_norm_residual", "bwd") as kernel_name:
         dx, dg_acc, db_acc = pl.pallas_call(
             _ln_bwd_kernel,
             grid=(rows_pad // br,),
@@ -198,6 +199,7 @@ def _fused_ln_residual_2d_bwd(eps, res, do):
                 jax.ShapeDtypeStruct((8, n), jnp.float32),
             ],
             interpret=_interpret(),
+            name=kernel_name,
         )(sp, gamma.reshape(1, n), mu, rstd, dop)
     dgamma = dg_acc[0].astype(gamma.dtype)
     dbeta = db_acc[0].astype(gamma.dtype)
@@ -323,7 +325,7 @@ def _matmul_epilogue_2d_fwd(x, w, b, act):
     xp = _pad_dim(x, 0, m_pad)
     wp = _pad_dim(w, 1, n_pad)
     bp = _pad_dim(b.reshape(1, n), 1, n_pad)
-    with _kernel_span("matmul_epilogue", "fwd"):
+    with _kernel_span("matmul_epilogue", "fwd") as kernel_name:
         out, z = pl.pallas_call(
             functools.partial(_me_fwd_kernel, act=act),
             grid=(m_pad // bm, n_pad // bn),
@@ -341,6 +343,7 @@ def _matmul_epilogue_2d_fwd(x, w, b, act):
                 jax.ShapeDtypeStruct((m_pad, n_pad), x.dtype),
             ],
             interpret=_interpret(),
+            name=kernel_name,
         )(xp, wp, bp)
     return out[:m, :n], (x, w, b, z[:m, :n])
 
@@ -353,7 +356,7 @@ def _matmul_epilogue_2d_bwd(act, res, g):
     bm, bn, m_pad, n_pad = _me_blocks(m, k, n, x.dtype)
     zp = _pad_dim(_pad_dim(z, 0, m_pad), 1, n_pad)
     gp = _pad_dim(_pad_dim(g, 0, m_pad), 1, n_pad)
-    with _kernel_span("matmul_epilogue", "bwd"):
+    with _kernel_span("matmul_epilogue", "bwd") as kernel_name:
         dz_pad, db_acc = pl.pallas_call(
             functools.partial(_me_bwd_kernel, act=act),
             grid=(n_pad // bn, m_pad // bm),
@@ -370,6 +373,7 @@ def _matmul_epilogue_2d_bwd(act, res, g):
                 jax.ShapeDtypeStruct((8, n_pad), jnp.float32),
             ],
             interpret=_interpret(),
+            name=kernel_name,
         )(zp, gp)
     dz = dz_pad[:m, :n]
     # dx / dw are plain matmuls XLA already schedules optimally — the
@@ -452,7 +456,7 @@ def _matmul_epilogue_int8_2d_fwd(x, w_q, scale, b, act):
     # never sees a synthetic zero (their columns are sliced off anyway)
     sp = _pad_dim(scale.reshape(1, n).astype(jnp.float32), 1, n_pad, 1.0)
     bp = _pad_dim(b.reshape(1, n), 1, n_pad)
-    with _kernel_span("matmul_epilogue_int8", "fwd"):
+    with _kernel_span("matmul_epilogue_int8", "fwd") as kernel_name:
         out, z = pl.pallas_call(
             functools.partial(_me_int8_fwd_kernel, act=act),
             grid=(m_pad // bm, n_pad // bn),
@@ -471,6 +475,7 @@ def _matmul_epilogue_int8_2d_fwd(x, w_q, scale, b, act):
                 jax.ShapeDtypeStruct((m_pad, n_pad), x.dtype),
             ],
             interpret=_interpret(),
+            name=kernel_name,
         )(xp, wp, sp, bp)
     return out[:m, :n], (x, w_q, scale, b, z[:m, :n])
 
@@ -485,7 +490,7 @@ def _matmul_epilogue_int8_2d_bwd(act, res, g):
     gp = _pad_dim(_pad_dim(g, 0, m_pad), 1, n_pad)
     # dz/db epilogue backward is dtype-agnostic over z/g — reuse the
     # float kernel at the int8 plan's block sizes
-    with _kernel_span("matmul_epilogue_int8", "bwd"):
+    with _kernel_span("matmul_epilogue_int8", "bwd") as kernel_name:
         dz_pad, db_acc = pl.pallas_call(
             functools.partial(_me_bwd_kernel, act=act),
             grid=(n_pad // bn, m_pad // bm),
@@ -502,6 +507,7 @@ def _matmul_epilogue_int8_2d_bwd(act, res, g):
                 jax.ShapeDtypeStruct((8, n_pad), jnp.float32),
             ],
             interpret=_interpret(),
+            name=kernel_name,
         )(zp, gp)
     dz = dz_pad[:m, :n]
     s32 = scale.reshape(n).astype(jnp.float32)

@@ -103,7 +103,7 @@ def _gmm_call(xp, wp, bp, gid, act, bm, bn, direction):
     R, K = xp.shape
     n_pad = wp.shape[2]
     nb = R // bm
-    with _kernel_span("grouped_matmul", direction):
+    with _kernel_span("grouped_matmul", direction) as kernel_name:
         out, z = pl.pallas_call(
             functools.partial(_gmm_fwd_kernel, act=act),
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -126,6 +126,7 @@ def _gmm_call(xp, wp, bp, gid, act, bm, bn, direction):
                 jax.ShapeDtypeStruct((R, n_pad), xp.dtype),
             ],
             interpret=_interpret(),
+            name=kernel_name,
         )(gid, xp, wp, bp)
     return out, z
 
@@ -162,7 +163,7 @@ def _gmm_dw_call(xp, dzp, gid, num_experts, bm, bk, bn):
     R, k_pad = xp.shape
     n_pad = dzp.shape[1]
     nb = R // bm
-    with _kernel_span("grouped_matmul", "bwd_dw"):
+    with _kernel_span("grouped_matmul", "bwd_dw") as kernel_name:
         dw = pl.pallas_call(
             _gmm_dw_kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -181,6 +182,7 @@ def _gmm_dw_call(xp, dzp, gid, num_experts, bm, bk, bn):
             out_shape=jax.ShapeDtypeStruct(
                 (num_experts + 1, k_pad, n_pad), jnp.float32),
             interpret=_interpret(),
+            name=kernel_name,
         )(gid, xp, dzp)
     return dw
 
@@ -380,7 +382,7 @@ def _lora_call(zp, xp, ap, bp, aid, act, bm, bn, direction):
     n_pad = bp.shape[2]
     r = ap.shape[2]
     nb = R // bm
-    with _kernel_span("lora_sgmv", direction):
+    with _kernel_span("lora_sgmv", direction) as kernel_name:
         out, s = pl.pallas_call(
             functools.partial(_lora_fwd_kernel, act=act),
             grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -404,6 +406,7 @@ def _lora_call(zp, xp, ap, bp, aid, act, bm, bn, direction):
                 jax.ShapeDtypeStruct((R, n_pad), xp.dtype),
             ],
             interpret=_interpret(),
+            name=kernel_name,
         )(aid, zp, xp, ap, bp)
     return out, s
 

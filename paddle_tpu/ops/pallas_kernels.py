@@ -224,7 +224,7 @@ def _flash_fwd(q, k, v, scale, causal, sq_real, sk_real, block_q, block_k):
     sk_pad = k.shape[1]
     offset = sk_real - sq_real  # causal alignment for cross-length attn
     grid = (bh, sq_pad // block_q)
-    with _kernel_span("flash_attention", "fwd"):
+    with _kernel_span("flash_attention", "fwd") as kernel_name:
         out, lse = pl.pallas_call(
         functools.partial(_attn_fwd_kernel, scale=scale, causal=causal,
                           block_k=block_k, sk_real=sk_real, offset=offset),
@@ -243,6 +243,7 @@ def _flash_fwd(q, k, v, scale, causal, sq_real, sk_real, block_q, block_k):
             jax.ShapeDtypeStruct((bh, sq_pad, _STAT_LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name=kernel_name,
     )(q, k, v)
     return out, lse
 
@@ -263,7 +264,7 @@ def _flash_bwd(q, k, v, do, out, lse, scale, causal, sq_real, sk_real,
     row = jnp.arange(sq_pad)[None, :, None]
     empty = jnp.logical_or(row >= sq_real, lse <= _NEG_INF / 2)
     lse_safe = jnp.where(empty, jnp.float32(1e30), lse)
-    with _kernel_span("flash_attention", "bwd_dq"):
+    with _kernel_span("flash_attention", "bwd_dq") as kernel_name:
         dq = pl.pallas_call(
         functools.partial(_attn_bwd_dq_kernel, scale=scale, causal=causal,
                           block_k=block_k, sk_real=sk_real, offset=offset),
@@ -279,8 +280,9 @@ def _flash_bwd(q, k, v, do, out, lse, scale, causal, sq_real, sk_real,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq_pad, d), q.dtype),
         interpret=_interpret(),
+        name=kernel_name,
     )(q, k, v, do, lse_safe, delta)
-    with _kernel_span("flash_attention", "bwd_dkv"):
+    with _kernel_span("flash_attention", "bwd_dkv") as kernel_name:
         dk, dv = pl.pallas_call(
         functools.partial(_attn_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, sq_real=sq_real, offset=offset),
@@ -302,6 +304,7 @@ def _flash_bwd(q, k, v, do, out, lse, scale, causal, sq_real, sk_real,
             jax.ShapeDtypeStruct((bh, sk_pad, d), v.dtype),
         ],
         interpret=_interpret(),
+        name=kernel_name,
     )(q, k, v, do, lse_safe, delta)
     return dq, dk, dv
 
@@ -533,7 +536,7 @@ def _fused_layer_norm_2d_fwd(x, gamma, beta, eps):
     br = _ln_block_rows(rows, n)
     rows_pad = _round_up(rows, br)
     xp = _pad_dim(x, 0, rows_pad)
-    with _kernel_span("layer_norm", "fwd"):
+    with _kernel_span("layer_norm", "fwd") as kernel_name:
         out, mu, rstd = pl.pallas_call(
         functools.partial(_ln_fwd_kernel, eps=eps),
         grid=(rows_pad // br,),
@@ -553,6 +556,7 @@ def _fused_layer_norm_2d_fwd(x, gamma, beta, eps):
             jax.ShapeDtypeStruct((rows_pad, _STAT_LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name=kernel_name,
     )(xp, gamma.reshape(1, n), beta.reshape(1, n))
     return out[:rows], (x, gamma, mu, rstd)
 
@@ -566,7 +570,7 @@ def _fused_layer_norm_2d_bwd(eps, res, do):
     nb = rows_pad // br
     xp = _pad_dim(x, 0, rows_pad)
     dop = _pad_dim(do, 0, rows_pad)
-    with _kernel_span("layer_norm", "bwd"):
+    with _kernel_span("layer_norm", "bwd") as kernel_name:
         dx, dg_acc, db_acc = pl.pallas_call(
         _ln_bwd_kernel,
         grid=(nb,),
@@ -588,6 +592,7 @@ def _fused_layer_norm_2d_bwd(eps, res, do):
             jax.ShapeDtypeStruct((8, n), jnp.float32),
         ],
         interpret=_interpret(),
+        name=kernel_name,
     )(xp, gamma.reshape(1, n), mu, rstd, dop)
     dgamma = dg_acc[0].astype(gamma.dtype)
     dbeta = db_acc[0].astype(gamma.dtype)
@@ -648,7 +653,7 @@ def _fused_rms_norm_2d_fwd(x, gamma, eps):
     br = _ln_block_rows(rows, n)
     rows_pad = _round_up(rows, br)
     xp = _pad_dim(x, 0, rows_pad)
-    with _kernel_span("rms_norm", "fwd"):
+    with _kernel_span("rms_norm", "fwd") as kernel_name:
         out, rstd = pl.pallas_call(
         functools.partial(_rms_fwd_kernel, eps=eps),
         grid=(rows_pad // br,),
@@ -665,6 +670,7 @@ def _fused_rms_norm_2d_fwd(x, gamma, eps):
             jax.ShapeDtypeStruct((rows_pad, _STAT_LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name=kernel_name,
     )(xp, gamma.reshape(1, n))
     return out[:rows], (x, gamma, rstd)
 
@@ -678,7 +684,7 @@ def _fused_rms_norm_2d_bwd(eps, res, do):
     nb = rows_pad // br
     xp = _pad_dim(x, 0, rows_pad)
     dop = _pad_dim(do, 0, rows_pad)
-    with _kernel_span("rms_norm", "bwd"):
+    with _kernel_span("rms_norm", "bwd") as kernel_name:
         dx, dg_acc = pl.pallas_call(
         _rms_bwd_kernel,
         grid=(nb,),
@@ -697,6 +703,7 @@ def _fused_rms_norm_2d_bwd(eps, res, do):
             jax.ShapeDtypeStruct((8, n), jnp.float32),
         ],
         interpret=_interpret(),
+        name=kernel_name,
     )(xp, gamma.reshape(1, n), rstd, dop)
     dgamma = dg_acc[0].astype(gamma.dtype)
     return dx[:rows], dgamma
@@ -787,7 +794,7 @@ def _fused_xent_2d_fwd(logits, labels):
     xp = _pad_dim(_pad_dim(logits, 0, rows_pad), 1, v_pad,
                   value=_NEG_INF)
     lp = _lanes(_pad_dim(labels.astype(jnp.int32), 0, rows_pad, value=-1))
-    with _kernel_span("softmax_cross_entropy", "fwd"):
+    with _kernel_span("softmax_cross_entropy", "fwd") as kernel_name:
         loss, lse = pl.pallas_call(
         functools.partial(_xent_fwd_kernel, block_v=bv),
         grid=(rows_pad // br, v_pad // bv),
@@ -805,6 +812,7 @@ def _fused_xent_2d_fwd(logits, labels):
         ],
         scratch_shapes=stat_scratch(br, 3),
         interpret=_interpret(),
+        name=kernel_name,
     )(xp, lp)
     return loss[:rows, 0], (logits, labels, lse[:rows])
 
@@ -819,7 +827,7 @@ def _fused_xent_2d_bwd(res, g):
     lp = _lanes(_pad_dim(labels.astype(jnp.int32), 0, rows_pad, value=-1))
     lsep = _pad_dim(lse, 0, rows_pad)
     gp = _lanes(_pad_dim(g.astype(jnp.float32), 0, rows_pad))
-    with _kernel_span("softmax_cross_entropy", "bwd"):
+    with _kernel_span("softmax_cross_entropy", "bwd") as kernel_name:
         dx = pl.pallas_call(
         functools.partial(_xent_bwd_kernel, block_v=bv),
         grid=(rows_pad // br, v_pad // bv),
@@ -832,6 +840,7 @@ def _fused_xent_2d_bwd(res, g):
         out_specs=pl.BlockSpec((br, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows_pad, v_pad), logits.dtype),
         interpret=_interpret(),
+        name=kernel_name,
     )(xp, lp, lsep, gp)
     return dx[:rows, :v], None
 
@@ -946,7 +955,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     bt = block_tables.astype(jnp.int32)
     cl = context_lens.astype(jnp.int32)
 
-    with _kernel_span("paged_attention", "fwd"):
+    with _kernel_span("paged_attention", "fwd") as kernel_name:
         out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, block_size=block_size,
                           scale=float(scale), w_last=W - 1),
@@ -967,5 +976,6 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
         interpret=_interpret(),
+        name=kernel_name,
     )(bt, cl, qt, k_pool, v_pool)
     return jnp.swapaxes(out, 1, 2)                      # [B, 1, H, D]

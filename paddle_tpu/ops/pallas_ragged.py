@@ -275,7 +275,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
         kernel = _ragged_attn_int8_kernel
         name = "ragged_attention_int8"
 
-    with _kernel_span(name, "fwd"):
+    with _kernel_span(name, "fwd") as kernel_name:
         out = pl.pallas_call(
             functools.partial(
                 kernel, block_size=block_size,
@@ -291,6 +291,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
             ),
             out_shape=jax.ShapeDtypeStruct((H, T, D), q.dtype),
             interpret=_interpret(),
+            name=kernel_name,
         )(bt, cl, sid, qs, qv, *operands)
     return jnp.swapaxes(out, 0, 1)                      # [T, H, D]
 

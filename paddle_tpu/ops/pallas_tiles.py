@@ -8,7 +8,8 @@ shape/layout/tracing policy — no kernel bodies:
 
   * tracing + dispatch policy: `_x32` (trace pallas_call builders under
     x32 because the framework globally enables x64), `_interpret`
-    (interpret mode off-TPU), `_kernel_span` (timeline attribution);
+    (interpret mode off-TPU), `_kernel_span` (the kernel's name in the
+    device trace);
   * dtype-aware block picking: `_min_rows` (Mosaic sublane minima),
     `_sane_block` (clamp requested blocks to legality),
     `_ln_block_rows` / `_xent_blocks` (VMEM-budgeted row/vocab blocks),
@@ -32,6 +33,7 @@ scripts/aot_check_smoke.py) must patch each kernel module's own global.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -76,17 +78,21 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+@contextlib.contextmanager
 def _kernel_span(name: str, direction: str):
-    """Timeline span around one pallas_call build+dispatch.
+    """Names one pallas_call for the device trace.
 
-    Spans land in the ``kernel`` category so `phase_breakdown()` can
-    attribute step time per kernel and direction
-    (``kernel_<name>_<direction>_ms``).  The timeline returns a no-op
-    singleton when observability is disabled, so this costs one global
-    read on the hot path.
+    Every pallas_call is built inside ``with _kernel_span(name,
+    direction) as kernel_name`` and passes ``name=kernel_name``: the
+    ``jax.named_scope("<name>.<direction>")`` puts the kernel into the
+    op's ``op_name`` path, and ``<name>_<direction>`` names the Mosaic
+    kernel itself.  A profiler trace then tells ``ragged_attention`` from
+    ``layer_norm`` whatever the dispatcher's jitted functions are called
+    (``benchmarks/span_reduce.py`` holds the rule that reads the names).
+    Nothing is timed here: in a compiled step this runs at trace time.
     """
-    from ..observability.timeline import span
-    return span(f"kernel:{name}.{direction}", cat="kernel")
+    with jax.named_scope(f"{name}.{direction}"):
+        yield f"{name}_{direction}"
 
 
 def _round_up(x: int, m: int) -> int:

@@ -20,6 +20,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..core import autograd
+from ..observability.timeline import span as _span
 from ..core.dispatch import dispatch
 from ..core.tensor import Tensor, to_tensor
 from .lr import LRScheduler
@@ -139,6 +140,10 @@ class Optimizer:
     # ---- main API ----
     @autograd.no_grad()
     def step(self):
+        with _span("opt:step", boundary=True):
+            self._step()
+
+    def _step(self):
         self._sync_lr()
         params = self._params_with_grad()
         if not params:

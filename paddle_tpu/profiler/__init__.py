@@ -6,8 +6,9 @@ RECORD scheduler, RecordEvent spans, chrome-trace export;
 reference mount].
 
 Rebuilt as a thin shim over ``paddle_tpu.observability`` (ISSUE 3):
-``RecordEvent`` records spans into the shared bounded timeline (plus an
-XLA TraceAnnotation so the name shows in the device trace),
+``RecordEvent`` is a boundary span of the shared timeline (a timeline
+record plus an XLA TraceAnnotation, so the name shows in the device
+trace),
 ``Profiler.step()`` drives timeline step attribution,
 ``export_chrome_tracing`` serializes a real Perfetto-loadable trace
 through the shared exporter, and ``summary()`` renders the shared op
@@ -101,28 +102,21 @@ def export_chrome_tracing(dir_name, worker_name=None):
 
 
 class RecordEvent:
-    """Host-side span (shared timeline) + XLA TraceAnnotation (shows in
-    the device timeline).  Recording follows the observability gate —
-    a Profiler session enables it; so does ``PADDLE_TPU_OBS``."""
+    """A user's boundary span (``observability.span(name,
+    boundary=True)``): always an XLA TraceAnnotation, so the name shows
+    beside the device timeline of any profiler trace, and a span in the
+    shared timeline when the observability gate is on — a Profiler
+    session enables it; so does ``PADDLE_TPU_OBS``."""
 
     def __init__(self, name, event_type=None):
         self.name = name
-        self._ann = None
         self._span = None
 
     def begin(self):
-        self._span = _obs.span(self.name, cat="host")
+        self._span = _obs.span(self.name, cat="host", boundary=True)
         self._span.begin()
-        try:
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
-        except Exception:
-            self._ann = None
 
     def end(self):
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
         if self._span is not None:
             self._span.end()
             self._span = None

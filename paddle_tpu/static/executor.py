@@ -89,14 +89,17 @@ def _nbytes_of(vals):
     return n
 
 
-def _obs_step(step_val):
-    """Step id for span attribution (None when not collecting)."""
-    if not obs.enabled():
-        return None
-    try:
-        return int(step_val)
-    except Exception:
-        return None
+def _dispatch_span(entry, label, flow, step, feed_vals, **attrs):
+    """The span around the call that enqueues a program: ``label`` in
+    the timeline (``cat="dispatch"``, with the payload bytes, counted
+    only while it collects), ``exe:dispatch`` [program, step] on the
+    profiler's clock."""
+    if obs.enabled():
+        attrs["h2d_bytes"] = _nbytes_of(feed_vals)
+    if entry.get("plan") is not None:
+        attrs["mesh"] = entry["plan"].describe()
+    return obs.span(label, cat="dispatch", step=step, flow_in=flow,
+                    boundary="exe:dispatch", program=label, **attrs)
 
 
 def _feed_shape(v):
@@ -197,7 +200,14 @@ class Executor:
         """Shared by run()/run_steps(): resolve (program, feed, fetch),
         get-or-build the cache entry, convert feeds, snapshot param/opt
         state, and advance the host-side lr/step bookkeeping by
-        ``n_steps``.  Returns None (empty program) or the call tuple."""
+        ``n_steps``.  Returns None (empty program) or the call tuple.
+        One ``exe:prologue`` boundary span covers all of it."""
+        with obs.span("exe:prologue", boundary=True):
+            return self._prepare(program, feed, fetch_list, n_steps,
+                                 use_program_cache)
+
+    def _prepare(self, program, feed, fetch_list, n_steps,
+                 use_program_cache):
         if isinstance(program, CompiledProgram):
             program = program._program
         program = program or default_main_program()
@@ -253,15 +263,16 @@ class Executor:
         opt_state_vals = concrete_values(entry["opt_state"])
         rng_vals = concrete_values(entry["rng_states"])
         lr_val = jnp.asarray(0.0, jnp.float32)
-        step_val = jnp.asarray(0, jnp.int32)
+        # the step count stays a host scalar until the dispatch puts it
+        # on the device: the spans read their step id from it for free
+        step_val = np.int32(0)
         if program._optimize_info is not None:
             optimizer = program._optimize_info[0]
             optimizer._sync_lr()  # pick up LRScheduler.step() changes
             lr_val = jnp.asarray(optimizer._lr_tensor._value, jnp.float32)
-            step_val = jnp.asarray(
-                np.asarray(optimizer._step_count._value), jnp.int32)
-            optimizer._step_count._inplace_update(
-                np.asarray(optimizer._step_count._value) + n_steps)
+            count = np.asarray(optimizer._step_count._value)
+            step_val = np.int32(count)
+            optimizer._step_count._inplace_update(count + n_steps)
         return (entry, feed_vals, param_vals, opt_state_vals, rng_vals,
                 lr_val, step_val), fetch_list
 
@@ -276,7 +287,8 @@ class Executor:
             t._value = v  # eager rng continues from the program's state
         if return_numpy:
             # the synchronous sync point: d2h every fetch before return
-            return [np.asarray(o) for o in outs]
+            with obs.span("exe:fetch", cat="d2h", boundary=True):
+                return [np.asarray(o) for o in outs]
         # non-blocking path: the dispatch stays in flight.  Admit it to
         # the bounded pipeline window (depth 1 blocks it right here —
         # synchronous semantics) and hand back lazy handles whose FIRST
@@ -310,23 +322,21 @@ class Executor:
             return [None for _ in fetch_list]
         (entry, feed_vals, param_vals, opt_state_vals, rng_vals,
          lr_val, step_val) = call
+        step = int(step_val)
         if entry["compiled"] is None:
             entry["compiled"] = entry["compile_step"]()
-        sp = obs.span(entry["program_label"], cat="dispatch",
-                      step=_obs_step(step_val), flow_in=entry["flow"],
-                      h2d_bytes=_nbytes_of(feed_vals),
-                      **({"mesh": entry["plan"].describe()}
-                         if entry.get("plan") is not None else {}))
+        sp = _dispatch_span(entry, entry["program_label"], entry["flow"],
+                            step, feed_vals)
         from ..device import hbm_oom_context
         with sp, hbm_oom_context(program=entry["program_label"],
                                  estimate=entry["estimate"]):
             outs, new_params, new_opt_state, new_rng = entry["compiled"](
                 feed_vals, param_vals, opt_state_vals, rng_vals,
                 lr_val, step_val)
-            sp.set("d2h_bytes", _nbytes_of(outs))
+            if obs.enabled():
+                sp.set("d2h_bytes", _nbytes_of(outs))
         return self._epilogue(entry, outs, new_params, new_opt_state,
-                              new_rng, return_numpy,
-                              step=_obs_step(step_val),
+                              new_rng, return_numpy, step=step,
                               fetch_labels=self._fetch_labels(fetch_list))
 
     # ------------------------------------------------------------------
@@ -709,6 +719,7 @@ class Executor:
             return [None for _ in fetch_list]
         (entry, feed_vals, param_vals, opt_state_vals, rng_vals,
          lr_val, step_val) = call
+        step = int(step_val)
 
         loop_fn = entry.get("loop_fn")
         if loop_fn is None:
@@ -771,12 +782,9 @@ class Executor:
             self._last_estimate = entry["loop_estimate"]
             entry["loop_fn"] = loop_fn
 
-        sp = obs.span(entry["program_label"] + ".run_steps",
-                      cat="dispatch", step=_obs_step(step_val),
-                      flow_in=entry["loop_flow"], n_iters=n_iters,
-                      h2d_bytes=_nbytes_of(feed_vals),
-                      **({"mesh": entry["plan"].describe()}
-                         if entry.get("plan") is not None else {}))
+        sp = _dispatch_span(entry, entry["program_label"] + ".run_steps",
+                            entry["loop_flow"], step, feed_vals,
+                            n_iters=n_iters)
         from ..device import hbm_oom_context
         with sp, hbm_oom_context(program=entry["program_label"]
                                  + ".run_steps",
@@ -784,10 +792,10 @@ class Executor:
             outs, new_params, new_opt_state, new_rng = loop_fn(
                 feed_vals, param_vals, opt_state_vals, rng_vals,
                 lr_val, step_val, jnp.asarray(n_iters, jnp.int32))
-            sp.set("d2h_bytes", _nbytes_of(outs))
+            if obs.enabled():
+                sp.set("d2h_bytes", _nbytes_of(outs))
         return self._epilogue(entry, outs, new_params, new_opt_state,
-                              new_rng, return_numpy,
-                              step=_obs_step(step_val),
+                              new_rng, return_numpy, step=step,
                               fetch_labels=self._fetch_labels(fetch_list))
 
     def close(self):

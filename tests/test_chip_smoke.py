@@ -63,13 +63,14 @@ def test_train_static_mesh_tiny():
 
 
 def test_mosaic_kernel_names_from_hlo():
-    import base64
-    body = base64.b64encode(b"\x00func\x00_me_fwd_kernel\x00_act_f32")
-    line = ('custom-call(), custom_call_target="tpu_custom_call", '
-            'backend_config={"custom_call_config":{"body":"%s"}}'
-            % body.decode())
-    assert chip_smoke.mosaic_kernels(line + "\n" + line + "\nadd()") == {
-        "_me_fwd_kernel": 2}
+    line = ('  %%matmul_epilogue_fwd%s = bf16[8,8]{1,0} custom-call(bf16[8,8] '
+            '%%x), custom_call_target="tpu_custom_call", backend_config={}')
+    assert chip_smoke.mosaic_kernels(
+        line % ".3" + "\n" + line % "" + "\n  %add.1 = f32[] add()\n"
+        '  ROOT %layer_norm_bwd.12 = f32[8] custom-call(), '
+        'custom_call_target="tpu_custom_call"\n'
+        '  custom-call(), custom_call_target="tpu_custom_call"') == {
+        "matmul_epilogue_fwd": 2, "layer_norm_bwd": 1, "unnamed": 1}
 
 
 def test_refuses_to_start_without_a_chip(capsys):

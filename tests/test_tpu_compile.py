@@ -28,6 +28,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from benchmarks import span_reduce
 import paddle_tpu.ops.pallas_fused as pf
 import paddle_tpu.ops.pallas_grouped as pgm
 import paddle_tpu.ops.pallas_kernels as pk
@@ -207,6 +208,14 @@ def test_kernel_compiles_for_v5e(one_chip, case):
              for s, d in args]
     compiled = jax.jit(fn).lower(*avals).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    # every Mosaic call is named after its kernel in the compiled HLO,
+    # which is the name a device trace shows, and the benchmark's
+    # reader (span_reduce.kernel_of) finds that name by its own rule
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all(span_reduce.kernel_of(c) in span_reduce.KERNELS
+                         for c in calls), calls
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < 16e9
