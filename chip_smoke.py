@@ -1,7 +1,8 @@
 """Prove that the trainer and the serving engine start on the chip.
 
-    python chip_smoke.py            # one TPU chip: four phases
+    python chip_smoke.py            # one TPU chip: five phases
     python chip_smoke.py --chips 4  # four chips: the sharded phase only
+    python chip_smoke.py --phase attention_dropout   # that phase alone
 
 One process, JAX imported once, no platform forced in code: the script
 refuses to start unless ``jax.devices()[0].platform == "tpu"``, so no
@@ -22,6 +23,7 @@ wall time of whole phases, compilation included.
 """
 import argparse
 import contextlib
+import dataclasses
 import gc
 import json
 import math
@@ -208,6 +210,78 @@ def train_static(cfg, batch, seq, steps=8, fused_steps=4):
                 "loss_rtol": BF16_LOSS_RTOL,
                 "compiles_after_first_step": 0,
                 "probe_ok": _probed()}}
+
+
+# ---------------------------------------------------------------------
+# attention dropout inside the flash kernels
+# ---------------------------------------------------------------------
+def attention_dropout(cfg, batch, seq, depth=2):
+    """The flash kernels with the chip's bit source at the width of
+    ``cfg``: the keep rate of the written-out tile stream, forward and
+    backward against ``_sdpa_ref`` under that mask in bf16, and the
+    step-0 loss of a ``depth``-layer BERT whose compiled step holds
+    the three flash kernels."""
+    import jax.numpy as jnp
+    from paddle_tpu.nn.functional.flash_attention import _sdpa_ref
+    from paddle_tpu.ops import pallas_kernels as pk
+    heads, p = cfg.num_attention_heads, cfg.attention_probs_dropout_prob
+    hd = cfg.hidden_size // heads
+    scale, seed = hd ** -0.5, jnp.array([20260930], jnp.int32)
+    with jax.enable_x64(False):
+        keep = pk.flash_dropout_keep(seed, batch, seq, seq, heads, hd,
+                                     dropout_p=p, dtype=jnp.bfloat16)
+        other = pk.flash_dropout_keep(seed + 1, batch, seq, seq, heads, hd,
+                                      dropout_p=p, dtype=jnp.bfloat16)
+        rate = float(jnp.mean(keep, dtype=jnp.float32))
+        sigma = math.sqrt(p * (1 - p) / keep.size)
+        # two draws are independent: both keep with probability (1-p)^2
+        both = float(jnp.mean(keep & other, dtype=jnp.float32))
+        shifted = float(jnp.mean(keep[..., 1:, :] & keep[..., :-1, :],
+                                 dtype=jnp.float32))
+        q, k, v = (jax.random.normal(kk, (batch, seq, heads, hd),
+                                     jnp.bfloat16)
+                   for kk in jax.random.split(jax.random.PRNGKey(SEED), 3))
+
+        def loss_of(attend):
+            return lambda q, k, v: jnp.sum(
+                attend(q, k, v).astype(jnp.float32) ** 2)
+
+        flash = lambda q, k, v: pk.flash_attention(  # noqa: E731
+            q, k, v, scale=scale, dropout_p=p, seed=seed)
+        ref = lambda q, k, v: _sdpa_ref(  # noqa: E731
+            q, k, v, None, False, scale, p, keep=keep)
+        errs = {"fwd": _rel_l2(jax.jit(flash)(q, k, v),
+                               jax.jit(ref)(q, k, v))}
+        got = jax.jit(jax.grad(loss_of(flash), (0, 1, 2)))(q, k, v)
+        want = jax.jit(jax.grad(loss_of(ref), (0, 1, 2)))(q, k, v)
+        for name, a, b in zip(("dq", "dk", "dv"), got, want):
+            errs[name] = _rel_l2(a, b)
+    check(abs(rate - (1 - p)) < 4 * sigma,
+          f"keep rate {rate} not within 4 sigma ({sigma}) of {1 - p}")
+    pair = (1 - p) ** 2
+    pair_sigma = math.sqrt(pair * (1 - pair) / keep.size)
+    for name, got_share in (("another seed", both), ("next row", shifted)):
+        check(abs(got_share - pair) < 5 * pair_sigma,
+              f"keep and keep of {name} are both set on {got_share}, "
+              f"independent draws on {pair} (sigma {pair_sigma})")
+    for name, err in errs.items():
+        check(err <= BF16_REL_L2, f"flash dropout {name} rel L2 {err}")
+    on = _bert_static_run(
+        dataclasses.replace(cfg, num_hidden_layers=depth), batch, seq, 1)
+    ln_v = math.log(cfg.vocab_size)
+    check(abs(on["losses"][0] - ln_v) <= 0.05 * ln_v,
+          f"step-0 loss {on['losses'][0]} not within 5% of ln(vocab)")
+    return {"kernels": on["kernels"],
+            "checked": {"dropout_p": p, "keep_rate": rate,
+                        "keep_rate_sigma": sigma,
+                        "keep_and_other_seed": both,
+                        "keep_and_next_row": shifted,
+                        "independent": pair, "pair_sigma": pair_sigma,
+                        "rel_l2_vs_masked_composite": errs,
+                        "rel_l2_limit": BF16_REL_L2,
+                        "bert_layers": depth,
+                        "loss_step0": on["losses"][0], "ln_vocab": ln_v,
+                        "probe_ok": _probed()}}
 
 
 # ---------------------------------------------------------------------
@@ -472,6 +546,8 @@ def run_phase(name, fn, *args, **kwargs):
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--phase", action="append", metavar="NAME",
+                    help="run only this phase (repeatable); default all")
     args = ap.parse_args(argv)
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -487,15 +563,22 @@ def main(argv=None):
     # batch stay.
     bert_eager = BertConfig(num_hidden_layers=6)
     if args.chips == 4:
-        lines = [run_phase("train_static_mesh", train_static_mesh,
-                           bert, batch, seq)]
+        phases = [("train_static_mesh", train_static_mesh,
+                   (bert, batch, seq), {})]
     else:
-        lines = [
-            run_phase("train_static", train_static, bert, batch, seq),
-            run_phase("train_eager", train_eager, bert_eager, batch, seq),
-            run_phase("train_lazy", train_eager, bert, batch, seq,
-                      lazy_tier=True),
-            run_phase("serve", serve, GPTConfig(), [37, 200, 513, 900])]
+        phases = [
+            ("attention_dropout", attention_dropout, (bert, batch, seq), {}),
+            ("train_static", train_static, (bert, batch, seq), {}),
+            ("train_eager", train_eager, (bert_eager, batch, seq), {}),
+            ("train_lazy", train_eager, (bert, batch, seq),
+             {"lazy_tier": True}),
+            ("serve", serve, (GPTConfig(), [37, 200, 513, 900]), {})]
+    unknown = set(args.phase or ()) - {name for name, *_ in phases}
+    if unknown:
+        sys.exit(f"no such phase with --chips {args.chips}: "
+                 f"{sorted(unknown)}")
+    lines = [run_phase(name, fn, *a, **kw) for name, fn, a, kw in phases
+             if not args.phase or name in args.phase]
     for line in lines:
         check(line["kernels"], f"phase {line['phase']}: no Pallas "
               "tpu_custom_call in its compiled program")

@@ -24,8 +24,11 @@ __all__ = ["scaled_dot_product_attention", "flash_attention",
            "flash_attn_unpadded", "sdp_kernel"]
 
 
-def _sdpa_ref(q, k, v, bias, causal, scale, dropout_p=0.0, key=None):
-    """XLA-composite attention: [B, S, H, D] layout, f32 softmax."""
+def _sdpa_ref(q, k, v, bias, causal, scale, dropout_p=0.0, key=None,
+              keep=None):
+    """XLA-composite attention: [B, S, H, D] layout, f32 softmax.
+    Dropout draws its [B, H, Sq, Sk] mask from `key`, or takes `keep`
+    as given (the flash kernels' mask written out, for parity checks)."""
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     qt = jnp.swapaxes(q, 1, 2)  # B H S D
@@ -45,8 +48,9 @@ def _sdpa_ref(q, k, v, bias, causal, scale, dropout_p=0.0, key=None):
         # and prevents cross-sequence leakage in the varlen path)
         any_visible = jnp.any(scores > -1e29, axis=-1, keepdims=True)
         probs = jnp.where(any_visible, probs, jnp.zeros((), probs.dtype))
-    if dropout_p > 0.0 and key is not None:
-        keep = jax.random.bernoulli(key, 1.0 - dropout_p, probs.shape)
+    if dropout_p > 0.0 and (key is not None or keep is not None):
+        if keep is None:
+            keep = jax.random.bernoulli(key, 1.0 - dropout_p, probs.shape)
         probs = jnp.where(keep, probs / (1.0 - dropout_p),
                           jnp.zeros((), probs.dtype))
     out = jnp.einsum("bhqk,bhkd->bhqd", probs, vt,
@@ -54,9 +58,10 @@ def _sdpa_ref(q, k, v, bias, causal, scale, dropout_p=0.0, key=None):
     return jnp.swapaxes(out, 1, 2)
 
 
-def _use_pallas(head_dim, seqlen_k, dtype) -> bool:
-    """Gate the Mosaic kernel: TPU backend, MXU-friendly head_dim, and a
-    K/V working set that fits VMEM.
+def _flash_refusal(head_dim, seqlen_k, dtype, dropout=False):
+    """Why the Mosaic kernel cannot take a call of these shapes
+    ("dtype", "vmem", "gate"), or None if it can: TPU backend,
+    MXU-friendly head_dim, and a K/V working set that fits VMEM.
 
     head_dim does not need to be a multiple of 128 — the kernel keeps D as
     the lane dim and Mosaic pads to 128 lanes, so 64/96/128/256 all work
@@ -70,13 +75,55 @@ def _use_pallas(head_dim, seqlen_k, dtype) -> bool:
     from ...core.dtypes import to_jax_dtype
     jd = jnp.dtype(to_jax_dtype(dtype))
     if jd not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
-        return False
+        return "dtype"
     d_pad = max(head_dim, 128)  # Mosaic pads lanes to 128
     kv_bytes = 2 * seqlen_k * d_pad * jd.itemsize
     if head_dim > 256 or kv_bytes > 8 * 1024 * 1024:
-        return False
+        return "vmem"
     from ...ops.pallas_gate import pallas_enabled
-    return pallas_enabled("flash_attention")
+    if not pallas_enabled("flash_attention_dropout" if dropout
+                          else "flash_attention"):
+        return "gate"
+    return None
+
+
+def _attention_path(query, key, attn_mask, dropout):
+    """Which implementation this call builds, counted once a build in
+    `attention.path.<flash|flash_dropout|composite.<reason>>`: the
+    kernel takes every call it can, with dropout or without; a mask,
+    `sdp_kernel(enable_flash=False)` or `_flash_refusal` leave the XLA
+    composite.  Returns whether the kernel runs."""
+    if attn_mask is not None:
+        reason = "mask"
+    elif not _flash_allowed():
+        reason = "sdp_kernel"
+    else:
+        reason = _flash_refusal(query.shape[-1], key.shape[1], query.dtype,
+                                dropout)
+    path = (f"composite.{reason}" if reason
+            else "flash_dropout" if dropout else "flash")
+    from ... import observability as obs
+    obs.get_registry().counter(f"attention.path.{path}").inc()
+    return reason is None
+
+
+def _kernel_seed(key_arr):
+    """The flash kernels' int32[1] seed from the sub-key `_rng_op` hands
+    one attention call."""
+    return jax.lax.bitcast_convert_type(
+        jax.random.bits(key_arr, (1,), jnp.uint32), jnp.int32)
+
+
+def _attend(q, k, v, bias, causal, scale, use_pallas, dropout_p=0.0,
+            key_arr=None):
+    """One attention call on arrays: the flash kernel, seeded from the
+    generator's sub-key when it drops, or the composite."""
+    if not use_pallas:
+        return _sdpa_ref(q, k, v, bias, causal, scale, dropout_p, key_arr)
+    from ...ops.pallas_kernels import flash_attention
+    return flash_attention(
+        q, k, v, causal=causal, scale=scale, dropout_p=dropout_p,
+        seed=_kernel_seed(key_arr) if dropout_p > 0.0 else None)
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
@@ -85,40 +132,35 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     """Paddle-layout SDPA: q/k/v are [batch, seqlen, num_heads, head_dim].
 
     Attention dropout (dropout_p>0, training) uses the framework RNG via
-    the same generator-state threading as F.dropout; the Pallas kernel
-    has no dropout path, so dropout falls back to the XLA composite.
+    the same generator-state threading as F.dropout: the state advances
+    once a call, and the flash kernel draws its masks tile by tile from
+    a seed derived from the call's sub-key (the composite, taken with an
+    `attn_mask` or off the TPU, draws one mask tensor from that key).
     """
     scale = 1.0 / (query.shape[-1] ** 0.5)
     drop = float(dropout_p) if training else 0.0
-    use_pallas = (drop == 0.0 and _flash_allowed()
-                  and _use_pallas(query.shape[-1], key.shape[1],
-                                  query.dtype))
+    use_pallas = _attention_path(query, key, attn_mask, drop > 0.0)
+    args = (query, key, value) + ((attn_mask,) if attn_mask is not None
+                                  else ())
+    attrs = dict(causal=bool(is_causal), scale=scale,
+                 use_pallas=use_pallas)
 
     if drop > 0.0:
         from .common import _rng_op
 
-        def impl_drop(key_arr, q, k, v, *mask, causal, scale, p):
-            bias = mask[0] if mask else None
-            return _sdpa_ref(q, k, v, bias, causal, scale, p, key_arr)
+        def impl_drop(key_arr, q, k, v, *mask, causal, scale, use_pallas,
+                      p):
+            return _attend(q, k, v, mask[0] if mask else None, causal,
+                           scale, use_pallas, p, key_arr)
 
-        args = (query, key, value) + ((attn_mask,)
-                                      if attn_mask is not None else ())
         return _rng_op("scaled_dot_product_attention_drop", impl_drop,
-                       args, dict(causal=bool(is_causal), scale=scale,
-                                  p=drop))
+                       args, dict(attrs, p=drop))
 
     def impl(q, k, v, *mask, causal, scale, use_pallas):
-        bias = mask[0] if mask else None
-        if use_pallas and bias is None:
-            from ...ops.pallas_kernels import flash_attention
-            return flash_attention(q, k, v, causal=causal, scale=scale)
-        return _sdpa_ref(q, k, v, bias, causal, scale)
+        return _attend(q, k, v, mask[0] if mask else None, causal, scale,
+                       use_pallas)
 
-    args = (query, key, value) + ((attn_mask,) if attn_mask is not None
-                                  else ())
-    return dispatch("scaled_dot_product_attention", impl, args,
-                    dict(causal=bool(is_causal), scale=scale,
-                         use_pallas=use_pallas))
+    return dispatch("scaled_dot_product_attention", impl, args, attrs)
 
 
 def flash_attention(query, key, value, dropout=0.0, causal=False,
@@ -201,8 +243,8 @@ def _varlen_attention(key_arr, q, k, v, cu_q, cu_k, *, causal, scale, p):
 from .common import RNG_INFER_IMPLS as _INFER  # noqa: E402
 
 _INFER["scaled_dot_product_attention_drop"] = (
-    lambda q, k, v, *mask, causal, scale, p: _sdpa_ref(
-        q, k, v, mask[0] if mask else None, causal, scale))
+    lambda q, k, v, *mask, causal, scale, use_pallas, p: _attend(
+        q, k, v, mask[0] if mask else None, causal, scale, use_pallas))
 _INFER["flash_attn_unpadded_drop"] = (
     lambda q, k, v, cu_q, cu_k, *, causal, scale, p: _varlen_attention(
         None, q, k, v, cu_q, cu_k, causal=causal, scale=scale, p=0.0))
@@ -219,7 +261,7 @@ class sdp_kernel:
 
     ``enable_flash=False`` forces the XLA composite even where the
     Pallas kernel is eligible; with ``enable_flash=True`` (default)
-    selection stays automatic (_use_pallas gate).  ``enable_math`` /
+    selection stays automatic (_attention_path).  ``enable_math`` /
     ``enable_mem_efficient`` are accepted for parity; the composite is
     the math path and Pallas flash is inherently memory-efficient.
     """

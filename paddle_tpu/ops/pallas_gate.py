@@ -75,14 +75,21 @@ def _flag_on() -> bool:
                 ["FLAGS_use_pallas_kernels"])
 
 
-def _probe_flash_attention():
+def _probe_flash_attention(dropout_p=0.0):
     from . import pallas_kernels as pk
     q = jnp.zeros((1, 128, 1, 64), jnp.bfloat16)
+    seed = jnp.zeros((1,), jnp.int32) if dropout_p else None
     fn = jax.jit(jax.grad(
         lambda q, k, v: pk.flash_attention(
-            q, k, v, causal=True).astype(jnp.float32).sum(),
+            q, k, v, causal=True, dropout_p=dropout_p,
+            seed=seed).astype(jnp.float32).sum(),
         argnums=(0, 1, 2)))
     jax.block_until_ready(fn(q, q, q))
+
+
+def _probe_flash_attention_dropout():
+    """The kernels with the generator in them (per-tile reseeding)."""
+    _probe_flash_attention(dropout_p=0.1)
 
 
 def _probe_layer_norm():
@@ -231,6 +238,7 @@ def _probe_ragged_attention_int8():
 
 _PROBES = {
     "flash_attention": _probe_flash_attention,
+    "flash_attention_dropout": _probe_flash_attention_dropout,
     "paged_attention": _probe_paged_attention,
     "ragged_attention": _probe_ragged_attention,
     "ragged_attention_int8": _probe_ragged_attention_int8,
@@ -250,7 +258,7 @@ def _static_diagnose(kernel):
     attributes a Mosaic failure to a concrete TPU1xx rule when one is
     violated (plan shapes mirror the _probe_* functions above)."""
     from ..analysis import tiling
-    if kernel == "flash_attention":
+    if kernel in ("flash_attention", "flash_attention_dropout"):
         diags = []
         for direction in ("fwd", "bwd_dq", "bwd_dkv"):
             diags.extend(tiling.audit_flash_attention(

@@ -53,6 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = [
     "flash_attention",
     "flash_block_plan",
+    "flash_dropout_keep",
     "fused_layer_norm",
     "fused_rms_norm",
     "fused_softmax_cross_entropy",
@@ -75,13 +76,88 @@ from .pallas_tiles import (_NEG_INF, _STAT_LANES, _demote_f64,
 # Flash attention
 # =====================================================================
 
-def _attn_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                     scale, causal, block_k, sk_real, offset):
-    """One (batch*head, q-block) program: online-softmax over K blocks."""
-    q = q_ref[0].astype(jnp.float32)                     # (block_q, D)
+def _keep_threshold(dropout_p: float) -> int:
+    """A 32-bit word below this keeps its element: floor((1-p) * 2**32),
+    so the keep probability is 1-p to 2**-32."""
+    return int((1.0 - dropout_p) * 2 ** 32)
+
+
+def _mix32(x):
+    """One round of an integer finalizer (xor-shift, odd multiply) on
+    uint32 words: every input bit reaches every output bit."""
+    x = x ^ (x >> 16)
+    x = x * jnp.uint32(0x7FEB352D)
+    x = x ^ (x >> 15)
+    x = x * jnp.uint32(0x846CA68B)
+    return x ^ (x >> 16)
+
+
+def _hash_bits(seed, tile, shape):
+    """Counter-based stand-in for the core's generator: two finalizer
+    rounds over the element index, keyed by (seed, tile)."""
+    def u32(x):
+        return jnp.asarray(x, jnp.int32).astype(jnp.uint32)
+
+    key = _mix32(_mix32(u32(seed)) + u32(tile))
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return _mix32(_mix32(u32(row * shape[1] + col) ^ key) + key)
+
+
+def _tile_bits(seed, tile, shape):
+    """The `shape` uint32 words of dropout tile number `tile` under
+    `seed`: the one bit source of the forward kernel, both backward
+    kernels and `flash_dropout_keep`.  On the chip the core's generator,
+    reseeded per tile (this Mosaic takes two seed words); it has no CPU
+    form (jax 0.9 lowers `prng_seed` for no other platform), so
+    interpret mode hashes instead."""
+    if _interpret():
+        return _hash_bits(seed, tile, shape)
+    pltpu.prng_seed(seed, tile)
+    return pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
+
+
+def _tile_keep(seed_ref, bh, qi, ki, grid, shape, dropout_p):
+    """Keep mask of the tile at (batch*head `bh`, q-block `qi`, k-block
+    `ki`) of a `grid` = (q-blocks, k-blocks) attention matrix."""
+    tile = (bh * grid[0] + qi) * grid[1] + ki
+    return _tile_bits(seed_ref[0], tile, shape) < jnp.uint32(
+        _keep_threshold(dropout_p))
+
+
+# dot_general dimension numbers of a @ b.T, a @ b and a.T @ b
+_NT = (((1,), (1,)), ((), ()))
+_NN = (((1,), (0,)), ((), ()))
+_TN = (((0,), (0,)), ((), ()))
+
+
+def _mxu_dot(a, b, dims):
+    """`a` . `b` contracted over `dims`, operands in the dtype they come
+    in, accumulated in float32.  The framework's process-wide "highest"
+    matmul precision is meant for float32 operands; on bfloat16 ones
+    Mosaic refuses it ("Bad lhs type"), and their products are exact in
+    float32 anyway, so they ask for the default."""
+    precision = (None if a.dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    return jax.lax.dot_general(a, b, dims, precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _attn_fwd_kernel(*refs, scale, causal, block_k, sk_real, offset,
+                     dropout_p):
+    """One (batch*head, q-block) program: online-softmax over K blocks.
+
+    With `dropout_p` > 0 the first ref is the scalar-prefetched seed;
+    `l` and `lse` come from the undropped probabilities, the
+    accumulator from the kept ones, scaled by 1/(1-p) once at the end."""
+    seed_ref, refs = (refs[0], refs[1:]) if dropout_p else (None, refs)
+    q_ref, k_ref, v_ref, o_ref, lse_ref = refs
+    q = q_ref[0]                                          # (block_q, D)
     block_q, _ = q.shape
     sk_pad = k_ref.shape[1]
-    q_start = pl.program_id(1) * block_q
+    bh, q_blk = pl.program_id(0), pl.program_id(1)  # not inside the loop
+    tiles = (pl.num_programs(1), sk_pad // block_k)
+    q_start = q_blk * block_q
 
     num_k_blocks = sk_pad // block_k
     if causal:
@@ -92,11 +168,9 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 
     def body(i, carry):
         m_prev, l_prev, acc = carry                       # (bq,1)x2,(bq,D)
-        k_blk = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, bk)
+        k_blk = k_ref[0, pl.ds(i * block_k, block_k), :]
+        v_blk = v_ref[0, pl.ds(i * block_k, block_k), :]
+        s = _mxu_dot(q, k_blk, _NT) * scale               # (bq, bk)
         col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + i * block_k
         mask = col < sk_real                              # K padding
         if causal:
@@ -110,9 +184,11 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        if dropout_p:
+            keep = _tile_keep(seed_ref, bh, q_blk, i, tiles, p.shape,
+                              dropout_p)
+            p = jnp.where(keep, p, 0.0)
+        acc = acc * alpha + _mxu_dot(p.astype(v_blk.dtype), v_blk, _NN)
         return m_new, l_new, acc
 
     m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
@@ -120,20 +196,31 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     acc0 = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, num_k_blocks, body, (m0, l0, acc0))
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
+    denom = l_safe * (1.0 - dropout_p) if dropout_p else l_safe
+    o_ref[0] = (acc / denom).astype(o_ref.dtype)
     lse = jnp.where(l == 0.0, _NEG_INF, m + jnp.log(l_safe))  # (bq, 1)
     lse_ref[0] = jnp.broadcast_to(lse, (block_q, _STAT_LANES))
 
 
-def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        dq_ref, *, scale, causal, block_k, sk_real, offset):
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
+def _attn_bwd_dq_kernel(*refs, scale, causal, block_k, sk_real, offset,
+                        dropout_p):
+    """dq of one (batch*head, q-block).  Dropout (FlashAttention-2): the
+    tile's mask is drawn again and applied to dP = dO V^T; its 1/(1-p)
+    is folded into `scale` and `delta` (dS = P (keep dP/(1-p) - delta))."""
+    seed_ref, refs = (refs[0], refs[1:]) if dropout_p else (None, refs)
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref = refs
+    q = q_ref[0]
+    do = do_ref[0]
     lse = lse_ref[0][:, :1]                               # (bq, 1)
     delta = delta_ref[0][:, :1]
+    if dropout_p:
+        delta = delta * (1.0 - dropout_p)
+    scale_ds = scale / (1.0 - dropout_p)
     block_q = q.shape[0]
     sk_pad = k_ref.shape[1]
-    q_start = pl.program_id(1) * block_q
+    bh, q_blk = pl.program_id(0), pl.program_id(1)  # not inside the loop
+    tiles = (pl.num_programs(1), sk_pad // block_k)
+    q_start = q_blk * block_q
 
     num_k_blocks = sk_pad // block_k
     if causal:
@@ -142,11 +229,9 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             num_k_blocks, (jnp.maximum(hi, 0) + block_k - 1) // block_k)
 
     def body(i, dq):
-        k_blk = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+        k_blk = k_ref[0, pl.ds(i * block_k, block_k), :]
+        v_blk = v_ref[0, pl.ds(i * block_k, block_k), :]
+        s = _mxu_dot(q, k_blk, _NT) * scale
         col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + i * block_k
         mask = col < sk_real
         if causal:
@@ -154,27 +239,34 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             mask = jnp.logical_and(mask, col <= row + offset)
         s = jnp.where(mask, s, _NEG_INF)
         p = jnp.exp(s - lse)                              # (bq, bk)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        return dq + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dp = _mxu_dot(do, v_blk, _NT)
+        if dropout_p:
+            keep = _tile_keep(seed_ref, bh, q_blk, i, tiles, p.shape,
+                              dropout_p)
+            dp = jnp.where(keep, dp, 0.0)
+        ds = p * (dp - delta) * scale_ds
+        return dq + _mxu_dot(ds.astype(k_blk.dtype), k_blk, _NN)
 
-    dq0 = jnp.zeros_like(q)
+    dq0 = jnp.zeros(q.shape, jnp.float32)
     dq = jax.lax.fori_loop(0, num_k_blocks, body, dq0)
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
-def _attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dk_ref, dv_ref, *, scale, causal, block_q,
-                         sq_real, offset):
-    k = k_ref[0].astype(jnp.float32)                     # (block_k, D)
-    v = v_ref[0].astype(jnp.float32)
+def _attn_bwd_dkv_kernel(*refs, scale, causal, block_q, sq_real, offset,
+                         dropout_p):
+    """dk, dv of one (batch*head, k-block); dropout as in the dq kernel,
+    and dV = (P keep / (1-p))^T dO with its 1/(1-p) applied at the end."""
+    seed_ref, refs = (refs[0], refs[1:]) if dropout_p else (None, refs)
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+     dk_ref, dv_ref) = refs
+    k = k_ref[0]                                          # (block_k, D)
+    v = v_ref[0]
+    scale_ds = scale / (1.0 - dropout_p)
     block_k = k.shape[0]
     sq_pad = q_ref.shape[1]
-    k_start = pl.program_id(1) * block_k
+    bh, k_blk = pl.program_id(0), pl.program_id(1)  # not inside the loop
+    tiles = (sq_pad // block_q, pl.num_programs(1))
+    k_start = k_blk * block_k
 
     lo = 0
     num_q_blocks = sq_pad // block_q
@@ -184,14 +276,11 @@ def _attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def body(i, carry):
         dk, dv = carry
-        q_blk = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        do_blk = do_ref[0, pl.ds(i * block_q, block_q), :].astype(
-            jnp.float32)
+        q_blk = q_ref[0, pl.ds(i * block_q, block_q), :]
+        do_blk = do_ref[0, pl.ds(i * block_q, block_q), :]
         lse_blk = lse_ref[0, pl.ds(i * block_q, block_q), :][:, :1]
         delta_blk = delta_ref[0, pl.ds(i * block_q, block_q), :][:, :1]
-        s = jax.lax.dot_general(
-            q_blk, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, bk)
+        s = _mxu_dot(q_blk, k, _NT) * scale               # (bq, bk)
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + i * block_q
         mask = row < sq_real
         if causal:
@@ -199,58 +288,91 @@ def _attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             mask = jnp.logical_and(mask, col <= row + offset)
         s = jnp.where(mask, s, _NEG_INF)
         p = jnp.exp(s - lse_blk)
-        dv = dv + jax.lax.dot_general(
-            p, do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do_blk, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_blk) * scale
-        dk = dk + jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dp = _mxu_dot(do_blk, v, _NT)
+        p_kept = p
+        if dropout_p:
+            keep = _tile_keep(seed_ref, bh, i, k_blk, tiles, p.shape,
+                              dropout_p)
+            p_kept = jnp.where(keep, p, 0.0)
+            dp = jnp.where(keep, dp, 0.0)
+            delta_blk = delta_blk * (1.0 - dropout_p)
+        dv = dv + _mxu_dot(p_kept.astype(do_blk.dtype), do_blk, _TN)
+        ds = p * (dp - delta_blk) * scale_ds
+        dk = dk + _mxu_dot(ds.astype(q_blk.dtype), q_blk, _TN)
         return dk, dv
 
-    dk0 = jnp.zeros_like(k)
-    dv0 = jnp.zeros_like(v)
+    dk0 = jnp.zeros(k.shape, jnp.float32)
+    dv0 = jnp.zeros(v.shape, jnp.float32)
     dk, dv = jax.lax.fori_loop(lo, num_q_blocks, body, (dk0, dv0))
+    if dropout_p:
+        dv = dv / (1.0 - dropout_p)
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
+def _flash_call(kernel, grid, in_specs, out_specs, out_shape, name, seed,
+                *operands):
+    """The pallas_call of one flash kernel on `operands`; with a `seed`
+    (dropout) it rides in front of them as scalar prefetch."""
+    if seed is None:
+        return pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
+                              out_specs=out_specs, out_shape=out_shape,
+                              interpret=_interpret(), name=name)(*operands)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs),
+        out_shape=out_shape, interpret=_interpret(), name=name)(
+            seed, *operands)
+
+
+def _blk(shape):
+    """Block (1, rows, d) that walks rows with the second grid index."""
+    return pl.BlockSpec(shape, lambda b, i, *_: (b, i, 0))
+
+
+def _whole(shape):
+    """Block (1, rows, d) holding every row of one batch*head."""
+    return pl.BlockSpec(shape, lambda b, i, *_: (b, 0, 0))
+
+
+# The two builders below are jitted on their static arguments: a model
+# calls them once a layer with the same shapes, and a jitted callee is
+# traced once and lowered once (one Mosaic kernel, N calls to it) where
+# a plain one is traced and lowered N times.  Twelve layers cost a
+# second of every process's start that way, more in the lazy tier,
+# which traces a step several times (PERF.md section 6, PR 27).
+_FLASH_STATIC = ("scale", "causal", "sq_real", "sk_real", "block_q",
+                 "block_k", "dropout_p")
+
+
+@functools.partial(jax.jit, static_argnames=_FLASH_STATIC)
 @_x32
-def _flash_fwd(q, k, v, scale, causal, sq_real, sk_real, block_q, block_k):
+def _flash_fwd(q, k, v, scale, causal, sq_real, sk_real, block_q, block_k,
+               dropout_p=0.0, seed=None):
     bh, sq_pad, d = q.shape
     sk_pad = k.shape[1]
     offset = sk_real - sq_real  # causal alignment for cross-length attn
-    grid = (bh, sq_pad // block_q)
     with _kernel_span("flash_attention", "fwd") as kernel_name:
-        out, lse = pl.pallas_call(
-        functools.partial(_attn_fwd_kernel, scale=scale, causal=causal,
-                          block_k=block_k, sk_real=sk_real, offset=offset),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, sk_pad, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, sk_pad, d), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _STAT_LANES), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sq_pad, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq_pad, _STAT_LANES), jnp.float32),
-        ],
-        interpret=_interpret(),
-        name=kernel_name,
-    )(q, k, v)
+        out, lse = _flash_call(
+            functools.partial(_attn_fwd_kernel, scale=scale, causal=causal,
+                              block_k=block_k, sk_real=sk_real,
+                              offset=offset, dropout_p=dropout_p),
+            (bh, sq_pad // block_q),
+            [_blk((1, block_q, d)), _whole((1, sk_pad, d)),
+             _whole((1, sk_pad, d))],
+            [_blk((1, block_q, d)), _blk((1, block_q, _STAT_LANES))],
+            [jax.ShapeDtypeStruct((bh, sq_pad, d), q.dtype),
+             jax.ShapeDtypeStruct((bh, sq_pad, _STAT_LANES), jnp.float32)],
+            kernel_name, seed, q, k, v)
     return out, lse
 
 
+@functools.partial(jax.jit, static_argnames=_FLASH_STATIC)
 @_x32
 def _flash_bwd(q, k, v, do, out, lse, scale, causal, sq_real, sk_real,
-               block_q, block_k):
+               block_q, block_k, dropout_p=0.0, seed=None):
     """lse arrives in the (BH, Sq_pad, _STAT_LANES) stat-lane layout."""
     bh, sq_pad, d = q.shape
     sk_pad = k.shape[1]
@@ -265,47 +387,34 @@ def _flash_bwd(q, k, v, do, out, lse, scale, causal, sq_real, sk_real,
     empty = jnp.logical_or(row >= sq_real, lse <= _NEG_INF / 2)
     lse_safe = jnp.where(empty, jnp.float32(1e30), lse)
     with _kernel_span("flash_attention", "bwd_dq") as kernel_name:
-        dq = pl.pallas_call(
-        functools.partial(_attn_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_k=block_k, sk_real=sk_real, offset=offset),
-        grid=(bh, sq_pad // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, sk_pad, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, sk_pad, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _STAT_LANES), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _STAT_LANES), lambda b, i: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq_pad, d), q.dtype),
-        interpret=_interpret(),
-        name=kernel_name,
-    )(q, k, v, do, lse_safe, delta)
+        dq = _flash_call(
+            functools.partial(_attn_bwd_dq_kernel, scale=scale,
+                              causal=causal, block_k=block_k,
+                              sk_real=sk_real, offset=offset,
+                              dropout_p=dropout_p),
+            (bh, sq_pad // block_q),
+            [_blk((1, block_q, d)), _whole((1, sk_pad, d)),
+             _whole((1, sk_pad, d)), _blk((1, block_q, d)),
+             _blk((1, block_q, _STAT_LANES)),
+             _blk((1, block_q, _STAT_LANES))],
+            _blk((1, block_q, d)),
+            jax.ShapeDtypeStruct((bh, sq_pad, d), q.dtype),
+            kernel_name, seed, q, k, v, do, lse_safe, delta)
     with _kernel_span("flash_attention", "bwd_dkv") as kernel_name:
-        dk, dv = pl.pallas_call(
-        functools.partial(_attn_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, sq_real=sq_real, offset=offset),
-        grid=(bh, sk_pad // block_k),
-        in_specs=[
-            pl.BlockSpec((1, sq_pad, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, sq_pad, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, sq_pad, _STAT_LANES), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, sq_pad, _STAT_LANES), lambda b, j: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sk_pad, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, sk_pad, d), v.dtype),
-        ],
-        interpret=_interpret(),
-        name=kernel_name,
-    )(q, k, v, do, lse_safe, delta)
+        dk, dv = _flash_call(
+            functools.partial(_attn_bwd_dkv_kernel, scale=scale,
+                              causal=causal, block_q=block_q,
+                              sq_real=sq_real, offset=offset,
+                              dropout_p=dropout_p),
+            (bh, sk_pad // block_k),
+            [_whole((1, sq_pad, d)), _blk((1, block_k, d)),
+             _blk((1, block_k, d)), _whole((1, sq_pad, d)),
+             _whole((1, sq_pad, _STAT_LANES)),
+             _whole((1, sq_pad, _STAT_LANES))],
+            [_blk((1, block_k, d)), _blk((1, block_k, d))],
+            [jax.ShapeDtypeStruct((bh, sk_pad, d), k.dtype),
+             jax.ShapeDtypeStruct((bh, sk_pad, d), v.dtype)],
+            kernel_name, seed, q, k, v, do, lse_safe, delta)
     return dq, dk, dv
 
 
@@ -318,17 +427,37 @@ def set_flash_block_sizes(block_q=None, block_k=None):
 _block_override = (None, None)
 
 
-def _pick_block(seq: int, which: int = 0, dtype=jnp.float32) -> int:
+# Largest Q/K block rows by (head_dim, dtype), from the sweep on a TPU
+# v5e (scripts/flash_block_sweep.py, PERF.md section 6, PR 27): forward
+# plus backward at 192 x 512 x 64 took 4.35 ms with 128 x 128 blocks,
+# 2.20 with 256 x 256, 1.51 with 512 x 512 (dropout 0.1; the same
+# order without), and 128 x 1024 x 64 causal 6.51 / 3.49 / 2.73 (1024 x
+# 1024: 3.02).  What was not swept keeps 128.
+_FLASH_BLOCK_CAP = {(64, jnp.dtype(jnp.bfloat16)): 512}
+
+
+def _pick_block(seq: int, which: int = 0, dtype=jnp.float32,
+                head_dim: int = 0) -> int:
     """Q/K block rows for `seq`: legal by construction for `dtype`
     (sublane multiple of _min_rows), covering `seq` after _round_up
-    padding.  The sweep harness's override is clamped to legality
-    rather than trusted — an illegal sweep value degrades to the
-    default instead of crashing Mosaic."""
+    padding.  From 128 rows on, the largest multiple of 128 under the
+    (head_dim, dtype) cap that pads `seq` no further than 128-row blocks
+    would.  The sweep harness's override is clamped to legality rather
+    than trusted — an illegal sweep value degrades to the default
+    instead of crashing Mosaic."""
     mr = _min_rows(dtype)
     ov = _sane_block(_block_override[which], seq, mr)
     if ov:
         return ov
-    return 128 if seq >= 128 else _round_up(max(seq, mr), mr)
+    if seq < 128:
+        return _round_up(max(seq, mr), mr)
+    n = _round_up(seq, 128) // 128
+    cap = _FLASH_BLOCK_CAP.get((head_dim, jnp.dtype(dtype)), 128) // 128
+    return 128 * max(m for m in range(1, cap + 1) if n % m == 0)
+
+
+def _flash_blocks(sq, sk, d, dtype):
+    return _pick_block(sq, 0, dtype, d), _pick_block(sk, 1, dtype, d)
 
 
 def flash_block_plan(batch, seq_q, seq_k, heads, head_dim,
@@ -345,8 +474,7 @@ def flash_block_plan(batch, seq_q, seq_k, heads, head_dim,
     kernel builders' specs.
     """
     dtype = jnp.dtype(dtype)
-    block_q = _pick_block(seq_q, 0, dtype)
-    block_k = _pick_block(seq_k, 1, dtype)
+    block_q, block_k = _flash_blocks(seq_q, seq_k, head_dim, dtype)
     bh = batch * heads
     sq_pad = _round_up(seq_q, block_q)
     sk_pad = _round_up(seq_k, block_k)
@@ -421,45 +549,58 @@ def paged_block_plan(num_heads, head_dim, block_size, num_blocks=64,
     }
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_attention_bhsd(q, k, v, scale, causal):
-    out, _ = _flash_attention_bhsd_fwd(q, k, v, scale, causal)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_attention_bhsd(q, k, v, seed, scale, causal, dropout_p=0.0):
+    """`seed`: int32[1] when `dropout_p` > 0, else None."""
+    out, _ = _flash_attention_bhsd_fwd(q, k, v, seed, scale, causal,
+                                       dropout_p)
     return out
 
 
-def _flash_attention_bhsd_fwd(q, k, v, scale, causal):
+def _flash_attention_bhsd_fwd(q, k, v, seed, scale, causal, dropout_p):
     bh, sq, d = q.shape
     sk = k.shape[1]
-    block_q = _pick_block(sq, 0, q.dtype)
-    block_k = _pick_block(sk, 1, q.dtype)
+    block_q, block_k = _flash_blocks(sq, sk, d, q.dtype)
     qp = _pad_dim(q, 1, _round_up(sq, block_q))
     kp = _pad_dim(k, 1, _round_up(sk, block_k))
     vp = _pad_dim(v, 1, _round_up(sk, block_k))
     out, lse = _flash_fwd(qp, kp, vp, scale, causal, sq, sk,
-                          block_q, block_k)
-    return out[:, :sq], (q, k, v, out, lse)
+                          block_q, block_k, dropout_p, seed)
+    return out[:, :sq], (q, k, v, seed, out, lse)
 
 
-def _flash_attention_bhsd_bwd(scale, causal, res, g):
-    q, k, v, out_pad, lse = res
+def _flash_attention_bhsd_bwd(scale, causal, dropout_p, res, g):
+    q, k, v, seed, out_pad, lse = res
     bh, sq, d = q.shape
     sk = k.shape[1]
-    block_q = _pick_block(sq, 0, q.dtype)
-    block_k = _pick_block(sk, 1, q.dtype)
+    block_q, block_k = _flash_blocks(sq, sk, d, q.dtype)
     qp = _pad_dim(q, 1, _round_up(sq, block_q))
     kp = _pad_dim(k, 1, _round_up(sk, block_k))
     vp = _pad_dim(v, 1, _round_up(sk, block_k))
     gp = _pad_dim(g, 1, _round_up(sq, block_q))
     dq, dk, dv = _flash_bwd(qp, kp, vp, gp, out_pad, lse, scale, causal,
-                            sq, sk, block_q, block_k)
-    return dq[:, :sq], dk[:, :sk], dv[:, :sk]
+                            sq, sk, block_q, block_k, dropout_p, seed)
+    return dq[:, :sq], dk[:, :sk], dv[:, :sk], None
 
 
 _flash_attention_bhsd.defvjp(_flash_attention_bhsd_fwd,
                              _flash_attention_bhsd_bwd)
 
 
-def flash_attention(q, k, v, *, causal=False, scale=None):
+def _dropout_seed(dropout_p, seed):
+    """(static rate, int32[1] seed or None) of one flash call."""
+    dropout_p = float(dropout_p)
+    if not 0.0 <= dropout_p < 1.0:
+        raise ValueError(f"dropout_p must be in [0, 1), got {dropout_p}")
+    if dropout_p == 0.0:
+        return 0.0, None
+    if seed is None:
+        raise ValueError("flash_attention with dropout_p > 0 needs a seed")
+    return dropout_p, jnp.asarray(seed).astype(jnp.int32).reshape((1,))
+
+
+def flash_attention(q, k, v, *, causal=False, scale=None, dropout_p=0.0,
+                    seed=None):
     """Flash attention over Paddle layout [B, S, H, D]; differentiable.
 
     Online-softmax tiled for the MXU with a hand-written flash backward
@@ -467,17 +608,63 @@ def flash_attention(q, k, v, *, causal=False, scale=None):
     Supports head_dim not a multiple of 128 (Mosaic pads lanes), uneven
     sequence lengths (padded + masked here), causal cross-attention
     (Sk != Sq aligned bottom-right, matching flash-attn semantics).
+
+    `dropout_p` (static) > 0 drops attention probabilities inside the
+    kernels: every element of every (batch, head) draws its own 32-bit
+    word from a stream fixed by the integer `seed` and its tile, is kept
+    with probability 1 - p and scaled by 1 / (1 - p); the backward
+    kernels draw the same words again, so no mask reaches HBM.
+    `flash_dropout_keep` writes that mask out.  At 0 the kernels hold no
+    generator code.
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
+    dropout_p, seed = _dropout_seed(dropout_p, seed)
     q, k, v = _demote_f64(q, k, v)
     b, sq, h, d = q.shape
     sk = k.shape[1]
     qt = jnp.swapaxes(q, 1, 2).reshape(b * h, sq, d)
     kt = jnp.swapaxes(k, 1, 2).reshape(b * h, sk, d)
     vt = jnp.swapaxes(v, 1, 2).reshape(b * h, sk, d)
-    out = _flash_attention_bhsd(qt, kt, vt, float(scale), bool(causal))
+    out = _flash_attention_bhsd(qt, kt, vt, seed, float(scale),
+                                bool(causal), dropout_p)
     return jnp.swapaxes(out.reshape(b, h, sq, d), 1, 2)
+
+
+def _keep_kernel(seed_ref, keep_ref, *, dropout_p):
+    keep = _tile_keep(seed_ref, pl.program_id(0), pl.program_id(1),
+                      pl.program_id(2),
+                      (pl.num_programs(1), pl.num_programs(2)),
+                      keep_ref.shape[1:], dropout_p)
+    keep_ref[0] = keep.astype(keep_ref.dtype)
+
+
+@_x32
+def flash_dropout_keep(seed, batch, seq_q, seq_k, heads, head_dim, *,
+                       dropout_p, dtype=jnp.float32):
+    """The keep mask `flash_attention(..., dropout_p, seed)` applies to
+    [batch, seq, heads, head_dim] inputs of `dtype`, as bool
+    [batch, heads, seq_q, seq_k]: the same tile stream, written out.
+    For checking the kernels against a composite under an explicit
+    mask; the training path never builds it."""
+    dropout_p, seed = _dropout_seed(dropout_p, seed)
+    block_q, block_k = _flash_blocks(seq_q, seq_k, head_dim, dtype)
+    bh = batch * heads
+    sq_pad, sk_pad = _round_up(seq_q, block_q), _round_up(seq_k, block_k)
+    with _kernel_span("flash_attention", "keep") as kernel_name:
+        keep = pl.pallas_call(
+            functools.partial(_keep_kernel, dropout_p=dropout_p),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(bh, sq_pad // block_q, sk_pad // block_k),
+                in_specs=[],
+                out_specs=pl.BlockSpec((1, block_q, block_k),
+                                       lambda b, i, j, *_: (b, i, j))),
+            out_shape=jax.ShapeDtypeStruct((bh, sq_pad, sk_pad),
+                                           jnp.int32),
+            interpret=_interpret(), name=kernel_name)(seed)
+    return keep[:, :seq_q, :seq_k].reshape(
+        batch, heads, seq_q, seq_k).astype(bool)
 
 
 # =====================================================================

@@ -66,8 +66,8 @@ def _ring_flash_bhsd(q, k, v, scale, causal, axis, axis_size):
 
 def _ring_flash_bhsd_fwd(q, k, v, scale, causal, axis, axis_size):
     bh, s, d = q.shape
-    bq = _pick_block(s, 0)
-    bk = _pick_block(s, 1)
+    bq = _pick_block(s, 0, q.dtype, d)
+    bk = _pick_block(s, 1, q.dtype, d)
     s_pad = _round_up(s, bq)
     qp = _pad_dim(q, 1, s_pad)
     me = jax.lax.axis_index(axis)
@@ -105,8 +105,8 @@ def _ring_flash_bhsd_fwd(q, k, v, scale, causal, axis, axis_size):
 def _ring_flash_bhsd_bwd(scale, causal, axis, axis_size, res, g):
     q, k, v, out_pad, lse_tot = res
     bh, s, d = q.shape
-    bq = _pick_block(s, 0)
-    bk = _pick_block(s, 1)
+    bq = _pick_block(s, 0, q.dtype, d)
+    bk = _pick_block(s, 1, q.dtype, d)
     s_pad = _round_up(s, bq)
     qp = _pad_dim(q, 1, s_pad)
     gp = _pad_dim(g, 1, s_pad)

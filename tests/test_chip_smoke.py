@@ -31,6 +31,16 @@ def test_train_static_tiny():
     assert out["kernels"] == {}
 
 
+def test_attention_dropout_tiny():
+    """Interpret mode: the hash stands in for the chip's generator."""
+    out = chip_smoke.attention_dropout(BERT, 2, 48, depth=1)
+    c = out["checked"]
+    assert abs(c["keep_rate"] - 0.9) < 4 * c["keep_rate_sigma"]
+    assert set(c["rel_l2_vs_masked_composite"]) == {"fwd", "dq", "dk", "dv"}
+    assert max(c["rel_l2_vs_masked_composite"].values()) < 3e-2
+    assert out["kernels"] == {}
+
+
 @pytest.mark.parametrize("lazy_tier", [False, True])
 def test_train_eager_tiny(lazy_tier):
     out = chip_smoke.train_eager(BERT, 4, 32, steps=3,

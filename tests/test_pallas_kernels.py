@@ -102,6 +102,165 @@ def test_flash_attention_grad(causal):
                                    atol=1e-4, rtol=1e-4)
 
 
+# -- attention dropout inside the flash kernels (interpret mode: the
+# hash bit source; chip_smoke.py checks the chip's generator) ----------
+
+def _masked_ref(q, k, v, keep, causal, p):
+    """nn.functional's composite under an explicit keep mask."""
+    from paddle_tpu.nn.functional.flash_attention import _sdpa_ref as ref
+    return ref(q, k, v, None, causal, 1.0 / q.shape[-1] ** 0.5, p,
+               keep=keep)
+
+
+def _qkv(shape, dtype, seed=5):
+    return [jax.random.normal(kk, shape, jnp.float32).astype(dtype)
+            for kk in jax.random.split(jax.random.PRNGKey(seed), 3)]
+
+
+@pytest.mark.parametrize("p", [0.1, 0.5])
+def test_flash_dropout_keep_rate(p):
+    """Each element keeps with probability 1 - p: the rate over 2 x 2 x
+    200 x 200 draws is within 4 sigma, and so is every tile's."""
+    b, s, h, d = 2, 200, 2, 32
+    with jax.enable_x64(False):
+        keep = np.asarray(pk.flash_dropout_keep(
+            jnp.array([7], jnp.int32), b, s, s, h, d, dropout_p=p))
+    assert keep.shape == (b, h, s, s) and keep.dtype == bool
+    sigma = (p * (1 - p) / keep.size) ** 0.5
+    assert abs(keep.mean() - (1 - p)) < 4 * sigma
+    tile = keep[:, :, :128, :128]
+    sigma = (p * (1 - p) / (128 * 128)) ** 0.5
+    assert np.abs(tile.mean(axis=(2, 3)) - (1 - p)).max() < 4 * sigma
+    # no row, column, head or tile repeats another
+    assert not (keep[0, 0] == keep[0, 1]).all()
+    assert not (keep[0, 0, :64, :64] == keep[0, 0, 128:192, 128:192]).all()
+    assert not (keep[0, 0, 0] == keep[0, 0, 1]).all()
+
+
+def test_flash_dropout_seed_reproduces():
+    q, k, v = _qkv((1, 100, 2, 32), jnp.float32)
+    with jax.enable_x64(False):
+        a, b, c = (np.asarray(pk.flash_attention(
+            q, k, v, dropout_p=0.5, seed=jnp.array([sd], jnp.int32)))
+            for sd in (3, 3, 4))
+    np.testing.assert_array_equal(a, b)
+    assert not np.allclose(a, c)
+    with pytest.raises(ValueError, match="needs a seed"):
+        pk.flash_attention(q, k, v, dropout_p=0.5)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("dtype,tol,gtol", [
+    (jnp.float32, dict(atol=1e-5, rtol=1e-5), dict(atol=1e-5, rtol=1e-5)),
+    (jnp.bfloat16, dict(atol=3e-2, rtol=3e-2), dict(atol=1e-1, rtol=6e-2)),
+])
+def test_flash_dropout_matches_masked_composite(dtype, tol, gtol, causal):
+    """Forward, dq, dk, dv equal `_sdpa_ref` under the written-out mask
+    of the same tile stream; 200 is no multiple of the 128 block."""
+    shape, p = (2, 200, 2, 32), 0.1
+    q, k, v = _qkv(shape, dtype)
+    seed = jnp.array([1234], jnp.int32)
+    f32 = lambda x: np.asarray(x, np.float32)  # noqa: E731
+    with jax.enable_x64(False):
+        keep = pk.flash_dropout_keep(seed, 2, 200, 200, 2, 32,
+                                     dropout_p=p, dtype=dtype)
+        out = pk.flash_attention(q, k, v, causal=causal, dropout_p=p,
+                                 seed=seed)
+        ref = _masked_ref(q, k, v, keep, causal, p)
+        assert out.dtype == dtype
+        np.testing.assert_allclose(f32(out), f32(ref), **tol)
+        # mean, not sum, of squares keeps float32 gradients near 1
+        g = jax.grad(lambda q, k, v: jnp.mean(pk.flash_attention(
+            q, k, v, causal=causal, dropout_p=p,
+            seed=seed).astype(jnp.float32) ** 2) * 1e3, (0, 1, 2))(q, k, v)
+        g_ref = jax.grad(lambda q, k, v: jnp.mean(_masked_ref(
+            q, k, v, keep, causal, p).astype(jnp.float32) ** 2) * 1e3,
+            (0, 1, 2))(q, k, v)
+    for a, b in zip(g, g_ref):
+        np.testing.assert_allclose(f32(a), f32(b), **gtol)
+
+
+def _primitives(jaxpr, out):
+    """Count of every primitive in `jaxpr`, nested jaxprs included
+    (custom_vjp, pjit, pallas_call, loops)."""
+    for eqn in jaxpr.eqns:
+        out[eqn.primitive.name] += 1
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _primitives(sub, out)
+    return out
+
+
+def test_flash_without_dropout_traces_no_prng(monkeypatch):
+    """dropout_p == 0: no generator primitive and no hash in any of the
+    three kernels; dropout_p > 0 on the chip's path: prng_seed and
+    prng_random_bits in all three."""
+    import collections
+    monkeypatch.setattr(pk, "_interpret", lambda: False)  # the chip's body
+    # the jitted builders key their traces on shapes, not on _interpret
+    jax.clear_caches()
+    q, k, v = _qkv((1, 128, 1, 64), jnp.bfloat16)
+    seed = jnp.array([1], jnp.int32)
+
+    def grads(p):
+        return _primitives(jax.make_jaxpr(jax.grad(
+            lambda q, k, v: pk.flash_attention(
+                q, k, v, dropout_p=p, seed=seed if p else None).astype(
+                    jnp.float32).sum(), (0, 1, 2)))(q, k, v).jaxpr,
+            collections.Counter())
+
+    with jax.enable_x64(False):
+        plain, dropped = grads(0.0), grads(0.1)
+    jax.clear_caches()
+    assert plain["pallas_call"] == dropped["pallas_call"] == 3
+    assert not {"prng_seed", "prng_random_bits", "shift_right_logical",
+                "random_bits", "threefry2x32"} & set(plain)
+    # fwd, dq, dkv each reseed and draw once per tile
+    assert dropped["prng_seed"] == dropped["prng_random_bits"] == 3
+
+
+@pytest.mark.parametrize("seq,dtype,head_dim,want", [
+    (512, jnp.bfloat16, 64, 512),     # the BERT cells: one block
+    (1024, jnp.bfloat16, 64, 512),
+    (384, jnp.bfloat16, 64, 384),     # no padding beyond 128-row blocks
+    (200, jnp.bfloat16, 64, 256),
+    (640, jnp.bfloat16, 64, 128),     # 5 x 128: nothing larger divides
+    (512, jnp.float32, 64, 128),      # not swept: as before
+    (512, jnp.bfloat16, 128, 128),
+    (100, jnp.bfloat16, 64, 112),
+    (48, jnp.float32, 32, 48),
+])
+def test_flash_block_table(seq, dtype, head_dim, want):
+    assert pk._pick_block(seq, 0, dtype, head_dim) == want
+    assert pk._pick_block(seq, 1, dtype, head_dim) == want
+    plan = pk.flash_block_plan(1, seq, seq, 1, head_dim, dtype=dtype)
+    assert (plan["block_q"], plan["block_k"]) == (want, want)
+    pk.set_flash_block_sizes(64, 32)          # the sweep's override wins
+    try:
+        assert pk._pick_block(seq, 0, dtype, head_dim) == min(
+            64, pk._round_up(seq, 16))
+    finally:
+        pk.set_flash_block_sizes(None, None)
+
+
+def test_flash_attention_bf16_one_block_of_384():
+    """A length the table serves with one 384-row block, with dropout:
+    forward against the composite under the written-out mask."""
+    q, k, v = _qkv((1, 384, 1, 64), jnp.bfloat16)
+    seed = jnp.array([9], jnp.int32)
+    with jax.enable_x64(False):
+        keep = pk.flash_dropout_keep(seed, 1, 384, 384, 1, 64,
+                                     dropout_p=0.1, dtype=jnp.bfloat16)
+        out = pk.flash_attention(q, k, v, causal=True, dropout_p=0.1,
+                                 seed=seed)
+        ref = _masked_ref(q, k, v, keep, True, 0.1)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=3e-2, rtol=3e-2)
+
+
 def test_fused_layer_norm():
     key = jax.random.PRNGKey(3)
     x = jax.random.normal(key, (37, 96), jnp.float32) * 3 + 1

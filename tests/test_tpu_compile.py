@@ -83,6 +83,16 @@ def _flash(direction):
     return (fwd if direction == "fwd" else _sum_grad(fwd, (0, 1, 2))), qkv
 
 
+def _flash_dropout(direction, batch=B, seq=S):
+    """BERT's attention as the cells run it: dropout 0.1 drawn inside
+    the kernels by the core's generator.  384 and 200 are lengths the
+    block table serves with one 384-row and one 256-row block."""
+    def fwd(q, k, v, seed):
+        return pk.flash_attention(q, k, v, dropout_p=0.1, seed=seed)
+    return ((fwd if direction == "fwd" else _sum_grad(fwd, (0, 1, 2))),
+            [((batch, seq, H, D), bf16)] * 3 + [((1,), i32)])
+
+
 def _layer_norm():
     return (_sum_grad(pk.fused_layer_norm, (0, 1, 2)),
             [((ROWS, HID), bf16), ((HID,), bf16), ((HID,), bf16)])
@@ -133,6 +143,10 @@ def _ragged(kv_dtype):
 SMOKE_CASES = {
     "flash_fwd_16x512x12x64": lambda: _flash("fwd"),
     "flash_bwd_16x512x12x64": lambda: _flash("bwd"),
+    "flash_dropout_fwd_16x512x12x64": lambda: _flash_dropout("fwd"),
+    "flash_dropout_bwd_16x512x12x64": lambda: _flash_dropout("bwd"),
+    "flash_dropout_bwd_4x384x12x64": lambda: _flash_dropout("bwd", 4, 384),
+    "flash_dropout_bwd_4x200x12x64": lambda: _flash_dropout("bwd", 4, 200),
     "layer_norm_8192x768": _layer_norm,
     "ln_residual_8192x768": _ln_residual,
     "matmul_epilogue_8192x768x3072": lambda: _matmul_epilogue(HID, FFN),

@@ -299,6 +299,9 @@ SITES = {
     "flash_bwd_16x512x12x64": ["flash_attention.fwd",
                                "flash_attention.bwd_dq",
                                "flash_attention.bwd_dkv"],
+    "flash_dropout_bwd_16x512x12x64": ["flash_attention.fwd",
+                                       "flash_attention.bwd_dq",
+                                       "flash_attention.bwd_dkv"],
     "layer_norm_8192x768": ["layer_norm.fwd", "layer_norm.bwd"],
     "ln_residual_8192x768": ["layer_norm_residual.fwd",
                              "layer_norm_residual.bwd"],
