@@ -1,6 +1,6 @@
 """Prove that the trainer and the serving engine start on the chip.
 
-    python chip_smoke.py            # one TPU chip: five phases
+    python chip_smoke.py            # one TPU chip: six phases
     python chip_smoke.py --chips 4  # four chips: the sharded phase only
     python chip_smoke.py --phase attention_dropout   # that phase alone
 
@@ -27,6 +27,7 @@ import dataclasses
 import gc
 import json
 import math
+import os
 import re
 import sys
 import time
@@ -484,6 +485,124 @@ def serve(cfg, prompt_lens, new_tokens=32, arrivals=None):
 
 
 # ---------------------------------------------------------------------
+# serving a model with two kinds of state
+# ---------------------------------------------------------------------
+# bfloat16 weights and activations against the float32 reference,
+# through four layers and two kinds of cache: a rounding of 2^-8 at
+# every product, a few dozen products deep, and a head over 4,096
+# values.  A flipped block (below) moves single rows further than
+# rounding does, so the bound holds for the worst row, flips included.
+SALA_REL_L2 = 5e-2
+
+
+def serve_sala(config, prompt_lens, new_tokens=8, engine=None):
+    """MiniCPM-SALA at the published widths, two layers of each kind:
+    prefill in chunks then decode through the ``GenerationEngine``
+    against the plain reference's full forward, on logits.  The step is
+    compiled with one more output, the logits row each request samples
+    from.  Also counted, in the reference alone: how many block choices
+    change when the selector's scores are taken from bfloat16-rounded q
+    and pooled keys (what the program scores with), and what those
+    flips do to the logits."""
+    import jax.numpy as jnp
+    from benchmarks.families import _plain, minicpm_sala as family
+    from paddle_tpu.core.dispatch import dispatch
+    from paddle_tpu.inference.serving.engine import ragged_sample_next
+    paddle.seed(SEED)
+    model = family.build(config)
+    rng = np.random.default_rng(SEED)
+    prompts = [rng.integers(0, config["vocab_size"], n).tolist()
+               for n in prompt_lens]
+    gc.collect()
+    eng = GenerationEngine(model, **(engine or {
+        "max_batch": 4, "block_size": 64, "num_blocks": 512,
+        "max_model_len": 12288, "prefill_chunk": 1024}))
+    view, rows = eng._view, {}
+
+    def tapped(ids, seeds, *controls):
+        with paddle.no_grad():
+            logits = model(ids, cache=view, use_cache=False)
+            picked = dispatch(
+                "tap_rows", lambda z, i: z[0, i].astype(jnp.float32),
+                (logits, view.last_index), {}, differentiable=False)
+            return ragged_sample_next(logits, view.last_index, seeds,
+                                      view.sample_pos, *controls), picked
+
+    step_fn = paddle.jit.to_static(tapped)
+
+    def step(ids, *args):
+        tok, picked = step_fn(ids, *args)
+        where = np.asarray(view.sample_pos._value)
+        for r, req in enumerate(eng._rows):
+            if req is not None and where[r] > 0:
+                rows[(req.id, int(where[r]))] = (r, picked._value)
+        return tok
+
+    step._cache = step_fn._cache
+    eng._step_fn = step
+    try:
+        ids = [eng.add_request(p, max_new_tokens=new_tokens)
+               for p in prompts]
+        while eng.has_unfinished():
+            eng.step()
+        outs = [eng.result(i) for i in ids]
+        stats = eng.stats()
+        (entry,) = step_fn._cache.values()
+        kernels = mosaic_kernels(entry["compiled"].as_text())
+    finally:
+        eng.close()
+    params = _plain.arrays(model)
+    worst, flips, flip_rel, positions = 0.0, 0, 0.0, 0
+    for rid, out, prompt in zip(ids, outs, prompts):
+        check(len(out) == len(prompt) + new_tokens,
+              f"{rid} ended with {len(out) - len(prompt)} tokens")
+        seq = jnp.asarray(np.asarray(out))
+        at = np.arange(len(prompt) - 1, len(out) - 1)
+        ref = np.asarray(family.reference_head(
+            params, config, family.reference_hidden(params, config, seq)[at]))
+        hidden, n = family.reference_hidden(params, config, seq,
+                                            score_dtype="bfloat16")
+        flips += int(n)
+        flip_rel = max(flip_rel, _rel_l2(np.asarray(family.reference_head(
+            params, config, hidden[at])), ref))
+        for j, pos in enumerate(range(len(prompt), len(out))):
+            r, picked = rows[(rid, pos)]
+            worst = max(worst, _rel_l2(np.asarray(picked[r]), ref[j]))
+            positions += 1
+    check(np.isfinite(worst) and worst <= SALA_REL_L2,
+          f"served logits differ from the reference by {worst} (rel L2)")
+    check(stats["sparse_blocks_selected"] < stats["sparse_blocks_visible"],
+          "no decode row pruned: the contexts are too short")
+    return {"kernels": kernels,
+            "checked": {
+                "requests": len(prompts), "prompt_lens": list(prompt_lens),
+                "positions": positions,
+                "worst_row_rel_l2_vs_reference": worst,
+                "rel_l2_tolerance": SALA_REL_L2,
+                "block_choices_flipped_by_bf16_scores": flips,
+                "logits_rel_l2_moved_by_those_flips": flip_rel,
+                "sparse_blocks_selected": stats["sparse_blocks_selected"],
+                "sparse_blocks_visible": stats["sparse_blocks_visible"],
+                "state_resets": stats["state_resets"],
+                "state_pool_bytes": stats["state_pool_bytes"],
+                "compressed_pool_bytes": stats["compressed_pool_bytes"],
+                "step_program_compiles": len(step_fn._cache),
+                "probe_ok": _probed()}}
+
+
+def sala_config(layers=("minicpm4", "lightning-attn", "lightning-attn",
+                        "minicpm4")):
+    """The benchmark's configuration file with the layer list cut to
+    two of each kind (depth is what a smoke may cut; no width is)."""
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmarks", "configs",
+                           "MiniCPM-SALA.json")) as f:
+        config = json.load(f)
+    return {**config, "mixer_types": list(layers),
+            "num_hidden_layers": len(layers)}
+
+
+# ---------------------------------------------------------------------
 # training across four chips
 # ---------------------------------------------------------------------
 def train_static_mesh(cfg, batch, seq, mesh="dp=2,tp=2", steps=4):
@@ -572,7 +691,10 @@ def main(argv=None):
             ("train_eager", train_eager, (bert_eager, batch, seq), {}),
             ("train_lazy", train_eager, (bert, batch, seq),
              {"lazy_tier": True}),
-            ("serve", serve, (GPTConfig(), [37, 200, 513, 900]), {})]
+            ("serve", serve, (GPTConfig(), [37, 200, 513, 900]), {}),
+            # 9,500 tokens prune (64 of 115 candidate blocks), 3,000
+            # attend densely; both cross chunk boundaries
+            ("serve_sala", serve_sala, (sala_config(), [9500, 3000]), {})]
     unknown = set(args.phase or ()) - {name for name, *_ in phases}
     if unknown:
         sys.exit(f"no such phase with --chips {args.chips}: "

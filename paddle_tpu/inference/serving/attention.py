@@ -35,6 +35,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ...core.dispatch import dispatch
 from ...core.tensor import Tensor
@@ -42,7 +43,9 @@ from ...core.tensor import Tensor
 __all__ = ["kv_cache_scatter", "kv_cache_scatter_quant",
            "paged_attention", "ragged_attention",
            "PagedCacheView", "PagedLayerCache", "RaggedCacheView",
-           "RaggedLayerCache", "kv_blocks_gather", "kv_blocks_scatter"]
+           "RaggedLayerCache", "SparseLayerCache", "RecurrentLayerCache",
+           "grouped_decode_attention", "kv_blocks_gather",
+           "kv_blocks_scatter"]
 
 _NEG_INF = -1e30
 
@@ -482,6 +485,231 @@ class RaggedLayerCache:
                                 view.block_q)
 
 
+# ---------------------------------------------------------------------
+# layers that keep per-request state: block-sparse and recurrent
+# ---------------------------------------------------------------------
+def grouped_decode_attention(q, k_pool, v_pool, sel_tables, sel_ctx,
+                             use_pallas):
+    """Decode rows with grouped KV heads over a block table of their
+    own, through the ragged kernel: ``q`` [S, H, D] (one token a row,
+    heads grouped by KV head), pools [nb, Hkv, bs, D], ``sel_tables``
+    [S, Hkv, W], ``sel_ctx`` [S, Hkv] (0: an idle row).
+
+    The kernel learns grouped heads by layout: a (row, KV head) pair is
+    a sequence of its own whose q-block holds the group's ``G`` query
+    heads as rows, all at the same position (``q_starts = ctx - 1``
+    lets every row see the whole context), and the pool is viewed as
+    ``[nb * Hkv, 1, bs, D]`` so that a table entry names a block *and*
+    a KV head.  Sixteen MXU rows a token where one head a row gives
+    one."""
+    S, H, D = q.shape
+    nb, kv_heads, bs, _ = k_pool.shape
+    G, n = H // kv_heads, S * kv_heads
+    heads = jnp.arange(kv_heads, dtype=jnp.int32)[None, :, None]
+    tables = (sel_tables * kv_heads + heads).reshape(n, -1)
+    ctx = sel_ctx.reshape(n).astype(jnp.int32)
+    seq = jnp.where(ctx > 0, jnp.arange(n, dtype=jnp.int32), n)
+    out = _ragged_attention_impl(
+        q.reshape(1, n * G, 1, D), k_pool.reshape(nb * kv_heads, 1, bs, D),
+        v_pool.reshape(nb * kv_heads, 1, bs, D), tables, ctx, seq,
+        jnp.maximum(ctx - 1, 0), jnp.full((n,), G, jnp.int32),
+        block_q=G, scale=1.0 / math.sqrt(D), use_pallas=use_pallas)
+    return out.reshape(S, H, D)
+
+
+def _chunk_rows(x, meta, chunk_rows):
+    """The step's prefill chunk: ``chunk_rows`` rows of ``x`` [T, ...]
+    from the chunk's flat offset."""
+    return jax.lax.dynamic_slice_in_dim(x, meta[0], chunk_rows, 0)
+
+
+def _merge_rows(like, chunk_out, dec_out, meta, dec_index):
+    """Chunk rows and decode rows back into the flat buffer (rows that
+    carry nothing stay zero; idle decode rows are dropped)."""
+    out = jax.lax.dynamic_update_slice_in_dim(
+        jnp.zeros_like(like), chunk_out.astype(like.dtype), meta[0], 0)
+    return out.at[dec_index].set(dec_out.astype(like.dtype), mode="drop")
+
+
+def _sparse_attend_impl(q, k, v, k_pool, v_pool, ck_pool, slots, tables,
+                        dec_index, row_slots, row_pos, meta, ck_seq, ck_j,
+                        ck_slot, *, sizes, chunk_rows, sel_width,
+                        pallas_attn, pallas_select):
+    """One block-sparse layer of the ragged step.  Scatter K/V, pool the
+    keys this step completed, then: decode rows score their slot's
+    pooled keys, select, and read the selected blocks through the ragged
+    kernel; the chunk's tokens each select for themselves and run the
+    masked composite (skipped when the step carries no chunk)."""
+    from ...ops import pallas_sparse as pls
+    k_pool, v_pool = _kv_scatter_impl(k_pool, v_pool, k, v, slots)
+    S, W = tables.shape
+    tables_ext = jnp.concatenate(
+        [tables.astype(jnp.int32), jnp.zeros((1, W), jnp.int32)], axis=0)
+    ck_pool = pls.compress_keys(k_pool, ck_pool, tables_ext, ck_seq, ck_j,
+                                ck_slot, sizes)
+    q0 = q[0]
+    T, H, D = q0.shape
+    kv_heads, bs = k_pool.shape[1], k_pool.shape[2]
+    qd = q0[jnp.minimum(dec_index, T - 1)]                   # [S, H, D]
+    scores = pls.sparse_select_scores(qd, ck_pool, row_slots, row_pos,
+                                      sizes, use_pallas=pallas_select)
+    sel_tables, sel_ctx = pls.selected_tables(scores, row_pos, tables,
+                                              sizes, sel_width)
+    dec_out = grouped_decode_attention(qd, k_pool, v_pool, sel_tables,
+                                       sel_ctx, pallas_attn)
+
+    def chunk(_):
+        qc = _chunk_rows(q0, meta, chunk_rows)
+        r = jnp.arange(chunk_rows, dtype=jnp.int32)
+        t = jnp.where(r < meta[1], meta[5] + r, -1)
+        table = tables_ext[meta[4]]
+        gather = lambda pool: jnp.swapaxes(               # noqa: E731
+            pool[table], 1, 2).reshape(W * bs, kv_heads, D)
+        out = pls.sparse_block_attention(
+            qc.reshape(chunk_rows, kv_heads, H // kv_heads, D), t,
+            gather(k_pool), gather(v_pool), ck_pool[meta[2]], sizes)
+        return out.reshape(chunk_rows, H, D)
+
+    chunk_out = jax.lax.cond(
+        meta[1] > 0, chunk,
+        lambda _: jnp.zeros((chunk_rows, H, D), q.dtype), None)
+    out = _merge_rows(q0, chunk_out, dec_out, meta, dec_index)
+    return out[None], k_pool, v_pool, ck_pool
+
+
+def _lightning_update_impl(q, k, v, pool, dec_index, row_slots, meta, *,
+                           slopes, chunk_rows, use_pallas):
+    """One lightning layer of the ragged step: the one-step form for
+    the decode rows, the chunked form for the prefill chunk (``meta``:
+    flat offset, valid rows, state slot, first-chunk flag), each
+    against its request's slot of ``pool``, in place."""
+    from ...ops import pallas_lightning as pll
+    q0, k0, v0 = q[0], k[0], v[0]
+    T = q0.shape[0]
+    idx = jnp.minimum(dec_index, T - 1)
+    sl = lambda x: _chunk_rows(x, meta, chunk_rows)       # noqa: E731
+    if use_pallas:
+        dec_out, pool = pll.lightning_attention_step(
+            q0[idx], k0[idx], v0[idx], pool, row_slots, slopes)
+        chunk_out, pool = pll.lightning_attention_fwd(
+            sl(q0), sl(k0), sl(v0), pool, meta[2], meta[1], meta[3],
+            slopes)
+    else:
+        dec_out, pool = pll.lightning_step_ref(
+            q0[idx], k0[idx], v0[idx], pool, row_slots, slopes)
+        start = jnp.where(meta[3] > 0, 0.0, 1.0) * pool[meta[2]]
+        chunk_out, state = pll.lightning_chunk_ref(
+            sl(q0), sl(k0), sl(v0), start, slopes, meta[1])
+        pool = pool.at[meta[2]].set(state.astype(pool.dtype))
+    return _merge_rows(q0, chunk_out, dec_out, meta, dec_index)[None], pool
+
+
+class _StatefulLayerCache:
+    """One layer's view of the ragged step, for a layer that keeps
+    per-request state in a slot pool."""
+
+    __slots__ = ("_view", "_layer")
+
+    def __init__(self, view, layer):
+        self._view = view
+        self._layer = layer
+
+
+class SparseLayerCache(_StatefulLayerCache):
+    """A block-sparse layer: paged K/V with grouped KV heads, and the
+    selector's pooled keys in the request's state slot."""
+
+    __slots__ = ()
+
+    @staticmethod
+    def stage(view, sizes, layers, row_slots, row_pos, meta):
+        """What the sparse layers read beside the rows and the chunk:
+        the pooled keys that this step's tokens complete, as (batch
+        row, key index, slot) to a fixed width (a key a decode row and
+        a chunk's share; unused entries name the null sequence and the
+        pad slot).  Returns the step's selected and visible blocks
+        (summed over decode rows, the ``layers`` sparse layers and KV
+        heads) and its dense rows: host arithmetic, since how many
+        blocks a row reads follows from its position, not its scores."""
+        from ...ops.pallas_sparse import selected_count
+        S = len(row_pos)
+
+        def completed(start, n):
+            # key j is whole once token stride * j + kernel - 1 is in
+            t = np.arange(start, start + n) - (sizes.kernel - 1)
+            return t[(t >= 0) & (t % sizes.stride == 0)] // sizes.stride
+
+        keys = [(r, j, row_slots[r]) for r in np.flatnonzero(row_pos >= 0)
+                for j in completed(row_pos[r], 1)]
+        if meta[1]:
+            keys += [(meta[4], j, meta[2])
+                     for j in completed(meta[5], meta[1])]
+        ck = np.zeros((3, S + -(-view.chunk_rows // sizes.stride) + 1),
+                      np.int32)
+        ck[0] = S                                # the null sequence
+        if keys:
+            ck[:, :len(keys)] = np.asarray(keys, np.int32).T
+        for name, value in zip(("ck_seq", "ck_j", "ck_slot"), ck):
+            setattr(view, name, view._stage(name, getattr(view, name),
+                                            value, jnp.int32))
+        contexts = row_pos[row_pos >= 0] + 1
+        counts = [selected_count(c, sizes) for c in contexts]
+        per_row = layers * view.cache.num_heads
+        return {"sparse_blocks_selected": per_row * sum(c[0] for c in counts),
+                "sparse_blocks_visible": per_row * sum(c[1] for c in counts),
+                "sparse_dense_rows": int((contexts <= sizes.dense_len).sum())}
+
+    def attend(self, q, k, v, sizes):
+        """q [1, T, H, D], k/v [1, T, Hkv, D] -> [1, T, H, D]."""
+        from ...ops.pallas_gate import pallas_enabled
+        view = self._view
+        cache = view.cache
+        k_pool, v_pool = cache.layer_pools(self._layer)
+        ck_pool = cache.layer_compressed(self._layer)
+        kv, qv_ = k_pool._value, q._value
+        group = qv_.shape[2] // kv.shape[1]
+        out, new_k, new_v, new_ck = dispatch(
+            "sparse_paged_attention", _sparse_attend_impl,
+            (q, k, v, k_pool, v_pool, ck_pool, view.slot_mapping,
+             view.block_tables, view.dec_index, view.row_slots,
+             view.row_pos, view.chunk_meta, view.ck_seq, view.ck_j,
+             view.ck_slot),
+            dict(sizes=sizes, chunk_rows=view.chunk_rows,
+                 sel_width=sizes.table_width(
+                     cache.table_width * cache.block_size),
+                 pallas_attn=_use_pallas_ragged(
+                     kv.shape[3], kv.shape[2], kv.dtype, group, qv_.dtype),
+                 pallas_select=pallas_enabled("sparse_select")),
+            differentiable=False)
+        k_pool._inplace_update(new_k._value)
+        v_pool._inplace_update(new_v._value)
+        ck_pool._inplace_update(new_ck._value)
+        return out
+
+
+class RecurrentLayerCache(_StatefulLayerCache):
+    """A lightning layer: no K/V, one state a head in the request's
+    slot."""
+
+    __slots__ = ()
+
+    def update(self, q, k, v, slopes):
+        """q/k/v [1, T, H, D] -> [1, T, H, D]; the state pool is
+        updated in place."""
+        from ...ops.pallas_gate import pallas_enabled
+        view = self._view
+        pool = view.cache.layer_state(self._layer)
+        out, new_pool = dispatch(
+            "lightning_state_update", _lightning_update_impl,
+            (q, k, v, pool, view.dec_index, view.row_slots,
+             view.chunk_meta),
+            dict(slopes=tuple(slopes), chunk_rows=view.chunk_rows,
+                 use_pallas=pallas_enabled("lightning_attention")),
+            differentiable=False)
+        pool._inplace_update(new_pool._value)
+        return out
+
+
 class RaggedCacheView:
     """Adapts PagedKVCache to the model for the unified ragged step.
 
@@ -498,9 +726,12 @@ class RaggedCacheView:
 
     mode = "ragged"
 
-    def __init__(self, cache, block_q):
+    def __init__(self, cache, block_q, chunk_rows=None):
         self.cache = cache
         self.block_q = int(block_q)
+        #: rows the step's prefill chunk takes in the flat buffer (the
+        #: layers that treat the chunk apart from the decode rows)
+        self.chunk_rows = chunk_rows
         self.slot_mapping = None   # [T] int32 flat pool slots
         self.block_tables = None   # [S, W] int32
         self.context_lens = None   # [S] int32
@@ -511,8 +742,27 @@ class RaggedCacheView:
         self.last_index = None     # [S, C] int32 flat sampling indices
         self.sample_pos = None     # [S, C] int64 absolute sampling pos
         self.lora = None           # SegmentAdapterState when multi-LoRA on
-        self._layers = [RaggedLayerCache(self, i)
-                        for i in range(cache.num_layers)]
+        # per-request state (models with sparse or recurrent layers)
+        self.dec_index = None      # [S] int32 flat row of a decode token
+        self.row_slots = None      # [S] int32 state slot (0 = pad)
+        self.row_pos = None        # [S] int32 decode position (-1 idle)
+        self.chunk_meta = None     # [6] int32 offset, rows, slot, first,
+        #                            batch row (S = none), start position
+        self.ck_seq = None         # [N] int32 pooled keys to write: row,
+        self.ck_j = None           # [N] int32 index,
+        self.ck_slot = None        # [N] int32 and slot (0 = none)
+        sparse = [spec["sparse_sizes"] for spec in cache.layer_specs
+                  if spec.get("sparse_sizes")]
+        if len(set(sparse)) > 1:
+            raise ValueError("the sparse layers of a model share one "
+                             f"selector's sizes; got {sorted(set(sparse))}")
+        #: the selector's sizes and how many layers select with them
+        self._sparse = (sparse[0], len(sparse)) if sparse else None
+        self._layers = [
+            RecurrentLayerCache(self, i) if spec["kind"] == "recurrent"
+            else SparseLayerCache(self, i) if spec.get("sparse_sizes")
+            else RaggedLayerCache(self, i)
+            for i, spec in enumerate(cache.layer_specs)]
 
     def set_lora(self, state):
         """Attach the multi-LoRA segment state (serving.lora); model
@@ -548,5 +798,20 @@ class RaggedCacheView:
             "last_index", self.last_index, last_index, jnp.int32)
         self.sample_pos = self._stage(
             "sample_pos", self.sample_pos, sample_pos, jnp.int64)
+
+    def stage_state(self, dec_index, row_slots, row_pos, chunk_meta):
+        """Stage what the layers with per-request state read: each
+        decode row's flat index, state slot and position (-1: idle),
+        and the chunk's meta.  The sparse layers stage the rest of
+        their inputs from these; returns what they counted."""
+        for name, value in (("dec_index", dec_index),
+                            ("row_slots", row_slots), ("row_pos", row_pos),
+                            ("chunk_meta", chunk_meta)):
+            setattr(self, name, self._stage(name, getattr(self, name),
+                                            value, jnp.int32))
+        if self._sparse is None:
+            return {}
+        return SparseLayerCache.stage(self, *self._sparse, row_slots,
+                                      row_pos, chunk_meta)
 
     _stage = PagedCacheView._stage

@@ -229,22 +229,24 @@ class GenerationEngine:
         if weight_dtype is not None and str(weight_dtype) == "int8":
             from ...quantization import convert_to_int8
             convert_to_int8(model)  # no-op on already-converted layers
-        num_layers = cfg.num_hidden_layers
-        num_heads = cfg.num_attention_heads
-        head_dim = cfg.hidden_size // num_heads
         self.max_model_len = int(min(
             max_model_len or cfg.max_position_embeddings,
             cfg.max_position_embeddings))
         param = next(iter(model.parameters()))
         if kv_cache_dtype is None:
             kv_cache_dtype = os.environ.get(ENV_KV_DTYPE) or param.dtype
+        self.max_batch = int(max_batch or max_batch_size())
+        # geometry from the model's own per-layer cache spec: which
+        # layers page K/V (and with how many KV heads), which keep a
+        # recurrent state or pooled keys per live request (a state
+        # slot a row of the batch; the cache takes none without them)
         self.cache = PagedKVCache(
-            num_layers, num_heads, head_dim, dtype=kv_cache_dtype,
+            layer_specs=model.cache_spec(), dtype=kv_cache_dtype,
             block_size=block_size, num_blocks=num_blocks,
             max_model_len=self.max_model_len, hbm_fraction=hbm_fraction,
             prefix_cache=prefix_cache, tiering=kv_tiering,
-            host_budget=kv_host_budget, resident_name=resident_name)
-        self.max_batch = int(max_batch or max_batch_size())
+            host_budget=kv_host_budget, resident_name=resident_name,
+            state_slots=self.max_batch)
 
         # unified step geometry: one prefill chunk (padded to whole
         # q-blocks) + one q-block per decode row, ALL in a single
@@ -275,12 +277,25 @@ class GenerationEngine:
         self.spec = SpeculativeConfig.resolve(speculative)
         self.proposer = None
         self.spec_cols = 1
+        if self.spec is not None and self.cache.state_slots:
+            raise ValueError(
+                "speculative decoding rolls rejected drafts back with "
+                "truncate(); a recurrent state or pooled keys cannot be "
+                "rolled back, so this model decodes without it")
         if self.spec is not None:
             self.spec.k = max(1, min(self.spec.k, self.block_q - 1))
             self.spec_cols = self.spec.k + 1
             self.proposer = self.spec.build_proposer(self)
 
-        self._view = RaggedCacheView(self.cache, self.block_q)
+        self._view = RaggedCacheView(self.cache, self.block_q,
+                                     chunk_rows=chunk_pad)
+        # cumulative, under their stats() names: what the steps carried
+        # (decode rows, prompt tokens and the chunks they came in), the
+        # first chunks run for models with per-request state, and what
+        # the layer caches count as they stage (`stage_state`)
+        self._counters = dict.fromkeys((
+            "decode_rows_carried", "prompt_tokens_carried",
+            "prefill_chunks", "state_resets"), 0)
         self._step_fn = paddle.jit.to_static(self._ragged_step)
 
         # fault-tolerance knobs: a per-step wall-clock deadline (the
@@ -653,6 +668,7 @@ class GenerationEngine:
                                    if self._tokens_drafted else 0.0),
                  token_budget=self.token_budget,
                  step_compiles=compiles,
+                 **self._counters,
                  step_timeouts=self._step_timeouts,
                  step_aborts=self._step_aborts,
                  shed_requests=self._shed_requests,
@@ -841,6 +857,10 @@ class GenerationEngine:
         """`_checked_dispatch` under its spans: ``engine:dispatch`` on
         the profiler's clock, and the timeline's ``decode`` /
         ``prefill:chunk`` (what the step carried) inside it."""
+        self._counters["decode_rows_carried"] += len(decodes)
+        if chunk is not None:
+            self._counters["prompt_tokens_carried"] += chunk.length
+            self._counters["prefill_chunks"] += 1
         with contextlib.ExitStack() as stack:
             stack.enter_context(obs.span(
                 "engine:dispatch", boundary=True, step=self._step_idx,
@@ -930,6 +950,8 @@ class GenerationEngine:
         self._view.set_inputs(slots, tables, ctx, positions, seq_ids,
                               q_starts, q_valids, last_index,
                               sample_pos)
+        if self.cache.state_slots:
+            self._stage_state(chunk, decodes)
         if lora_slots is not None:
             self._lora.stage(lora_slots)
         args = self._control_tensors(
@@ -944,6 +966,35 @@ class GenerationEngine:
                 self._last_tokens[rows])
         ids_t = Tensor(ids_dev, _internal=True, stop_gradient=True)
         return ids_t, args, rows_reqs
+
+    def _stage_state(self, chunk, decodes):
+        """What the layers with per-request state read this step: each
+        decode row's flat index, state slot and position, and the
+        chunk's offset, rows, slot, first-chunk flag, batch row and
+        start.  The layer caches stage what only they read from these,
+        and hand back what they counted on the way."""
+        T, S, BQ = self.token_budget, self.max_batch, self.block_q
+        cache = self.cache
+        dec_index = np.full(S, T, np.int32)      # T: dropped on scatter
+        row_slots = np.zeros(S, np.int32)
+        row_pos = np.full(S, -1, np.int32)       # -1: an idle row
+        for i, req in enumerate(decodes):        # packed first, in order
+            dec_index[req.row] = i * BQ
+            row_slots[req.row] = cache.slot(req.id)
+            row_pos[req.row] = cache.length(req.id) - 1
+        # the chunk follows the decode rows in the flat buffer
+        meta = np.asarray([len(decodes) * BQ if chunk is not None else 0,
+                           0, 0, 0, S, 0], np.int32)
+        if chunk is not None:
+            req, start, n = chunk
+            meta[1:] = (n, cache.slot(req.id), start == 0, req.row, start)
+        step = self._view.stage_state(dec_index, row_slots, row_pos, meta)
+        step["state_resets"] = int(meta[3])
+        for name, n in step.items():
+            self._counters[name] = self._counters.get(name, 0) + n
+            if n and obs.enabled():     # "state.resets", "sparse.*"
+                obs.get_registry().counter(
+                    name.replace("_", ".", 1)).inc(n)
 
     # -- the speculative step -------------------------------------------
     def _run_spec_step(self, plan):

@@ -41,6 +41,22 @@ block just de-index it.  ``truncate`` never touches block contents —
 it releases whole blocks refcount-aware, so preemption rollback cannot
 corrupt a prefix another sequence still reads.
 
+**Per-layer kinds and state slots** (``layer_specs=``, a model's
+``cache_spec()``): ``paged_kv`` layers share the block pool and the
+tables above (one geometry: KV heads and head dim, which need not be the
+query heads'); a ``recurrent`` layer keeps no K/V but one fixed-size
+state per live request, and a paged layer with ``sparse_sizes`` also
+keeps one mean-pooled key per ``stride`` tokens per request (the
+block-sparse selector's cache).  Both live in **slot pools**
+``[state_slots + 1, ...]``: slot 0 is the pad slot, ``allocate`` hands a
+request one slot for all such layers (``can_allocate`` counts them:
+slots are a resource the scheduler admits by), its first prefill chunk
+zeroes the state, ``free`` returns the slot, and a requeued request
+recomputes its state as it does its blocks.  A block hash cannot
+restore either, so the prefix cache stands aside for such models
+(``prefix_bypassed`` / ``prefix_cache.bypassed_recurrent``), and a
+sequence with state cannot be exported to another pool.
+
 The pool tensors are ordinary framework Tensors.  The engine's
 ``to_static`` step functions read them (discovered as state) and write
 them via ``_inplace_update`` (mutated state → donated to XLA), so the
@@ -140,20 +156,48 @@ class PagedKVCache:
     device work initiated here is the COW block copy.
     """
 
-    def __init__(self, num_layers, num_heads, head_dim, dtype="float32",
-                 block_size=None, num_blocks=None, max_model_len=None,
-                 hbm_fraction=0.3, register=True, prefix_cache=None,
-                 resident_name=None, tiering=None, host_budget=None):
+    def __init__(self, num_layers=None, num_heads=None, head_dim=None,
+                 dtype="float32", block_size=None, num_blocks=None,
+                 max_model_len=None, hbm_fraction=0.3, register=True,
+                 prefix_cache=None, resident_name=None, tiering=None,
+                 host_budget=None, layer_specs=None, state_slots=None):
         import jax.numpy as jnp
         from ...core.dtypes import to_jax_dtype
         from ...core.tensor import Tensor
 
         from ...ops.pallas_ragged import KV_SCALE_LANES
 
-        self.num_layers = int(num_layers)
-        self.num_heads = int(num_heads)
-        self.head_dim = int(head_dim)
-        self.block_size = int(block_size or kv_block_size())
+        # per-layer kinds (a model's ``cache_spec()``): "paged_kv"
+        # layers share the block pool and the tables (one geometry),
+        # "recurrent" layers keep a fixed-size state per live request
+        # in a slot pool, and a paged layer with "sparse_sizes" (a
+        # block-sparse selector's sizes) also keeps one pooled key per
+        # ``stride`` tokens per request
+        if layer_specs is None:
+            layer_specs = [{"kind": "paged_kv", "num_kv_heads": num_heads,
+                            "head_dim": head_dim}] * int(num_layers)
+        self.layer_specs = [dict(s) for s in layer_specs]
+        paged = [(i, s) for i, s in enumerate(self.layer_specs)
+                 if s["kind"] == "paged_kv"]
+        geometry = {(int(s["num_kv_heads"]), int(s["head_dim"]))
+                    for _, s in paged}
+        if len(geometry) != 1:
+            raise ValueError(
+                "the paged layers of a model share one block pool "
+                f"geometry; got {sorted(geometry) or 'no paged layer'}")
+        #: model layer -> index into the per-paged-layer pools
+        self._kv_index = {i: n for n, (i, _) in enumerate(paged)}
+        self.num_layers = len(paged)
+        (self.num_heads, self.head_dim), = geometry
+        forced = {int(s["block_size"]) for _, s in paged
+                  if s.get("block_size")}
+        if len(forced) > 1 or (forced and block_size
+                               and int(block_size) not in forced):
+            raise ValueError(
+                f"the model's layers fix the KV block size to "
+                f"{sorted(forced)}; the pool was asked for {block_size}")
+        self.block_size = int(block_size or (forced and forced.pop())
+                              or kv_block_size())
         self._jdtype = jnp.dtype(to_jax_dtype(dtype))
         #: int8 pools carry per-slot f32 dequant scale tables
         #: ``[num_blocks, block_size, KV_SCALE_LANES]`` per layer per
@@ -204,6 +248,45 @@ class PagedKVCache:
                             _internal=True, stop_gradient=True)
                 vs.name = f"kv_cache.v_scale.layer{i}"
                 self._scales.append((ks, vs))
+
+        # -- per-request state (slot pools) --------------------------------
+        # slot 0 is the pad slot (idle rows point at it, as padded
+        # tokens point at block 0); a request holds one slot from
+        # admission to its end, across every layer that keeps state
+        stateful = [(i, s) for i, s in enumerate(self.layer_specs)
+                    if s["kind"] == "recurrent"
+                    or s.get("sparse_sizes")]
+        self.state_slots = int(state_slots or 0) if stateful else 0
+        if stateful and not self.state_slots:
+            raise ValueError("layers that keep per-request state need "
+                             "state_slots (one per live request)")
+        self._state = {}        # model layer -> [slots + 1, *state_shape]
+        self._compressed = {}   # model layer -> [slots + 1, Hkv, J, D]
+        for i, spec in stateful:
+            if spec["kind"] == "recurrent":
+                t = Tensor(jnp.zeros(
+                    (self.state_slots + 1,) + tuple(spec["state_shape"]),
+                    jnp.dtype(to_jax_dtype(spec.get("dtype", "float32")))),
+                    _internal=True, stop_gradient=True)
+                t.name = f"kv_cache.state.layer{i}"
+                self._state[i] = t
+            else:
+                # one pooled key per `stride` tokens, to the longest
+                # context, in whole 128-lane tiles
+                keys = spec["sparse_sizes"].num_keys(cap)
+                t = Tensor(jnp.zeros(
+                    (self.state_slots + 1, self.num_heads,
+                     -(-keys // 128) * 128, self.head_dim), self._jdtype),
+                    _internal=True, stop_gradient=True)
+                t.name = f"kv_cache.compressed.layer{i}"
+                self._compressed[i] = t
+        self._free_slots = list(range(self.state_slots, 0, -1))
+        self._slot_of = {}     # seq_id -> state slot
+        if self.state_slots:
+            # a block hash cannot restore a recurrent state or the
+            # selector's pooled keys: the prefix cache stands aside
+            self.prefix_cache = False
+        self.prefix_bypassed = 0   # admissions it stood aside for
 
         self._free = list(range(self.num_blocks - 1, 0, -1))  # pop() → 1
         self._tables = {}      # seq_id -> [block ids]
@@ -274,13 +357,25 @@ class PagedKVCache:
     def pool_bytes(self):
         return self.num_blocks * self.bytes_per_block
 
+    @property
+    def state_pool_bytes(self):
+        """Bytes of the recurrent layers' state slots."""
+        return sum(int(t._value.nbytes) for t in self._state.values())
+
+    @property
+    def compressed_pool_bytes(self):
+        """Bytes of the sparse layers' pooled-key slots."""
+        return sum(int(t._value.nbytes)
+                   for t in self._compressed.values())
+
     def _register_resident(self):
         from ...memory.guard import register_resident
         register_resident(
-            self.resident_name, self.pool_bytes,
+            self.resident_name,
+            self.pool_bytes + self.state_pool_bytes
+            + self.compressed_pool_bytes,
             buffer_ids=lambda: {id(t._value)
-                                for kv in (self._pools + self._scales)
-                                for t in kv})
+                                for t in self.pool_tensors()})
         self._registered = True
         if self.host is not None:
             # host=True: a named line item for triage, NOT charged
@@ -307,18 +402,37 @@ class PagedKVCache:
 
     # -- pool tensors ----------------------------------------------------
     def layer_pools(self, layer):
-        """(k_pool, v_pool) Tensors for one layer."""
-        return self._pools[layer]
+        """(k_pool, v_pool) Tensors for one (model) layer."""
+        return self._pools[self._kv_index[layer]]
 
     def layer_scales(self, layer):
         """(k_scale, v_scale) per-slot dequant tables for one layer
         (int8 pools only; None otherwise)."""
         if not self.quantized:
             return None
-        return self._scales[layer]
+        return self._scales[self._kv_index[layer]]
+
+    def layer_state(self, layer):
+        """A recurrent layer's state pool ``[slots + 1, *state_shape]``."""
+        return self._state[layer]
+
+    def layer_compressed(self, layer):
+        """A sparse layer's pooled-key pool ``[slots + 1, Hkv, J, D]``."""
+        return self._compressed[layer]
 
     def pool_tensors(self):
-        return [t for kv in (self._pools + self._scales) for t in kv]
+        return ([t for kv in (self._pools + self._scales) for t in kv]
+                + list(self._state.values())
+                + list(self._compressed.values()))
+
+    # -- state slots -----------------------------------------------------
+    def slot(self, seq_id):
+        """The state slot a live sequence holds (0: none)."""
+        return self._slot_of.get(seq_id, 0)
+
+    @property
+    def free_state_slots(self):
+        return len(self._free_slots)
 
     # -- allocator -------------------------------------------------------
     @property
@@ -356,6 +470,8 @@ class PagedKVCache:
         admission that consumed them could be preempted right back out
         by the very decode appends it displaced, and the retry would
         livelock."""
+        if self.state_slots and not self._free_slots:
+            return False          # every state slot is held
         chain = self._walk_chain(tokens, num_tokens, adapter=adapter)
         hbm_hits = [ref for _, kind, ref in chain if kind == "hbm"]
         # a HOST hit still consumes a physical block (the promotion
@@ -620,6 +736,8 @@ class PagedKVCache:
         # pool mutation, so a failed admission provably leaks nothing.
         from ...distributed.fault_tolerance.plan import fault_point
         fault_point("serve.alloc_fail")
+        if self.state_slots and not self._free_slots:
+            return False
         chain = self._walk_chain(tokens, num_tokens, adapter=adapter)
         hbm_hits = [ref for _, kind, ref in chain if kind == "hbm"]
         host_slots = [ref for _, kind, ref in chain if kind == "host"]
@@ -681,6 +799,13 @@ class PagedKVCache:
         self._lengths[seq_id] = int(num_tokens)
         if adapter is not None:
             self._seq_adapter[seq_id] = adapter
+        if self.state_slots:
+            # the slot is zeroed by the request's first chunk, not here
+            self._slot_of[seq_id] = self._free_slots.pop()
+            if tokens is not None:
+                self.prefix_bypassed += 1
+                obs.get_registry().counter(
+                    "prefix_cache.bypassed_recurrent").inc()
         cached = len(chain) * self.block_size
         self._cached_len[seq_id] = cached
         if self.prefix_cache and tokens is not None:
@@ -911,6 +1036,11 @@ class PagedKVCache:
         self._lengths.pop(seq_id, None)
         self._cached_len.pop(seq_id, None)
         self._seq_adapter.pop(seq_id, None)
+        slot = self._slot_of.pop(seq_id, None)
+        if slot is not None:
+            # the state is dropped with the slot: a requeued request
+            # recomputes it from its first chunk, as it does its blocks
+            self._free_slots.append(slot)
         for blk in reversed(blocks):
             self._release(blk)
         self._update_gauges()
@@ -934,6 +1064,13 @@ class PagedKVCache:
         return self._host_hit_tokens / max(1, self._lookup_tokens)
 
     # -- cross-pool transfer (disaggregated prefill -> decode) -----------
+    def _no_state_transfer(self):
+        if self.state_slots:
+            raise NotImplementedError(
+                "a sequence with per-request state (recurrent layers, "
+                "pooled keys) cannot move between pools yet: the "
+                "handoff payload carries K/V blocks only")
+
     def export_sequence(self, seq_id):
         """The sequence's paged KV state as a host-side
         :class:`HandoffPayload` — per-layer stacked block data (+ int8
@@ -944,6 +1081,7 @@ class PagedKVCache:
         prefix-indexed for the NEXT request sharing the prompt."""
         from .attention import kv_blocks_gather
         from ...core.pipeline import get_window
+        self._no_state_transfer()
         table = self._tables[seq_id]
         nbytes = len(table) * self.bytes_per_block
         t0 = time.perf_counter()
@@ -970,6 +1108,7 @@ class PagedKVCache:
         is short; payload geometry must match this pool."""
         if seq_id in self._tables:
             raise KeyError(f"sequence {seq_id!r} already allocated")
+        self._no_state_transfer()
         if (int(payload.block_size) != self.block_size
                 or payload.kv_dtype != str(self._jdtype)):
             raise ValueError(
@@ -1085,6 +1224,8 @@ class PagedKVCache:
             used / max(1, self.num_blocks - 1))
         reg.gauge("serving.kv_blocks_shared").set(self.shared_blocks)
         reg.gauge("serving.prefix_hit_rate").set(self.prefix_hit_rate)
+        if self.state_slots:
+            reg.gauge("state.slots_live").set(len(self._slot_of))
         if self.host is not None:
             reg.gauge("serving.host_blocks_used").set(
                 len(self._host_lru))
@@ -1120,6 +1261,11 @@ class PagedKVCache:
             "host_hit_rate": self.host_hit_rate,
             "stale_hash_drops": self.stale_hash_drops,
             "commit_gen": self._commit_gen,
+            "state_slots": self.state_slots,
+            "state_slots_live": len(self._slot_of),
+            "state_pool_bytes": self.state_pool_bytes,
+            "compressed_pool_bytes": self.compressed_pool_bytes,
+            "prefix_bypassed_recurrent": self.prefix_bypassed,
         }
 
     def __repr__(self):

@@ -14,6 +14,7 @@ from .ernie import (ErnieConfig, ErnieModel, ErnieForMaskedLM,
                     ErnieForSequenceClassification)
 from .moe_gpt import (MoEGPTConfig, MoEGPTModel, MoEGPTForCausalLM,
                       MoEGPTPretrainingCriterion)
+from .minicpm_sala import MiniCPMSALAConfig, MiniCPMSALAForCausalLM
 from .generation import GenerationMixin, generate
 
 __all__ = [
@@ -23,5 +24,6 @@ __all__ = [
     "ErnieConfig", "ErnieModel", "ErnieForMaskedLM",
     "ErnieForSequenceClassification",
     "MoEGPTConfig", "MoEGPTModel", "MoEGPTForCausalLM",
-    "MoEGPTPretrainingCriterion", "GenerationMixin", "generate",
+    "MoEGPTPretrainingCriterion", "MiniCPMSALAConfig",
+    "MiniCPMSALAForCausalLM", "GenerationMixin", "generate",
 ]

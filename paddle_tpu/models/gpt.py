@@ -201,6 +201,17 @@ class GPTModel(nn.Layer):
         return x
 
 
+def paged_kv_spec(cfg):
+    """What each layer of a GPT keeps between steps, for the serving
+    engine (inference/serving/engine.py reads its geometry from the
+    model's ``cache_spec()``): paged K/V with as many KV heads as query
+    heads."""
+    return [{"kind": "paged_kv",
+             "num_kv_heads": cfg.num_attention_heads,
+             "head_dim": cfg.hidden_size // cfg.num_attention_heads}
+            for _ in range(cfg.num_hidden_layers)]
+
+
 class GPTForCausalLM(nn.Layer, GenerationMixin):
     def __init__(self, cfg: GPTConfig):
         super().__init__()
@@ -210,6 +221,9 @@ class GPTForCausalLM(nn.Layer, GenerationMixin):
         else:
             self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size,
                                      bias_attr=False)
+
+    def cache_spec(self):
+        return paged_kv_spec(self.gpt.config)
 
     def forward(self, input_ids, cache=None, use_cache=False):
         if use_cache:

@@ -38,7 +38,8 @@ from .. import nn
 from ..nn import functional as F  # noqa: F401 (criterion parity imports)
 from ..nn import initializer as I
 from .generation import GenerationMixin
-from .gpt import GPTAttention, GPTConfig, GPTPretrainingCriterion
+from .gpt import (GPTAttention, GPTConfig, GPTPretrainingCriterion,
+                  paged_kv_spec)
 
 __all__ = [
     "MoEGPTConfig", "MoEMLP", "MoEGPTBlock", "MoEGPTModel",
@@ -319,6 +320,9 @@ class MoEGPTForCausalLM(nn.Layer, GenerationMixin):
         else:
             self.lm_head = nn.Linear(cfg.hidden_size, cfg.vocab_size,
                                      bias_attr=False)
+
+    def cache_spec(self):
+        return paged_kv_spec(self.config)
 
     def forward(self, input_ids, cache=None, use_cache=False):
         if use_cache:

