@@ -1,6 +1,6 @@
 """Prove that the trainer and the serving engine start on the chip.
 
-    python chip_smoke.py            # one TPU chip: six phases
+    python chip_smoke.py            # one TPU chip: seven phases
     python chip_smoke.py --chips 4  # four chips: the sharded phase only
     python chip_smoke.py --phase attention_dropout   # that phase alone
 
@@ -281,6 +281,104 @@ def attention_dropout(cfg, batch, seq, depth=2):
                         "rel_l2_vs_masked_composite": errs,
                         "rel_l2_limit": BF16_REL_L2,
                         "bert_layers": depth,
+                        "loss_step0": on["losses"][0], "ln_vocab": ln_v,
+                        "probe_ok": _probed()}}
+
+
+# ---------------------------------------------------------------------
+# hidden dropout inside the LayerNorm-residual kernels
+# ---------------------------------------------------------------------
+def hidden_dropout(cfg, batch, seq, depth=2):
+    """The LN+residual kernels with the chip's bit source, at ``batch *
+    seq`` rows of ``cfg.hidden_size`` in bf16: the keep rate of the
+    written-out tile stream, forward and the four gradients against the
+    float32 composite under that mask, and a ``depth``-layer BERT step
+    whose every post-norm sublayer took the dropout form.  The cells'
+    ``correct`` runs the forward with dropout off and reads the loss:
+    dropout's own correctness is held here and by the CPU tests."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_fused as pf
+    rows, n, p = batch * seq, cfg.hidden_size, cfg.hidden_dropout_prob
+    seed = jnp.array([20261001], jnp.int32)
+    f32 = jnp.float32
+    with jax.enable_x64(False):
+        keep = pf.layer_norm_residual_dropout_keep(seed, rows, n, p,
+                                                   jnp.bfloat16)
+        other = pf.layer_norm_residual_dropout_keep(seed + 1, rows, n, p,
+                                                    jnp.bfloat16)
+        rate = float(jnp.mean(keep, dtype=f32))
+        sigma = math.sqrt(p * (1 - p) / keep.size)
+        # two draws are independent: both keep with probability (1-p)^2
+        both = float(jnp.mean(keep & other, dtype=f32))
+        br = pf._ln_res_block_rows(rows, n, True, jnp.bfloat16)
+        next_tile = float(jnp.mean(keep[br:] & keep[:-br], dtype=f32)
+                          if rows > br else (1 - p) ** 2)
+        x, r = (jax.random.normal(k, (rows, n), jnp.bfloat16)
+                for k in jax.random.split(jax.random.PRNGKey(SEED)))
+        g = jnp.linspace(0.5, 1.5, n).astype(jnp.bfloat16)
+        b = jnp.linspace(-1.0, 1.0, n).astype(jnp.bfloat16)
+
+        def ref(x, r, g, b):
+            s = jnp.where(keep, x.astype(f32) / (1 - p), 0.0) + r.astype(f32)
+            mu = jnp.mean(s, -1, keepdims=True)
+            var = jnp.mean(jnp.square(s - mu), -1, keepdims=True)
+            return (s - mu) * jax.lax.rsqrt(var + cfg.layer_norm_eps) \
+                * g.astype(f32) + b.astype(f32)
+
+        def fused(x, r, g, b):
+            return pf.fused_layer_norm_residual(
+                x, r, g, b, cfg.layer_norm_eps, dropout_p=p, seed=seed)
+
+        def loss_of(fn):
+            return lambda *a: jnp.sum(jnp.sin(fn(*a).astype(f32)))
+
+        errs = {"fwd": _rel_l2(jax.jit(fused)(x, r, g, b),
+                               jax.jit(ref)(x, r, g, b))}
+        got = jax.jit(jax.grad(loss_of(fused), (0, 1, 2, 3)))(x, r, g, b)
+        want = jax.jit(jax.grad(loss_of(ref), (0, 1, 2, 3)))(x, r, g, b)
+        for name, a, w in zip(("d_x", "d_residual", "d_gamma", "d_beta"),
+                              got, want):
+            errs[name] = _rel_l2(a, w)
+        leaked = float(jnp.sum(jnp.where(keep, 0.0, got[0].astype(f32)) != 0))
+    check(abs(rate - (1 - p)) < 3 * sigma,
+          f"keep rate {rate} not within 3 sigma ({sigma}) of {1 - p}")
+    pair = (1 - p) ** 2
+    pair_sigma = math.sqrt(pair * (1 - pair) / keep.size)
+    for name, got_share in (("another seed", both), ("next tile", next_tile)):
+        check(abs(got_share - pair) < 5 * pair_sigma,
+              f"keep and keep of {name} are both set on {got_share}, "
+              f"independent draws on {pair} (sigma {pair_sigma})")
+    for name, err in errs.items():
+        check(err <= BF16_REL_L2, f"hidden dropout {name} rel L2 {err}")
+    check(leaked == 0, f"{leaked} dropped elements carry a gradient")
+    prev = obs.enable(True)
+    try:
+        on = _bert_static_run(
+            dataclasses.replace(cfg, num_hidden_layers=depth), batch, seq, 1)
+        paths = {k.rpartition("path.")[2]: v for k, v in
+                 obs.get_registry().snapshot()["counters"].items()
+                 if k.startswith("layer_norm_residual.path.")}
+    finally:
+        obs.enable(prev)
+    ln_v = math.log(cfg.vocab_size)
+    check(abs(on["losses"][0] - ln_v) <= 0.05 * ln_v,
+          f"step-0 loss {on['losses'][0]} not within 5% of ln(vocab)")
+    if on["kernels"]:       # on the chip: every sublayer drew in-kernel
+        check(paths == {"dropout": 2 * depth}, f"paths taken: {paths}")
+        check(on["kernels"].get("layer_norm_residual_fwd") == 2 * depth
+              and on["kernels"].get("layer_norm_residual_bwd") == 2 * depth,
+              f"kernels of the step: {on['kernels']}")
+    return {"kernels": on["kernels"],
+            "checked": {"dropout_p": p, "rows": rows, "hidden": n,
+                        "block_rows": br, "keep_rate": rate,
+                        "keep_rate_sigma": sigma,
+                        "keep_and_other_seed": both,
+                        "keep_and_next_tile": next_tile,
+                        "independent": pair, "pair_sigma": pair_sigma,
+                        "rel_l2_vs_masked_composite": errs,
+                        "rel_l2_limit": BF16_REL_L2,
+                        "dropped_with_gradient": leaked,
+                        "paths": paths, "bert_layers": depth,
                         "loss_step0": on["losses"][0], "ln_vocab": ln_v,
                         "probe_ok": _probed()}}
 
@@ -687,6 +785,7 @@ def main(argv=None):
     else:
         phases = [
             ("attention_dropout", attention_dropout, (bert, batch, seq), {}),
+            ("hidden_dropout", hidden_dropout, (bert, batch, seq), {}),
             ("train_static", train_static, (bert, batch, seq), {}),
             ("train_eager", train_eager, (bert_eager, batch, seq), {}),
             ("train_lazy", train_eager, (bert, batch, seq),

@@ -180,16 +180,19 @@ def audit_flash_attention(batch, seq_q, seq_k, heads, head_dim,
 
 
 def audit_layer_norm_residual(rows, hidden, dtype="float32",
-                              direction="fwd"):
+                              direction="fwd", dropout=False):
     """Statically validate the fused layernorm+residual block plan
-    (see ``ops.pallas_fused.ln_residual_block_plan``)."""
+    (see ``ops.pallas_fused.ln_residual_block_plan``); ``dropout``
+    audits the form that draws its mask in the kernels.  Its seed is
+    scalar-prefetched into SMEM, untiled, and so not among the checked
+    operands, like the ragged kernels' block tables."""
     from ..ops.pallas_fused import ln_residual_block_plan
     plan = ln_residual_block_plan(rows, hidden, dtype=dtype,
-                                  direction=direction)
+                                  direction=direction, dropout=dropout)
     report = check_pallas_call(
         plan["operands"], scratch=plan.get("scratch", ()),
-        site=f"layer_norm_residual.{direction}"
-             f"[{np.dtype(dtype).name} rows={rows} n={hidden}]")
+        site=f"layer_norm_residual{'_dropout' if dropout else ''}."
+             f"{direction}[{np.dtype(dtype).name} rows={rows} n={hidden}]")
     report.plan = plan
     return report
 

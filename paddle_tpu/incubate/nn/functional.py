@@ -15,6 +15,7 @@ from ...core.dispatch import dispatch
 from ...core.tensor import Tensor
 
 __all__ = ["fused_linear", "fused_feedforward", "fused_multi_head_attention",
+           "fused_bias_dropout_residual_layer_norm",
            "fused_rms_norm", "fused_layer_norm",
            "fused_rotary_position_embedding", "fused_bias_act", "swiglu",
            "fused_dropout_add", "fused_linear_activation",
@@ -108,6 +109,22 @@ def fused_dropout_add(x, y, p=0.5, training=True, mode="upscale_in_train",
     from ...ops.math import add
 
     return add(dropout(x, p, training=training, mode=mode), y)
+
+
+def fused_bias_dropout_residual_layer_norm(
+        x, residual, bias=None, ln_scale=None, ln_bias=None,
+        dropout_rate=0.5, ln_epsilon=1e-5, training=True,
+        mode="upscale_in_train", name=None):
+    """layer_norm(residual + dropout(x + bias)) over the last axis: on
+    the TPU one kernel with the dropout mask drawn inside it
+    (`nn.functional.fused_residual_layer_norm`)."""
+    from ...nn.functional.norm import fused_residual_layer_norm
+    if bias is not None:
+        from ...ops.math import add
+        x = add(x, bias)
+    return fused_residual_layer_norm(
+        x, residual, [x.shape[-1]], ln_scale, ln_bias, ln_epsilon,
+        dropout_p=dropout_rate, training=training, mode=mode)
 
 
 def fused_rotary_position_embedding(q, k=None, v=None, sin=None, cos=None,

@@ -102,16 +102,17 @@ class BertLayer(nn.Layer):
         self.fc2 = nn.Linear(cfg.intermediate_size, cfg.hidden_size,
                              weight_attr=_weight_attr(cfg))
         self.ln2 = nn.LayerNorm(cfg.hidden_size, epsilon=cfg.layer_norm_eps)
-        self.dropout = nn.Dropout(cfg.hidden_dropout_prob)
+        self.hidden_drop_p = cfg.hidden_dropout_prob
 
     def forward(self, x, attn_mask=None):
-        # post-norm: residual adds fuse into the LN kernel; fc1's
-        # bias+gelu fold into the matmul epilogue (both TPU-gated)
-        x = self.ln1.forward_fused(
-            self.dropout(self.attention(x, attn_mask)), x)
+        # post-norm: hidden dropout and the residual adds fuse into the
+        # LN kernel; fc1's bias+gelu fold into the matmul epilogue (both
+        # TPU-gated)
+        p = self.hidden_drop_p
+        x = self.ln1.forward_fused(self.attention(x, attn_mask), x, p)
         h = F.linear_act(x, self.fc1.weight, self.fc1.bias,
                          act="gelu_tanh")
-        x = self.ln2.forward_fused(self.dropout(self.fc2(h)), x)
+        x = self.ln2.forward_fused(self.fc2(h), x, p)
         return x
 
 

@@ -55,49 +55,118 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05,
                          use_pallas=use_pallas))
 
 
+def _ln_residual_composite(v, r, wb, eps, naxes, has_w, has_b):
+    """layer_norm(v + r) in XLA with the kernel's semantics."""
+    axes = tuple(range(v.ndim - naxes, v.ndim))
+    # the add itself runs in f32 (matching the kernel) so bf16
+    # residual streams don't round twice
+    vf = v.astype(jnp.float32) + r.astype(jnp.float32)
+    mean = jnp.mean(vf, axis=axes, keepdims=True)
+    var = jnp.mean(jnp.square(vf - mean), axis=axes, keepdims=True)
+    out = (vf - mean) * jax.lax.rsqrt(var + eps)
+    out = out.astype(v.dtype)
+    i = 0
+    if has_w:
+        out = out * wb[i]
+        i += 1
+    if has_b:
+        out = out + wb[i]
+    return out
+
+
+def _ln_residual_impl(v, r, *wb, eps, naxes, has_w, has_b,
+                      use_pallas=False):
+    if use_pallas:
+        from ...ops.pallas_fused import fused_layer_norm_residual
+        return fused_layer_norm_residual(v, r, wb[0], wb[1], eps=eps)
+    return _ln_residual_composite(v, r, wb, eps, naxes, has_w, has_b)
+
+
+def _ln_residual_drop_impl(key, v, r, *wb, eps, naxes, has_w, has_b, p,
+                           use_pallas=False):
+    """The op with the draw in it: the kernel, seeded from the call's
+    sub-key, or one bernoulli mask from that sub-key and the composite."""
+    if use_pallas:
+        from ...ops.pallas_fused import fused_layer_norm_residual
+        from .flash_attention import _kernel_seed
+        return fused_layer_norm_residual(v, r, wb[0], wb[1], eps=eps,
+                                         dropout_p=p,
+                                         seed=_kernel_seed(key))
+    keep = jax.random.bernoulli(key, 1.0 - p, v.shape)
+    v = jnp.where(keep, v / (1.0 - p), jnp.zeros((), v.dtype))
+    return _ln_residual_composite(v, r, wb, eps, naxes, has_w, has_b)
+
+
+def _ln_residual_path(dropout, kernel_shaped):
+    """Which implementation this call builds, counted once a build in
+    `layer_norm_residual.path.<dropout|plain|composite.<reason>>`: the
+    kernel takes a call normalized over one axis with weight and bias,
+    with the draw in it or without; anything else ("shape"), or a
+    closed gate, leaves the XLA composite.  Returns whether the kernel
+    runs."""
+    from ...ops.pallas_gate import pallas_enabled
+    if not kernel_shaped:
+        reason = "shape"
+    elif not pallas_enabled("layer_norm_residual_dropout" if dropout
+                            else "layer_norm_residual"):
+        reason = "gate"
+    else:
+        reason = None
+    path = (f"composite.{reason}" if reason
+            else "dropout" if dropout else "plain")
+    from ... import observability as obs
+    obs.get_registry().counter(f"layer_norm_residual.path.{path}").inc()
+    return reason is None
+
+
 def fused_residual_layer_norm(x, residual, normalized_shape, weight=None,
-                              bias=None, epsilon=1e-05, name=None):
-    """layer_norm(x + residual) with the add fused into the norm.
+                              bias=None, epsilon=1e-05, dropout_p=0.0,
+                              training=True, mode="upscale_in_train",
+                              name=None):
+    """layer_norm(dropout(x) + residual) with dropout and add fused into
+    the norm.
 
     The post-norm transformer sublayer epilogue.  On TPU (behind the
     ``layer_norm_residual`` gate) a single Pallas kernel streams x and
     the residual once, adds in f32 and normalizes in the same pass; the
     XLA fallback computes the identical f32 add + f32-stat composite so
     both paths agree bitwise-closely for bf16 inputs.
+
+    With ``dropout_p`` > 0 in training the generator advances once a
+    call, exactly as the `F.dropout` it stands for would, and the
+    kernel draws its keep mask block by block from a seed derived from
+    the call's sub-key, forward and backward: no mask tensor exists.
+    The composite, taken off the TPU, draws one mask from that key.
+    Dropout that is not plain ``upscale_in_train``, or a norm the kernel
+    does not take, runs as `F.dropout` and then this op without it.
     """
     if isinstance(normalized_shape, int):
         normalized_shape = (normalized_shape,)
     naxes = len(tuple(normalized_shape))
-    from ...ops.pallas_gate import pallas_enabled
-    use_pallas = (naxes == 1 and weight is not None and bias is not None
-                  and pallas_enabled("layer_norm_residual"))
-
-    def impl(v, r, *wb, eps, naxes, has_w, has_b, use_pallas=False):
-        if use_pallas:
-            from ...ops.pallas_fused import fused_layer_norm_residual
-            return fused_layer_norm_residual(v, r, wb[0], wb[1], eps=eps)
-        axes = tuple(range(v.ndim - naxes, v.ndim))
-        # the add itself runs in f32 (matching the kernel) so bf16
-        # residual streams don't round twice
-        vf = v.astype(jnp.float32) + r.astype(jnp.float32)
-        mean = jnp.mean(vf, axis=axes, keepdims=True)
-        var = jnp.mean(jnp.square(vf - mean), axis=axes, keepdims=True)
-        out = (vf - mean) * jax.lax.rsqrt(var + eps)
-        out = out.astype(v.dtype)
-        i = 0
-        if has_w:
-            out = out * wb[i]
-            i += 1
-        if has_b:
-            out = out + wb[i]
-        return out
-
+    kernel_shaped = naxes == 1 and weight is not None and bias is not None
+    drop = float(dropout_p)
+    if drop > 0.0 and not (training and kernel_shaped
+                           and mode == "upscale_in_train"):
+        from .common import dropout
+        x, drop = dropout(x, drop, training=training, mode=mode), 0.0
     args = (x, residual) + tuple(t for t in (weight, bias)
                                  if t is not None)
-    return dispatch("fused_residual_layer_norm", impl, args,
-                    dict(eps=float(epsilon), naxes=naxes,
-                         has_w=weight is not None, has_b=bias is not None,
-                         use_pallas=use_pallas))
+    attrs = dict(eps=float(epsilon), naxes=naxes,
+                 has_w=weight is not None, has_b=bias is not None,
+                 use_pallas=_ln_residual_path(drop > 0.0, kernel_shaped))
+    if drop > 0.0:
+        from .common import _rng_op
+        return _rng_op("fused_residual_layer_norm_drop",
+                       _ln_residual_drop_impl, args, dict(attrs, p=drop))
+    return dispatch("fused_residual_layer_norm", _ln_residual_impl, args,
+                    attrs)
+
+
+# Program.clone(for_test=True): the norm still computes, dropout off
+from .common import RNG_INFER_IMPLS as _INFER  # noqa: E402
+
+_INFER["fused_residual_layer_norm_drop"] = (
+    lambda v, r, *wb, p, **attrs: _ln_residual_impl(v, r, *wb, **attrs))
 
 
 def rms_norm(x, weight=None, epsilon=1e-6, name=None):

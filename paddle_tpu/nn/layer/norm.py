@@ -41,13 +41,14 @@ class LayerNorm(Layer):
         return F.layer_norm(x, self._normalized_shape, self.weight,
                             self.bias, self._epsilon)
 
-    def forward_fused(self, x, residual):
-        """layer_norm(x + residual) — the post-norm transformer sublayer
-        epilogue, with the residual add fused into the norm kernel on
+    def forward_fused(self, x, residual, dropout_p=0.0):
+        """layer_norm(dropout(x) + residual) — the post-norm transformer
+        sublayer epilogue, with the residual add and, while training,
+        the dropout at rate ``dropout_p`` fused into the norm kernel on
         TPU (``layer_norm_residual`` gate)."""
         return F.fused_residual_layer_norm(
             x, residual, self._normalized_shape, self.weight, self.bias,
-            self._epsilon)
+            self._epsilon, dropout_p=dropout_p, training=self.training)
 
 
 class RMSNorm(Layer):

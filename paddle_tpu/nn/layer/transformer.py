@@ -19,6 +19,17 @@ __all__ = ["MultiHeadAttention", "TransformerEncoderLayer",
            "TransformerDecoder", "Transformer"]
 
 
+def _post_norm(norm, dropout, x, residual):
+    """norm(residual + dropout(x)), the post-norm sublayer epilogue.
+    A plain Dropout layer rides in the norm kernel as its rate; one over
+    an axis, in another mode or in another training state than the norm
+    runs first, as its own op."""
+    if (dropout.axis is None and dropout.mode == "upscale_in_train"
+            and dropout.training == norm.training):
+        return norm.forward_fused(x, residual, dropout.p)
+    return norm.forward_fused(dropout(x), residual)
+
+
 class MultiHeadAttention(Layer):
     Cache = collections.namedtuple("Cache", ["k", "v"])
     StaticCache = collections.namedtuple("StaticCache", ["k", "v"])
@@ -142,19 +153,18 @@ class TransformerEncoderLayer(Layer):
         else:  # incremental encoding (paddle cache protocol)
             src, new_cache = self.self_attn(src, src, src, src_mask,
                                             cache=cache)
-        src = self.dropout1(src)
         if self.normalize_before:
-            src = residual + src
-        else:  # post-norm: residual add fused into the norm kernel
-            src = self.norm1.forward_fused(src, residual)
+            src = residual + self.dropout1(src)
+        else:  # post-norm: dropout and residual add fused into the norm
+            src = _post_norm(self.norm1, self.dropout1, src, residual)
         residual = src
         if self.normalize_before:
             src = self.norm2(src)
-        src = self.dropout2(self._ffn(src))
+        src = self._ffn(src)
         if self.normalize_before:
-            src = residual + src
+            src = residual + self.dropout2(src)
         else:
-            src = self.norm2.forward_fused(src, residual)
+            src = _post_norm(self.norm2, self.dropout2, src, residual)
         return src if cache is None else (src, new_cache)
 
     def gen_cache(self, src):
@@ -272,11 +282,10 @@ class TransformerDecoderLayer(Layer):
         else:
             tgt, inc_cache = self.self_attn(tgt, tgt, tgt, tgt_mask,
                                             cache=cache[0])
-        tgt = self.dropout1(tgt)
         if self.normalize_before:
-            tgt = residual + tgt
-        else:  # post-norm: residual add fused into the norm kernel
-            tgt = self.norm1.forward_fused(tgt, residual)
+            tgt = residual + self.dropout1(tgt)
+        else:  # post-norm: dropout and residual add fused into the norm
+            tgt = _post_norm(self.norm1, self.dropout1, tgt, residual)
         residual = tgt
         if self.normalize_before:
             tgt = self.norm2(tgt)
@@ -285,19 +294,18 @@ class TransformerDecoderLayer(Layer):
         else:
             tgt, static_cache = self.cross_attn(
                 tgt, memory, memory, memory_mask, cache=cache[1])
-        tgt = self.dropout2(tgt)
         if self.normalize_before:
-            tgt = residual + tgt
+            tgt = residual + self.dropout2(tgt)
         else:
-            tgt = self.norm2.forward_fused(tgt, residual)
+            tgt = _post_norm(self.norm2, self.dropout2, tgt, residual)
         residual = tgt
         if self.normalize_before:
             tgt = self.norm3(tgt)
-        tgt = self.dropout3(self._ffn(tgt))
+        tgt = self._ffn(tgt)
         if self.normalize_before:
-            tgt = residual + tgt
+            tgt = residual + self.dropout3(tgt)
         else:
-            tgt = self.norm3.forward_fused(tgt, residual)
+            tgt = _post_norm(self.norm3, self.dropout3, tgt, residual)
         if cache is None:
             return tgt
         return tgt, (inc_cache, static_cache)

@@ -30,7 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from .pallas_kernels import _ln_bwd_kernel
+from .pallas_kernels import (_dropout_seed, _keep_threshold, _ln_bwd_kernel,
+                             _ln_bwd_tile, _seeded_call, _tile_bits)
 from .pallas_tiles import (_STAT_LANES, _demote_f64, _interpret,
                            _kernel_span, _ln_block_rows, _pad_dim,
                            _round_up, _x32, matmul_accum_blocks)
@@ -40,6 +41,7 @@ __all__ = [
     "fused_layer_norm_residual",
     "fused_linear_act",
     "fused_linear_act_int8",
+    "layer_norm_residual_dropout_keep",
     "ln_residual_block_plan",
     "matmul_epilogue_block_plan",
 ]
@@ -103,19 +105,51 @@ def _act_grad_f32(z, act):
 # Fused layernorm + residual add
 # =====================================================================
 
-def _ln_res_block_rows(rows, n):
-    # the forward streams 4 (br, N) blocks (x, r, out, s) where plain LN
-    # streams 2; halve the row budget so the double-buffered VMEM
-    # estimate stays well under the 16MB ceiling at BERT-base widths
-    return min(_ln_block_rows(rows, n), 256)
+# Most bytes in one streamed block of the form that draws a dropout
+# mask, from the sweep on a TPU v5e at (8192, 768)
+# (scripts/ln_residual_block_sweep.py, PERF.md section 6, PR 29):
+# forward plus backward took 0.173 / 0.133 / 0.120 / 0.112 ms with 64 /
+# 128 / 256 / 512 rows in bfloat16 and 0.286 / 0.264 / 0.261 ms with 64
+# / 128 / 256 in float32, where 512 rows no longer fit VMEM: 768 KiB in
+# both.  The plain form's 256 rows were within 1.2 % of its best there.
+_LN_RES_DROPOUT_BLOCK_BYTES = 768 << 10
 
 
-def _ln_res_fwd_kernel(x_ref, r_ref, g_ref, b_ref, o_ref, s_ref,
-                       mu_ref, rstd_ref, *, eps):
+def _ln_res_block_rows(rows, n, dropout=False, dtype=jnp.float32):
+    """Rows in one block of the LN+residual kernels at (rows, n).  The
+    forward and the backward of a call both take it from here, so the
+    dropout form numbers its mask tiles the same way in both."""
+    if dropout:
+        fit = _LN_RES_DROPOUT_BLOCK_BYTES // (n * jnp.dtype(dtype).itemsize)
+        cap = max(16, min(512, fit // 16 * 16))
+    else:
+        # the forward streams 4 (br, N) blocks (x, r, out, s) where plain
+        # LN streams 2; halve the row budget so the double-buffered VMEM
+        # estimate stays well under the 16MB ceiling at BERT-base widths
+        cap = 256
+    return min(_ln_block_rows(rows, n), cap)
+
+
+def _block_keep(seed_ref, shape, dropout_p):
+    """Keep mask of this program's row block: tile `program_id(0)` of
+    the stream under the call's seed, in the forward, the backward and
+    `layer_norm_residual_dropout_keep` alike."""
+    return _tile_bits(seed_ref[0], pl.program_id(0), shape) < jnp.uint32(
+        _keep_threshold(dropout_p))
+
+
+def _ln_res_fwd_kernel(*refs, eps, dropout_p=0.0):
+    """refs: [seed, if dropout_p], x, r, gamma, beta, out, s, mu, rstd."""
+    if dropout_p:
+        seed_ref, *refs = refs
+    x_ref, r_ref, g_ref, b_ref, o_ref, s_ref, mu_ref, rstd_ref = refs
+    x = x_ref[:].astype(jnp.float32)
+    if dropout_p:
+        x = jnp.where(_block_keep(seed_ref, x.shape, dropout_p),
+                      x * (1.0 / (1.0 - dropout_p)), 0.0)
     # add and statistics both run in f32; the saved sum is stored in
     # the input dtype (the residual stream's own precision)
-    s = (x_ref[:].astype(jnp.float32)
-         + r_ref[:].astype(jnp.float32))                # (block_rows, N)
+    s = x + r_ref[:].astype(jnp.float32)                # (block_rows, N)
     br = s.shape[0]
     mu = jnp.mean(s, axis=-1, keepdims=True)
     sc = s - mu
@@ -129,117 +163,185 @@ def _ln_res_fwd_kernel(x_ref, r_ref, g_ref, b_ref, o_ref, s_ref,
     rstd_ref[:] = jnp.broadcast_to(rstd, (br, _STAT_LANES))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _fused_ln_residual_2d(x, r, gamma, beta, eps):
-    return _fused_ln_residual_2d_fwd(x, r, gamma, beta, eps)[0]
+def _ln_res_drop_bwd_kernel(seed_ref, s_ref, g_ref, mu_ref, rstd_ref,
+                            do_ref, dx_ref, dr_ref, dg_ref, db_ref, *,
+                            dropout_p):
+    """The LN backward on the saved sum is d(residual); d(x) is that
+    under the block's mask, drawn again from the forward's tile."""
+    ds = _ln_bwd_tile(s_ref, g_ref, mu_ref, rstd_ref, do_ref, dg_ref,
+                      db_ref)
+    dr_ref[:] = ds.astype(dr_ref.dtype)
+    dx_ref[:] = jnp.where(_block_keep(seed_ref, ds.shape, dropout_p),
+                          ds * (1.0 / (1.0 - dropout_p)),
+                          0.0).astype(dx_ref.dtype)
+
+
+def _rows(shape):
+    """Block that walks the rows with the grid index."""
+    return pl.BlockSpec(shape, lambda i, *_: (i, 0))
+
+
+def _fixed(shape):
+    """Block every program sees whole: gamma, beta, their gradients."""
+    return pl.BlockSpec(shape, lambda i, *_: (0, 0))
 
 
 @_x32
-def _fused_ln_residual_2d_fwd(x, r, gamma, beta, eps):
+def _ln_res_fwd_call(x, r, gamma, beta, seed, eps, dropout_p):
+    """(out, s, mu, rstd) of the forward kernel; mu and rstd keep their
+    padded rows for the backward."""
     rows, n = x.shape
-    br = _ln_res_block_rows(rows, n)
+    br = _ln_res_block_rows(rows, n, dropout_p > 0.0, x.dtype)
     rows_pad = _round_up(rows, br)
-    xp = _pad_dim(x, 0, rows_pad)
-    rp = _pad_dim(r, 0, rows_pad)
+    row_out = jax.ShapeDtypeStruct((rows_pad, n), x.dtype)
+    stat_out = jax.ShapeDtypeStruct((rows_pad, _STAT_LANES), jnp.float32)
     with _kernel_span("layer_norm_residual", "fwd") as kernel_name:
-        out, s, mu, rstd = pl.pallas_call(
-            functools.partial(_ln_res_fwd_kernel, eps=eps),
-            grid=(rows_pad // br,),
-            in_specs=[
-                pl.BlockSpec((br, n), lambda i: (i, 0)),
-                pl.BlockSpec((br, n), lambda i: (i, 0)),
-                pl.BlockSpec((1, n), lambda i: (0, 0)),
-                pl.BlockSpec((1, n), lambda i: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((br, n), lambda i: (i, 0)),
-                pl.BlockSpec((br, n), lambda i: (i, 0)),
-                pl.BlockSpec((br, _STAT_LANES), lambda i: (i, 0)),
-                pl.BlockSpec((br, _STAT_LANES), lambda i: (i, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((rows_pad, n), x.dtype),
-                jax.ShapeDtypeStruct((rows_pad, n), x.dtype),
-                jax.ShapeDtypeStruct((rows_pad, _STAT_LANES), jnp.float32),
-                jax.ShapeDtypeStruct((rows_pad, _STAT_LANES), jnp.float32),
-            ],
-            interpret=_interpret(),
-            name=kernel_name,
-        )(xp, rp, gamma.reshape(1, n), beta.reshape(1, n))
-    return out[:rows], (s[:rows], gamma, mu, rstd)
+        out, s, mu, rstd = _seeded_call(
+            functools.partial(_ln_res_fwd_kernel, eps=eps,
+                              dropout_p=dropout_p),
+            (rows_pad // br,),
+            [_rows((br, n)), _rows((br, n)), _fixed((1, n)),
+             _fixed((1, n))],
+            [_rows((br, n)), _rows((br, n)), _rows((br, _STAT_LANES)),
+             _rows((br, _STAT_LANES))],
+            [row_out, row_out, stat_out, stat_out], kernel_name, seed,
+            _pad_dim(x, 0, rows_pad), _pad_dim(r, 0, rows_pad),
+            gamma.reshape(1, n), beta.reshape(1, n))
+    return out[:rows], s[:rows], mu, rstd
 
 
 @_x32
-def _fused_ln_residual_2d_bwd(eps, res, do):
-    s, gamma, mu, rstd = res
+def _ln_res_bwd_call(s, gamma, mu, rstd, do, seed, dropout_p):
+    """(d_x, d_residual, dgamma, dbeta); without dropout the first two
+    are one array, the plain LN backward on the saved sum."""
     rows, n = s.shape
-    br = _ln_res_block_rows(rows, n)
+    br = _ln_res_block_rows(rows, n, dropout_p > 0.0, s.dtype)
     rows_pad = _round_up(rows, br)
+    if dropout_p:
+        kernel, row_outs = functools.partial(
+            _ln_res_drop_bwd_kernel, dropout_p=dropout_p), 2
+    else:
+        kernel, row_outs = _ln_bwd_kernel, 1
     sp = _pad_dim(s, 0, rows_pad)
     dop = _pad_dim(do, 0, rows_pad)
+    row_out = jax.ShapeDtypeStruct((rows_pad, n), s.dtype)
+    acc_out = jax.ShapeDtypeStruct((8, n), jnp.float32)
     with _kernel_span("layer_norm_residual", "bwd") as kernel_name:
-        dx, dg_acc, db_acc = pl.pallas_call(
-            _ln_bwd_kernel,
-            grid=(rows_pad // br,),
-            in_specs=[
-                pl.BlockSpec((br, n), lambda i: (i, 0)),
-                pl.BlockSpec((1, n), lambda i: (0, 0)),
-                pl.BlockSpec((br, _STAT_LANES), lambda i: (i, 0)),
-                pl.BlockSpec((br, _STAT_LANES), lambda i: (i, 0)),
-                pl.BlockSpec((br, n), lambda i: (i, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((br, n), lambda i: (i, 0)),
-                pl.BlockSpec((8, n), lambda i: (0, 0)),
-                pl.BlockSpec((8, n), lambda i: (0, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((rows_pad, n), s.dtype),
-                jax.ShapeDtypeStruct((8, n), jnp.float32),
-                jax.ShapeDtypeStruct((8, n), jnp.float32),
-            ],
-            interpret=_interpret(),
-            name=kernel_name,
-        )(sp, gamma.reshape(1, n), mu, rstd, dop)
+        *d_rows, dg_acc, db_acc = _seeded_call(
+            kernel, (rows_pad // br,),
+            [_rows((br, n)), _fixed((1, n)), _rows((br, _STAT_LANES)),
+             _rows((br, _STAT_LANES)), _rows((br, n))],
+            [_rows((br, n))] * row_outs + [_fixed((8, n))] * 2,
+            [row_out] * row_outs + [acc_out] * 2, kernel_name, seed,
+            sp, gamma.reshape(1, n), mu, rstd, dop)
     dgamma = dg_acc[0].astype(gamma.dtype)
     dbeta = db_acc[0].astype(gamma.dtype)
-    dx = dx[:rows]
-    return dx, dx, dgamma, dbeta  # d(x) == d(residual)
+    d_rows = [d[:rows] for d in d_rows]
+    return d_rows[0], d_rows[-1], dgamma, dbeta
+
+
+# A model calls the dropout form once a sublayer with the same shapes:
+# through jitted builders it is traced and lowered once (one Mosaic
+# kernel, N calls to it), as the flash kernels are (PERF.md section 6,
+# PR 27).  The plain form stays inline: its lowered program is the one
+# every eval() path and the serving cells had.
+_ln_res_fwd_call_jit = jax.jit(_ln_res_fwd_call,
+                               static_argnames=("eps", "dropout_p"))
+_ln_res_bwd_call_jit = jax.jit(_ln_res_bwd_call,
+                               static_argnames=("dropout_p",))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _fused_ln_residual_2d(x, r, gamma, beta, seed, eps, dropout_p=0.0):
+    """`seed`: int32[1] when `dropout_p` > 0, else None."""
+    return _fused_ln_residual_2d_fwd(x, r, gamma, beta, seed, eps,
+                                     dropout_p)[0]
+
+
+def _fused_ln_residual_2d_fwd(x, r, gamma, beta, seed, eps, dropout_p):
+    call = _ln_res_fwd_call_jit if dropout_p else _ln_res_fwd_call
+    out, s, mu, rstd = call(x, r, gamma, beta, seed, eps=eps,
+                            dropout_p=dropout_p)
+    return out, (s, gamma, mu, rstd, seed)
+
+
+def _fused_ln_residual_2d_bwd(eps, dropout_p, res, do):
+    s, gamma, mu, rstd, seed = res
+    call = _ln_res_bwd_call_jit if dropout_p else _ln_res_bwd_call
+    return call(s, gamma, mu, rstd, do, seed, dropout_p=dropout_p) + (None,)
 
 
 _fused_ln_residual_2d.defvjp(_fused_ln_residual_2d_fwd,
                              _fused_ln_residual_2d_bwd)
 
 
-def fused_layer_norm_residual(x, residual, gamma, beta, eps=1e-5):
-    """LayerNorm(x + residual) over the last dim, fused; differentiable.
+def fused_layer_norm_residual(x, residual, gamma, beta, eps=1e-5, *,
+                              dropout_p=0.0, seed=None):
+    """LayerNorm(dropout(x) + residual) over the last dim, fused;
+    differentiable.
 
     The residual add, mean/variance, normalize and affine all run in a
     single VMEM pass (one read of x/residual instead of the unfused
     add-then-norm's two), and the backward reuses the plain LN backward
     on the saved sum.
+
+    `dropout_p` (static) > 0 drops elements of `x` inside the kernels:
+    every element draws its own 32-bit word from a stream fixed by the
+    integer `seed` and its row block, is kept with probability 1 - p
+    and scaled by 1 / (1 - p); the backward kernel draws the same words
+    again, so no mask reaches HBM.  `layer_norm_residual_dropout_keep`
+    writes that mask out.  At 0 the kernels hold no generator code.
     """
+    dropout_p, seed = _dropout_seed(dropout_p, seed)
     x, residual, gamma, beta = _demote_f64(x, residual, gamma, beta)
     shape = x.shape
     n = shape[-1]
     out = _fused_ln_residual_2d(x.reshape(-1, n), residual.reshape(-1, n),
-                                gamma, beta, float(eps))
+                                gamma, beta, seed, float(eps), dropout_p)
     return out.reshape(shape)
 
 
+def _ln_res_keep_kernel(seed_ref, keep_ref, *, dropout_p):
+    keep_ref[:] = _block_keep(seed_ref, keep_ref.shape,
+                              dropout_p).astype(keep_ref.dtype)
+
+
+@_x32
+def layer_norm_residual_dropout_keep(seed, rows, n, dropout_p,
+                                     dtype=jnp.float32):
+    """The keep mask `fused_layer_norm_residual(..., dropout_p, seed)`
+    applies to `rows` x `n` inputs of `dtype`, as bool [rows, n]: the
+    same tile stream, written out.  For checking the kernels against a
+    composite under an explicit mask; the training path never builds
+    it."""
+    dropout_p, seed = _dropout_seed(dropout_p, seed)
+    br = _ln_res_block_rows(rows, n, True, dtype)
+    rows_pad = _round_up(rows, br)
+    with _kernel_span("layer_norm_residual", "keep") as kernel_name:
+        keep = _seeded_call(
+            functools.partial(_ln_res_keep_kernel, dropout_p=dropout_p),
+            (rows_pad // br,), [], _rows((br, n)),
+            jax.ShapeDtypeStruct((rows_pad, n), jnp.int32), kernel_name,
+            seed)
+    return keep[:rows].astype(bool)
+
+
 def ln_residual_block_plan(rows, hidden, dtype=jnp.float32,
-                           direction="fwd"):
+                           direction="fwd", dropout=False):
     """The exact block plan the LN+residual kernels use for (rows, N).
 
     Same contract as `flash_block_plan`: per-operand (name, block_shape,
     padded_array_shape, dtype) in pallas_call order, statically
     checkable by `analysis.tiling.check_pallas_call`.  Keep in lockstep
-    with `_fused_ln_residual_2d_fwd` / `_fused_ln_residual_2d_bwd`.
+    with `_ln_res_fwd_call` / `_ln_res_bwd_call`.  ``dropout`` gives the
+    form that draws its mask: its own row cap, the seed as a scalar-
+    prefetch operand (SMEM, untiled, listed under ``scalar_prefetch``)
+    and d(x) beside d(residual) in the backward.
     """
     dtype = jnp.dtype(dtype)
     f32 = jnp.dtype(jnp.float32)
     n = hidden
-    br = _ln_res_block_rows(rows, n)
+    br = _ln_res_block_rows(rows, n, dropout, dtype)
     rows_pad = _round_up(rows, br)
     row_blk = lambda name, dt: (  # noqa: E731 - local table helper
         name, (br, n), (rows_pad, n), dt)
@@ -259,6 +361,7 @@ def ln_residual_block_plan(rows, hidden, dtype=jnp.float32,
             ("gamma", (1, n), (1, n), dtype),
             stat("mu"), stat("rstd"),
             row_blk("do", dtype), row_blk("dx", dtype),
+            *([row_blk("d_residual", dtype)] if dropout else []),
             ("dgamma", (8, n), (8, n), f32),
             ("dbeta", (8, n), (8, n), f32),
         ]
@@ -268,6 +371,8 @@ def ln_residual_block_plan(rows, hidden, dtype=jnp.float32,
         "direction": direction,
         "grid": (rows_pad // br,),
         "block_rows": br,
+        "scalar_prefetch": ((("seed", (1,), jnp.dtype(jnp.int32)),)
+                            if dropout else ()),
         "operands": operands,
         "scratch": (),
     }

@@ -121,14 +121,21 @@ def _probe_softmax_cross_entropy():
     jax.block_until_ready(fn(x))
 
 
-def _probe_layer_norm_residual():
+def _probe_layer_norm_residual(dropout_p=0.0):
     from . import pallas_fused as pf
     x = jnp.zeros((32, 256), jnp.bfloat16)
     g = jnp.ones((256,), jnp.bfloat16)
+    seed = jnp.zeros((1,), jnp.int32) if dropout_p else None
     fn = jax.jit(jax.grad(
         lambda x, r, g, b: pf.fused_layer_norm_residual(
-            x, r, g, b).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3)))
+            x, r, g, b, dropout_p=dropout_p,
+            seed=seed).astype(jnp.float32).sum(), argnums=(0, 1, 2, 3)))
     jax.block_until_ready(fn(x, x, g, g))
+
+
+def _probe_layer_norm_residual_dropout():
+    """The kernels with the generator in them (per-block reseeding)."""
+    _probe_layer_norm_residual(dropout_p=0.1)
 
 
 def _probe_matmul_epilogue():
@@ -267,6 +274,7 @@ _PROBES = {
     "ragged_attention_int8": _probe_ragged_attention_int8,
     "layer_norm": _probe_layer_norm,
     "layer_norm_residual": _probe_layer_norm_residual,
+    "layer_norm_residual_dropout": _probe_layer_norm_residual_dropout,
     "lightning_attention": _probe_lightning_attention,
     "sparse_select": _probe_sparse_select,
     "grouped_matmul": _probe_grouped_matmul,
@@ -301,11 +309,12 @@ def _static_diagnose(kernel):
         return list(tiling.audit_ragged_attention(
             2, 64, 16, num_q_blocks=3, num_blocks=4, table_width=2,
             dtype=jnp.float32, kv_dtype=jnp.int8))
-    if kernel == "layer_norm_residual":
+    if kernel in ("layer_norm_residual", "layer_norm_residual_dropout"):
         diags = []
         for direction in ("fwd", "bwd"):
             diags.extend(tiling.audit_layer_norm_residual(
-                32, 256, dtype=jnp.bfloat16, direction=direction))
+                32, 256, dtype=jnp.bfloat16, direction=direction,
+                dropout=kernel.endswith("_dropout")))
         return diags
     if kernel == "grouped_matmul":
         diags = []

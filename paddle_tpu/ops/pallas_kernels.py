@@ -310,10 +310,11 @@ def _attn_bwd_dkv_kernel(*refs, scale, causal, block_q, sq_real, offset,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _flash_call(kernel, grid, in_specs, out_specs, out_shape, name, seed,
+def _seeded_call(kernel, grid, in_specs, out_specs, out_shape, name, seed,
                 *operands):
-    """The pallas_call of one flash kernel on `operands`; with a `seed`
-    (dropout) it rides in front of them as scalar prefetch."""
+    """The pallas_call of one kernel on `operands`; with a `seed` (the
+    kernel draws a dropout mask) it rides in front of them as scalar
+    prefetch, and every index map takes it as a trailing argument."""
     if seed is None:
         return pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
                               out_specs=out_specs, out_shape=out_shape,
@@ -355,7 +356,7 @@ def _flash_fwd(q, k, v, scale, causal, sq_real, sk_real, block_q, block_k,
     sk_pad = k.shape[1]
     offset = sk_real - sq_real  # causal alignment for cross-length attn
     with _kernel_span("flash_attention", "fwd") as kernel_name:
-        out, lse = _flash_call(
+        out, lse = _seeded_call(
             functools.partial(_attn_fwd_kernel, scale=scale, causal=causal,
                               block_k=block_k, sk_real=sk_real,
                               offset=offset, dropout_p=dropout_p),
@@ -387,7 +388,7 @@ def _flash_bwd(q, k, v, do, out, lse, scale, causal, sq_real, sk_real,
     empty = jnp.logical_or(row >= sq_real, lse <= _NEG_INF / 2)
     lse_safe = jnp.where(empty, jnp.float32(1e30), lse)
     with _kernel_span("flash_attention", "bwd_dq") as kernel_name:
-        dq = _flash_call(
+        dq = _seeded_call(
             functools.partial(_attn_bwd_dq_kernel, scale=scale,
                               causal=causal, block_k=block_k,
                               sk_real=sk_real, offset=offset,
@@ -401,7 +402,7 @@ def _flash_bwd(q, k, v, do, out, lse, scale, causal, sq_real, sk_real,
             jax.ShapeDtypeStruct((bh, sq_pad, d), q.dtype),
             kernel_name, seed, q, k, v, do, lse_safe, delta)
     with _kernel_span("flash_attention", "bwd_dkv") as kernel_name:
-        dk, dv = _flash_call(
+        dk, dv = _seeded_call(
             functools.partial(_attn_bwd_dkv_kernel, scale=scale,
                               causal=causal, block_q=block_q,
                               sq_real=sq_real, offset=offset,
@@ -588,14 +589,15 @@ _flash_attention_bhsd.defvjp(_flash_attention_bhsd_fwd,
 
 
 def _dropout_seed(dropout_p, seed):
-    """(static rate, int32[1] seed or None) of one flash call."""
+    """(static rate, int32[1] seed or None) of one call to a kernel
+    that draws its dropout mask."""
     dropout_p = float(dropout_p)
     if not 0.0 <= dropout_p < 1.0:
         raise ValueError(f"dropout_p must be in [0, 1), got {dropout_p}")
     if dropout_p == 0.0:
         return 0.0, None
     if seed is None:
-        raise ValueError("flash_attention with dropout_p > 0 needs a seed")
+        raise ValueError("dropout_p > 0 needs a seed")
     return dropout_p, jnp.asarray(seed).astype(jnp.int32).reshape((1,))
 
 
@@ -685,8 +687,10 @@ def _ln_fwd_kernel(x_ref, g_ref, b_ref, o_ref, mu_ref, rstd_ref, *, eps):
     rstd_ref[:] = jnp.broadcast_to(rstd, (br, _STAT_LANES))
 
 
-def _ln_bwd_kernel(x_ref, g_ref, mu_ref, rstd_ref, do_ref,
-                   dx_ref, dg_ref, db_ref):
+def _ln_bwd_tile(x_ref, g_ref, mu_ref, rstd_ref, do_ref, dg_ref, db_ref):
+    """One row block of the layer-norm backward: accumulates dgamma and
+    dbeta into their revisited blocks and returns the block's dx in
+    float32."""
     x = x_ref[:].astype(jnp.float32)
     do = do_ref[:].astype(jnp.float32)
     gamma = g_ref[:].astype(jnp.float32)                # (1, N)
@@ -708,8 +712,13 @@ def _ln_bwd_kernel(x_ref, g_ref, mu_ref, rstd_ref, do_ref,
     dxhat = do * gamma
     m1 = jnp.mean(dxhat, axis=-1, keepdims=True)
     m2 = jnp.mean(dxhat * xhat, axis=-1, keepdims=True)
-    dx = (dxhat - m1 - xhat * m2) * rstd
-    dx_ref[:] = dx.astype(dx_ref.dtype)
+    return (dxhat - m1 - xhat * m2) * rstd
+
+
+def _ln_bwd_kernel(x_ref, g_ref, mu_ref, rstd_ref, do_ref,
+                   dx_ref, dg_ref, db_ref):
+    dx_ref[:] = _ln_bwd_tile(x_ref, g_ref, mu_ref, rstd_ref, do_ref,
+                             dg_ref, db_ref).astype(dx_ref.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

@@ -304,8 +304,9 @@ def lint_lora():
 
 def lint_pallas():
     """Fused-suite block plans vs the Mosaic tiling rules: flash
-    attention (fwd + both backward passes), layernorm+residual and
-    matmul-epilogue fusion (fwd + bwd, float and int8-weight), paged
+    attention (fwd + both backward passes), layernorm+residual (plain
+    and with its dropout drawn in-kernel) and matmul-epilogue fusion
+    (fwd + bwd, float and int8-weight), paged
     decode attention, ragged mixed prefill+decode attention (float and
     int8 KV)."""
     import jax.numpy as jnp
@@ -321,9 +322,11 @@ def lint_pallas():
                     dtype=dtype, causal=True, direction=direction)
                 report.extend(r.diagnostics)
         for direction in ("fwd", "bwd"):
-            r = analysis.audit_layer_norm_residual(
-                512, 768, dtype=dtype, direction=direction)
-            report.extend(r.diagnostics)
+            for dropout in (False, True):
+                r = analysis.audit_layer_norm_residual(
+                    8192, 768, dtype=dtype, direction=direction,
+                    dropout=dropout)
+                report.extend(r.diagnostics)
             r = analysis.audit_matmul_epilogue(
                 512, 768, 3072, dtype=dtype, direction=direction)
             report.extend(r.diagnostics)

@@ -505,13 +505,34 @@ class TestFusedSuitePlans:
 
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("direction", ["fwd", "bwd"])
+    def test_ln_residual_dropout_plan_legal(self, dtype, direction):
+        """The form that draws its mask, at the BERT cells' size: the
+        seed rides as scalar prefetch, the backward writes d(x) beside
+        d(residual), and the blocks stay legal and inside VMEM."""
+        report = analysis.audit_layer_norm_residual(
+            8192, 768, dtype=dtype, direction=direction, dropout=True)
+        assert list(report) == [], report.render()
+        plan = report.plan
+        assert plan["scalar_prefetch"] == (("seed", (1,), jnp.int32),)
+        assert "dropout" in report.label
+        names = [o[0] for o in plan["operands"]]
+        plain = analysis.audit_layer_norm_residual(
+            8192, 768, dtype=dtype, direction=direction).plan
+        assert plain["scalar_prefetch"] == ()
+        extra = ["d_residual"] if direction == "bwd" else []
+        assert sorted(names) == sorted(
+            [o[0] for o in plain["operands"]] + extra)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("direction", ["fwd", "bwd"])
     def test_matmul_epilogue_plan_legal(self, dtype, direction):
         report = analysis.audit_matmul_epilogue(
             512, 768, 3072, dtype=dtype, direction=direction)
         assert list(report) == [], report.render()
 
     @pytest.mark.parametrize(
-        "kernel", ["layer_norm_residual", "matmul_epilogue"])
+        "kernel", ["layer_norm_residual", "layer_norm_residual_dropout",
+                   "matmul_epilogue"])
     def test_fused_kernels_force_probe_ok(self, kernel):
         # fwd AND bwd: both probes take a grad through the kernel
         from paddle_tpu.ops import pallas_gate as pg

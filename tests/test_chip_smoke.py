@@ -41,6 +41,20 @@ def test_attention_dropout_tiny():
     assert out["kernels"] == {}
 
 
+def test_hidden_dropout_tiny():
+    """Interpret mode: the hash stands in for the chip's generator; 96
+    rows of 128, one block."""
+    out = chip_smoke.hidden_dropout(BERT, 2, 48, depth=1)
+    c = out["checked"]
+    assert abs(c["keep_rate"] - 0.9) < 3 * c["keep_rate_sigma"]
+    assert set(c["rel_l2_vs_masked_composite"]) == {
+        "fwd", "d_x", "d_residual", "d_gamma", "d_beta"}
+    assert max(c["rel_l2_vs_masked_composite"].values()) < 3e-2
+    assert c["dropped_with_gradient"] == 0
+    # off the chip the gate is closed: the op's composite, once a norm
+    assert c["paths"] == {"composite.gate": 2} and out["kernels"] == {}
+
+
 @pytest.mark.parametrize("lazy_tier", [False, True])
 def test_train_eager_tiny(lazy_tier):
     out = chip_smoke.train_eager(BERT, 4, 32, steps=3,

@@ -105,6 +105,18 @@ def _ln_residual():
             [((ROWS, HID), bf16)] * 2 + [((HID,), bf16)] * 2)
 
 
+def _ln_residual_dropout(dtype):
+    """BERT's hidden dropout as the cells run it: rate 0.1 drawn inside
+    both kernels by the core's generator (float32 under amp O1, which
+    black-lists the op; bfloat16 for a model held in it)."""
+    def fwd(x, r, g, b, seed):
+        return pf.fused_layer_norm_residual(x, r, g, b, dropout_p=0.1,
+                                            seed=seed)
+    return (_sum_grad(fwd, (0, 1, 2, 3)),
+            [((ROWS, HID), dtype)] * 2 + [((HID,), dtype)] * 2
+            + [((1,), i32)])
+
+
 def _matmul_epilogue(k, n, act="gelu_tanh", rows=ROWS):
     return (_sum_grad(lambda x, w, b: pf.fused_linear_act(
                 x, w, b, act), (0, 1, 2)),
@@ -151,6 +163,9 @@ SMOKE_CASES = {
     "flash_dropout_bwd_4x200x12x64": lambda: _flash_dropout("bwd", 4, 200),
     "layer_norm_8192x768": _layer_norm,
     "ln_residual_8192x768": _ln_residual,
+    "ln_residual_dropout_8192x768_f32": lambda: _ln_residual_dropout(f32),
+    "ln_residual_dropout_8192x768_bf16":
+        lambda: _ln_residual_dropout(bf16),
     "matmul_epilogue_8192x768x3072": lambda: _matmul_epilogue(HID, FFN),
     "matmul_epilogue_8192x3072x768": lambda: _matmul_epilogue(FFN, HID),
     "softmax_xent_8192x30522": _xent,
