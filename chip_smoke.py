@@ -351,13 +351,19 @@ def hidden_dropout(cfg, batch, seq, depth=2):
     for name, err in errs.items():
         check(err <= BF16_REL_L2, f"hidden dropout {name} rel L2 {err}")
     check(leaked == 0, f"{leaked} dropped elements carry a gradient")
+    def path_counts():
+        return {k.rpartition("path.")[2]: v for k, v in
+                obs.get_registry().snapshot()["counters"].items()
+                if k.startswith("layer_norm_residual.path.")}
+
     prev = obs.enable(True)
     try:
+        # the counters are the process's: what this step's build added
+        before = path_counts()
         on = _bert_static_run(
             dataclasses.replace(cfg, num_hidden_layers=depth), batch, seq, 1)
-        paths = {k.rpartition("path.")[2]: v for k, v in
-                 obs.get_registry().snapshot()["counters"].items()
-                 if k.startswith("layer_norm_residual.path.")}
+        paths = {k: v - before.get(k, 0) for k, v in path_counts().items()
+                 if v != before.get(k, 0)}
     finally:
         obs.enable(prev)
     ln_v = math.log(cfg.vocab_size)

@@ -33,7 +33,6 @@ from .recompile import (audit_eager_cache, audit_executor_cache,
 from .tiling import (LANE, VMEM_BYTES, audit_flash_attention,
                      audit_grouped_matmul, audit_layer_norm_residual,
                      audit_lora_sgmv, audit_matmul_epilogue,
-                     audit_paged_attention,
                      audit_ragged_attention, check_block_spec,
                      check_pallas_call, estimate_vmem_bytes, min_tile)
 
@@ -48,7 +47,7 @@ __all__ = [
     "audit_grouped_matmul", "audit_host_sync",
     "audit_jaxpr", "audit_layer_norm_residual", "audit_lora_rank",
     "audit_lora_sgmv", "audit_matmul_epilogue",
-    "audit_paged_attention", "audit_ragged_attention",
+    "audit_ragged_attention",
     "audit_routing_balance",
     "audit_sharding", "audit_trace_cache", "check_collective_axis",
     "audit_weak_types", "check_block_spec", "check_collective_payload",

@@ -192,7 +192,8 @@ class DiagnosticReport:
         return [d for d in self._diags if d.severity == WARNING]
 
     def counts(self):
-        """{code: count}, the compact summary bench.py records."""
+        """{code: count}, the compact summary `lint_summary` and
+        scripts/tpu_lint.py report."""
         return dict(Counter(d.code for d in self._diags))
 
     def max_severity(self):

@@ -36,7 +36,6 @@ __all__ = ["audit_fault_sites", "iter_source_files",
 
 # repo-relative scan roots: every tree that references fault sites
 _SCAN_DIRS = ("paddle_tpu", "scripts", "tests")
-_SCAN_FILES = ("bench.py",)
 
 
 def _literal_site(node):
@@ -127,10 +126,6 @@ def iter_source_files(root):
             for fn in sorted(filenames):
                 if fn.endswith(".py"):
                     yield os.path.join(dirpath, fn)
-    for fn in _SCAN_FILES:
-        p = os.path.join(root, fn)
-        if os.path.isfile(p):
-            yield p
 
 
 def audit_fault_sites(root=None, *, report=None, emit=True):

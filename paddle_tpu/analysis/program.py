@@ -6,7 +6,8 @@
 job: ``jax.make_jaxpr`` over the cached pure function + avals, no XLA
 compile) and runs the static audits.  ``analyze_runtime`` inspects the
 live process (timeline events, executable caches) after some steps ran.
-``lint_summary`` is the compact dict bench.py attaches to its JSON.
+``lint_summary`` is the compact dict of a run's diagnostics; no program
+reads it today (tests/test_analysis.py holds its shape).
 """
 from __future__ import annotations
 

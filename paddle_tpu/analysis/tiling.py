@@ -6,7 +6,7 @@ depends on itemsize — (8,128) for 4-byte dtypes, (16,128) for 2-byte,
 (32,128) for 1-byte — and a block dim must either equal the array dim
 or be a multiple of the minimum tile, with the grid covering the array
 exactly.  Violating either is a Mosaic *compile* error on hardware
-(the (1,128) flash-attention block that killed BENCH_r02), which the
+(the (1,128) flash-attention block of round 2's chip run), which the
 interpret-mode CPU path never sees; this module checks the same rules
 statically so the CLI and the gate catch them before dispatch.
 
@@ -23,8 +23,8 @@ from .diagnostics import Diagnostic, DiagnosticReport
 
 __all__ = ["LANE", "VMEM_BYTES", "min_tile", "check_block_spec",
            "check_pallas_call", "estimate_vmem_bytes",
-           "audit_flash_attention", "audit_paged_attention",
-           "audit_ragged_attention", "audit_layer_norm_residual",
+           "audit_flash_attention", "audit_ragged_attention",
+           "audit_layer_norm_residual",
            "audit_matmul_epilogue", "audit_grouped_matmul",
            "audit_lora_sgmv"]
 
@@ -279,20 +279,6 @@ def _flag_int8_relayout(report, plan, *, site):
             site=site,
             hint="round the sublane block dim up to 32 (int8 itemsize "
                  "1 => 32-row minimum tile)"))
-
-
-def audit_paged_attention(num_heads, head_dim, block_size, num_blocks=64,
-                          dtype="float32"):
-    """Statically validate the paged decode-attention block plan."""
-    from ..ops.pallas_kernels import paged_block_plan
-    plan = paged_block_plan(num_heads, head_dim, block_size,
-                            num_blocks=num_blocks, dtype=dtype)
-    report = check_pallas_call(
-        plan["operands"], scratch=plan.get("scratch", ()),
-        site=f"paged_attention[{np.dtype(dtype).name} H={num_heads} "
-             f"D={head_dim} bs={block_size}]")
-    report.plan = plan
-    return report
 
 
 def audit_ragged_attention(num_heads, head_dim, block_size,

@@ -110,7 +110,8 @@ _EVER_ENABLED = False
 # past the cap evict the least-recently-replayed segment.
 _segment_cache: OrderedDict = OrderedDict()
 _SEGMENT_CACHE_MAX = 512
-# capture statistics (read by jit/sot.py reports and bench.py):
+# capture statistics (read by jit/sot.py reports, chip_smoke.py and
+# benchmarks/runners/train_dygraph.py):
 # monotonic counters
 stats = {"flushes": 0, "cache_hits": 0, "compiles": 0, "nodes": 0,
          "evictions": 0, "donated": 0}

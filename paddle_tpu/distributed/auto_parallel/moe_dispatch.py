@@ -27,25 +27,17 @@ transfer under the MXU; the sequential fallback is one
 movement, no arithmetic).  Mode selection reuses
 ``overlap.select_mode`` so ``PADDLE_TPU_OVERLAP`` and the cached probe
 govern MoE dispatch exactly like the TP matmul ring.
-
-`measured_ep_dispatch` drives the ring from the host (the
-``measured_sharded_matmul`` pattern), emitting ``cat="collective"``
-spans carrying ``axis="ep"`` whose lifetime brackets the in-flight hop
-while the resident chunk's expert compute dispatches inside the window
-— that is what ``observability.phase_breakdown()`` turns into
-``overlap_ratio_ep``.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from ... import observability as obs
 from ...ops.pallas_tiles import group_segments, num_group_blocks
 
 __all__ = [
     "dropless_combine", "dropless_dispatch", "dropless_plan",
-    "expert_imbalance", "measured_ep_dispatch", "ring_all_to_all_local",
+    "expert_imbalance", "ring_all_to_all_local",
 ]
 
 
@@ -109,7 +101,7 @@ def dropless_combine(y_rows, rows, topk_val):
 
 def expert_imbalance(counts):
     """Load-imbalance gauge: ``max(counts) / mean(counts)`` (1.0 =
-    perfectly balanced; the bench gauge and the TPU508 threshold)."""
+    perfectly balanced; the TPU508 threshold)."""
     c = jnp.asarray(counts, jnp.float32)
     return jnp.max(c) / jnp.maximum(jnp.mean(c), 1.0)
 
@@ -156,88 +148,4 @@ def ring_all_to_all_local(x, *, axis, axis_size, mode="overlap"):
         recv = jax.lax.ppermute(chunk(me + r), axis, perm)
         out = jax.lax.dynamic_update_slice(
             out, recv, (((me - r) % P) * C,) + (zero,) * (x.ndim - 1))
-    return out
-
-
-# ---------------------------------------------------------------------------
-# Measured host-driven ring (timeline evidence for overlap_ratio_ep)
-# ---------------------------------------------------------------------------
-
-#: (plan token, axis, shape, dtype) -> compiled one-hop rotation
-_rot_cache: dict = {}
-
-
-def _rot_fn(plan, axis, x):
-    from jax.sharding import PartitionSpec as P
-    key = (plan.cache_token(), axis, x.shape, str(x.dtype))
-    fn = _rot_cache.get(key)
-    if fn is not None:
-        return fn
-    size = plan.axis_size(axis)
-    perm = [(i, (i + 1) % size) for i in range(size)]
-    spec = P(*((axis,) + (None,) * (x.ndim - 1)))
-    rot = jax.shard_map(lambda v: jax.lax.ppermute(v, axis, perm),
-                        mesh=plan.mesh, in_specs=spec, out_specs=spec,
-                        check_vma=False)
-    fn = jax.jit(rot).lower(x).compile()
-    _rot_cache[key] = fn
-    return fn
-
-
-def measured_ep_dispatch(xd, expert_fn, *, plan, axis="ep", mode=None):
-    """Drive the expert-dispatch ring step-wise from the host so the
-    timeline records *real* ``axis="ep"`` collective spans.
-
-    ``xd``: the global grouped token buffer, dim 0 sharded over
-    ``axis`` (each of the P ring positions holds one chunk);
-    ``expert_fn(xd)`` is the expert compute over the whole buffer.  Each of the P-1 ring
-    hops is a compiled one-hop ``ppermute`` over the plan's mesh
-    running inside a ``cat="collective"`` span carrying the ``ep`` axis
-    attr; overlapped mode dispatches the resident chunks' expert
-    compute while the hop is in flight — that nesting is what
-    ``phase_breakdown()`` turns into ``overlap_ratio_ep``.  Sequential
-    mode blocks on each hop first, so its ratio is ~0.  Step 0's
-    compute over the un-rotated buffer is the real result (later
-    steps' compute on rotated copies models the pipelined chunk
-    arrival, exactly like ``measured_sharded_matmul``'s replicated
-    partials).
-    """
-    from . import overlap as _overlap
-    if plan is None or plan.is_virtual or plan.axis_size(axis) <= 1:
-        raise ValueError("measured_ep_dispatch needs a real plan with "
-                         f"axis {axis!r} > 1")
-    if mode is None:
-        mode = _overlap.select_mode(plan, axis)
-    P = int(plan.axis_size(axis))
-    xd = jnp.asarray(xd)
-    rot = _rot_fn(plan, axis, xd)
-    nb = int(xd.size) * xd.dtype.itemsize // P
-    out = None
-    cur = xd
-    for r in range(P):
-        if mode == "overlap" and r < P - 1:
-            with obs.span("collective:moe.all_to_all", cat="collective",
-                          axis=axis, bytes=nb, mode=mode, peers=P):
-                nxt = rot(cur)
-                with obs.span("dispatch:moe.expert_chunk",
-                              cat="dispatch", axis=axis, mode=mode):
-                    y = expert_fn(cur)
-                    jax.block_until_ready(y)
-                jax.block_until_ready(nxt)
-        else:
-            nxt = None
-            if r < P - 1:
-                with obs.span("collective:moe.all_to_all",
-                              cat="collective", axis=axis, bytes=nb,
-                              mode=mode, peers=P):
-                    nxt = rot(cur)
-                    jax.block_until_ready(nxt)
-            with obs.span("dispatch:moe.expert_chunk", cat="dispatch",
-                          axis=axis, mode=mode):
-                y = expert_fn(cur)
-                jax.block_until_ready(y)
-        if r == 0:
-            out = y
-        if nxt is not None:
-            cur = nxt
     return out

@@ -443,7 +443,7 @@ class ElasticTrainer:
 
 
 # ---------------------------------------------------------------------------
-# The chaos drill (shared by scripts/chaos_smoke.py, bench.py, tests)
+# The chaos drill (shared by scripts/chaos_smoke.py and the tests)
 # ---------------------------------------------------------------------------
 
 def run_elastic_drill(n_steps=8, kill_step=5, kill_rank=3,

@@ -268,8 +268,9 @@ class PipelineParallelWithInterleave(PipelineParallel):
     n_micro*v + pp - 1 ticks: the fill/drain bubble shrinks from
     (pp-1) full-stage ticks to (pp-1) chunk ticks — the Megatron
     bubble reduction, inside one compiled SPMD program.  See
-    GlobalPipelineEngine(n_virtual=v) and PP_MEMORY.md for the
-    measured bubble/memory table.
+    GlobalPipelineEngine(n_virtual=v); scripts/pp_memory_probe.py
+    prints the bubble/memory table (XLA's memory analysis on virtual
+    CPU devices).
     """
 
     def __init__(self, layers, hcg=None, strategy=None,

@@ -19,11 +19,9 @@ from .kv_cache import (ENV_KV_BLOCK_SIZE, ENV_PREFIX_CACHE,
 from .tiering import (ENV_KV_HOST_BUDGET, ENV_KV_TIERING,
                       HandoffPayload, HostKVPool, kv_host_budget,
                       kv_tiering_enabled)
-from .attention import (PagedCacheView, PagedLayerCache,
-                        RaggedCacheView, RaggedLayerCache,
+from .attention import (RaggedCacheView, RaggedLayerCache,
                         kv_blocks_gather, kv_blocks_scatter,
-                        kv_cache_scatter, paged_attention,
-                        ragged_attention)
+                        kv_cache_scatter, ragged_attention)
 from .scheduler import (ENV_MAX_BATCH, ENV_PREFILL_CHUNK,
                         AdmissionPolicy, ContinuousBatchingScheduler,
                         PrefillChunk, Request, TokenBudgetPolicy,
@@ -44,8 +42,7 @@ from .streaming import (ENV_STREAM_QUEUE, StreamEvent, TokenStream,
 from .errors import (RequestRejected, ServingError, ServingStepTimeout,
                      ServingUnavailable)
 from .engine import (ENV_SHED_DEPTH, ENV_STEP_DEADLINE_MS,
-                     GenerationEngine, ragged_sample_next,
-                     serving_sample_next)
+                     GenerationEngine, ragged_sample_next)
 from .dp import (HEALTHY, PROBATION, UNHEALTHY, DataParallelEngine,
                  ReplicaHealth)
 from .disagg import DisaggregatedEngine
@@ -63,10 +60,8 @@ __all__ = [
     "PagedKVCache", "kv_block_size", "prefix_cache_enabled",
     "ENV_KV_TIERING", "ENV_KV_HOST_BUDGET", "HandoffPayload",
     "HostKVPool", "kv_tiering_enabled", "kv_host_budget",
-    "PagedCacheView", "PagedLayerCache", "RaggedCacheView",
-    "RaggedLayerCache", "kv_blocks_gather", "kv_blocks_scatter",
-    "kv_cache_scatter", "paged_attention",
-    "ragged_attention",
+    "RaggedCacheView", "RaggedLayerCache", "kv_blocks_gather",
+    "kv_blocks_scatter", "kv_cache_scatter", "ragged_attention",
     "ENV_MAX_BATCH", "ENV_PREFILL_CHUNK", "ContinuousBatchingScheduler",
     "PrefillChunk", "Request", "max_batch_size", "prefill_chunk_size",
     "AdmissionPolicy", "TokenBudgetPolicy", "VictimPolicy",
@@ -84,7 +79,7 @@ __all__ = [
     "RequestRejected", "ServingError", "ServingStepTimeout",
     "ServingUnavailable",
     "ENV_SHED_DEPTH", "ENV_STEP_DEADLINE_MS",
-    "GenerationEngine", "ragged_sample_next", "serving_sample_next",
+    "GenerationEngine", "ragged_sample_next",
     "DataParallelEngine", "ReplicaHealth",
     "HEALTHY", "PROBATION", "UNHEALTHY",
     "DisaggregatedEngine",

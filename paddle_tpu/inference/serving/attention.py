@@ -1,33 +1,46 @@
-"""Paged decode attention: gather K/V through block tables.
+"""The engine's attention over a paged K/V pool: scatter, ragged
+attention, and the cache view the model sees.
 
-Two device ops, both pure-jnp impls routed through ``core.dispatch`` so
-they work identically in eager mode, under ``jit.to_static`` replay, and
-in the engine's compiled step functions:
+Device ops, all pure-jnp impls routed through ``core.dispatch`` so they
+work identically in eager mode, under ``jit.to_static`` replay, and in
+the engine's compiled step function:
 
-  ``kv_cache_scatter``   write this step's freshly projected K/V into
-                         the flat block pool at ``slot_mapping``
-                         (functional ``.at[].set`` — the engine's
-                         to_static step donates the pool, so the
-                         compiled update is in-place at 1x memory)
-  ``paged_attention``    one-query-token attention over a sequence's
-                         pool blocks.  On TPU the Pallas kernel
-                         (ops/pallas_kernels.paged_attention) runs
-                         behind the ``pallas_gate`` probe; everywhere
-                         else (and whenever the gate declines) the
-                         pure-XLA gather fallback below executes the
-                         IDENTICAL semantics, so tier-1 CPU tests
-                         exercise the same math the TPU serves.
+  ``kv_cache_scatter``         write this step's freshly projected K/V
+  ``kv_cache_scatter_quant``   into the flat block pool at
+                               ``slot_mapping`` (functional
+                               ``.at[].set`` — the engine's to_static
+                               step donates the pool, so the compiled
+                               update is in-place at 1x memory); the
+                               int8 form quantizes per token and writes
+                               the per-slot scale tables beside it
+  ``ragged_attention``         every scheduled token of a step — one
+                               prefill chunk and the decode rows — in
+                               one flat buffer of block-aligned
+                               segments, each attending through its
+                               sequence's block table.  On TPU the
+                               Pallas kernel (``ops/pallas_ragged``)
+                               runs behind the ``pallas_gate`` probe;
+                               everywhere else (and whenever the gate
+                               declines) ``_ragged_ref`` below executes
+                               the IDENTICAL semantics, so tier-1 CPU
+                               tests exercise the same math the TPU
+                               serves.
 
-The fallback replicates ``_sdpa_ref``'s numerics op-for-op (f32 score
+``_ragged_ref`` replicates ``_sdpa_ref``'s numerics op-for-op (f32 score
 einsum, -1e30 mask, f32 softmax, ``any_visible`` zeroing, f32 output
-einsum) so greedy decoding through the paged path is token-for-token
-identical to the dense-cache path.
+einsum) so greedy decoding through the pool is token-for-token identical
+to the dense-cache path.
 
-``PagedCacheView`` adapts a PagedKVCache to the model's ``cache``
-argument: ``models/gpt.py`` detects it by its ``attend``/"position_ids"
-attributes.  Prefill (mode="prefill") attends densely over the call's
-own K/V (bitwise the training attention); decode (mode="decode")
-attends through block tables.  Both scatter into the pool first.
+``RaggedCacheView`` adapts a PagedKVCache to the model's ``cache``
+argument (``models/gpt.py`` detects it by its ``attend`` /
+``position_ids`` attributes) and owns the per-step driving Tensors.  It
+hands each layer one of three layer caches, chosen from the cache's
+``layer_specs``: ``RaggedLayerCache`` (paged K/V, scatter then ragged
+attention), ``SparseLayerCache`` (paged K/V with grouped heads plus the
+selector's pooled keys in a state slot) and ``RecurrentLayerCache`` (no
+K/V, one state a head in the request's slot).  ``kv_blocks_gather`` /
+``kv_blocks_scatter`` move whole pool blocks to and from the host
+(tiering, disaggregation).
 """
 from __future__ import annotations
 
@@ -41,8 +54,7 @@ from ...core.dispatch import dispatch
 from ...core.tensor import Tensor
 
 __all__ = ["kv_cache_scatter", "kv_cache_scatter_quant",
-           "paged_attention", "ragged_attention",
-           "PagedCacheView", "PagedLayerCache", "RaggedCacheView",
+           "ragged_attention", "RaggedCacheView",
            "RaggedLayerCache", "SparseLayerCache", "RecurrentLayerCache",
            "grouped_decode_attention", "kv_blocks_gather",
            "kv_blocks_scatter"]
@@ -164,70 +176,6 @@ def kv_cache_scatter_quant(k_pool, v_pool, k_scales, v_scales,
 
 
 # ---------------------------------------------------------------------
-# paged attention (decode: one query token per sequence)
-# ---------------------------------------------------------------------
-def _paged_ref(q, k_pool, v_pool, block_tables, context_lens, scale):
-    """Pure-XLA fallback.  q: [B, 1, H, D]; pools [nb, H, bs, D];
-    block_tables [B, W]; context_lens [B].  Mirrors _sdpa_ref's op
-    order exactly (see module doc)."""
-    B, s, H, D = q.shape
-    nb, _, bs, _ = k_pool.shape
-    W = block_tables.shape[1]
-    k = k_pool[block_tables]                       # [B, W, H, bs, D]
-    k = jnp.moveaxis(k, 2, 1).reshape(B, H, W * bs, D)
-    v = v_pool[block_tables]
-    v = jnp.moveaxis(v, 2, 1).reshape(B, H, W * bs, D)
-    qt = jnp.swapaxes(q, 1, 2)                     # [B, H, 1, D]
-    scores = jnp.einsum("bhqd,bhkd->bhqk", qt, k,
-                        preferred_element_type=jnp.float32) * scale
-    pos = jnp.arange(W * bs, dtype=jnp.int32)
-    visible = pos[None, :] < context_lens.astype(jnp.int32)[:, None]
-    scores = jnp.where(visible[:, None, None, :], scores,
-                       jnp.asarray(_NEG_INF, scores.dtype))
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    any_visible = jnp.any(scores > -1e29, axis=-1, keepdims=True)
-    probs = jnp.where(any_visible, probs, jnp.zeros((), probs.dtype))
-    out = jnp.einsum("bhqk,bhkd->bhqd", probs, v,
-                     preferred_element_type=jnp.float32).astype(q.dtype)
-    return jnp.swapaxes(out, 1, 2)                 # [B, 1, H, D]
-
-
-def _paged_attention_impl(q, k_pool, v_pool, block_tables, context_lens,
-                          *, scale, use_pallas):
-    if use_pallas:
-        from ...ops.pallas_kernels import paged_attention as _kernel
-        return _kernel(q, k_pool, v_pool, block_tables, context_lens,
-                       scale=scale)
-    return _paged_ref(q, k_pool, v_pool, block_tables, context_lens,
-                      scale)
-
-
-def _use_pallas_paged(head_dim, block_size, dtype):
-    import numpy as np
-    jd = jnp.dtype(dtype)
-    if jd not in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
-        return False
-    if head_dim > 256 or block_size % 8 != 0:
-        return False
-    from ...ops.pallas_gate import pallas_enabled
-    return pallas_enabled("paged_attention")
-
-
-def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
-                    scale=None):
-    """Decode attention for q [B, 1, H, D] over paged K/V."""
-    head_dim = q.shape[-1]
-    if scale is None:
-        scale = 1.0 / math.sqrt(head_dim)
-    kv = k_pool._value if isinstance(k_pool, Tensor) else k_pool
-    use_pallas = _use_pallas_paged(head_dim, kv.shape[2], kv.dtype)
-    return dispatch("paged_attention", _paged_attention_impl,
-                    (q, k_pool, v_pool, block_tables, context_lens),
-                    dict(scale=float(scale), use_pallas=use_pallas),
-                    differentiable=False)
-
-
-# ---------------------------------------------------------------------
 # ragged mixed prefill+decode attention (one flat token buffer)
 # ---------------------------------------------------------------------
 def _ragged_ref(q, k_pool, v_pool, block_tables, context_lens, seq_ids,
@@ -237,7 +185,7 @@ def _ragged_ref(q, k_pool, v_pool, block_tables, context_lens, seq_ids,
 
     q: [T, H, D] flat block-aligned ragged queries (see
     ops/pallas_ragged.py for the seq_ids/q_starts/q_valids layout;
-    ``seq_ids == S`` is the null segment).  Mirrors `_paged_ref`'s
+    ``seq_ids == S`` is the null segment).  Mirrors `_sdpa_ref`'s
     numerics op-for-op (f32 score einsum, -1e30 mask, f32 softmax,
     any_visible zeroing, f32 output einsum) with per-segment causal
     masking; a fully masked row emits exact zeros.
@@ -351,94 +299,6 @@ def ragged_attention(q, k_pool, v_pool, block_tables, context_lens,
 # ---------------------------------------------------------------------
 # the model-facing cache adapter
 # ---------------------------------------------------------------------
-class PagedLayerCache:
-    """One layer's view: what GPTAttention receives as ``cache``."""
-
-    __slots__ = ("_view", "_layer")
-
-    def __init__(self, view, layer):
-        self._view = view
-        self._layer = layer
-
-    def attend(self, q, k, v, use_flash=True):
-        """Scatter this step's K/V into the pool, then attend.
-
-        q/k/v: [b, s, num_heads, head_dim] Tensors.  Returns the
-        attention output [b, s, num_heads, head_dim]."""
-        view = self._view
-        k_pool, v_pool = view.cache.layer_pools(self._layer)
-        new_k, new_v = kv_cache_scatter(k_pool, v_pool, k, v,
-                                        view.slot_mapping)
-        # thread the updated pool through the surrounding trace: the
-        # engine's to_static step discovers the pools as mutated state
-        # (donated), and eager callers see the write immediately
-        k_pool._inplace_update(new_k._value)
-        v_pool._inplace_update(new_v._value)
-        if view.mode == "prefill":
-            # the whole context is this call's own K/V: dense causal
-            # attention, bitwise the no-cache path (padded tail rows are
-            # below-diagonal garbage nobody reads)
-            from ...nn import functional as F
-            from ...nn.functional.flash_attention import sdp_kernel
-            with sdp_kernel(enable_flash=use_flash):
-                return F.scaled_dot_product_attention(q, k, v,
-                                                      is_causal=True)
-        return paged_attention(q, new_k, new_v, view.block_tables,
-                               view.context_lens)
-
-
-class PagedCacheView:
-    """Adapts PagedKVCache to the model's ``cache`` argument.
-
-    One view per compiled program family (the engine keeps a "prefill"
-    view and a "decode" view): the view owns the per-step driving
-    Tensors whose VALUES the engine swaps before every compiled call —
-    under to_static they are discovered as read-only state and re-read
-    at each dispatch, so one executable serves every step of its shape
-    bucket.
-    """
-
-    def __init__(self, cache, mode):
-        if mode not in ("prefill", "decode"):
-            raise ValueError(f"mode must be prefill|decode, got {mode!r}")
-        self.cache = cache
-        self.mode = mode
-        self.slot_mapping = None   # [tokens] int32 flat pool slots
-        self.block_tables = None   # [b, W] int32
-        self.context_lens = None   # [b] int32
-        self.position_ids = None   # [b, s] int64 absolute positions
-        self._layers = [PagedLayerCache(self, i)
-                        for i in range(cache.num_layers)]
-
-    def __getitem__(self, layer):
-        return self._layers[layer]
-
-    def __len__(self):
-        return len(self._layers)
-
-    def set_inputs(self, slot_mapping, block_tables, context_lens,
-                   position_ids):
-        """Stage this step's driving arrays.  Shapes must stay constant
-        within a compiled bucket (the engine guarantees it)."""
-        self.slot_mapping = self._stage(
-            "slot_mapping", self.slot_mapping, slot_mapping, jnp.int32)
-        self.block_tables = self._stage(
-            "block_tables", self.block_tables, block_tables, jnp.int32)
-        self.context_lens = self._stage(
-            "context_lens", self.context_lens, context_lens, jnp.int32)
-        self.position_ids = self._stage(
-            "position_ids", self.position_ids, position_ids, jnp.int64)
-
-    def _stage(self, name, tensor, value, dtype):
-        val = jnp.asarray(value, dtype)
-        if tensor is None:
-            tensor = Tensor(val, _internal=True, stop_gradient=True)
-            tensor.name = f"kv_cache.{self.mode}.{name}"
-            return tensor
-        tensor._value = val
-        return tensor
-
-
 class RaggedLayerCache:
     """One layer's view of the ragged mixed-batch step."""
 
@@ -713,10 +573,12 @@ class RecurrentLayerCache(_StatefulLayerCache):
 class RaggedCacheView:
     """Adapts PagedKVCache to the model for the unified ragged step.
 
-    Same value-swap staging contract as `PagedCacheView` (one set of
-    driving Tensors, re-read by the single compiled executable every
-    dispatch), extended with the per-q-block segment descriptors and
-    the per-sequence sampling indices the engine's in-graph sampler
+    The view owns the per-step driving Tensors whose VALUES the engine
+    swaps before every compiled call — under to_static they are
+    discovered as read-only state and re-read at each dispatch, so the
+    single compiled executable serves every step.  Beside the pool's
+    slots, tables and positions they carry the per-q-block segment
+    descriptors and the sampling indices the engine's in-graph sampler
     reads (``last_index`` into the flat token dim, ``sample_pos``
     absolute positions for schedule-invariant keys).  Both sampling
     arrays are ``[S, C]``: C sampling *columns* per row — C = 1 for
@@ -814,4 +676,11 @@ class RaggedCacheView:
         return SparseLayerCache.stage(self, *self._sparse, row_slots,
                                       row_pos, chunk_meta)
 
-    _stage = PagedCacheView._stage
+    def _stage(self, name, tensor, value, dtype):
+        val = jnp.asarray(value, dtype)
+        if tensor is None:
+            tensor = Tensor(val, _internal=True, stop_gradient=True)
+            tensor.name = f"kv_cache.{self.mode}.{name}"
+            return tensor
+        tensor._value = val
+        return tensor

@@ -79,8 +79,8 @@ from .scheduler import (ContinuousBatchingScheduler, Request,
 from .speculative import SpeculativeConfig
 from .streaming import TokenStream
 
-__all__ = ["GenerationEngine", "serving_sample_next",
-           "ragged_sample_next", "ENV_STEP_DEADLINE_MS",
+__all__ = ["GenerationEngine", "ragged_sample_next",
+           "ENV_STEP_DEADLINE_MS",
            "ENV_SHED_DEPTH", "ENV_KV_DTYPE", "ENV_WEIGHT_DTYPE"]
 
 #: per-step wall-clock deadline in ms (watchdog; unset/empty disables)
@@ -100,7 +100,7 @@ ENV_WEIGHT_DTYPE = "PADDLE_TPU_WEIGHT_DTYPE"
 # ---------------------------------------------------------------------
 def _filter_and_draw(z, seeds, positions, do_sample, top_k, top_p,
                      temperature):
-    """z [B, V] f32 -> next token [B] int64 (see _sample_next_impl)."""
+    """z [B, V] f32 -> next token [B] int64 (see _ragged_sample_impl)."""
     V = z.shape[-1]
     greedy = jnp.argmax(z, axis=-1)
 
@@ -129,31 +129,6 @@ def _filter_and_draw(z, seeds, positions, do_sample, top_k, top_p,
     return jnp.where(use_sample, sampled, greedy).astype(jnp.int64)
 
 
-def _sample_next_impl(logits, last_index, seeds, positions, do_sample,
-                      top_k, top_p, temperature):
-    """logits [B, S, V] -> next token [B] int64.
-
-    Row r reads logits[r, last_index[r]]; greedy rows take the argmax;
-    sampling rows apply temperature -> top-k -> top-p (the dense
-    baseline's filter order) and draw with a key folded from
-    (seed, absolute position) so the result does not depend on how the
-    scheduler packed or when it ran this row."""
-    B, S, V = logits.shape
-    rows = jnp.arange(B)
-    z = logits[rows, last_index.astype(jnp.int32)].astype(jnp.float32)
-    return _filter_and_draw(z, seeds, positions, do_sample, top_k,
-                            top_p, temperature)
-
-
-def serving_sample_next(logits, last_index, seeds, positions, do_sample,
-                        top_k, top_p, temperature):
-    """Batched next-token selection (see _sample_next_impl)."""
-    return dispatch("serving_sample_next", _sample_next_impl,
-                    (logits, last_index, seeds, positions, do_sample,
-                     top_k, top_p, temperature), {},
-                    differentiable=False)
-
-
 def _ragged_sample_impl(logits, last_index, seeds, positions, do_sample,
                         top_k, top_p, temperature):
     """logits [1, T, V] (flat ragged step) -> next tokens, int64.
@@ -167,8 +142,12 @@ def _ragged_sample_impl(logits, last_index, seeds, positions, do_sample,
     would have used at that absolute position — the result is [S, C].
     Rows/columns that scheduled no sampling token this step
     (mid-prefill, idle, width < C) read a clamped/stale index and
-    produce garbage the engine never drains.  Same filter/draw
-    semantics as `_sample_next_impl`."""
+    produce garbage the engine never drains.
+
+    Greedy rows take the argmax; sampling rows apply temperature ->
+    top-k -> top-p (the dense baseline's filter order) and draw with a
+    key folded from (seed, absolute position), so the result does not
+    depend on how the scheduler packed or when it ran this row."""
     li = last_index.astype(jnp.int32)
     if li.ndim == 1:
         z = logits[0, li].astype(jnp.float32)

@@ -37,11 +37,6 @@ class GPTConfig:
             self.intermediate_size = 4 * self.hidden_size
 
 
-# 1.3B preset (GPT-3 XL shape) used by bench configs
-GPT_1P3B = dict(vocab_size=50304, hidden_size=2048, num_hidden_layers=24,
-                num_attention_heads=16, max_position_embeddings=2048)
-
-
 class GPTAttention(nn.Layer):
     def __init__(self, cfg: GPTConfig):
         super().__init__()

@@ -12,7 +12,8 @@ append-only, for machine consumption (fleet aggregation, test replay —
 
 ``summary(view=...)`` renders the text table (op view: per-name totals;
 step view: per-step per-category totals); ``phase_breakdown()`` is the
-compact dict bench.py attaches to the BENCH json.
+compact dict the smoke scripts (``scripts/obs_smoke.py``,
+``serving_smoke.py``, ``lazy_smoke.py``, ...) and the tests read.
 """
 from __future__ import annotations
 

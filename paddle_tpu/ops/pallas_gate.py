@@ -199,16 +199,6 @@ def _probe_lora_sgmv():
     jax.block_until_ready(fn(z, x, a, b))
 
 
-def _probe_paged_attention():
-    from . import pallas_kernels as pk
-    q = jnp.zeros((2, 1, 2, 64), jnp.float32)
-    pool = jnp.zeros((4, 2, 16, 64), jnp.float32)
-    bt = jnp.array([[1, 2], [3, 0]], jnp.int32)
-    cl = jnp.array([20, 5], jnp.int32)
-    fn = jax.jit(lambda q, kp, vp: pk.paged_attention(q, kp, vp, bt, cl))
-    jax.block_until_ready(fn(q, pool, pool))
-
-
 def _probe_ragged_attention():
     from . import pallas_ragged as pr
     block_q = pr.ragged_q_block(jnp.float32)
@@ -269,7 +259,6 @@ def _probe_sparse_select():
 _PROBES = {
     "flash_attention": _probe_flash_attention,
     "flash_attention_dropout": _probe_flash_attention_dropout,
-    "paged_attention": _probe_paged_attention,
     "ragged_attention": _probe_ragged_attention,
     "ragged_attention_int8": _probe_ragged_attention_int8,
     "layer_norm": _probe_layer_norm,
@@ -298,9 +287,6 @@ def _static_diagnose(kernel):
                 1, 128, 128, 1, 64, dtype=jnp.bfloat16, causal=True,
                 direction=direction))
         return diags
-    if kernel == "paged_attention":
-        return list(tiling.audit_paged_attention(
-            2, 64, 16, num_blocks=4, dtype=jnp.float32))
     if kernel == "ragged_attention":
         return list(tiling.audit_ragged_attention(
             2, 64, 16, num_q_blocks=3, num_blocks=4, table_width=2,

@@ -57,8 +57,6 @@ __all__ = [
     "fused_layer_norm",
     "fused_rms_norm",
     "fused_softmax_cross_entropy",
-    "paged_attention",
-    "paged_block_plan",
 ]
 
 # Shared tile primitives (see ops/pallas_tiles.py): tracing policy,
@@ -69,7 +67,7 @@ from .pallas_tiles import (_NEG_INF, _STAT_LANES, _demote_f64,
                            _interpret, _kernel_span, _lanes,
                            _ln_block_rows, _min_rows, _pad_dim,
                            _round_up, _sane_block, _x32, _xent_blocks,
-                           softmax_scratch, stat_scratch)
+                           stat_scratch)
 
 
 # =====================================================================
@@ -527,29 +525,6 @@ def flash_block_plan(batch, seq_q, seq_k, heads, head_dim,
     return base
 
 
-def paged_block_plan(num_heads, head_dim, block_size, num_blocks=64,
-                     batch=1, table_width=8, dtype=jnp.float32):
-    """The paged decode-attention block plan (see `paged_attention`)."""
-    dtype = jnp.dtype(dtype)
-    f32 = jnp.dtype(jnp.float32)
-    D = head_dim
-    pool = (num_blocks, num_heads, block_size, D)
-    return {
-        "grid": (batch, num_heads, table_width),
-        "operands": [
-            ("q", (1, 1, 1, D), (batch, num_heads, 1, D), dtype),
-            ("k_pool", (1, 1, block_size, D), pool, dtype),
-            ("v_pool", (1, 1, block_size, D), pool, dtype),
-            ("out", (1, 1, 1, D), (batch, num_heads, 1, D), dtype),
-        ],
-        "scratch": (
-            ((_STAT_LANES, D), f32),
-            ((_STAT_LANES, _STAT_LANES), f32),
-            ((_STAT_LANES, _STAT_LANES), f32),
-        ),
-    }
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _flash_attention_bhsd(q, k, v, seed, scale, causal, dropout_p=0.0):
     """`seed`: int32[1] when `dropout_p` > 0, else None."""
@@ -930,7 +905,7 @@ def _xent_fwd_kernel(x_ref, lbl_ref, loss_ref, lse_ref,
     scratch accumulators (running max / sum-exp / picked logit) persist
     across them.  VMEM use is O(block_rows * block_v) regardless of the
     full vocab size — round 2's full-row (br, V) blocks OOMed scoped VMEM
-    at V=30k in the backward (BENCH_r02/r03 crash).
+    at V=30k in the backward.
     """
     j = pl.program_id(1)
     x = x_ref[:].astype(jnp.float32)                   # (block_rows, bv)
@@ -1056,122 +1031,3 @@ def fused_softmax_cross_entropy(logits, labels):
     v = shape[-1]
     loss = _fused_xent_2d(logits.reshape(-1, v), labels.reshape(-1))
     return loss.reshape(shape[:-1])
-
-
-# =====================================================================
-# Paged decode attention (serving)
-# =====================================================================
-
-def _paged_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc_ref, m_ref, l_ref, *, block_size, scale,
-                       w_last):
-    """One (batch, head, table-slot) program over a paged KV pool.
-
-    Scalar-prefetched block tables drive the K/V BlockSpec index maps,
-    so each program streams exactly the block its sequence owns at slot
-    ``w`` — the online-softmax state (acc/m/l) lives in VMEM scratch
-    and survives the sequential innermost grid dim.  The single query
-    row is broadcast to 8 sublanes to satisfy Mosaic's (8, 128) tiling;
-    row 0 is written out at the last slot.
-    """
-    b = pl.program_id(0)
-    w = pl.program_id(2)
-    ctx = cl_ref[b]
-
-    @pl.when(w == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(w * block_size < ctx)
-    def _block():
-        d = q_ref.shape[-1]
-        q = jnp.broadcast_to(q_ref[0, 0].astype(jnp.float32),
-                             (_STAT_LANES, d))          # (8, D)
-        k = k_ref[0, 0].astype(jnp.float32)             # (bs, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (8, bs)
-        col = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-               + w * block_size)
-        mask = col < ctx
-        s = jnp.where(mask, s, _NEG_INF)
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # explicit zero on masked cols (exp(_NEG_INF - m) is 1 when a
-        # block were fully masked; the pl.when guard makes that
-        # unreachable but keep the invariant local)
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = _lanes(alpha * l_ref[:, :1]
-                            + jnp.sum(p, axis=-1, keepdims=True))
-        v = v_ref[0, 0].astype(jnp.float32)             # (bs, D)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = _lanes(m_new)
-
-    @pl.when(w == w_last)
-    def _emit():
-        l = l_ref[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out = acc_ref[...] / l_safe
-        # ctx==0 pad row -> zeros.  Broadcast the f32 stat and compare
-        # at full shape, never broadcast the (rows, 1) predicate: the
-        # Mosaic lowering of a bool broadcast_in_dim expands i1 through
-        # an integer select/compare whose width follows the x64 mode AT
-        # LOWERING TIME (outside the _x32 scope), and the layout pass
-        # aborts on i64 ("bitwidth_ <= 32").
-        out = jnp.where(jnp.broadcast_to(l, out.shape) > 0.0, out, 0.0)
-        o_ref[...] = out[:1][None, None].astype(o_ref.dtype)
-
-
-@_x32
-def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
-                    scale=None):
-    """Decode attention through per-sequence block tables.
-
-    q: [B, 1, H, D]; k_pool/v_pool: [num_blocks, H, block_size, D];
-    block_tables: [B, W] int32 pool block ids (pad entries -> block 0);
-    context_lens: [B] int32 visible tokens per sequence (0 -> zero
-    output, matching the XLA fallback's any_visible semantics).
-    Returns [B, 1, H, D].
-    """
-    q, k_pool, v_pool = _demote_f64(q, k_pool, v_pool)
-    B, s, H, D = q.shape
-    if s != 1:
-        raise ValueError(f"paged_attention decodes 1 token, got s={s}")
-    num_blocks, _, block_size, _ = k_pool.shape
-    W = block_tables.shape[1]
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
-
-    qt = jnp.swapaxes(q, 1, 2)                          # [B, H, 1, D]
-    bt = block_tables.astype(jnp.int32)
-    cl = context_lens.astype(jnp.int32)
-
-    with _kernel_span("paged_attention", "fwd") as kernel_name:
-        out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel, block_size=block_size,
-                          scale=float(scale), w_last=W - 1),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, H, W),
-            in_specs=[
-                pl.BlockSpec((1, 1, 1, D),
-                             lambda b, h, w, bt, cl: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, block_size, D),
-                             lambda b, h, w, bt, cl: (bt[b, w], h, 0, 0)),
-                pl.BlockSpec((1, 1, block_size, D),
-                             lambda b, h, w, bt, cl: (bt[b, w], h, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, 1, D),
-                                   lambda b, h, w, bt, cl: (b, h, 0, 0)),
-            scratch_shapes=softmax_scratch(_STAT_LANES, D),
-        ),
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
-        interpret=_interpret(),
-        name=kernel_name,
-    )(bt, cl, qt, k_pool, v_pool)
-    return jnp.swapaxes(out, 1, 2)                      # [B, 1, H, D]

@@ -18,7 +18,9 @@ per-q-block scalar arrays describe the ragged layout:
 
 K/V live in the PR-5 paged pool ``[num_blocks, H, block_size, D]``;
 ``block_tables [S, W]`` / ``context_lens [S]`` are scalar-prefetched
-exactly like `paged_attention`, and the grid is
+(they drive the K/V BlockSpec index maps, so each program streams
+exactly the block its sequence owns at table slot ``w``), and the
+grid is
 
     (num_q_blocks, num_heads, W)     w innermost, sequential
 
@@ -31,8 +33,7 @@ ragged segment: row ``r`` of q-block ``i`` sees KV position ``c`` iff
 which makes a decode row (query_len 1, start ``ctx-1``) and a prefill
 chunk row fall out of the same predicate.  A fully masked row keeps
 ``l == 0`` and emits exact zeros — the same any-visible semantics as
-the XLA fallback (`serving/attention._ragged_ref`) and the dense paged
-kernel.
+the XLA fallback (`serving/attention._ragged_ref`).
 
 Gated through ``pallas_gate`` ("ragged_attention" probe);
 `ragged_block_plan` exports the exact specs for
@@ -181,7 +182,7 @@ def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
         # (bq, 1) predicate: Mosaic lowers a bool broadcast_in_dim
         # through an integer select/compare whose width follows the x64
         # mode at LOWERING time (outside _x32) and aborts on i64
-        # ("bitwidth_ <= 32") — see _paged_attn_kernel.
+        # ("bitwidth_ <= 32"); compare at full shape instead.
         out = jnp.where(jnp.broadcast_to(l, out.shape) > 0.0, out, 0.0)
         o_ref[...] = out[None].astype(o_ref.dtype)
 
@@ -301,8 +302,8 @@ def ragged_block_plan(num_heads, head_dim, block_size, num_q_blocks=4,
                       dtype=jnp.float32, kv_dtype=None):
     """The ragged mixed-batch attention block plan (see
     `ragged_paged_attention`).  Scalar-prefetch operands (block tables,
-    context lens, segment descriptors) are untiled and omitted, like
-    `paged_block_plan`.
+    context lens, segment descriptors) live whole in SMEM, have no
+    BlockSpec to audit, and are omitted.
 
     ``kv_dtype=int8`` exports the int8-pool variant: int8 k/v blocks
     plus the two (1, block_size, KV_SCALE_LANES) f32 per-slot scale

@@ -2,7 +2,7 @@
 
 ThunderKittens (arxiv 2410.20399) argues a small set of reusable
 tile/layout primitives covers the fast-kernel design space; this module
-is that layer for the ~9-kernel suite (flash, paged, ragged, fused
+is that layer for the kernel suite (flash, ragged, fused
 LN/RMS/xent, matmul-epilogue, grouped-expert).  Everything here is
 shape/layout/tracing policy — no kernel bodies:
 
@@ -191,7 +191,7 @@ def softmax_scratch(rows, width):
     """The acc/m/l VMEM triplet of an online-softmax accumulation:
     (rows, width) f32 weighted-value accumulator plus (rows,
     _STAT_LANES) running max and running sum-exp, persisting across a
-    sequential innermost grid dim (paged/ragged attention pattern)."""
+    sequential innermost grid dim (the ragged attention pattern)."""
     return [
         pltpu.VMEM((rows, width), jnp.float32),
         pltpu.VMEM((rows, _STAT_LANES), jnp.float32),

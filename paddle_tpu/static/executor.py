@@ -192,7 +192,7 @@ class Executor:
     def last_memory_estimate(self):
         """The memory guard's pre-flight estimate for the most recently
         compiled executable (run or run_steps), or None when no guard
-        analysis ran — bench.py records this in the BENCH json."""
+        analysis ran (read by tests/test_memory_guard.py alone)."""
         return self._last_estimate
 
     def _prologue(self, program, feed, fetch_list, n_steps,

@@ -4,7 +4,7 @@
     python scripts/fusion_smoke.py [--json]
 
 Force-probes each kernel registered with ``pallas_gate`` (flash
-attention, paged attention, layer_norm, layer_norm+residual,
+attention, ragged attention, layer_norm, layer_norm+residual,
 matmul-epilogue, rms_norm, softmax cross-entropy) — fwd AND bwd where
 the probe takes a grad — without needing a TPU, then prints the
 ``probe_report()`` outcome and each probe's wall time (interpret mode

@@ -17,7 +17,7 @@ Lints the bundled models without needing a TPU:
   * **lora**  — multi-LoRA serving: adapter-store working set replayed
     through the LRU policy (TPU509), rank vs the dtype sublane floor
     (TPU510), and the segmented SGMV epilogue's fwd/bwd block plans;
-  * **pallas** — flash / paged attention block plans checked against the
+  * **pallas** — flash / ragged attention block plans checked against the
     Mosaic tiling rules (``analysis.tiling``), no kernel launch;
   * **sharding** — built-in BERT/GPT partition-rule sets audited against
     virtual ``dp=2,tp=2`` / ``fsdp=2`` meshes (TPU501 rule miss,
@@ -306,9 +306,8 @@ def lint_pallas():
     """Fused-suite block plans vs the Mosaic tiling rules: flash
     attention (fwd + both backward passes), layernorm+residual (plain
     and with its dropout drawn in-kernel) and matmul-epilogue fusion
-    (fwd + bwd, float and int8-weight), paged
-    decode attention, ragged mixed prefill+decode attention (float and
-    int8 KV)."""
+    (fwd + bwd, float and int8-weight), ragged mixed prefill+decode
+    attention (float and int8 KV)."""
     import jax.numpy as jnp
     from paddle_tpu import analysis
     from paddle_tpu.analysis.diagnostics import DiagnosticReport, record
@@ -334,10 +333,6 @@ def lint_pallas():
                 512, 768, 3072, dtype=dtype, direction=direction,
                 weight_dtype=jnp.int8)
             report.extend(r.diagnostics)
-    r = analysis.audit_paged_attention(num_heads=8, head_dim=64,
-                                       block_size=16, num_blocks=64,
-                                       dtype=jnp.bfloat16)
-    report.extend(r.diagnostics)
     for dtype in (jnp.float32, jnp.bfloat16):
         r = analysis.audit_ragged_attention(num_heads=8, head_dim=64,
                                             block_size=16,
@@ -451,8 +446,8 @@ def lint_faults():
     tree references through fault_point()/FaultEvent/FaultPlan.add or a
     compact parse()/inject() spec must match a FAULT_SITES registry
     pattern, and every registry pattern must have at least one
-    fault_point() behind it.  Pure AST over paddle_tpu/, scripts/,
-    tests/ and bench.py — no scanned module is imported."""
+    fault_point() behind it.  Pure AST over paddle_tpu/, scripts/
+    and tests/ — no scanned module is imported."""
     from paddle_tpu.analysis.fault_lint import audit_fault_sites
     return audit_fault_sites()
 

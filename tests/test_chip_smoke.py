@@ -41,9 +41,17 @@ def test_attention_dropout_tiny():
     assert out["kernels"] == {}
 
 
-def test_hidden_dropout_tiny():
+@pytest.mark.parametrize("counted_before", [0, 3])
+def test_hidden_dropout_tiny(counted_before):
     """Interpret mode: the hash stands in for the chip's generator; 96
-    rows of 128, one block."""
+    rows of 128, one block.  The paths reported are the phase's own,
+    whatever the process counted before it."""
+    from paddle_tpu import observability as obs
+    prev = obs.enable(True)
+    for path in ("plain", "composite.gate"):
+        obs.get_registry().counter(
+            f"layer_norm_residual.path.{path}").inc(counted_before)
+    obs.enable(prev)
     out = chip_smoke.hidden_dropout(BERT, 2, 48, depth=1)
     c = out["checked"]
     assert abs(c["keep_rate"] - 0.9) < 3 * c["keep_rate_sigma"]

@@ -370,30 +370,6 @@ def test_xent_multi_vocab_block():
     assert float(jnp.abs(gp[3]).sum()) == 0.0  # ignored row: zero grad
 
 
-def test_paged_attention_kernel_matches_fallback():
-    """Serving decode kernel (scalar-prefetched block tables) vs the
-    pure-XLA gather fallback, including a partially filled block and a
-    ctx==0 padded row (must emit exact zeros, not NaN)."""
-    from paddle_tpu.inference.serving.attention import _paged_ref
-
-    B, H, D, bs, nb, W = 3, 4, 32, 16, 10, 4
-    kq, kk, kv = jax.random.split(jax.random.PRNGKey(20), 3)
-    q = jax.random.normal(kq, (B, 1, H, D), jnp.float32)
-    k_pool = jax.random.normal(kk, (nb, H, bs, D), jnp.float32)
-    v_pool = jax.random.normal(kv, (nb, H, bs, D), jnp.float32)
-    tables = jnp.asarray(np.array([[1, 2, 3, 4],
-                                   [5, 6, 0, 0],
-                                   [7, 0, 0, 0]], np.int32))
-    ctx = jnp.asarray(np.array([60, 17, 0], np.int32))
-
-    out = pk.paged_attention(q, k_pool, v_pool, tables, ctx)
-    ref = _paged_ref(q, k_pool, v_pool, tables, ctx, 1.0 / D ** 0.5)
-    assert out.shape == (B, 1, H, D)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
-    assert float(jnp.abs(out[2]).sum()) == 0.0
-
-
 # ---------------------------------------------------------------------
 # Ragged mixed prefill+decode attention (pallas_ragged)
 # ---------------------------------------------------------------------

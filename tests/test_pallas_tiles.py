@@ -22,8 +22,7 @@ _REBOUND = [
                       "_interpret", "_kernel_span", "_lanes",
                       "_ln_block_rows", "_min_rows", "_pad_dim",
                       "_round_up", "_sane_block", "_x32",
-                      "_xent_blocks", "softmax_scratch",
-                      "stat_scratch"]),
+                      "_xent_blocks", "stat_scratch"]),
     (pallas_fused, ["_STAT_LANES", "_demote_f64", "_interpret",
                     "_kernel_span", "_ln_block_rows", "_pad_dim",
                     "_round_up", "_x32", "matmul_accum_blocks"]),

@@ -442,25 +442,6 @@ def _load_script(fname, modname):
     return mod
 
 
-def test_bench_gate_greedy_match_zero_tolerance():
-    """bench_gate refuses any capture whose greedy-match drops below
-    last-good — even inside the throughput threshold — while equal or
-    better passes."""
-    gate = _load_script("bench_gate.py", "bench_gate_quant")
-
-    def payload(match):
-        return {"metric": "x_tokens_per_sec", "value": 100.0,
-                "extra_metrics": {"gpt_int8_greedy_match": match}}
-
-    assert "gpt_int8_greedy_match" in gate.gated_metrics(payload(0.99))
-    reg, _ = gate.compare(payload(0.99), payload(0.98), threshold=0.05)
-    assert "gpt_int8_greedy_match" in reg
-    reg, _ = gate.compare(payload(0.99), payload(0.99), threshold=0.05)
-    assert not reg
-    reg, _ = gate.compare(payload(0.99), payload(1.0), threshold=0.05)
-    assert not reg
-
-
 # ---------------------------------------------------------------------
 # CI gate: the quant smoke runs green inside tier-1
 # ---------------------------------------------------------------------

@@ -149,15 +149,19 @@ def test_kv_cache_resident_line_item(monkeypatch):
 
 
 # ---------------------------------------------------------------------
-# paged attention fallback vs dense attention
+# ragged attention's reference vs dense attention
 # ---------------------------------------------------------------------
 def test_paged_attention_matches_dense():
+    """`_ragged_ref` over one-token segments (every row a decode step)
+    reading through block tables, against dense single-query attention
+    over each sequence's own prefix."""
     import jax.numpy as jnp
-    from paddle_tpu.inference.serving.attention import _paged_ref
+    from paddle_tpu.inference.serving.attention import _ragged_ref
     from paddle_tpu.nn.functional.flash_attention import _sdpa_ref
+    from paddle_tpu.ops.pallas_ragged import ragged_segments
 
     rng = np.random.RandomState(3)
-    H, D, bs = 4, 16, 4
+    H, D, bs, block_q = 4, 16, 4, 8
     ctxs = [9, 3, 1]
     W = 4
     kd = rng.randn(len(ctxs), max(ctxs), H, D).astype(np.float32)
@@ -176,18 +180,27 @@ def test_paged_attention_matches_dense():
             blk, off = tables[i, t // bs], t % bs
             kp[blk, :, off] = kd[i, t]
             vp[blk, :, off] = vd[i, t]
-    out = _paged_ref(jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-                     jnp.asarray(tables), jnp.asarray(np.array(ctxs)),
-                     1.0 / np.sqrt(D))
+    # one token a sequence: each fills row 0 of a q-block of its own
+    sid, qs, qv, offsets, rows = ragged_segments([1] * len(ctxs), ctxs,
+                                                 block_q)
+    flat = np.zeros((rows, H, D), np.float32)
+    flat[offsets] = q[:, 0]
+    out = _ragged_ref(jnp.asarray(flat), jnp.asarray(kp), jnp.asarray(vp),
+                      jnp.asarray(tables), jnp.asarray(np.array(ctxs)),
+                      jnp.asarray(sid), jnp.asarray(qs), jnp.asarray(qv),
+                      block_q, 1.0 / np.sqrt(D))
     for i, ctx in enumerate(ctxs):
         # dense single-query attention over that sequence's prefix
         ref = _sdpa_ref(jnp.asarray(q[i:i + 1]),
                         jnp.asarray(kd[i:i + 1, :ctx]),
                         jnp.asarray(vd[i:i + 1, :ctx]),
                         None, False, 1.0 / np.sqrt(D))
-        np.testing.assert_allclose(np.asarray(out[i]),
-                                   np.asarray(ref[0]), rtol=2e-5,
+        np.testing.assert_allclose(np.asarray(out[offsets[i]]),
+                                   np.asarray(ref[0, 0]), rtol=2e-5,
                                    atol=2e-6)
+    # the padding rows of each q-block see nothing: exact zeros
+    pad = np.setdiff1d(np.arange(rows), offsets)
+    assert float(np.abs(np.asarray(out)[pad]).sum()) == 0.0
 
 
 # ---------------------------------------------------------------------
@@ -516,19 +529,27 @@ def test_engine_rejects_bad_requests(gpt_mini):
 # ---------------------------------------------------------------------
 # sampling ops
 # ---------------------------------------------------------------------
-def test_serving_sample_next_greedy_matches_argmax():
+@pytest.mark.parametrize("columns", [None, 2])
+def test_ragged_sample_next_greedy_matches_argmax(columns):
+    """`_ragged_sample_impl`, greedy: the argmax of the flat row each
+    sequence (1-D ``last_index``) or each of its columns (``[S, C]``,
+    the speculative verify) names."""
     import jax.numpy as jnp
-    from paddle_tpu.inference.serving.engine import _sample_next_impl
+    from paddle_tpu.inference.serving.engine import _ragged_sample_impl
     rng = np.random.RandomState(5)
-    logits = jnp.asarray(rng.randn(3, 4, 11).astype(np.float32))
-    last = jnp.asarray(np.array([3, 0, 2], np.int32))
-    z = np.asarray(logits)
-    want = [int(z[b, last[b]].argmax()) for b in range(3)]
-    got = _sample_next_impl(
-        logits, last, jnp.zeros(3, jnp.int32), jnp.zeros(3, jnp.int32),
+    logits = jnp.asarray(rng.randn(1, 12, 11).astype(np.float32))
+    last = np.array([3, 0, 10], np.int32)
+    if columns:
+        last = np.stack([last, last + 1], axis=1)
+    z = np.asarray(logits)[0]
+    want = z[last].argmax(-1)
+    got = _ragged_sample_impl(
+        logits, jnp.asarray(last), jnp.zeros(3, jnp.int32),
+        jnp.zeros(last.shape, jnp.int32),
         jnp.zeros(3, bool), jnp.zeros(3, jnp.int32),
         jnp.ones(3, jnp.float32), jnp.ones(3, jnp.float32))
-    assert np.asarray(got).tolist() == want
+    assert got.shape == last.shape
+    assert np.asarray(got).tolist() == want.tolist()
 
 
 def test_top_p_sampling_deterministic_under_seed():

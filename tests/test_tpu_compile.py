@@ -209,13 +209,6 @@ def _lora_sgmv():
              ((adapters, HID, r), bf16), ((adapters, r, FFN), bf16)])
 
 
-def _paged_attention():
-    pool = ((128, 8, 16, 64), bf16)
-    return (pk.paged_attention,
-            [((4, 1, 8, 64), bf16), pool, pool, ((4, 8), i32),
-             ((4,), i32)])
-
-
 OTHER_CASES = {
     # every epilogue the kernels offer ("gelu" needs an in-kernel erf:
     # Mosaic lowers no erf primitive)
@@ -226,7 +219,6 @@ OTHER_CASES = {
     "matmul_epilogue_int8_768x768x3072": _matmul_epilogue_int8,
     "grouped_matmul_8x768x3072": _grouped_matmul,
     "lora_sgmv_64x768x3072_r16": _lora_sgmv,
-    "paged_attention_bf16": _paged_attention,
 }
 
 CASES = {**SMOKE_CASES, **OTHER_CASES}

@@ -319,7 +319,6 @@ SITES = {
                                   "grouped_matmul.bwd_dw"],
     "lora_sgmv_64x768x3072_r16": ["lora_sgmv.fwd", "grouped_matmul.bwd_dx",
                                   "grouped_matmul.bwd_dw"],
-    "paged_attention_bf16": ["paged_attention.fwd"],
 }
 
 
