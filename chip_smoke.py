@@ -1,6 +1,6 @@
 """Prove that the trainer and the serving engine start on the chip.
 
-    python chip_smoke.py            # one TPU chip: seven phases
+    python chip_smoke.py            # one TPU chip: eight phases
     python chip_smoke.py --chips 4  # four chips: the sharded phase only
     python chip_smoke.py --phase attention_dropout   # that phase alone
 
@@ -387,6 +387,78 @@ def hidden_dropout(cfg, batch, seq, depth=2):
                         "paths": paths, "bert_layers": depth,
                         "loss_step0": on["losses"][0], "ln_vocab": ln_v,
                         "probe_ok": _probed()}}
+
+
+# ---------------------------------------------------------------------
+# the loss kernels at the MLM head's own shape
+# ---------------------------------------------------------------------
+XENT_REL_L2 = 1e-5      # float32 in, float32 arithmetic on both sides
+
+
+def loss_head(cfg, batch, seq):
+    """The softmax-cross-entropy kernels on ``batch * seq`` rows of
+    ``cfg.vocab_size`` float32 logits from a seeded bf16 matmul, as amp
+    O1 hands them over: the kernels read the matrix and write its
+    gradient at their own shape (30522 is no multiple of the 2048-column
+    block, so the last block is masked in the kernel), against the
+    float32 composite: loss, ``d_logits`` under a per-row cotangent, and
+    no gradient in an ignored row."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas_kernels as pk
+    rows, n, v = batch * seq, cfg.hidden_size, cfg.vocab_size
+    f32 = jnp.float32
+    with jax.enable_x64(False):
+        kh, kw, kl, kc = jax.random.split(jax.random.PRNGKey(SEED), 4)
+        h = jax.random.normal(kh, (rows, n), jnp.bfloat16)
+        w = (jax.random.normal(kw, (v, n), f32) * n ** -0.5).astype(
+            jnp.bfloat16)
+        logits = jax.jit(lambda h, w: jnp.dot(h, w.T).astype(f32))(h, w)
+        labels = jax.random.randint(kl, (rows,), 0, v)
+        labels = labels.at[0].set(v - 1)            # in the masked block
+        ignored = jnp.arange(rows) % 7 == 3
+        labels = jnp.where(ignored, -1, labels)
+        cot = jax.random.uniform(kc, (rows,), f32, 0.5, 1.5)
+
+        def composite(x):
+            lse = jax.nn.logsumexp(x, axis=-1)
+            picked = jnp.take_along_axis(
+                x, jnp.maximum(labels, 0)[:, None], 1)[:, 0]
+            return jnp.where(labels >= 0, lse - picked, 0.0)
+
+        def kernel(x):
+            return pk.fused_softmax_cross_entropy(x, labels)
+
+        def loss_and_grad(fn):
+            return jax.jit(jax.value_and_grad(
+                lambda x: jnp.sum(cot * fn(x)))
+            ).lower(logits).compile()
+
+        step = loss_and_grad(kernel)
+        kernels = mosaic_kernels(step.as_text())
+        errs = {"loss": _rel_l2(jax.jit(kernel)(logits),
+                                jax.jit(composite)(logits))}
+        (total, dx), (total_ref, dx_ref) = (
+            step(logits), loss_and_grad(composite)(logits))
+        errs["d_logits"] = _rel_l2(dx, dx_ref)
+        leaked = int(jnp.sum(jnp.any(dx != 0, axis=-1) & ignored))
+        shape_ok = dx.shape == (rows, v) and dx.dtype == f32
+    for name, err in errs.items():
+        check(err <= XENT_REL_L2, f"loss head {name} rel L2 {err}")
+    check(leaked == 0, f"{leaked} ignored rows carry a gradient")
+    check(shape_ok, f"d_logits is {dx.dtype}{list(dx.shape)}")
+    if kernels:             # on the chip: both directions are Mosaic's
+        check(kernels == {"softmax_cross_entropy_fwd": 1,
+                          "softmax_cross_entropy_bwd": 1},
+              f"kernels of the step: {kernels}")
+    return {"kernels": kernels,
+            "checked": {"rows": rows, "vocab": v,
+                        "blocks": list(pk._xent_blocks(rows, v)),
+                        "ignored_rows": int(jnp.sum(ignored)),
+                        "ignored_rows_with_gradient": leaked,
+                        "rel_l2_vs_composite": errs,
+                        "rel_l2_limit": XENT_REL_L2,
+                        "weighted_loss": float(total),
+                        "weighted_loss_composite": float(total_ref)}}
 
 
 # ---------------------------------------------------------------------
@@ -792,6 +864,7 @@ def main(argv=None):
         phases = [
             ("attention_dropout", attention_dropout, (bert, batch, seq), {}),
             ("hidden_dropout", hidden_dropout, (bert, batch, seq), {}),
+            ("loss_head", loss_head, (bert, batch, seq), {}),
             ("train_static", train_static, (bert, batch, seq), {}),
             ("train_eager", train_eager, (bert_eager, batch, seq), {}),
             ("train_lazy", train_eager, (bert, batch, seq),

@@ -35,20 +35,48 @@ def _reduce(out, reduction):
     return out
 
 
+def _xent_path(input, weight, soft_label, axis, use_softmax,
+               label_smoothing):
+    """Which implementation this call builds, counted once a build in
+    `softmax_cross_entropy.path.<fused|composite.<reason>>`: the kernel
+    takes hard labels over the last axis of float logits, unweighted
+    and unsmoothed; anything else, or a closed gate, leaves the XLA
+    composite.  The kernel is vocab-tiled (bounded VMEM at any V), so
+    `vocab_cap` only keeps absurd widths off it.  Returns whether the
+    kernel runs."""
+    from ...ops.pallas_gate import pallas_enabled
+    if soft_label:
+        reason = "soft_label"
+    elif weight is not None:
+        reason = "weight"
+    elif label_smoothing != 0.0:
+        reason = "label_smoothing"
+    elif not use_softmax:
+        reason = "no_softmax"
+    elif axis not in (-1, input.ndim - 1):
+        reason = "axis"
+    elif input.dtype not in ("float32", "bfloat16", "float16"):
+        reason = "dtype"
+    elif input.shape[-1] > 128 * 1024:
+        reason = "vocab_cap"
+    elif not pallas_enabled("softmax_cross_entropy"):
+        reason = "gate"
+    else:
+        reason = None
+    from ... import observability as obs
+    obs.get_registry().counter(
+        "softmax_cross_entropy.path."
+        + (f"composite.{reason}" if reason else "fused")).inc()
+    return reason is None
+
+
 def cross_entropy(input, label, weight=None, ignore_index=-100,
                   reduction="mean", soft_label=False, axis=-1,
                   use_softmax=True, label_smoothing=0.0, name=None):
     # Hard-label fast path → Pallas fused softmax-xent on TPU (the
     # reference's fused c_softmax_with_cross_entropy kernel role).
-    from ...ops.pallas_gate import pallas_enabled
-    # the kernel is vocab-tiled (bounded VMEM at any V); the cap only
-    # avoids pathological pad blow-up for absurd vocab sizes
-    use_fused = (not soft_label
-                 and weight is None and label_smoothing == 0.0
-                 and use_softmax and axis in (-1, input.ndim - 1)
-                 and input.shape[-1] <= 128 * 1024
-                 and input.dtype in ("float32", "bfloat16", "float16")
-                 and pallas_enabled("softmax_cross_entropy"))
+    use_fused = _xent_path(input, weight, soft_label, axis, use_softmax,
+                           label_smoothing)
 
     def impl(logits, lab, *w, ignore_index, reduction, soft_label, axis,
              use_softmax, smooth, use_fused=False):

@@ -896,8 +896,23 @@ def fused_rms_norm(x, gamma, eps=1e-6):
 # Fused softmax cross-entropy (from logits + integer labels)
 # =====================================================================
 
+def _xent_tile(x_ref, *, block_v, v):
+    """One (block_rows, block_v) tile of the logits in float32 beside its
+    column numbers.  The operand is read at its own width, so the last
+    vocabulary block may reach past column ``v``: what lies there is
+    unspecified (it may be NaN, so a select and not a multiply) and is
+    set to -inf, which drops it from the logsumexp and zeroes its
+    gradient.  Where ``block_v`` divides ``v`` no mask is traced."""
+    x = x_ref[:].astype(jnp.float32)
+    col = (jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+           + pl.program_id(1) * block_v)
+    if v % block_v:
+        x = jnp.where(col < v, x, _NEG_INF)
+    return x, col
+
+
 def _xent_fwd_kernel(x_ref, lbl_ref, loss_ref, lse_ref,
-                     m_acc, l_acc, pick_acc, *, block_v):
+                     m_acc, l_acc, pick_acc, *, block_v, v):
     """Online logsumexp over vocab blocks.
 
     Grid is (row_blocks, vocab_blocks) with the vocab dim minor, so for a
@@ -908,7 +923,7 @@ def _xent_fwd_kernel(x_ref, lbl_ref, loss_ref, lse_ref,
     at V=30k in the backward.
     """
     j = pl.program_id(1)
-    x = x_ref[:].astype(jnp.float32)                   # (block_rows, bv)
+    x, col = _xent_tile(x_ref, block_v=block_v, v=v)   # (block_rows, bv)
     br = x.shape[0]
     lbl = lbl_ref[:][:, :1]                            # (block_rows, 1)
 
@@ -923,7 +938,6 @@ def _xent_fwd_kernel(x_ref, lbl_ref, loss_ref, lse_ref,
     m_new = jnp.maximum(m_prev, m_blk)
     l_new = (l_acc[:][:, :1] * jnp.exp(m_prev - m_new)
              + jnp.sum(jnp.exp(x - m_new), axis=-1, keepdims=True))
-    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + j * block_v
     picked = jnp.sum(jnp.where(col == lbl, x, 0.0), axis=-1, keepdims=True)
     m_acc[:] = jnp.broadcast_to(m_new, (br, _STAT_LANES))
     l_acc[:] = jnp.broadcast_to(l_new, (br, _STAT_LANES))
@@ -939,14 +953,12 @@ def _xent_fwd_kernel(x_ref, lbl_ref, loss_ref, lse_ref,
         lse_ref[:] = jnp.broadcast_to(lse, (br, _STAT_LANES))
 
 
-def _xent_bwd_kernel(x_ref, lbl_ref, lse_ref, g_ref, dx_ref, *, block_v):
-    x = x_ref[:].astype(jnp.float32)                   # (block_rows, bv)
+def _xent_bwd_kernel(x_ref, lbl_ref, lse_ref, g_ref, dx_ref, *, block_v, v):
+    x, col = _xent_tile(x_ref, block_v=block_v, v=v)   # (block_rows, bv)
     lbl = lbl_ref[:][:, :1]
     lse = lse_ref[:][:, :1]
     g = g_ref[:][:, :1]
     p = jnp.exp(x - lse)
-    col = (jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-           + pl.program_id(1) * block_v)
     valid = (lbl >= 0).astype(jnp.float32)
     dx = jnp.where(col == lbl, p - 1.0, p) * (g * valid)
     dx_ref[:] = dx.astype(dx_ref.dtype)
@@ -957,18 +969,22 @@ def _fused_xent_2d(logits, labels):
     return _fused_xent_2d_fwd(logits, labels)[0]
 
 
+# Only the [rows, _STAT_LANES] label / lse / cotangent strips below are
+# padded to whole row blocks.  The logits matrix and its gradient keep
+# their own shape: what a partial block reads past their edge is masked
+# (columns, `_xent_tile`) or never written back (rows, which do not
+# depend on each other).
+
 @_x32
 def _fused_xent_2d_fwd(logits, labels):
     rows, v = logits.shape
-    br, bv, rows_pad, v_pad = _xent_blocks(rows, v)
-    # pad vocab with -inf so padded columns vanish from the logsumexp
-    xp = _pad_dim(_pad_dim(logits, 0, rows_pad), 1, v_pad,
-                  value=_NEG_INF)
+    br, bv = _xent_blocks(rows, v)
+    rows_pad = _round_up(rows, br)
     lp = _lanes(_pad_dim(labels.astype(jnp.int32), 0, rows_pad, value=-1))
     with _kernel_span("softmax_cross_entropy", "fwd") as kernel_name:
         loss, lse = pl.pallas_call(
-        functools.partial(_xent_fwd_kernel, block_v=bv),
-        grid=(rows_pad // br, v_pad // bv),
+        functools.partial(_xent_fwd_kernel, block_v=bv, v=v),
+        grid=(rows_pad // br, pl.cdiv(v, bv)),
         in_specs=[
             pl.BlockSpec((br, bv), lambda i, j: (i, j)),
             pl.BlockSpec((br, _STAT_LANES), lambda i, j: (i, 0)),
@@ -984,7 +1000,7 @@ def _fused_xent_2d_fwd(logits, labels):
         scratch_shapes=stat_scratch(br, 3),
         interpret=_interpret(),
         name=kernel_name,
-    )(xp, lp)
+    )(logits, lp)
     return loss[:rows, 0], (logits, labels, lse[:rows])
 
 
@@ -992,16 +1008,15 @@ def _fused_xent_2d_fwd(logits, labels):
 def _fused_xent_2d_bwd(res, g):
     logits, labels, lse = res
     rows, v = logits.shape
-    br, bv, rows_pad, v_pad = _xent_blocks(rows, v)
-    xp = _pad_dim(_pad_dim(logits, 0, rows_pad), 1, v_pad,
-                  value=_NEG_INF)
+    br, bv = _xent_blocks(rows, v)
+    rows_pad = _round_up(rows, br)
     lp = _lanes(_pad_dim(labels.astype(jnp.int32), 0, rows_pad, value=-1))
     lsep = _pad_dim(lse, 0, rows_pad)
     gp = _lanes(_pad_dim(g.astype(jnp.float32), 0, rows_pad))
     with _kernel_span("softmax_cross_entropy", "bwd") as kernel_name:
         dx = pl.pallas_call(
-        functools.partial(_xent_bwd_kernel, block_v=bv),
-        grid=(rows_pad // br, v_pad // bv),
+        functools.partial(_xent_bwd_kernel, block_v=bv, v=v),
+        grid=(rows_pad // br, pl.cdiv(v, bv)),
         in_specs=[
             pl.BlockSpec((br, bv), lambda i, j: (i, j)),
             pl.BlockSpec((br, _STAT_LANES), lambda i, j: (i, 0)),
@@ -1009,11 +1024,11 @@ def _fused_xent_2d_bwd(res, g):
             pl.BlockSpec((br, _STAT_LANES), lambda i, j: (i, 0)),
         ],
         out_specs=pl.BlockSpec((br, bv), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rows_pad, v_pad), logits.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows, v), logits.dtype),
         interpret=_interpret(),
         name=kernel_name,
-    )(xp, lp, lsep, gp)
-    return dx[:rows, :v], None
+    )(logits, lp, lsep, gp)
+    return dx, None
 
 
 _fused_xent_2d.defvjp(_fused_xent_2d_fwd, _fused_xent_2d_bwd)

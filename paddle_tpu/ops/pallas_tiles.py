@@ -157,10 +157,11 @@ def _ln_block_rows(rows, n, itemsize=4):
 
 
 def _xent_blocks(rows, v):
-    """(block_rows, block_v, rows_pad, v_pad) with bounded VMEM."""
+    """(block_rows, block_v) with bounded VMEM; the grid is the ceiling
+    of each quotient over the logits at their own shape."""
     bv = min(_round_up(v, 128), 2048)
     br = min(_round_up(rows, 16), 256)
-    return br, bv, _round_up(rows, br), _round_up(v, bv)
+    return br, bv
 
 
 def matmul_accum_blocks(m, k, n, dtype, weight_itemsize=None):

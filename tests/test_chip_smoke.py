@@ -63,6 +63,20 @@ def test_hidden_dropout_tiny(counted_before):
     assert c["paths"] == {"composite.gate": 2} and out["kernels"] == {}
 
 
+def test_loss_head_tiny():
+    """Interpret mode: 96 rows of 2,298 logits, so the second vocabulary
+    block holds 250 real columns and the rest is masked in the kernel."""
+    import dataclasses
+    out = chip_smoke.loss_head(
+        dataclasses.replace(BERT, vocab_size=2048 + 250), 2, 48)
+    c = out["checked"]
+    assert c["blocks"] == [96, 2048] and c["ignored_rows"] == 14
+    assert c["ignored_rows_with_gradient"] == 0
+    assert set(c["rel_l2_vs_composite"]) == {"loss", "d_logits"}
+    assert max(c["rel_l2_vs_composite"].values()) <= c["rel_l2_limit"]
+    assert out["kernels"] == {}
+
+
 @pytest.mark.parametrize("lazy_tier", [False, True])
 def test_train_eager_tiny(lazy_tier):
     out = chip_smoke.train_eager(BERT, 4, 32, steps=3,

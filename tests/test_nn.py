@@ -131,6 +131,83 @@ def test_cross_entropy_ignore_index():
     np.testing.assert_allclose(loss.numpy(), ref, rtol=1e-5)
 
 
+@pytest.fixture
+def xent_paths():
+    """`softmax_cross_entropy.path.*` as counted inside the test, with
+    the observability gate on."""
+    from paddle_tpu import observability as obs
+    prev = obs.enable(True)
+    obs.get_registry().clear()
+    yield lambda: {
+        k[len("softmax_cross_entropy.path."):]: v for k, v in
+        obs.get_registry().snapshot()["counters"].items()
+        if k.startswith("softmax_cross_entropy.path.")}
+    obs.get_registry().clear()
+    obs.enable(prev)
+
+
+@pytest.mark.parametrize("case,expect", [
+    ("hard_label", "fused"),
+    ("soft_label", "composite.soft_label"),
+    ("weight", "composite.weight"),
+    ("label_smoothing", "composite.label_smoothing"),
+    ("no_softmax", "composite.no_softmax"),
+    ("axis", "composite.axis"),
+    ("dtype", "composite.dtype"),
+    ("vocab_cap", "composite.vocab_cap"),
+    ("gate", "composite.gate"),
+])
+def test_cross_entropy_path_counts(monkeypatch, xent_paths, case, expect):
+    """One count a build, under the path taken and, for the composite,
+    why: the kernel only for hard labels over the last axis through an
+    open gate.  Both paths give the same loss."""
+    from paddle_tpu.ops import pallas_gate
+    monkeypatch.setattr(pallas_gate, "pallas_enabled",
+                        lambda kernel, manual=False: case != "gate")
+    rng = np.random.RandomState(0)
+    logits = paddle.to_tensor(rng.randn(6, 10).astype(np.float32))
+    labels = paddle.to_tensor(np.array([1, 2, -100, 4, 9, 0]))
+    kw = {}
+    if case == "soft_label":
+        labels = F.softmax(paddle.to_tensor(
+            rng.randn(6, 10).astype(np.float32)))
+        kw["soft_label"] = True
+    elif case == "weight":
+        kw["weight"] = paddle.to_tensor(
+            rng.rand(10).astype(np.float32))
+    elif case == "label_smoothing":
+        kw["label_smoothing"] = 0.1
+    elif case == "no_softmax":
+        logits, kw["use_softmax"] = F.softmax(logits), False
+    elif case == "axis":
+        logits, kw["axis"] = logits.transpose([1, 0]), 0
+    elif case == "dtype":
+        logits = logits.astype("float64")
+    elif case == "vocab_cap":
+        logits = paddle.to_tensor(
+            rng.randn(6, 128 * 1024 + 1).astype(np.float32))
+    loss = F.cross_entropy(logits, labels, **kw)
+    assert xent_paths() == {expect: 1}
+    if case in ("hard_label", "gate", "dtype"):
+        x = logits.numpy().astype(np.float64)
+        lp = x - np.log(np.exp(x).sum(-1, keepdims=True))
+        keep = [0, 1, 3, 4, 5]
+        ref = -lp[keep, labels.numpy()[keep]].mean()
+        np.testing.assert_allclose(loss.numpy(), ref, rtol=1e-5)
+
+
+def test_cross_entropy_path_counts_nothing_with_observability_off():
+    from paddle_tpu import observability as obs
+    prev = obs.enable(False)
+    obs.get_registry().clear()
+    try:
+        F.cross_entropy(paddle.randn([4, 10]),
+                        paddle.to_tensor([1, 2, 3, 4]))
+        assert not any(obs.get_registry().snapshot()["counters"].values())
+    finally:
+        obs.enable(prev)
+
+
 def test_sequential_layerlist():
     model = nn.Sequential(nn.Linear(4, 8), nn.ReLU(), nn.Linear(8, 2))
     y = model(paddle.randn([3, 4]))

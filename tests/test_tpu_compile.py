@@ -20,6 +20,7 @@ loads libtpu (see the guide on why nothing here may run at import).
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -127,6 +128,17 @@ def _xent():
     return (jax.grad(lambda x, lbl: pk.fused_softmax_cross_entropy(
                 x, lbl).sum()),
             [((ROWS, VOCAB), f32), ((ROWS,), i32)])
+
+
+def _mlm_head():
+    """BERT's MLM head as amp O1 runs it: the logits' matmul in bf16,
+    the black-listed loss on their float32 cast, gradients to the hidden
+    states and to the tied embedding."""
+    def loss(h, w, lbl):
+        logits = jnp.dot(h, w.T).astype(f32)
+        return pk.fused_softmax_cross_entropy(logits, lbl).mean()
+    return (jax.grad(loss, argnums=(0, 1)),
+            [((ROWS, HID), bf16), ((VOCAB, HID), bf16), ((ROWS,), i32)])
 
 
 def _ragged(kv_dtype):
@@ -242,6 +254,23 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < 16e9
+
+
+def test_mlm_head_moves_the_logits_only_through_compute(one_chip):
+    """The loss kernels take the head's logits at their own width, so
+    XLA hands the matmul's output to them as it is: no instruction of
+    the compiled head re-lays (`copy`), pads or slices a matrix of the
+    logits' size.  Each of those was one bandwidth-bound pass over 1 GB
+    in BERT's step (8.4 of 88.9 ms: ledger, PR 30)."""
+    fn, args = _mlm_head()
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in args]
+    text = jax.jit(fn).lower(*avals).compile().as_text()
+    moved = [m.group(0) for m in re.finditer(
+        r"\[8192,(?:30522|30720)\](?:\{[^}]*\})? (?:copy|pad|slice)\(",
+        text)]
+    assert not moved, moved
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
 
 
 # -- MiniCPM-SALA's engine step (benchmarks/traffic/longdoc-closed32) ----
