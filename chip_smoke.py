@@ -766,6 +766,125 @@ def serve_sala(config, prompt_lens, new_tokens=8, engine=None):
                 "probe_ok": _probed()}}
 
 
+#: served against reference logits, worst row and median row.  In
+#: bfloat16 a token whose 8th and 9th router scores lie within rounding
+#: takes another expert than the float32 reference does, and that row
+#: moves by tenths (PERF.md section 6, PR 32): the median row holds the
+#: program to bfloat16's rounding, and float32 weights to the kernels'.
+AFMOE_REL_L2 = {"bfloat16": (0.5, 3e-2), "float32": (1e-3, 1e-3)}
+
+
+def serve_afmoe(config, prompt_lens, new_tokens=8, engine=None):
+    """The AFMoE family at the published widths, a dense sliding, an
+    expert sliding and an expert full layer: prefill in chunks then
+    decode through the ``GenerationEngine`` against the plain
+    reference's full forward, on logits (the step compiled with one
+    more output, the logits row each request samples from, as
+    `serve_sala` does).  Both groups of block tables, the grouped
+    expert kernel and the ragged kernel's window and head-group forms
+    run; a prompt longer than the window makes its group release."""
+    import jax.numpy as jnp
+    from benchmarks.families import _plain, afmoe as family
+    from paddle_tpu.core.dispatch import dispatch
+    from paddle_tpu.inference.serving.engine import ragged_sample_next
+    paddle.seed(SEED)
+    model = family.build(config)
+    rng = np.random.default_rng(SEED)
+    prompts = [rng.integers(0, config["vocab_size"], n).tolist()
+               for n in prompt_lens]
+    gc.collect()
+    eng = GenerationEngine(model, **(engine or {
+        "max_batch": 4, "block_size": 64, "num_blocks": 512,
+        "max_model_len": 8192, "prefill_chunk": 1024}))
+    view, rows = eng._view, {}
+
+    def tapped(ids, seeds, *controls):
+        with paddle.no_grad():
+            logits = model(ids, cache=view, use_cache=False)
+            view.take_reports()          # the plan counters: not read here
+            picked = dispatch(
+                "tap_rows", lambda z, i: z[0, i].astype(jnp.float32),
+                (logits, view.last_index), {}, differentiable=False)
+            return ragged_sample_next(logits, view.last_index, seeds,
+                                      view.sample_pos, *controls), picked
+
+    step_fn = paddle.jit.to_static(tapped)
+
+    def step(ids, *args):
+        tok, picked = step_fn(ids, *args)
+        where = np.asarray(view.sample_pos._value)
+        for r, req in enumerate(eng._rows):
+            if req is not None and where[r] > 0:
+                rows[(req.id, int(where[r]))] = (r, picked._value)
+        return tok
+
+    step._cache = step_fn._cache
+    eng._step_fn = step
+    try:
+        ids = [eng.add_request(p, max_new_tokens=new_tokens)
+               for p in prompts]
+        while eng.has_unfinished():
+            eng.step()
+        outs = [eng.result(i) for i in ids]
+        stats = eng.stats()
+        (entry,) = step_fn._cache.values()
+        kernels = mosaic_kernels(entry["compiled"].as_text())
+    finally:
+        eng.close()
+    params = _plain.arrays(model)
+    rel, agree = [], 0
+    for rid, out, prompt in zip(ids, outs, prompts):
+        check(len(out) == len(prompt) + new_tokens,
+              f"{rid} ended with {len(out) - len(prompt)} tokens")
+        at = np.arange(len(prompt) - 1, len(out) - 1)
+        ref = np.asarray(family.reference_head(
+            params, config, family.reference_hidden(
+                params, config, jnp.asarray(np.asarray(out)))[at]))
+        for j, pos in enumerate(range(len(prompt), len(out))):
+            r, picked = rows[(rid, pos)]
+            rel.append(_rel_l2(np.asarray(picked[r]), ref[j]))
+            agree += int(ref[j].argmax() == out[pos])
+    worst, median = float(np.max(rel)), float(np.median(rel))
+    worst_limit, median_limit = AFMOE_REL_L2[config["dtype"]]
+    check(np.isfinite(worst) and worst <= worst_limit
+          and median <= median_limit,
+          f"served logits differ from the reference by {sorted(rel)} "
+          "(rel L2 a row)")
+    check(stats["window_blocks_released"] > 0,
+          "no block was released: the contexts are inside the window")
+    return {"kernels": kernels,
+            "checked": {
+                "requests": len(prompts), "prompt_lens": list(prompt_lens),
+                "positions": len(rel),
+                "worst_row_rel_l2_vs_reference": worst,
+                "median_row_rel_l2_vs_reference": median,
+                "rel_l2_tolerance_worst_median": [worst_limit,
+                                                  median_limit],
+                "greedy_tokens_the_reference_agrees_with": agree,
+                "window_blocks_released": stats["window_blocks_released"],
+                "window_high_water": stats["window_high_water"],
+                "kv_blocks_read_window": stats["kv_blocks_read_window"],
+                "kv_blocks_context": stats["kv_blocks_context"],
+                "step_program_compiles": len(step_fn._cache),
+                "probe_ok": _probed()}}
+
+
+def afmoe_config(layers=(0, 1, 4), dtype=None):
+    """The benchmark's Trinity-Mini file with the layer list cut to a
+    dense sliding, an expert sliding and an expert full layer (depth is
+    what a smoke may cut; no width is); ``layers=(1, 4)`` leaves the
+    two expert layers, which fit the chip in float32."""
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmarks", "configs",
+                           "Trinity-Mini.json")) as f:
+        config = json.load(f)
+    return {**config, "num_hidden_layers": len(layers),
+            "num_dense_layers": sum(i < config["num_dense_layers"]
+                                    for i in layers),
+            "dtype": dtype or config["dtype"],
+            "layer_types": [config["layer_types"][i] for i in layers]}
+
+
 def sala_config(layers=("minicpm4", "lightning-attn", "lightning-attn",
                         "minicpm4")):
     """The benchmark's configuration file with the layer list cut to
@@ -872,7 +991,13 @@ def main(argv=None):
             ("serve", serve, (GPTConfig(), [37, 200, 513, 900]), {}),
             # 9,500 tokens prune (64 of 115 candidate blocks), 3,000
             # attend densely; both cross chunk boundaries
-            ("serve_sala", serve_sala, (sala_config(), [9500, 3000]), {})]
+            ("serve_sala", serve_sala, (sala_config(), [9500, 3000]), {}),
+            # 5,000 tokens pass the 2,048-token window twice; 1,500 fit
+            ("serve_afmoe", serve_afmoe, (afmoe_config(), [5000, 1500]),
+             {}),
+            # the same kernels on float32 weights: two expert layers
+            ("serve_afmoe_f32", serve_afmoe,
+             (afmoe_config((1, 4), "float32"), [3000, 700]), {})]
     unknown = set(args.phase or ()) - {name for name, *_ in phases}
     if unknown:
         sys.exit(f"no such phase with --chips {args.chips}: "

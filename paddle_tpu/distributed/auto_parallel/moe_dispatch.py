@@ -17,6 +17,11 @@ the stable argsort gives tokens of one expert their arrival order):
   * `dropless_combine`  — gather expert outputs back and weighted-sum
     the k choices per token.
 
+`sigmoid_topk_router` is the sigmoid router with a selection-only bias,
+and `gated_experts` strings plan, scatter, the gated grouped kernel,
+the down projection and the combine together for a SwiGLU expert layer
+(`models/afmoe.py`).
+
 Expert parallelism crosses the ``ep`` mesh axis with all-to-all.
 `ring_all_to_all_local` decomposes that collective into per-peer
 ``ppermute`` hops — the PR 11 ring-overlap discipline
@@ -37,7 +42,8 @@ from ...ops.pallas_tiles import group_segments, num_group_blocks
 
 __all__ = [
     "dropless_combine", "dropless_dispatch", "dropless_plan",
-    "expert_imbalance", "ring_all_to_all_local",
+    "expert_imbalance", "gated_experts", "plan_counters",
+    "ring_all_to_all_local", "sigmoid_topk_router",
 ]
 
 
@@ -45,16 +51,41 @@ __all__ = [
 # Dropless routing (single-device / inside one shard)
 # ---------------------------------------------------------------------------
 
-def dropless_plan(topk_idx, num_experts, block_rows, num_blocks=None):
+def sigmoid_topk_router(logits, bias, top_k, route_scale=1.0,
+                        route_norm=True):
+    """A sigmoid router with a selection-only bias.  ``logits`` [N, E]
+    float32; ``bias`` [E] or None.  Scores are ``sigmoid(logits)``; the
+    chosen set is the ``top_k`` largest of ``score + bias`` (ties to the
+    lower index: `jax.lax.top_k` is stable), and a chosen expert's
+    weight is its own score, bias left out, over the chosen scores' sum
+    (``route_norm``) times ``route_scale``.  Returns ``(topk_idx,
+    topk_weight)`` [N, top_k], int32 and float32."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, idx = jax.lax.top_k(choice, top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if route_norm:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * route_scale
+
+
+def dropless_plan(topk_idx, num_experts, block_rows, num_blocks=None,
+                  carried=None):
     """Plan the grouped layout for top-k assignments — droplessly.
 
     ``topk_idx``: [N, k] int expert choices.  ``num_blocks`` must be
     the static `pallas_tiles.num_group_blocks(N * k, num_experts,
-    block_rows)` (computed here when N is concrete).
+    block_rows)` (computed here when N is concrete).  ``carried`` [N]
+    bool marks the tokens that carry something (the serving engine's
+    flat buffer is mostly padding): the others are dispatched to no
+    expert, so a decode-only step plans its few rows' choices and the
+    rest of the buffer is null blocks.
 
     Returns ``(rows, block_group, counts)``:
       * ``rows``: [N * k] int32 — the grouped-buffer row of flat
         assignment ``n * k + j`` (rows are unique: scatter is exact);
+        an assignment of a token that carries nothing gets the row
+        past the buffer's end (dropped on scatter, zero on gather);
       * ``block_group``: [num_blocks] int32 kernel descriptor
         (``num_experts`` = null block);
       * ``counts``: [num_experts] int32 tokens per expert (the
@@ -66,37 +97,80 @@ def dropless_plan(topk_idx, num_experts, block_rows, num_blocks=None):
     N, k = topk_idx.shape
     T = N * k
     e_flat = topk_idx.reshape(-1).astype(jnp.int32)
-    counts = jnp.zeros((num_experts,), jnp.int32).at[e_flat].add(1)
+    if carried is not None:
+        e_flat = jnp.where(jnp.repeat(carried, k), e_flat, num_experts)
+    # group `num_experts` collects what goes nowhere
+    counts = jnp.zeros((num_experts + 1,), jnp.int32).at[e_flat].add(1)
     if num_blocks is None:
         num_blocks = num_group_blocks(T, num_experts, block_rows)
-    gid, offsets = group_segments(counts, block_rows, num_blocks)
+    gid, offsets = group_segments(counts[:num_experts], block_rows,
+                                  num_blocks)
     order = jnp.argsort(e_flat, stable=True)
     e_sorted = e_flat[order]
     csum = jnp.cumsum(counts) - counts                  # exclusive
     rank = jnp.arange(T, dtype=jnp.int32) - csum[e_sorted]
+    placed = offsets[jnp.minimum(e_sorted, num_experts - 1)] + rank
     rows = jnp.zeros((T,), jnp.int32).at[order].set(
-        offsets[e_sorted] + rank)
-    return rows, gid, counts
+        jnp.where(e_sorted < num_experts, placed, num_blocks * block_rows))
+    return rows, gid, counts[:num_experts]
 
 
 def dropless_dispatch(x, rows, top_k, padded_rows):
     """Scatter [N, D] tokens into the [padded_rows, D] grouped buffer:
     assignment ``n * k + j`` lands whole at ``rows[n * k + j]``;
-    padding rows stay zero (the grouped kernel's contract)."""
+    padding rows stay zero (the grouped kernel's contract), and a row
+    past the buffer's end (a token that carries nothing) is dropped."""
     N, D = x.shape
     xr = jnp.repeat(x, top_k, axis=0)                   # [N*k, D]
-    return jnp.zeros((padded_rows, D), x.dtype).at[rows].set(xr)
+    return jnp.zeros((padded_rows, D), x.dtype).at[rows].set(
+        xr, mode="drop")
 
 
 def dropless_combine(y_rows, rows, topk_val):
     """Gather expert outputs back and weighted-sum the k choices:
     ``y[n] = sum_j topk_val[n, j] * y_rows[rows[n*k+j]]`` (f32
-    accumulation, cast back to the buffer dtype)."""
+    accumulation, cast back to the buffer dtype); a row past the
+    buffer's end reads as zero."""
     N, k = topk_val.shape
-    g = y_rows[rows].reshape(N, k, y_rows.shape[-1])
+    g = y_rows.at[rows].get(mode="fill", fill_value=0) \
+        .reshape(N, k, y_rows.shape[-1])
     return jnp.einsum(
         "nk,nkd->nd", topk_val.astype(jnp.float32),
         g.astype(jnp.float32)).astype(y_rows.dtype)
+
+
+def plan_counters(counts, block_rows):
+    """What a plan dispatched, as int32 ``[assignments, experts
+    touched, rows of the grouped buffer the kernel runs over (padding
+    included), the fullest expert's rows]``."""
+    blocks = (counts + block_rows - 1) // block_rows
+    return jnp.stack([counts.sum(), (counts > 0).sum(),
+                      blocks.sum() * block_rows,
+                      counts.max()]).astype(jnp.int32)
+
+
+def gated_experts(x, topk_idx, topk_weight, w_gate_up, w_down,
+                  carried=None, act="silu", use_pallas=False):
+    """The routed half of a gated expert layer on flat tokens: plan,
+    scatter, ``act(x @ w_gate[e]) * (x @ w_up[e])`` then ``@ w_down[e]``
+    through the grouped kernel (or its composite), weighted sum.
+    ``w_gate_up`` [E, D, 2 I] and ``w_down`` [E, I, D] are read where
+    they lie.  Returns ``(y [N, D], plan_counters)``."""
+    from ...ops import pallas_grouped as pg
+    N, k = topk_idx.shape
+    E = w_gate_up.shape[0]
+    bm, nb, rows_total = pg.grouped_layout(N * k, E, x.dtype)
+    use_pallas = use_pallas and bool(pg.gated_block_n(
+        bm, x.shape[1], w_gate_up.shape[2] // 2, x.dtype))
+    rows, gid, counts = dropless_plan(topk_idx, E, bm, nb, carried)
+    xd = dropless_dispatch(x, rows, k, rows_total)
+    gated, down = (pg.grouped_gated_act, pg.grouped_linear_act) \
+        if use_pallas else (pg.grouped_gated_act_ref,
+                            pg.grouped_linear_act_ref)
+    h = gated(xd, w_gate_up, block_group=gid, act=act)
+    y_rows = down(h, w_down, block_group=gid)
+    return (dropless_combine(y_rows, rows, topk_weight),
+            plan_counters(counts, bm))
 
 
 def expert_imbalance(counts):

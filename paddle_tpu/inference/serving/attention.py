@@ -36,7 +36,8 @@ argument (``models/gpt.py`` detects it by its ``attend`` /
 ``position_ids`` attributes) and owns the per-step driving Tensors.  It
 hands each layer one of three layer caches, chosen from the cache's
 ``layer_specs``: ``RaggedLayerCache`` (paged K/V, scatter then ragged
-attention), ``SparseLayerCache`` (paged K/V with grouped heads plus the
+attention; with grouped KV heads or a ``window`` in its spec, the decode
+rows and the chunk apart, over its group's table), ``SparseLayerCache`` (paged K/V with grouped heads plus the
 selector's pooled keys in a state slot) and ``RecurrentLayerCache`` (no
 K/V, one state a head in the request's slot).  ``kv_blocks_gather`` /
 ``kv_blocks_scatter`` move whole pool blocks to and from the host
@@ -55,6 +56,7 @@ from ...core.tensor import Tensor
 
 __all__ = ["kv_cache_scatter", "kv_cache_scatter_quant",
            "ragged_attention", "RaggedCacheView",
+           "grouped_chunk_attention",
            "RaggedLayerCache", "SparseLayerCache", "RecurrentLayerCache",
            "grouped_decode_attention", "kv_blocks_gather",
            "kv_blocks_scatter"]
@@ -180,8 +182,10 @@ def kv_cache_scatter_quant(k_pool, v_pool, k_scales, v_scales,
 # ---------------------------------------------------------------------
 def _ragged_ref(q, k_pool, v_pool, block_tables, context_lens, seq_ids,
                 q_starts, q_valids, block_q, scale,
-                k_scales=None, v_scales=None):
-    """Pure-XLA segment-gather fallback for `ragged_paged_attention`.
+                k_scales=None, v_scales=None, window=None,
+                block_tokens=None):
+    """Pure-XLA segment-gather fallback for `ragged_paged_attention`
+    (``window`` and ``block_tokens`` as there).
 
     q: [T, H, D] flat block-aligned ragged queries (see
     ops/pallas_ragged.py for the seq_ids/q_starts/q_valids layout;
@@ -219,12 +223,16 @@ def _ragged_ref(q, k_pool, v_pool, block_tables, context_lens, seq_ids,
     scores = jnp.einsum("nhqd,nhkd->nhqk", qt, k,
                         preferred_element_type=jnp.float32) * scale
     row = jnp.arange(block_q, dtype=jnp.int32)
+    if block_tokens is not None:
+        row = row % block_tokens       # head groups of block_tokens rows
     col = jnp.arange(W * bs, dtype=jnp.int32)
     pos = q_starts.astype(jnp.int32)[:, None] + row[None, :]
     visible = ((row[None, :, None] < q_valids.astype(jnp.int32)
                 [:, None, None])
                & (col[None, None, :] <= pos[:, :, None])
                & (col[None, None, :] < cl[sid][:, None, None]))
+    if window is not None:
+        visible &= col[None, None, :] > pos[:, :, None] - window
     scores = jnp.where(visible[:, None, :, :], scores,
                        jnp.asarray(_NEG_INF, scores.dtype))
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
@@ -237,17 +245,20 @@ def _ragged_ref(q, k_pool, v_pool, block_tables, context_lens, seq_ids,
 
 def _ragged_attention_impl(q, k_pool, v_pool, block_tables,
                            context_lens, seq_ids, q_starts, q_valids,
-                           *scales, block_q, scale, use_pallas):
+                           *scales, block_q, scale, use_pallas,
+                           window=None, block_tokens=None):
     ks, vs = scales if scales else (None, None)
     if use_pallas:
         from ...ops.pallas_ragged import ragged_paged_attention as _krn
         out = _krn(q[0], k_pool, v_pool, block_tables, context_lens,
                    seq_ids, q_starts, q_valids, block_q=block_q,
-                   scale=scale, k_scales=ks, v_scales=vs)
+                   scale=scale, k_scales=ks, v_scales=vs, window=window,
+                   block_tokens=block_tokens)
     else:
         out = _ragged_ref(q[0], k_pool, v_pool, block_tables,
                           context_lens, seq_ids, q_starts, q_valids,
-                          block_q, scale, k_scales=ks, v_scales=vs)
+                          block_q, scale, k_scales=ks, v_scales=vs,
+                          window=window, block_tokens=block_tokens)
     return out[None]
 
 
@@ -302,16 +313,70 @@ def ragged_attention(q, k_pool, v_pool, block_tables, context_lens,
 class RaggedLayerCache:
     """One layer's view of the ragged mixed-batch step."""
 
-    __slots__ = ("_view", "_layer")
+    __slots__ = ("_view", "_layer", "_window", "_grouped")
 
     def __init__(self, view, layer):
         self._view = view
         self._layer = layer
+        spec = view.cache.layer_specs[layer]
+        self._window = spec.get("window")
+        #: query heads that share KV heads, or a window: the decode rows
+        #: and the chunk go through the kernel apart (`_attend_grouped`)
+        self._grouped = layer_is_grouped(spec)
 
     @property
     def lora(self):
         """The multi-LoRA segment state (serving.lora), or None."""
         return self._view.lora
+
+    def carried_rows(self):
+        """``[T]`` bool: the rows of the flat buffer that carry a token
+        (a decode row's first, a chunk's valid ones)."""
+        view = self._view
+        return dispatch("ragged_carried_rows", _carried_rows_impl,
+                        (view.q_valids,),
+                        dict(block_q=view.block_q), differentiable=False)
+
+    def report(self, name, value):
+        """Hand the engine a small per-step array (the expert layers'
+        plan counters): it leaves the step's program with the sampled
+        tokens and is read where they are."""
+        self._view.reports.setdefault(name, []).append(value)
+
+    def _attend_grouped(self, q, k, v):
+        """Grouped KV heads, or a window: scatter, then the decode rows
+        through `grouped_decode_attention` and the chunk through the
+        kernel's head-group form, each over this layer's group's table
+        (a windowed group's holds what the rows still hold, from their
+        context base)."""
+        view = self._view
+        cache = view.cache
+        k_pool, v_pool = cache.layer_pools(self._layer)
+        slots, tables, base = view.group_inputs(
+            cache.layer_group(self._layer))
+        kv, qv_ = k_pool._value, q._value
+        group = qv_.shape[2] // kv.shape[1]
+        dec_rows = decode_block_q(group, qv_.dtype)
+        chunk_bq = view.chunk_block_q
+        bs = kv.shape[2]
+        dec_width = tables.shape[1] if self._window is None else min(
+            tables.shape[1], self._window // bs + 2)
+        out, new_k, new_v = dispatch(
+            "grouped_paged_attention", _grouped_attend_impl,
+            (q, k, v, k_pool, v_pool, slots, tables, base,
+             view.dec_index, view.row_pos, view.chunk_meta),
+            dict(window=self._window, chunk_rows=view.chunk_rows,
+                 chunk_bq=chunk_bq, dec_width=int(dec_width),
+                 dec_rows=dec_rows,
+                 pallas_rows=_use_pallas_ragged(
+                     kv.shape[3], bs, kv.dtype, dec_rows, qv_.dtype),
+                 pallas_chunk=_use_pallas_ragged(
+                     kv.shape[3], bs, kv.dtype, group * chunk_bq,
+                     qv_.dtype)),
+            differentiable=False)
+        k_pool._inplace_update(new_k._value)
+        v_pool._inplace_update(new_v._value)
+        return out
 
     def attend(self, q, k, v, use_flash=True):
         """Scatter this step's K/V into the pool, then run ragged
@@ -319,6 +384,8 @@ class RaggedLayerCache:
         share one kernel call.  q/k/v: [1, T, H, D] Tensors.  Int8
         pools quantize per token at scatter time and thread the
         per-slot scale tables into the attention call."""
+        if self._grouped:
+            return self._attend_grouped(q, k, v)
         view = self._view
         k_pool, v_pool = view.cache.layer_pools(self._layer)
         scales = view.cache.layer_scales(self._layer)
@@ -348,8 +415,23 @@ class RaggedLayerCache:
 # ---------------------------------------------------------------------
 # layers that keep per-request state: block-sparse and recurrent
 # ---------------------------------------------------------------------
+def layer_is_grouped(spec):
+    """Whether a paged layer's spec asks for the grouped path: a window,
+    or more query heads than KV heads."""
+    return bool(spec.get("window")) or (
+        int(spec.get("query_heads") or spec["num_kv_heads"])
+        != int(spec["num_kv_heads"]))
+
+
+def decode_block_q(group, dtype):
+    """Rows of a decode row's q-block when its ``group`` query heads go
+    as rows: the group, in whole sublane tiles of ``dtype``."""
+    from ...ops.pallas_tiles import _min_rows, _round_up
+    return _round_up(group, _min_rows(jnp.dtype(dtype)))
+
+
 def grouped_decode_attention(q, k_pool, v_pool, sel_tables, sel_ctx,
-                             use_pallas):
+                             use_pallas, window=None, block_q=None):
     """Decode rows with grouped KV heads over a block table of their
     own, through the ragged kernel: ``q`` [S, H, D] (one token a row,
     heads grouped by KV head), pools [nb, Hkv, bs, D], ``sel_tables``
@@ -361,7 +443,12 @@ def grouped_decode_attention(q, k_pool, v_pool, sel_tables, sel_ctx,
     lets every row see the whole context), and the pool is viewed as
     ``[nb * Hkv, 1, bs, D]`` so that a table entry names a block *and*
     a KV head.  Sixteen MXU rows a token where one head a row gives
-    one."""
+    one.
+
+    With ``block_q`` (at least ``G``: `decode_block_q`) the q-block is
+    padded to that many rows and the kernel is told that its rows are
+    one token (``block_tokens = 1``), which a ``window`` needs: the
+    rows then share the position that the window is counted from."""
     S, H, D = q.shape
     nb, kv_heads, bs, _ = k_pool.shape
     G, n = H // kv_heads, S * kv_heads
@@ -369,12 +456,99 @@ def grouped_decode_attention(q, k_pool, v_pool, sel_tables, sel_ctx,
     tables = (sel_tables * kv_heads + heads).reshape(n, -1)
     ctx = sel_ctx.reshape(n).astype(jnp.int32)
     seq = jnp.where(ctx > 0, jnp.arange(n, dtype=jnp.int32), n)
+    if block_q is None:
+        if window is not None:
+            raise ValueError("a window needs the one-token q-block form "
+                             "(block_q)")
+        rows, valid, options = G, G, {}
+        qg = q.reshape(1, n * G, 1, D)
+    else:
+        rows, valid = int(block_q), 1
+        options = dict(window=window, block_tokens=1)
+        qg = jnp.pad(q.reshape(n, G, D), ((0, 0), (0, rows - G), (0, 0))) \
+            .reshape(1, n * rows, 1, D)
     out = _ragged_attention_impl(
-        q.reshape(1, n * G, 1, D), k_pool.reshape(nb * kv_heads, 1, bs, D),
+        qg, k_pool.reshape(nb * kv_heads, 1, bs, D),
         v_pool.reshape(nb * kv_heads, 1, bs, D), tables, ctx, seq,
-        jnp.maximum(ctx - 1, 0), jnp.full((n,), G, jnp.int32),
-        block_q=G, scale=1.0 / math.sqrt(D), use_pallas=use_pallas)
-    return out.reshape(S, H, D)
+        jnp.maximum(ctx - 1, 0), jnp.full((n,), valid, jnp.int32),
+        block_q=rows, scale=1.0 / math.sqrt(D), use_pallas=use_pallas,
+        **options)
+    return out.reshape(n, rows, D)[:, :G].reshape(S, H, D)
+
+
+def grouped_chunk_attention(q, k_pool, v_pool, table, context, start,
+                            valid, *, window, chunk_bq, use_pallas):
+    """A prefill chunk with grouped KV heads through the ragged kernel's
+    head-group form: ``q`` [C, H, D] at positions ``start ...`` (``valid``
+    of them real) of one sequence with ``table`` [W] and ``context``
+    tokens.  A q-block is ``chunk_bq`` tokens by the ``G`` query heads
+    of one KV head, so the kernel's head axis is the KV heads and a
+    K/V block is read once for the ``G`` heads that share it."""
+    C, H, D = q.shape
+    kv_heads = k_pool.shape[1]
+    G, nqb = H // kv_heads, C // chunk_bq
+    # [C, H, D] -> rows ordered (q-block, head of the group, token)
+    qg = q.reshape(nqb, chunk_bq, kv_heads, G, D).transpose(0, 3, 1, 2, 4) \
+        .reshape(1, nqb * G * chunk_bq, kv_heads, D)
+    first = jnp.arange(nqb, dtype=jnp.int32) * chunk_bq
+    valids = jnp.clip(valid - first, 0, chunk_bq).astype(jnp.int32)
+    out = _ragged_attention_impl(
+        qg, k_pool, v_pool, table[None], jnp.reshape(context, (1,)),
+        jnp.where(valids > 0, 0, 1).astype(jnp.int32),   # 1: null segment
+        (start + first).astype(jnp.int32), valids,
+        block_q=G * chunk_bq, scale=1.0 / math.sqrt(D),
+        use_pallas=use_pallas, window=window, block_tokens=chunk_bq)
+    return out[0].reshape(nqb, G, chunk_bq, kv_heads, D) \
+        .transpose(0, 2, 3, 1, 4).reshape(C, H, D)
+
+
+def _stack_impl(*xs):
+    return jnp.stack(xs)
+
+
+def _carried_rows_impl(q_valids, *, block_q):
+    row = jnp.arange(q_valids.shape[0] * block_q, dtype=jnp.int32)
+    return (row % block_q) < jnp.repeat(q_valids, block_q)
+
+
+def _grouped_attend_impl(q, k, v, k_pool, v_pool, slots, tables, base,
+                         dec_index, row_pos, meta, *, window, chunk_rows,
+                         chunk_bq, dec_width, dec_rows, pallas_rows,
+                         pallas_chunk):
+    """One layer with grouped KV heads (and perhaps a window) of the
+    ragged step.  Scatter K/V; the decode rows read their group's table
+    through the kernel, ``dec_width`` entries of it (all that a
+    windowed row still holds) in q-blocks of ``dec_rows`` rows; the chunk goes through the kernel's
+    head-group form (skipped when the step carries none).  ``base`` [S]
+    is each row's context base: positions in a windowed group's table
+    count from it."""
+    k_pool, v_pool = _kv_scatter_impl(k_pool, v_pool, k, v, slots)
+    q0 = q[0]
+    T, H, D = q0.shape
+    S, kv_heads = tables.shape[0], k_pool.shape[1]
+    tables = tables.astype(jnp.int32)
+    qd = q0[jnp.minimum(dec_index, T - 1)]                   # [S, H, D]
+    ctx = jnp.where(row_pos >= 0, row_pos + 1 - base, 0)
+    dec_out = grouped_decode_attention(
+        qd, k_pool, v_pool,
+        jnp.broadcast_to(tables[:, None, :dec_width],
+                         (S, kv_heads, dec_width)),
+        jnp.broadcast_to(ctx[:, None], (S, kv_heads)), pallas_rows,
+        window=window, block_q=dec_rows)
+
+    def chunk(_):
+        row = jnp.minimum(meta[4], S - 1)
+        start = meta[5] - base[row]
+        return grouped_chunk_attention(
+            _chunk_rows(q0, meta, chunk_rows), k_pool, v_pool, tables[row],
+            start + meta[1], start, meta[1], window=window,
+            chunk_bq=chunk_bq, use_pallas=pallas_chunk)
+
+    chunk_out = jax.lax.cond(
+        meta[1] > 0, chunk,
+        lambda _: jnp.zeros((chunk_rows, H, D), q.dtype), None)
+    out = _merge_rows(q0, chunk_out, dec_out, meta, dec_index)
+    return out[None], k_pool, v_pool
 
 
 def _chunk_rows(x, meta, chunk_rows):
@@ -613,6 +787,19 @@ class RaggedCacheView:
         self.ck_seq = None         # [N] int32 pooled keys to write: row,
         self.ck_j = None           # [N] int32 index,
         self.ck_slot = None        # [N] int32 and slot (0 = none)
+        #: what the layers hand the engine beside the tokens (`report`)
+        self.reports = {}
+        #: a windowed group's slots, table and context base a row,
+        #: staged beside the full group's (`set_group_inputs`)
+        self._group_inputs = {}
+        #: layers whose decode rows and chunk go through the kernel
+        #: apart; they read the per-row arrays of `stage_state`
+        self.grouped = any(spec["kind"] == "paged_kv"
+                           and layer_is_grouped(spec)
+                           for spec in cache.layer_specs)
+        #: tokens of a q-block of the chunk's head-group form
+        self.chunk_block_q = math.gcd(int(chunk_rows or block_q), 128)
+        self._zero_base = None
         sparse = [spec["sparse_sizes"] for spec in cache.layer_specs
                   if spec.get("sparse_sizes")]
         if len(set(sparse)) > 1:
@@ -660,6 +847,36 @@ class RaggedCacheView:
             "last_index", self.last_index, last_index, jnp.int32)
         self.sample_pos = self._stage(
             "sample_pos", self.sample_pos, sample_pos, jnp.int64)
+
+    def set_group_inputs(self, group, slot_mapping, block_tables, base):
+        """Stage a windowed group's slots [T], tables [S, W] (what each
+        row still holds) and context bases [S] (tokens before a row's
+        first held block)."""
+        staged = self._group_inputs.setdefault(group.window, [None] * 3)
+        names = ("slot_mapping", "block_tables", "context_base")
+        for n, (name, value) in enumerate(zip(
+                names, (slot_mapping, block_tables, base))):
+            staged[n] = self._stage(f"window{group.window}.{name}",
+                                    staged[n], value, jnp.int32)
+
+    def group_inputs(self, group):
+        """``(slot_mapping, block_tables, context_base)`` of a layer's
+        group (None: the full group, whose base is zero)."""
+        if group is not None:
+            return tuple(self._group_inputs[group.window])
+        if self._zero_base is None:
+            self._zero_base = self._stage(
+                "context_base", None,
+                np.zeros(self.block_tables.shape[0], np.int32), jnp.int32)
+        return self.slot_mapping, self.block_tables, self._zero_base
+
+    def take_reports(self):
+        """What the layers reported while the step was traced, stacked
+        a name: ``{name: [layers, ...]}``."""
+        taken, self.reports = self.reports, {}
+        return {name: dispatch("stack_reports", _stack_impl, tuple(values),
+                               {}, differentiable=False)
+                for name, values in taken.items()}
 
     def stage_state(self, dec_index, row_slots, row_pos, chunk_meta):
         """Stage what the layers with per-request state read: each

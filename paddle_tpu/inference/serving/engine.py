@@ -55,6 +55,7 @@ See README.md §"Serving" for usage and knobs.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import os
 import time
@@ -215,8 +216,13 @@ class GenerationEngine:
         if kv_cache_dtype is None:
             kv_cache_dtype = os.environ.get(ENV_KV_DTYPE) or param.dtype
         self.max_batch = int(max_batch or max_batch_size())
+        chunk = min(int(prefill_chunk or prefill_chunk_size()),
+                    self.max_model_len)
+        self.prefill_chunk = max(1, chunk)
         # geometry from the model's own per-layer cache spec: which
-        # layers page K/V (and with how many KV heads), which keep a
+        # layers page K/V (with how many KV heads, in which group: a
+        # layer with a window is in a group with tables of its own,
+        # sized for a row's window and one chunk), which keep a
         # recurrent state or pooled keys per live request (a state
         # slot a row of the batch; the cache takes none without them)
         self.cache = PagedKVCache(
@@ -225,7 +231,7 @@ class GenerationEngine:
             max_model_len=self.max_model_len, hbm_fraction=hbm_fraction,
             prefix_cache=prefix_cache, tiering=kv_tiering,
             host_budget=kv_host_budget, resident_name=resident_name,
-            state_slots=self.max_batch)
+            state_slots=self.max_batch, window_span=self.prefill_chunk)
 
         # unified step geometry: one prefill chunk (padded to whole
         # q-blocks) + one q-block per decode row, ALL in a single
@@ -235,9 +241,6 @@ class GenerationEngine:
         # step geometry as its bf16 baseline.
         from ...core.dtypes import to_jax_dtype
         self.block_q = ragged_q_block(to_jax_dtype(param.dtype))
-        chunk = min(int(prefill_chunk or prefill_chunk_size()),
-                    self.max_model_len)
-        self.prefill_chunk = max(1, chunk)
         chunk_pad = -(-self.prefill_chunk // self.block_q) * self.block_q
         self.token_budget = (chunk_pad
                              + (self.max_batch - 1) * self.block_q)
@@ -256,11 +259,13 @@ class GenerationEngine:
         self.spec = SpeculativeConfig.resolve(speculative)
         self.proposer = None
         self.spec_cols = 1
-        if self.spec is not None and self.cache.state_slots:
+        if self.spec is not None and (self.cache.state_slots
+                                      or self.cache.window_groups):
             raise ValueError(
                 "speculative decoding rolls rejected drafts back with "
-                "truncate(); a recurrent state or pooled keys cannot be "
-                "rolled back, so this model decodes without it")
+                "truncate(); a recurrent state, pooled keys or blocks a "
+                "window gave back cannot be rolled back, so this model "
+                "decodes without it")
         if self.spec is not None:
             self.spec.k = max(1, min(self.spec.k, self.block_q - 1))
             self.spec_cols = self.spec.k + 1
@@ -275,6 +280,12 @@ class GenerationEngine:
         self._counters = dict.fromkeys((
             "decode_rows_carried", "prompt_tokens_carried",
             "prefill_chunks", "state_resets"), 0)
+        # how many layers read which group's blocks (the grouped path's
+        # counters are host arithmetic from each row's position)
+        self._window_layers = collections.Counter(
+            s.get("window") for s in self.cache.layer_specs
+            if s["kind"] == "paged_kv") if self._view.grouped else {}
+        self._step_reports = None
         self._step_fn = paddle.jit.to_static(self._ragged_step)
 
         # fault-tolerance knobs: a per-step wall-clock deadline (the
@@ -300,7 +311,7 @@ class GenerationEngine:
 
         self._rows = [None] * self.max_batch
         self._last_tokens = jnp.zeros((self.max_batch,), jnp.int64)
-        self._pending = []        # [(rows_reqs, device_tokens)]
+        self._pending = []   # [(rows_reqs, device_tokens, reports)]
         self._results = {}        # req.id -> Request
         self._streams = {}        # req.id -> TokenStream
         self._req_counter = 0
@@ -321,9 +332,13 @@ class GenerationEngine:
         view = self._view
         with no_grad():
             logits = self.model(ids, cache=view, use_cache=False)
-            return ragged_sample_next(
+            tok = ragged_sample_next(
                 logits, view.last_index, seeds, view.sample_pos,
                 do_sample, top_k, top_p, temperature)
+            # what the layers counted in the step (an expert layer's
+            # plan) leaves the program beside the tokens
+            reports = view.take_reports()
+            return (tok, reports) if reports else tok
 
     # -- multi-LoRA tenancy ---------------------------------------------
     def enable_lora(self, rank=8, alpha=None, targets=None,
@@ -796,6 +811,9 @@ class GenerationEngine:
         try:
             fault_point("serve.step_fail")
             tok = self._step_fn(ids_t, *args)
+            self._step_reports = None
+            if isinstance(tok, (tuple, list)):
+                tok, self._step_reports = tok
             fault_point("serve.step_hang")
         except Exception as e:
             self._abort_step(chunk, decodes, appended, "step_fail", e)
@@ -815,6 +833,9 @@ class GenerationEngine:
     def _dispatch_step(self, chunk, decodes, appended):
         """Pack the chunk + decode rows into the flat ragged buffer and
         dispatch the ONE compiled step."""
+        if self._view.grouped:
+            with obs.span("engine:release", boundary=True):
+                self._release_passed(chunk, decodes)
         with obs.span("engine:pack", boundary=True):
             ids_t, args, rows_reqs = self._pack_step(chunk, decodes)
         tok = self._spanned_dispatch(ids_t, args, chunk, decodes,
@@ -822,14 +843,60 @@ class GenerationEngine:
         self._last_tokens = tok._value
         for _, req in rows_reqs:
             req.n_scheduled += 1
-        if rows_reqs:
-            self._pending.append((rows_reqs, tok._value))
+        reports = self._step_reports and {
+            name: t._value for name, t in self._step_reports.items()}
+        if rows_reqs or reports:
+            self._pending.append((rows_reqs, tok._value, reports))
         if chunk is not None:
             req = chunk.request
             req.num_computed = chunk.start + chunk.length
             # landed blocks join the prefix index for future sharers
             self.cache.commit_prefix(
                 req.id, req.prompt[:req.num_computed])
+
+    def _release_passed(self, chunk, decodes):
+        """Before the step is packed: every windowed group gives back
+        the blocks that lie wholly behind the window of each row's first
+        token of this step and holds the chunk's new ones; and the
+        grouped layers' block counters, host arithmetic from each row's
+        position: K/V blocks (all KV heads) the step's decode rows and
+        the chunk's q-blocks read in the windowed and in the full
+        layers, and what the windowed layers would have read with no
+        window."""
+        cache, bs = self.cache, self.cache.block_size
+        spans = [(cache.length(r.id) - 1, 1, r.id) for r in decodes]
+        reads = [(p, p) for p, _, _ in spans]    # (first, last) token
+        if chunk is not None:
+            req, start, n = chunk
+            spans.append((start, n, req.id))
+            bq = self._view.chunk_block_q
+            reads += [(a, min(a + bq, start + n) - 1)
+                      for a in range(start, start + n, bq)]
+        released = sum(cache.write_window(rid, start, n)
+                       for start, n, rid in spans)
+        step = dict.fromkeys(("kv_blocks_read_window", "kv_blocks_read_full",
+                              "kv_blocks_context"), 0)
+        step["window_blocks_released"] = released
+        for window, layers in self._window_layers.items():
+            for first, last in reads:
+                whole = last // bs + 1
+                if window is None:
+                    step["kv_blocks_read_full"] += layers * whole
+                else:
+                    step["kv_blocks_context"] += layers * whole
+                    step["kv_blocks_read_window"] += layers * (
+                        whole - max(0, first - window + 1) // bs)
+        self._count(step)
+
+    def _count(self, step):
+        """A step's counts into the cumulative counters (`stats()`), and
+        into the registry while observability is on (``state.resets``,
+        ``sparse.*``, ``moe.*``, ``window.*``, ``kv.*``)."""
+        for name, n in step.items():
+            self._counters[name] = self._counters.get(name, 0) + n
+            if n and obs.enabled():
+                obs.get_registry().counter(
+                    name.replace("_", ".", 1)).inc(n)
 
     def _spanned_dispatch(self, ids_t, args, chunk, decodes, appended,
                           **decode_attrs):
@@ -873,6 +940,17 @@ class GenerationEngine:
         q_valids = np.zeros(NQB, np.int32)
         tables = np.zeros((S, W), np.int32)
         ctx = np.zeros(S, np.int32)
+        # a windowed group's own slots, tables and context bases
+        groups = [(g, np.zeros(T, np.int32),
+                   np.zeros((S, g.table_width), np.int32),
+                   np.zeros(S, np.int32)) for g in self.cache.window_groups]
+
+        def fill_groups(req, flat, start, n):
+            for g, g_slots, g_tables, g_base in groups:
+                g_slots[flat:flat + n] = g.slot_mapping(req.id, start, n)
+                g_tables[req.row] = g.block_table(req.id)
+                g_base[req.row] = g.context_base(req.id)
+
         last_index = np.zeros(S, np.int32)
         sample_pos = np.zeros(S, np.int64)
         lora_slots = None        # q-block -> adapter device slot
@@ -894,6 +972,7 @@ class GenerationEngine:
                 lora_slots[seg] = self._lora.store.slot_of(req.adapter)
             slots[flat] = self.cache.slot_mapping(
                 req.id, length - 1, 1)[0]
+            fill_groups(req, flat, length - 1, 1)
             positions[0, flat] = length - 1
             decode_feed.append((flat, r))
             tables[r] = self.cache.block_table(req.id)
@@ -908,6 +987,7 @@ class GenerationEngine:
             ids[0, flat:flat + n] = req.prompt[start:start + n]
             slots[flat:flat + n] = self.cache.slot_mapping(
                 req.id, start, n)
+            fill_groups(req, flat, start, n)
             positions[0, flat:flat + n] = np.arange(start, start + n)
             nseg = -(-n // BQ)
             for j in range(nseg):
@@ -929,7 +1009,9 @@ class GenerationEngine:
         self._view.set_inputs(slots, tables, ctx, positions, seq_ids,
                               q_starts, q_valids, last_index,
                               sample_pos)
-        if self.cache.state_slots:
+        for group in groups:
+            self._view.set_group_inputs(*group)
+        if self.cache.state_slots or self._view.grouped:
             self._stage_state(chunk, decodes)
         if lora_slots is not None:
             self._lora.stage(lora_slots)
@@ -968,12 +1050,9 @@ class GenerationEngine:
             req, start, n = chunk
             meta[1:] = (n, cache.slot(req.id), start == 0, req.row, start)
         step = self._view.stage_state(dec_index, row_slots, row_pos, meta)
-        step["state_resets"] = int(meta[3])
-        for name, n in step.items():
-            self._counters[name] = self._counters.get(name, 0) + n
-            if n and obs.enabled():     # "state.resets", "sparse.*"
-                obs.get_registry().counter(
-                    name.replace("_", ".", 1)).inc(n)
+        if cache.state_slots:
+            step["state_resets"] = int(meta[3])
+        self._count(step)
 
     # -- the speculative step -------------------------------------------
     def _run_spec_step(self, plan):
@@ -1215,12 +1294,25 @@ class GenerationEngine:
             return
         with obs.span("engine:drain", boundary=True, lag=lag):
             while len(self._pending) > lag:
-                rows_reqs, device_toks = self._pending.pop(0)
+                rows_reqs, device_toks, reports = self._pending.pop(0)
                 host = np.asarray(device_toks)
+                if reports:
+                    self._count_reports(reports)
                 for idx, req in rows_reqs:
                     if req.done:
                         continue     # tokens raced past EOS: discard
                     self._commit_token(req, int(host[idx]))
+
+    def _count_reports(self, reports):
+        """A drained step's reports into the counters: the expert
+        layers' plans (``moe_dispatch.plan_counters`` a layer)."""
+        moe = np.asarray(reports["moe"])                 # [layers, 4]
+        self._count({name: int(moe[:, n].sum()) for n, name in enumerate(
+            ("moe_assignments", "moe_experts_touched", "moe_plan_rows"))})
+        # the fullest expert of any layer of any step so far
+        self._counters["moe_max_expert_rows"] = max(
+            self._counters.get("moe_max_expert_rows", 0),
+            int(moe[:, 3].max()))
 
     def _collect_finished(self):
         for req in list(self.scheduler.running):

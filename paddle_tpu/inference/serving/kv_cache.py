@@ -57,6 +57,21 @@ restore either, so the prefix cache stands aside for such models
 (``prefix_bypassed`` / ``prefix_cache.bypassed_recurrent``), and a
 sequence with state cannot be exported to another pool.
 
+**Groups of paged layers** (a spec's ``window``): the paged layers that
+see the whole sequence (no ``window``) form the *full group*, which is
+everything above.  Layers that see only the last ``window`` tokens form
+a `WindowGroup` a window value: a pool, a free list and block tables of
+its own over the same block geometry, sized from the window, the most
+tokens a step writes to a row (``window_span``) and the rows
+(``state_slots``), so that a row's blocks are always there.  A windowed
+group gives a row's blocks back as its position passes them
+(`release_passed`), between the chunks of a long prompt too, and its
+table holds only what is still held, from a *context base*.  A block
+hash cannot restore blocks that were given back, so the prefix cache
+stands aside for such models (``prefix_cache.bypassed_window``), and a
+sequence cannot be exported.  ``max_model_len`` bounds the full group
+only.
+
 The pool tensors are ordinary framework Tensors.  The engine's
 ``to_static`` step functions read them (discovered as state) and write
 them via ``_inplace_update`` (mutated state → donated to XLA), so the
@@ -145,6 +160,113 @@ def prefix_cache_enabled():
         "0", "false", "off")
 
 
+class WindowGroup:
+    """The paged layers of one ``window``: a pool of blocks, a free list
+    and per-sequence tables of their own.  A sequence holds only the
+    blocks its next token can still see and the ones this step writes;
+    block 0 is the pad block, as in the full group."""
+
+    def __init__(self, window, layers, block_size, rows, span):
+        self.window, self.layers = int(window), tuple(layers)
+        self.block_size = int(block_size)
+        self.rows = int(rows)
+        #: a row's most blocks: the window behind a step's first token,
+        #: the ``span`` tokens the step writes, and the two partial
+        #: blocks at the ends
+        self.table_width = -(-(self.window + int(span))
+                             // self.block_size) + 2
+        self.num_blocks = self.rows * self.table_width + 1
+        self.bytes_per_block = 0       # set by the pool (its geometry)
+        self._free = list(range(self.num_blocks - 1, 0, -1))
+        self._first = {}       # seq_id -> logical index of its table[0]
+        self._tables = {}      # seq_id -> [block ids] from that index on
+        self.high_water = 0
+        self.released = 0      # blocks given back as positions passed
+
+    @property
+    def blocks_in_use(self):
+        return self.num_blocks - 1 - len(self._free)
+
+    @property
+    def pool_bytes(self):
+        return self.num_blocks * self.bytes_per_block
+
+    def admits(self):
+        """Whether one more sequence has a row's worth of blocks."""
+        return len(self._tables) < self.rows
+
+    def open(self, seq_id):
+        self._first[seq_id], self._tables[seq_id] = 0, []
+
+    def extend(self, seq_id, length):
+        """Hold blocks for every position below ``length``."""
+        table = self._tables[seq_id]
+        need = -(-int(length) // self.block_size) \
+            - self._first[seq_id] - len(table)
+        if need > len(self._free):
+            raise RuntimeError(
+                f"the window-{self.window} group has {len(self._free)} "
+                f"free blocks and sequence {seq_id!r} needs {need}: it "
+                "is sized so that every row's blocks are there")
+        for _ in range(need):
+            table.append(self._free.pop())
+        self.high_water = max(self.high_water, self.blocks_in_use)
+
+    def release_passed(self, seq_id, position):
+        """Give back the blocks that lie wholly behind the window of the
+        token at ``position`` (the lowest one this step computes).
+        Returns how many went."""
+        keep_from = max(0, int(position) - self.window + 1) \
+            // self.block_size
+        table = self._tables[seq_id]
+        gone = min(len(table), max(0, keep_from - self._first[seq_id]))
+        if gone:
+            self._free.extend(table[:gone])
+            del table[:gone]
+            self._first[seq_id] += gone
+            self.released += gone
+        return gone
+
+    def truncate(self, seq_id, length):
+        """Drop the blocks past ``length`` tokens (never one that was
+        given back: a roll-back ends at or after the step's first
+        token)."""
+        table = self._tables[seq_id]
+        keep = max(0, -(-int(length) // self.block_size)
+                   - self._first[seq_id])
+        while len(table) > keep:
+            self._free.append(table.pop())
+
+    def free(self, seq_id):
+        self._free.extend(self._tables.pop(seq_id, ()))
+        self._first.pop(seq_id, None)
+
+    def context_base(self, seq_id):
+        """Tokens before the first block the sequence still holds."""
+        return self._first[seq_id] * self.block_size
+
+    def slot_mapping(self, seq_id, start, count):
+        pos = np.arange(int(start), int(start) + int(count))
+        blocks = np.asarray(self._tables[seq_id], np.int32)[
+            pos // self.block_size - self._first[seq_id]]
+        return (blocks * self.block_size
+                + pos % self.block_size).astype(np.int32)
+
+    def block_table(self, seq_id):
+        out = np.zeros(self.table_width, np.int32)
+        table = self._tables[seq_id]
+        out[:len(table)] = table
+        return out
+
+    def stats(self):
+        return {"window": self.window, "layers": len(self.layers),
+                "num_blocks": self.num_blocks - 1,
+                "blocks_in_use": self.blocks_in_use,
+                "high_water": self.high_water,
+                "pool_bytes": self.pool_bytes,
+                "blocks_released": self.released}
+
+
 class PagedKVCache:
     """Block pool + allocator + per-sequence block tables + COW prefix
     cache.
@@ -160,7 +282,8 @@ class PagedKVCache:
                  dtype="float32", block_size=None, num_blocks=None,
                  max_model_len=None, hbm_fraction=0.3, register=True,
                  prefix_cache=None, resident_name=None, tiering=None,
-                 host_budget=None, layer_specs=None, state_slots=None):
+                 host_budget=None, layer_specs=None, state_slots=None,
+                 window_span=None):
         import jax.numpy as jnp
         from ...core.dtypes import to_jax_dtype
         from ...core.tensor import Tensor
@@ -182,12 +305,27 @@ class PagedKVCache:
         geometry = {(int(s["num_kv_heads"]), int(s["head_dim"]))
                     for _, s in paged}
         if len(geometry) != 1:
+            by_group = sorted({(s.get("window"), int(s["num_kv_heads"]),
+                                int(s["head_dim"])) for _, s in paged},
+                              key=str)
             raise ValueError(
-                "the paged layers of a model share one block pool "
-                f"geometry; got {sorted(geometry) or 'no paged layer'}")
-        #: model layer -> index into the per-paged-layer pools
-        self._kv_index = {i: n for n, (i, _) in enumerate(paged)}
-        self.num_layers = len(paged)
+                "the paged layers of a model, whatever group they are "
+                "in, share one block geometry (KV heads, head dim); got "
+                f"(window, heads, dim) {by_group or 'no paged layer'}")
+        # groups of paged layers by window: the layers with none are
+        # the full group (this object's own tables, free list and
+        # prefix cache); each window value is a WindowGroup
+        full = [(i, s) for i, s in paged if not s.get("window")]
+        if not full:
+            raise ValueError(
+                "a model's paged layers need at least one without a "
+                "window: the full group's tables carry every sequence's "
+                "length")
+        windows = sorted({int(s["window"]) for _, s in paged
+                          if s.get("window")})
+        #: model layer -> index into the full group's pools
+        self._kv_index = {i: n for n, (i, _) in enumerate(full)}
+        self.num_layers = len(full)
         (self.num_heads, self.head_dim), = geometry
         forced = {int(s["block_size"]) for _, s in paged
                   if s.get("block_size")}
@@ -249,6 +387,39 @@ class PagedKVCache:
                 vs.name = f"kv_cache.v_scale.layer{i}"
                 self._scales.append((ks, vs))
 
+        # -- windowed groups -----------------------------------------------
+        self.window_groups = []
+        self._layer_pool = {i: self._pools[n]
+                            for i, n in self._kv_index.items()}
+        self._group_of = {i: None for i in self._kv_index}
+        if windows and self.quantized:
+            raise NotImplementedError(
+                "an int8 pool keeps scale tables per block; the windowed "
+                "groups do not carry them yet")
+        if windows and not state_slots:
+            raise ValueError("windowed layers need state_slots (the rows "
+                             "their group is sized for)")
+        for window in windows:
+            layers = [i for i, s in paged if s.get("window") == window]
+            group = WindowGroup(window, layers, self.block_size,
+                                state_slots,
+                                window_span or self.block_size)
+            group.bytes_per_block = (2 * len(layers) * self.num_heads
+                                     * self.block_size * self.head_dim
+                                     * self._jdtype.itemsize)
+            gshape = (group.num_blocks,) + shape[1:]
+            for i in layers:
+                pair = []
+                for side in "kv":
+                    t = Tensor(jnp.zeros(gshape, self._jdtype),
+                               _internal=True, stop_gradient=True)
+                    t.name = f"kv_cache.{side}.window{window}.layer{i}"
+                    pair.append(t)
+                self._layer_pool[i] = tuple(pair)
+                self._group_of[i] = group
+            self.window_groups.append(group)
+        self.prefix_bypassed_window = 0   # admissions it stood aside for
+
         # -- per-request state (slot pools) --------------------------------
         # slot 0 is the pad slot (idle rows point at it, as padded
         # tokens point at block 0); a request holds one slot from
@@ -282,9 +453,10 @@ class PagedKVCache:
                 self._compressed[i] = t
         self._free_slots = list(range(self.state_slots, 0, -1))
         self._slot_of = {}     # seq_id -> state slot
-        if self.state_slots:
-            # a block hash cannot restore a recurrent state or the
-            # selector's pooled keys: the prefix cache stands aside
+        if self.state_slots or self.window_groups:
+            # a block hash cannot restore a recurrent state, the
+            # selector's pooled keys or blocks a window gave back: the
+            # prefix cache stands aside
             self.prefix_cache = False
         self.prefix_bypassed = 0   # admissions it stood aside for
 
@@ -305,13 +477,15 @@ class PagedKVCache:
         # -- host tier (tiering.py) --------------------------------------
         # evicted-but-indexed blocks spill into a bounded host ring; a
         # chain hash lives in EXACTLY one tier (_by_hash xor _host_of)
+        if self.window_groups:
+            tiering = False    # nothing is indexed, so nothing spills
         if tiering is None:
             tiering = kv_tiering_enabled() and kv_host_budget() is not None
         if host_budget is None:
             host_budget = kv_host_budget()
         if tiering and host_budget is None:
             # explicit tiering=True with no budget: mirror the HBM pool
-            host_budget = self.pool_bytes
+            host_budget = self.full_pool_bytes
         host_slots = (int(host_budget) // self.bytes_per_block
                       if tiering and host_budget else 0)
         self.host = None
@@ -355,6 +529,12 @@ class PagedKVCache:
 
     @property
     def pool_bytes(self):
+        """Bytes of every group's pool."""
+        return self.full_pool_bytes + sum(g.pool_bytes
+                                          for g in self.window_groups)
+
+    @property
+    def full_pool_bytes(self):
         return self.num_blocks * self.bytes_per_block
 
     @property
@@ -402,8 +582,13 @@ class PagedKVCache:
 
     # -- pool tensors ----------------------------------------------------
     def layer_pools(self, layer):
-        """(k_pool, v_pool) Tensors for one (model) layer."""
-        return self._pools[self._kv_index[layer]]
+        """(k_pool, v_pool) Tensors for one (model) layer, whichever
+        group it is in."""
+        return self._layer_pool[layer]
+
+    def layer_group(self, layer):
+        """The `WindowGroup` of a paged layer (None: the full group)."""
+        return self._group_of[layer]
 
     def layer_scales(self, layer):
         """(k_scale, v_scale) per-slot dequant tables for one layer
@@ -421,7 +606,10 @@ class PagedKVCache:
         return self._compressed[layer]
 
     def pool_tensors(self):
-        return ([t for kv in (self._pools + self._scales) for t in kv]
+        windowed = [self._layer_pool[i] for g in self.window_groups
+                    for i in g.layers]
+        return ([t for kv in (self._pools + self._scales + windowed)
+                 for t in kv]
                 + list(self._state.values())
                 + list(self._compressed.values()))
 
@@ -472,6 +660,8 @@ class PagedKVCache:
         livelock."""
         if self.state_slots and not self._free_slots:
             return False          # every state slot is held
+        if not all(g.admits() for g in self.window_groups):
+            return False          # every row of a windowed group is held
         chain = self._walk_chain(tokens, num_tokens, adapter=adapter)
         hbm_hits = [ref for _, kind, ref in chain if kind == "hbm"]
         # a HOST hit still consumes a physical block (the promotion
@@ -738,6 +928,8 @@ class PagedKVCache:
         fault_point("serve.alloc_fail")
         if self.state_slots and not self._free_slots:
             return False
+        if not all(g.admits() for g in self.window_groups):
+            return False
         chain = self._walk_chain(tokens, num_tokens, adapter=adapter)
         hbm_hits = [ref for _, kind, ref in chain if kind == "hbm"]
         host_slots = [ref for _, kind, ref in chain if kind == "host"]
@@ -806,6 +998,13 @@ class PagedKVCache:
                 self.prefix_bypassed += 1
                 obs.get_registry().counter(
                     "prefix_cache.bypassed_recurrent").inc()
+        for group in self.window_groups:
+            # a windowed group's blocks come as the steps write them
+            group.open(seq_id)
+        if self.window_groups and tokens is not None:
+            self.prefix_bypassed_window += 1
+            obs.get_registry().counter(
+                "prefix_cache.bypassed_window").inc()
         cached = len(chain) * self.block_size
         self._cached_len[seq_id] = cached
         if self.prefix_cache and tokens is not None:
@@ -979,8 +1178,21 @@ class PagedKVCache:
             self._ref[blk] = 1
             self._tables[seq_id].append(blk)
         self._lengths[seq_id] = length + int(num_tokens)
+        for group in self.window_groups:
+            group.extend(seq_id, length + int(num_tokens))
         self._update_gauges()
         return True
+
+    def write_window(self, seq_id, start, count):
+        """Before a step writes positions ``[start, start + count)`` of a
+        sequence: every windowed group gives back the blocks wholly
+        behind the window of ``start`` and holds blocks up to the last
+        position.  Returns the blocks given back (over groups)."""
+        gone = 0
+        for group in self.window_groups:
+            gone += group.release_passed(seq_id, start)
+            group.extend(seq_id, int(start) + int(count))
+        return gone
 
     def truncate(self, seq_id, length):
         """Shrink a sequence back to ``length`` tokens, releasing whole
@@ -997,6 +1209,8 @@ class PagedKVCache:
         keep = self.blocks_needed(length)
         while len(table) > keep:
             self._release(table.pop())
+        for group in self.window_groups:
+            group.truncate(seq_id, length)
         if length < self._lengths[seq_id]:
             # stale-guard epoch: anything spilled to the host ring
             # before this point must be re-verified against recomputed
@@ -1043,6 +1257,8 @@ class PagedKVCache:
             self._free_slots.append(slot)
         for blk in reversed(blocks):
             self._release(blk)
+        for group in self.window_groups:
+            group.free(seq_id)
         self._update_gauges()
         return len(blocks)
 
@@ -1065,11 +1281,12 @@ class PagedKVCache:
 
     # -- cross-pool transfer (disaggregated prefill -> decode) -----------
     def _no_state_transfer(self):
-        if self.state_slots:
+        if self.state_slots or self.window_groups:
             raise NotImplementedError(
                 "a sequence with per-request state (recurrent layers, "
-                "pooled keys) cannot move between pools yet: the "
-                "handoff payload carries K/V blocks only")
+                "pooled keys) or a windowed group's blocks cannot move "
+                "between pools yet: the handoff payload carries the "
+                "full group's K/V blocks only")
 
     def export_sequence(self, seq_id):
         """The sequence's paged KV state as a host-side
@@ -1177,6 +1394,13 @@ class PagedKVCache:
         self._lengths[seq_id] = length
         if adapter is not None:
             self._seq_adapter[seq_id] = adapter
+        for group in self.window_groups:
+            # a windowed group's blocks come as the steps write them
+            group.open(seq_id)
+        if self.window_groups and tokens is not None:
+            self.prefix_bypassed_window += 1
+            obs.get_registry().counter(
+                "prefix_cache.bypassed_window").inc()
         cached = len(chain) * self.block_size
         self._cached_len[seq_id] = cached
         if self.prefix_cache and tokens is not None:
@@ -1266,6 +1490,15 @@ class PagedKVCache:
             "state_pool_bytes": self.state_pool_bytes,
             "compressed_pool_bytes": self.compressed_pool_bytes,
             "prefix_bypassed_recurrent": self.prefix_bypassed,
+            "prefix_bypassed_window": self.prefix_bypassed_window,
+            "full_pool_bytes": self.full_pool_bytes,
+            "window_groups": [g.stats() for g in self.window_groups],
+            "window_pool_bytes": sum(g.pool_bytes
+                                     for g in self.window_groups),
+            "window_high_water": sum(g.high_water
+                                     for g in self.window_groups),
+            "window_blocks_released": sum(g.released
+                                          for g in self.window_groups),
         }
 
     def __repr__(self):

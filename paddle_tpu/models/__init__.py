@@ -15,6 +15,7 @@ from .ernie import (ErnieConfig, ErnieModel, ErnieForMaskedLM,
 from .moe_gpt import (MoEGPTConfig, MoEGPTModel, MoEGPTForCausalLM,
                       MoEGPTPretrainingCriterion)
 from .minicpm_sala import MiniCPMSALAConfig, MiniCPMSALAForCausalLM
+from .afmoe import AfmoeConfig, AfmoeForCausalLM
 from .generation import GenerationMixin, generate
 
 __all__ = [
@@ -25,5 +26,6 @@ __all__ = [
     "ErnieForSequenceClassification",
     "MoEGPTConfig", "MoEGPTModel", "MoEGPTForCausalLM",
     "MoEGPTPretrainingCriterion", "MiniCPMSALAConfig",
-    "MiniCPMSALAForCausalLM", "GenerationMixin", "generate",
+    "MiniCPMSALAForCausalLM", "AfmoeConfig", "AfmoeForCausalLM",
+    "GenerationMixin", "generate",
 ]

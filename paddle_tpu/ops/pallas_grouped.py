@@ -46,13 +46,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_fused import ACTIVATIONS, _act_f32, _act_grad_f32
+from .pallas_kernels import _mxu_dot
 from .pallas_tiles import (_demote_f64, _interpret, _kernel_span,
                            _min_rows, _pad_dim, _round_up, _x32,
                            group_segments, matmul_accum_blocks,
                            num_group_blocks)
 
+_NN = (((1,), (0,)), ((), ()))       # (m, k) . (k, n)
+
 __all__ = [
     "grouped_block_rows",
+    "grouped_gated_act",
+    "grouped_gated_act_ref",
     "grouped_layout",
     "grouped_linear_act",
     "grouped_linear_act_ref",
@@ -82,53 +87,134 @@ def grouped_layout(tokens, num_experts, dtype):
     return bm, nb, nb * bm
 
 
-def _gmm_fwd_kernel(gid_ref, x_ref, w_ref, b_ref, o_ref, z_ref, *, act):
-    """One (block, n-block) program: full-K f32 dot against the owning
-    expert's weight slice (gid routes the index map; the kernel body
-    never branches on it — null blocks hit the appended zero expert)."""
-    z = jax.lax.dot_general(
-        x_ref[:].astype(jnp.float32), w_ref[0].astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)             # (bm, bn)
-    z = z + b_ref[0].astype(jnp.float32)
-    z_ref[:] = z.astype(z_ref.dtype)
-    o_ref[:] = _act_f32(z, act).astype(o_ref.dtype)
+def _null_block(gid_ref, num_experts):
+    """Whether this program's row block belongs to no expert."""
+    return gid_ref[pl.program_id(0)] >= num_experts
+
+
+def _expert_block(gid, i, j, num_experts, offset=0):
+    """A weight index map's (expert, 0, column block) for row block
+    ``i``: a null block (``gid == num_experts``) names the last
+    expert's first column block whatever ``j`` is, so that a run of
+    null blocks fetches one tile and the stack needs no zero expert
+    appended."""
+    live = gid[i] < num_experts
+    return (jnp.minimum(gid[i], num_experts - 1), 0,
+            jnp.where(live, j, 0) + offset)
+
+
+def _gmm_fwd_kernel(gid_ref, x_ref, w_ref, *refs, act, num_experts,
+                    has_bias, save_z):
+    """One (block, n-block) program: full-K dot against the owning
+    expert's weight slice into a float32 accumulator (gid routes the
+    index map).  A null block does no dot: its rows are ``act(0)``."""
+    b_ref = refs[0] if has_bias else None
+    o_ref = refs[1 if has_bias else 0]
+    z_ref = refs[-1] if save_z else None
+    null = _null_block(gid_ref, num_experts)
+
+    def emit(z):
+        if z_ref is not None:
+            z_ref[:] = z.astype(z_ref.dtype)
+        o_ref[:] = _act_f32(z, act).astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_not(null))
+    def _live():
+        z = _mxu_dot(x_ref[:], w_ref[0], _NN)              # (bm, bn)
+        if b_ref is not None:
+            z = z + b_ref[0].astype(jnp.float32)
+        emit(z)
+
+    @pl.when(null)
+    def _null():
+        emit(jnp.zeros(o_ref.shape, jnp.float32))
 
 
 @_x32
-def _gmm_call(xp, wp, bp, gid, act, bm, bn, direction):
+def _gmm_call(xp, wp, bp, gid, act, bm, bn, direction, save_z=True):
     """Dispatch the grouped matmul pallas_call.  xp: [R, K] grouped
-    rows; wp: [E+1, K, n_pad] (zero null expert appended); bp:
-    [E+1, 1, n_pad]; gid: [R // bm] int32 block descriptors."""
+    rows; wp: [E, K, n_pad]; bp: [E, 1, n_pad] or None; gid: [R // bm]
+    int32 block descriptors (``E``: a null block).  Returns ``(out, z)``
+    (``z`` None unless ``save_z``)."""
     R, K = xp.shape
-    n_pad = wp.shape[2]
+    E, _, n_pad = wp.shape
     nb = R // bm
+    by_expert = lambda i, j, gid: _expert_block(gid, i, j, E)  # noqa: E731
+    in_specs = [pl.BlockSpec((bm, K), lambda i, j, gid: (i, 0)),
+                pl.BlockSpec((1, K, bn), by_expert)]
+    operands = [xp, wp]
+    if bp is not None:
+        in_specs.append(pl.BlockSpec((1, 1, bn), by_expert))
+        operands.append(bp)
+    out_spec = pl.BlockSpec((bm, bn), lambda i, j, gid: (i, j))
+    out_shape = jax.ShapeDtypeStruct((R, n_pad), xp.dtype)
     with _kernel_span("grouped_matmul", direction) as kernel_name:
-        out, z = pl.pallas_call(
-            functools.partial(_gmm_fwd_kernel, act=act),
+        outs = pl.pallas_call(
+            functools.partial(_gmm_fwd_kernel, act=act, num_experts=E,
+                              has_bias=bp is not None, save_z=save_z),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1,
                 grid=(nb, n_pad // bn),
-                in_specs=[
-                    pl.BlockSpec((bm, K), lambda i, j, gid: (i, 0)),
-                    pl.BlockSpec((1, K, bn),
-                                 lambda i, j, gid: (gid[i], 0, j)),
-                    pl.BlockSpec((1, 1, bn),
-                                 lambda i, j, gid: (gid[i], 0, j)),
-                ],
-                out_specs=[
-                    pl.BlockSpec((bm, bn), lambda i, j, gid: (i, j)),
-                    pl.BlockSpec((bm, bn), lambda i, j, gid: (i, j)),
-                ],
+                in_specs=in_specs,
+                out_specs=[out_spec] * (2 if save_z else 1),
             ),
-            out_shape=[
-                jax.ShapeDtypeStruct((R, n_pad), xp.dtype),
-                jax.ShapeDtypeStruct((R, n_pad), xp.dtype),
-            ],
+            out_shape=[out_shape] * (2 if save_z else 1),
             interpret=_interpret(),
             name=kernel_name,
-        )(gid, xp, wp, bp)
-    return out, z
+        )(gid, *operands)
+    return outs[0], (outs[1] if save_z else None)
+
+
+def _gmm_gated_kernel(gid_ref, x_ref, wg_ref, wu_ref, o_ref, *, act,
+                      num_experts):
+    """One (block, n-block) program of the gated form: the expert's gate
+    and up column blocks against the same resident rows, then
+    ``act(gate) * up``; operands in their own type into float32
+    accumulators.  No pre-activation is written."""
+    null = _null_block(gid_ref, num_experts)
+
+    @pl.when(jnp.logical_not(null))
+    def _live():
+        x = x_ref[:]
+        gate = _mxu_dot(x, wg_ref[0], _NN)
+        up = _mxu_dot(x, wu_ref[0], _NN)
+        o_ref[:] = (_act_f32(gate, act) * up).astype(o_ref.dtype)
+
+    @pl.when(null)
+    def _null():
+        o_ref[:] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+@_x32
+def _gmm_gated_call(xp, w, gid, act, bm, bn, direction="fwd"):
+    """xp: [R, K]; w: [E, K, 2 N], an expert's gate columns then its up
+    columns, N a multiple of ``bn``; gid: [R // bm].  Returns [R, N].
+    The stack is passed twice, read through two index maps: a program
+    takes column block ``j`` of the gate half and of the up half."""
+    R, K = xp.shape
+    E, _, n2 = w.shape
+    n = n2 // 2
+    nb, up0 = R // bm, n // bn
+
+    def w_spec(offset):
+        return pl.BlockSpec(
+            (1, K, bn),
+            lambda i, j, gid: _expert_block(gid, i, j, E, offset))
+
+    with _kernel_span("grouped_matmul", direction) as kernel_name:
+        return pl.pallas_call(
+            functools.partial(_gmm_gated_kernel, act=act, num_experts=E),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(nb, n // bn),
+                in_specs=[pl.BlockSpec((bm, K), lambda i, j, gid: (i, 0)),
+                          w_spec(0), w_spec(up0)],
+                out_specs=pl.BlockSpec((bm, bn), lambda i, j, gid: (i, j)),
+            ),
+            out_shape=jax.ShapeDtypeStruct((R, n), xp.dtype),
+            interpret=_interpret(),
+            name=kernel_name,
+        )(gid, xp, w, w)
 
 
 def _gmm_dw_kernel(gid_ref, x_ref, dz_ref, dw_ref):
@@ -187,30 +273,32 @@ def _gmm_dw_call(xp, dzp, gid, num_experts, bm, bk, bn):
     return dw
 
 
-def _stacked_pad(w, b, n_pad):
-    """Append the zero null expert and pad N: wp [E+1, K, n_pad],
-    bp [E+1, 1, n_pad]."""
-    E, K, N = w.shape
-    wp = _pad_dim(jnp.concatenate(
-        [w, jnp.zeros((1, K, N), w.dtype)], axis=0), 2, n_pad)
-    bp = _pad_dim(jnp.concatenate(
-        [b, jnp.zeros((1, N), b.dtype)], axis=0), 1, n_pad)[:, None, :]
-    return wp, bp
+def _padded(w, b, n_pad):
+    """Pad N to whole column blocks (no copy where it already is):
+    wp [E, K, n_pad], bp [E, 1, n_pad] or None."""
+    return (_pad_dim(w, 2, n_pad),
+            None if b is None else _pad_dim(b, 1, n_pad)[:, None, :])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _grouped_2d(x, w, b, gid, act):
-    return _grouped_2d_fwd(x, w, b, gid, act)[0]
+    # no gradient asked: the pre-activation is not written
+    return _grouped_2d_run(x, w, b, gid, act, save_z=False)[0]
 
 
-def _grouped_2d_fwd(x, w, b, gid, act):
+def _grouped_2d_run(x, w, b, gid, act, save_z):
     R, K = x.shape
     E, _, N = w.shape
     bm = R // gid.shape[0]
     _, bn, _, n_pad = matmul_accum_blocks(bm, K, N, x.dtype)
-    wp, bp = _stacked_pad(w, b, n_pad)
-    out, z = _gmm_call(x, wp, bp, gid, act, bm, bn, "fwd")
-    return out[:, :N], (x, w, b, gid, z[:, :N])
+    wp, bp = _padded(w, b, n_pad)
+    out, z = _gmm_call(x, wp, bp, gid, act, bm, bn, "fwd", save_z)
+    return out[:, :N], z
+
+
+def _grouped_2d_fwd(x, w, b, gid, act):
+    out, z = _grouped_2d_run(x, w, b, gid, act, save_z=True)
+    return out, (x, w, b, gid, z[:, :w.shape[2]])
 
 
 def _grouped_2d_bwd(act, res, g):
@@ -226,8 +314,8 @@ def _grouped_2d_bwd(act, res, g):
     # (contraction over N, output K); bias zeros, identity epilogue
     wt = jnp.swapaxes(w, 1, 2)                          # [E, N, K]
     _, bn2, _, k_pad = matmul_accum_blocks(bm, N, K, x.dtype)
-    wtp, btp = _stacked_pad(wt, jnp.zeros((E, K), x.dtype), k_pad)
-    dx_pad, _ = _gmm_call(dz, wtp, btp, gid, "none", bm, bn2, "bwd_dx")
+    dx_pad, _ = _gmm_call(dz, _pad_dim(wt, 2, k_pad), None, gid, "none",
+                          bm, bn2, "bwd_dx", save_z=False)
     dx = dx_pad[:, :K].astype(x.dtype)
     # dw through the grouped-accumulation kernel
     bk, bn, k_pad2, n_pad = _gmm_dw_blocks(K, N, x.dtype)
@@ -241,7 +329,7 @@ def _grouped_2d_bwd(act, res, g):
                    dw_full[:E, :K, :N], 0.0).astype(w.dtype)
     # db: per-expert row segment-sum (padding rows carry zero cotangent)
     row_gid = jnp.repeat(gid, bm)
-    db = jax.ops.segment_sum(
+    db = None if b is None else jax.ops.segment_sum(
         dz32, row_gid, num_segments=E + 1)[:E].astype(b.dtype)
     return dx, dw, db, np.zeros(gid.shape, dtype=jax.dtypes.float0)
 
@@ -272,21 +360,35 @@ def grouped_linear_act(x, w, b=None, *, block_group, act="none"):
     Pallas path (interpret mode off-TPU); differentiable in x, w, b.
 
     x: [R, K] rows in grouped layout (R = num_blocks * block_rows,
-    padding rows zero); w: [E, K, N] stacked expert weights; b: [E, N]
-    or None; block_group: [num_blocks] int32 from
-    `pallas_tiles.group_segments` (``E`` marks a null block).
-    Padding-row outputs are garbage-free but meaningless — callers
-    gather only the dispatched rows back out.
+    padding rows zero); w: [E, K, N] stacked expert weights, read where
+    they lie (no expert is appended and, with N in whole column blocks,
+    nothing is padded); b: [E, N] or None; block_group: [num_blocks]
+    int32 from `pallas_tiles.group_segments` (``E`` marks a null block,
+    whose rows come out as ``act(0)``).  Padding-row outputs are
+    garbage-free but meaningless — callers gather only the dispatched
+    rows back out.  Without a gradient the pre-activation is not
+    written.
     """
     if act not in ACTIVATIONS:
         raise ValueError(f"act must be one of {ACTIVATIONS}, got {act!r}")
     x, w, b = _demote_f64(x, w, b)
-    E, K, N = w.shape
-    if b is None:
-        b = jnp.zeros((E, N), x.dtype)
     _check_layout(x, w, b, block_group)
-    return _grouped_2d(x, w, b.astype(x.dtype),
+    return _grouped_2d(x, w, None if b is None else b.astype(x.dtype),
                        block_group.astype(jnp.int32), act)
+
+
+def _blocks_by_expert(x, w, block_group):
+    """The composites' view: rows as [nb, bm, K] float32, each block's
+    expert weights gathered (a null block takes the last expert's and
+    is masked by the caller), and which blocks are live."""
+    E = w.shape[0]
+    gid = block_group.astype(jnp.int32)
+    nb = gid.shape[0]
+    xb = x.reshape(nb, x.shape[0] // nb, x.shape[1]).astype(jnp.float32)
+    return xb, jnp.minimum(gid, E - 1), (gid < E)[:, None, None]
+
+
+_BLOCK_DOT = (((2,), (1,)), ((0,), (0,)))     # [nb, bm, K] . [nb, K, N]
 
 
 def grouped_linear_act_ref(x, w, b=None, *, block_group, act="none"):
@@ -299,23 +401,64 @@ def grouped_linear_act_ref(x, w, b=None, *, block_group, act="none"):
     if act not in ACTIVATIONS:
         raise ValueError(f"act must be one of {ACTIVATIONS}, got {act!r}")
     x, w, b = _demote_f64(x, w, b)
-    E, K, N = w.shape
-    if b is None:
-        b = jnp.zeros((E, N), x.dtype)
     _check_layout(x, w, b, block_group)
-    gid = block_group.astype(jnp.int32)
-    nb = gid.shape[0]
-    bm = x.shape[0] // nb
-    wp = jnp.concatenate([w, jnp.zeros((1, K, N), w.dtype)], axis=0)
-    bp = jnp.concatenate(
-        [b.astype(x.dtype), jnp.zeros((1, N), x.dtype)], axis=0)
-    xb = x.reshape(nb, bm, K).astype(jnp.float32)
-    wg = wp[gid].astype(jnp.float32)                    # [nb, K, N]
-    z = jax.lax.dot_general(
-        xb, wg, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    z = z + bp[gid][:, None, :].astype(jnp.float32)
-    return _act_f32(z, act).reshape(nb * bm, N).astype(x.dtype)
+    xb, eid, live = _blocks_by_expert(x, w, block_group)
+    z = jax.lax.dot_general(xb, w[eid].astype(jnp.float32), _BLOCK_DOT,
+                            preferred_element_type=jnp.float32)
+    if b is not None:
+        z = z + b.astype(x.dtype)[eid][:, None, :].astype(jnp.float32)
+    z = jnp.where(live, z, 0.0)
+    return _act_f32(z, act).reshape(x.shape[0], -1).astype(x.dtype)
+
+
+def _check_gated(x, w, block_group, act):
+    if act not in ACTIVATIONS:
+        raise ValueError(f"act must be one of {ACTIVATIONS}, got {act!r}")
+    if w.shape[2] % 2:
+        raise ValueError(f"a gated stack holds gate and up columns side "
+                         f"by side; got {w.shape[2]} columns")
+    _check_layout(x, w, None, block_group)
+
+
+def gated_block_n(block_rows, k, n, dtype) -> int:
+    """Column block of the gated form: two weight tiles a program, so
+    the budget is `matmul_accum_blocks`'s at twice the depth; a divisor
+    of ``n`` or 0 (no such block: the composite runs)."""
+    _, bn, _, _ = matmul_accum_blocks(block_rows, 2 * k, n, dtype)
+    while bn >= 128 and n % bn:
+        bn //= 2
+    return bn if bn >= 128 else 0
+
+
+def grouped_gated_act(x, w, *, block_group, act="silu"):
+    """``act(x @ w_gate[e]) * (x @ w_up[e])`` over block-aligned grouped
+    rows, one Mosaic program a (row block, column block): the SwiGLU
+    half of an expert.  x: [R, K]; w: [E, K, 2 N], each expert's gate
+    columns then its up columns, stored so once (N in whole 128-column
+    blocks); block_group as for `grouped_linear_act`.  Returns [R, N];
+    a null block's rows are zero.  Forward only: no pre-activation is
+    written and no gradient is defined."""
+    x, w = _demote_f64(x, w)
+    _check_gated(x, w, block_group, act)
+    bm = x.shape[0] // block_group.shape[0]
+    bn = gated_block_n(bm, x.shape[1], w.shape[2] // 2, x.dtype)
+    if not bn:
+        raise ValueError(f"{w.shape[2] // 2} gated columns do not split "
+                         "into 128-column blocks")
+    return _gmm_gated_call(x, w, block_group.astype(jnp.int32), act, bm, bn)
+
+
+def grouped_gated_act_ref(x, w, *, block_group, act="silu"):
+    """XLA composite of `grouped_gated_act` (the CPU's path and a
+    declined gate's): float32 dots batched over blocks."""
+    x, w = _demote_f64(x, w)
+    _check_gated(x, w, block_group, act)
+    n = w.shape[2] // 2
+    xb, eid, live = _blocks_by_expert(x, w, block_group)
+    both = jax.lax.dot_general(xb, w[eid].astype(jnp.float32), _BLOCK_DOT,
+                               preferred_element_type=jnp.float32)
+    out = _act_f32(both[..., :n], act) * both[..., n:]
+    return jnp.where(live, out, 0.0).reshape(x.shape[0], n).astype(x.dtype)
 
 
 # =====================================================================
@@ -444,19 +587,19 @@ def _lora_2d_bwd(act, res, g):
     # u = ds @ B[a]^T through the grouped kernel (contraction over N)
     bt = jnp.swapaxes(b, 1, 2)                          # [L, N, r]
     _, bn_u, _, r_pad = matmul_accum_blocks(bm, N, r, x.dtype)
-    btp, btb = _stacked_pad(bt, jnp.zeros((L, r), x.dtype), r_pad)
-    u_pad, _ = _gmm_call(ds, btp, btb, aid, "none", bm, bn_u, "bwd_dx")
+    u_pad, _ = _gmm_call(ds, _pad_dim(bt, 2, r_pad), None, aid, "none", bm,
+                         bn_u, "bwd_dx", save_z=False)
     u = u_pad[:, :r].astype(x.dtype)
     # dx = u @ A[a]^T
     at = jnp.swapaxes(a, 1, 2)                          # [L, r, K]
     _, bn_x, _, k_pad = matmul_accum_blocks(bm, r, K, x.dtype)
-    atp, atb = _stacked_pad(at, jnp.zeros((L, K), x.dtype), k_pad)
-    dx_pad, _ = _gmm_call(u, atp, atb, aid, "none", bm, bn_x, "bwd_dx")
+    dx_pad, _ = _gmm_call(u, _pad_dim(at, 2, k_pad), None, aid, "none", bm,
+                          bn_x, "bwd_dx", save_z=False)
     dx = dx_pad[:, :K].astype(x.dtype)
     # t = x @ A[a] recomputed (cheaper than a third fwd output)
     _, bn_t, _, r_pad2 = matmul_accum_blocks(bm, K, r, x.dtype)
-    ap2, ab2 = _stacked_pad(a, jnp.zeros((L, r), x.dtype), r_pad2)
-    t_pad, _ = _gmm_call(x, ap2, ab2, aid, "none", bm, bn_t, "fwd")
+    t_pad, _ = _gmm_call(x, _pad_dim(a, 2, r_pad2), None, aid, "none", bm,
+                         bn_t, "fwd", save_z=False)
     t = t_pad[:, :r].astype(x.dtype)
     # dA[l] = x^T @ u and dB[l] = t^T @ ds through the grouped dw
     # accumulator.  The accumulator's revisited-block init trick needs
@@ -643,8 +786,8 @@ def grouped_matmul_block_plan(tokens, k, n, num_experts,
         base["block_n"] = bn
         base["operands"] = [
             ("x", (bm, k), (rows, k), dtype),
-            ("w", (1, k, bn), (E + 1, k, n_pad), dtype),
-            ("b", (1, 1, bn), (E + 1, 1, n_pad), dtype),
+            ("w", (1, k, bn), (E, k, n_pad), dtype),
+            ("b", (1, 1, bn), (E, 1, n_pad), dtype),
             ("out", (bm, bn), (rows, n_pad), dtype),
             ("z", (bm, bn), (rows, n_pad), dtype),
         ]

@@ -35,6 +35,24 @@ chunk row fall out of the same predicate.  A fully masked row keeps
 ``l == 0`` and emits exact zeros — the same any-visible semantics as
 the XLA fallback (`serving/attention._ragged_ref`).
 
+Two static options, both ``None`` in the program above (whose lowering
+they leave as it was):
+
+    block_tokens  a q-block holds ``block_q / block_tokens`` *head
+                  groups* of ``block_tokens`` tokens each: row ``r`` is
+                  token ``r % block_tokens``, at position ``q_starts[i]
+                  + r % block_tokens``.  The ``H`` of ``q`` and of the
+                  pool is then the KV heads, and a q-block's rows are
+                  the query heads that share one: a KV block is read
+                  once for all of them (a prefill chunk with grouped KV
+                  heads; with ``block_tokens = 1``, a decode row's
+                  heads).  A KV block wholly after the q-block's last
+                  token is skipped.
+    window        row at position ``p`` sees ``c`` only if ``c > p -
+                  window``; a KV block wholly before the q-block's first
+                  token's window is skipped, and the first one read is
+                  masked inside.
+
 Gated through ``pallas_gate`` ("ragged_attention" probe);
 `ragged_block_plan` exports the exact specs for
 `analysis.tiling.audit_ragged_attention` / tpu_lint.
@@ -116,7 +134,7 @@ def ragged_segments(query_lens, context_lens, block_q,
 def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
                       q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
                       acc_ref, m_ref, l_ref, *, block_size, block_q,
-                      scale, w_last):
+                      scale, w_last, window=None, block_tokens=None):
     """One (q-block, head, table-slot) program over the paged pool.
 
     Scalar-prefetched ``seq_ids`` route each q-block to its sequence's
@@ -143,7 +161,15 @@ def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(w * block_size < ctx)
+    live = w * block_size < ctx
+    if block_tokens is not None:
+        # nothing after the q-block's last token
+        live &= w * block_size <= qs + (block_tokens - 1)
+    if window is not None:
+        # nothing wholly before the first token's window
+        live &= (w + 1) * block_size > qs - (window - 1)
+
+    @pl.when(live)
     def _block():
         q = q_ref[0].astype(jnp.float32)                # (bq, D)
         k = k_ref[0, 0].astype(jnp.float32)             # (bs, D)
@@ -155,9 +181,15 @@ def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         col = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
                + w * block_size)
+        if block_tokens is not None:
+            # head groups: row r is token r % block_tokens (a power of
+            # two) of the block
+            row = row & (block_tokens - 1)
         # causal inside the ragged segment: row r sits at absolute
         # position qs + r and padding rows (r >= qv) see nothing
         mask = (row < qv) & (col <= row + qs) & (col < ctx)
+        if window is not None:
+            mask &= col > row + (qs - window)
         s = jnp.where(mask, s, _NEG_INF)
         m_prev = m_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -206,7 +238,8 @@ def _ragged_attn_int8_kernel(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
 @_x32
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
                            seq_ids, q_starts, q_valids, block_q=None,
-                           scale=None, k_scales=None, v_scales=None):
+                           scale=None, k_scales=None, v_scales=None,
+                           window=None, block_tokens=None):
     """Mixed prefill+decode attention over the paged KV pool.
 
     q: [T, H, D] flat block-aligned ragged queries (T % block_q == 0);
@@ -220,6 +253,10 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     tables (kv_cache.py maintains them through every block lifecycle
     edge); the kernel walks them with the block tables and dequantizes
     in VMEM.
+
+    ``window`` and ``block_tokens`` (static; see the module doc) bound
+    what a row sees and lay head groups into a q-block; ``None`` for
+    both is the program without them.
     """
     q, k_pool, v_pool = _demote_f64(q, k_pool, v_pool)
     int8_kv = jnp.dtype(k_pool.dtype) == jnp.dtype(jnp.int8)
@@ -229,6 +266,15 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     if block_q is None:
         block_q = ragged_q_block(q.dtype)
     block_q = int(block_q)
+    if block_tokens is not None and (
+            block_tokens & (block_tokens - 1) or block_q % block_tokens):
+        raise ValueError(f"block_tokens {block_tokens} is no power of two "
+                         f"that divides block_q {block_q}")
+    options = {}
+    if window is not None:
+        options["window"] = int(window)
+    if block_tokens is not None:
+        options["block_tokens"] = int(block_tokens)
     if T % block_q:
         raise ValueError(f"flat query rows {T} not a multiple of "
                          f"block_q {block_q}")
@@ -280,7 +326,8 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
         out = pl.pallas_call(
             functools.partial(
                 kernel, block_size=block_size,
-                block_q=block_q, scale=float(scale), w_last=W - 1),
+                block_q=block_q, scale=float(scale), w_last=W - 1,
+                **options),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=5,
                 grid=(nqb, H, W),

@@ -6,7 +6,11 @@ described (not attached) TPU v5e:2x2 — the third rehearsal of the
 
 Programs: ``static`` (BERT-base train step, one chip), ``loop`` (its
 ``run_steps`` loop), ``mesh`` (the same step under dp=2,tp=2 over the
-four described chips), ``serve`` (the GPT engine's unified step).
+four described chips), ``serve`` (the GPT engine's unified step),
+``serve_afmoe`` (the engine's step for `benchmarks/configs/Trinity-
+Mini.json` at the cell's geometry, depth cut to a dense, a sliding
+expert and a full expert layer: both groups of block tables, the
+grouped expert kernel at 128 x 2048 x 1024, the 200,192-word head).
 Each prints its compile seconds, the Mosaic kernels and collectives in
 the compiled text, and ``memory_analysis()`` against the chip's 16 GB.
 
@@ -163,6 +167,45 @@ def check_serve(topo):
     engine.close()
 
 
+def check_serve_afmoe(topo):
+    """`check_serve` for the AFMoE family at the benchmark cell's sizes
+    (one CPU step at real widths first: a minute or two)."""
+    import json
+    pallas_gate.pallas_enabled = lambda name, manual=False: False
+    from benchmarks.families import afmoe
+    from paddle_tpu.inference.serving import GenerationEngine
+    with open(os.path.join(ROOT, "benchmarks/configs/Trinity-Mini.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(
+            ROOT, "benchmarks/traffic/mixedlen-closed32.json")) as f:
+        traffic = json.load(f)
+    kinds = cfg["layer_types"]
+    cfg.update(num_hidden_layers=3, layer_types=[kinds[0], kinds[1],
+                                                 kinds[-1]])
+    paddle.seed(cs.SEED)
+    engine = GenerationEngine(afmoe.build(cfg), **traffic["engine"])
+    engine.add_request(list(range(1, 1301)), max_new_tokens=2)
+    engine.step()
+    (entry,) = engine._step_fn._cache.values()
+    stats = engine.stats()
+    print(f"serve_afmoe: token_budget {engine.token_budget} table_width "
+          f"{engine.cache.table_width} pool {stats['full_pool_bytes']/1e9:.2f}G"
+          f" + {stats['window_pool_bytes']/1e9:.2f}G "
+          f"{stats['window_groups']}", flush=True)
+    open_gate()
+    chip = SingleDeviceSharding(topo.devices[0])
+    text = report("serve_afmoe", jax.jit(
+        lambda *a: entry["pure_fn"](*a), donate_argnums=(2,)).lower(
+        *_on(chip, entry["avals"])))
+    import re
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"bf16\[12[89],(?:2048|1024),(?:2048|1024)\]", line)
+             and re.search(r" (?:copy|pad|concatenate|transpose)\(", line)]
+    assert not moved, f"an expert stack is moved in the step: {moved}"
+    engine.close()
+    return text
+
+
 def main(names):
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -174,6 +217,8 @@ def main(names):
     names = names or ["serve", "static", "loop", "mesh"]
     if "serve" in names:        # before the gate opens for good
         check_serve(topo)
+    if "serve_afmoe" in names:
+        check_serve_afmoe(topo)
     open_gate()
     for name in names:
         if name == "static":
@@ -182,7 +227,7 @@ def main(names):
             check_static(topo, loop=True)
         elif name == "mesh":
             check_mesh(topo)
-        elif name != "serve":
+        elif name not in ("serve", "serve_afmoe"):
             raise SystemExit(f"unknown program {name!r}")
     print("AOT_SMOKE_OK", flush=True)
 

@@ -116,6 +116,30 @@ def test_serve_sala_tiny():
     assert c["step_program_compiles"] == 1 and out["kernels"] == {}
 
 
+def test_serve_afmoe_tiny():
+    """Float32 at width 64: the served rows equal the reference to
+    rounding while the windowed group releases."""
+    config = {**chip_smoke.afmoe_config(), "vocab_size": 97,
+              "hidden_size": 64, "intermediate_size": 96,
+              "moe_intermediate_size": 32, "num_attention_heads": 4,
+              "num_key_value_heads": 2, "head_dim": 16,
+              "sliding_window": 16, "num_experts": 16,
+              "num_experts_per_tok": 4, "kv_block_size": 8,
+              "dtype": "float32"}
+    out = chip_smoke.serve_afmoe(config, [70, 12], new_tokens=4, engine={
+        "max_batch": 2, "block_size": 8, "num_blocks": 32,
+        "max_model_len": 128, "prefill_chunk": 16})
+    c = out["checked"]
+    assert c["positions"] == 8
+    assert c["worst_row_rel_l2_vs_reference"] < 1e-4
+    assert chip_smoke.afmoe_config((1, 4), "float32")["num_dense_layers"] \
+        == 0
+    assert c["greedy_tokens_the_reference_agrees_with"] == 8
+    assert c["window_blocks_released"] > 0
+    assert c["kv_blocks_read_window"] < c["kv_blocks_context"]
+    assert c["step_program_compiles"] == 1 and out["kernels"] == {}
+
+
 def test_serve_tiny():
     out = chip_smoke.serve(GPT, [5, 40, 70, 90], new_tokens=6)
     c = out["checked"]

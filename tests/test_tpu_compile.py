@@ -346,6 +346,82 @@ def test_sala_kernel_compiles_for_v5e(one_chip, name):
     assert calls == [name], calls
 
 
+# -- Trinity-Mini's engine step (benchmarks/traffic/mixedlen-closed32) ---
+# 32 rows, a 1,024-token chunk, 32 query / 4 KV heads of 128; a full
+# group of 7,168 blocks of 64 tokens (224 table slots) and a windowed
+# group of 1,600 (50 slots, 34 read by a decode row); 128 experts of
+# 2048 x 1024, top-8 over the 1,520-row budget
+TRI_H, TRI_KV, TRI_D, TRI_ROWS, TRI_BUDGET = 32, 4, 128, 32, 1520
+
+
+def _trinity_experts():
+    from paddle_tpu.distributed.auto_parallel import moe_dispatch as md
+    return (lambda x, idx, w, gate_up, down, carried: md.gated_experts(
+                x, idx, w, gate_up, down, carried, use_pallas=True),
+            [((TRI_BUDGET, 2048), bf16), ((TRI_BUDGET, 8), i32),
+             ((TRI_BUDGET, 8), f32), ((128, 2048, 2048), bf16),
+             ((128, 1024, 2048), bf16), ((TRI_BUDGET,), jnp.bool_)])
+
+
+def _trinity_decode(window, blocks, width):
+    from paddle_tpu.inference.serving.attention import (
+        grouped_decode_attention)
+    pool = ((blocks + 1, TRI_KV, 64, TRI_D), bf16)
+    return (functools.partial(grouped_decode_attention, use_pallas=True,
+                              window=window, block_q=16),
+            [((TRI_ROWS, TRI_H, TRI_D), bf16), pool, pool,
+             ((TRI_ROWS, TRI_KV, width), i32), ((TRI_ROWS, TRI_KV), i32)])
+
+
+def _trinity_chunk(window, blocks, width):
+    from paddle_tpu.inference.serving.attention import (
+        grouped_chunk_attention)
+    pool = ((blocks + 1, TRI_KV, 64, TRI_D), bf16)
+    return (functools.partial(grouped_chunk_attention, window=window,
+                              chunk_bq=128, use_pallas=True),
+            [((1024, TRI_H, TRI_D), bf16), pool, pool, ((width,), i32)]
+            + [((), i32)] * 3)
+
+
+TRINITY_CASES = {
+    "experts_128x2048x1024": (_trinity_experts, ["grouped_matmul_fwd"] * 2),
+    "decode_rows_window": (lambda: _trinity_decode(2048, 1600, 34),
+                           ["ragged_attention_fwd"]),
+    "decode_rows_full": (lambda: _trinity_decode(None, 7168, 224),
+                         ["ragged_attention_fwd"]),
+    "chunk_window": (lambda: _trinity_chunk(2048, 1600, 50),
+                     ["ragged_attention_fwd"]),
+    "chunk_full": (lambda: _trinity_chunk(None, 7168, 224),
+                   ["ragged_attention_fwd"]),
+}
+
+
+@pytest.mark.parametrize("name", list(TRINITY_CASES))
+def test_trinity_kernel_compiles_for_v5e(one_chip, name):
+    """The grouped expert kernel's gated form and the ragged kernel's
+    window and head-group forms at the cell's sizes, under the names
+    the benchmark reads; and the expert stacks go to the kernel as they
+    lie: no copy, pad, concatenate or transpose of a stack."""
+    build, want = TRINITY_CASES[name]
+    fn, args = build()
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in args]
+    with jax.enable_x64(False):
+        text = jax.jit(fn).lower(*avals).compile().as_text()
+    calls = [span_reduce._INSTRUCTION.match(
+        line.strip().removeprefix("ROOT ")).group(1)
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls == want, calls
+    assert all(span_reduce.kernel_of(line.strip().removeprefix("ROOT "))
+               in span_reduce.KERNELS for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line)
+    moved = [m.group(0) for m in re.finditer(
+        r"bf16\[12[89],(?:2048|1024),(?:2048|1024)\](?:\{[^}]*\})? "
+        r"(?:copy|pad|concatenate|transpose)\(", text)]
+    assert not moved, moved
+
+
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_ring_flash_compiles_for_v5e_2x2(topo, one_chip, direction):
     """Mosaic kernels inside shard_map over the four described chips
