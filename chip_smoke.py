@@ -1,6 +1,6 @@
 """Prove that the trainer and the serving engine start on the chip.
 
-    python chip_smoke.py            # one TPU chip: eight phases
+    python chip_smoke.py            # one TPU chip: eleven phases
     python chip_smoke.py --chips 4  # four chips: the sharded phase only
     python chip_smoke.py --phase attention_dropout   # that phase alone
 
@@ -19,17 +19,20 @@ Every phase is a function of the model configuration and sizes:
 chip before chip time is spent.  The command line takes no size.
 
 This is a start-up proof, not a benchmark: the seconds it prints are
-wall time of whole phases, compilation included.
+wall time of whole phases, compilation included (`sampler_gate` alone
+reads device times of single programs from a profiler trace).
 """
 import argparse
 import contextlib
 import dataclasses
 import gc
+import glob
 import json
 import math
 import os
 import re
 import sys
+import tempfile
 import time
 from collections import Counter
 
@@ -869,6 +872,70 @@ def serve_afmoe(config, prompt_lens, new_tokens=8, engine=None):
                 "probe_ok": _probed()}}
 
 
+def _device_ms(fn, *args, calls=5):
+    """Median device milliseconds of one call of the compiled ``fn``
+    (already run once), from the profiler's ``XLA Modules`` line; None
+    where the trace holds no TPU plane (the CPU rehearsal)."""
+    from jax.profiler import ProfileData
+    with tempfile.TemporaryDirectory() as trace_dir:
+        with jax.profiler.trace(trace_dir):
+            for _ in range(calls):
+                jax.block_until_ready(fn(*args))
+        (path,) = glob.glob(os.path.join(
+            trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+        ms = [e.duration_ns / 1e6
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/device:TPU:")
+              for line in plane.lines if line.name == "XLA Modules"
+              for e in line.events]
+    return float(np.median(ms)) if ms else None
+
+
+def sampler_gate(rows, vocab):
+    """The in-graph sampler on ``rows`` float32 rows of ``vocab``
+    logits, as the engine's step reads them: a greedy batch, then the
+    same batch with one sampling row.  Tokens against the ungated
+    `_filter_and_draw` both times, and what each costs on the device:
+    the first takes the argmax alone, the second pays the whole filter
+    for every row."""
+    import jax.numpy as jnp
+    from paddle_tpu.inference.serving.engine import (_filter_and_draw,
+                                                     _ragged_sample_impl)
+    key = jax.random.PRNGKey(SEED)
+    logits = 4.0 * jax.random.normal(key, (1, rows, vocab), jnp.float32)
+    last = jnp.asarray(np.random.default_rng(SEED).permutation(rows)
+                       .astype(np.int32))
+    greedy = (jnp.arange(rows, dtype=jnp.int32),                # seeds
+              jnp.arange(50, 50 + rows, dtype=jnp.int64),    # positions
+              jnp.zeros(rows, bool), jnp.full(rows, 50, jnp.int32),
+              jnp.full(rows, 0.9, jnp.float32),
+              jnp.full(rows, 0.8, jnp.float32))
+    one = greedy[:2] + (greedy[2].at[rows // 2].set(True),) + greedy[3:]
+
+    def every_row_filtered(logits, last, *controls):
+        return _filter_and_draw(logits[0, last].astype(jnp.float32),
+                                *controls)
+
+    gated, ungated = (jax.jit(f).lower(logits, last, *greedy).compile()
+                      for f in (_ragged_sample_impl, every_row_filtered))
+    ms, drawn = {}, {}
+    for name, controls in (("greedy", greedy), ("one_sampling_row", one)):
+        got = np.asarray(gated(logits, last, *controls))
+        want = np.asarray(ungated(logits, last, *controls))
+        check((got == want).all(), f"{name}: gated tokens {got.tolist()}"
+              f" against ungated {want.tolist()}")
+        drawn[name] = int((got != np.asarray(
+            jnp.argmax(logits[0, last], axis=-1))).sum())
+        ms[name] = _device_ms(gated, logits, last, *controls)
+    ms["ungated_greedy"] = _device_ms(ungated, logits, last, *greedy)
+    check(drawn["greedy"] == 0, "a greedy batch left the argmax")
+    check(drawn["one_sampling_row"] <= 1,
+          "a greedy row beside the sampling row left the argmax")
+    return {"checked": {"rows": rows, "vocab": vocab,
+                        "tokens_off_the_argmax": drawn,
+                        "device_ms": ms}}
+
+
 def afmoe_config(layers=(0, 1, 4), dtype=None):
     """The benchmark's Trinity-Mini file with the layer list cut to a
     dense sliding, an expert sliding and an expert full layer (depth is
@@ -997,7 +1064,11 @@ def main(argv=None):
              {}),
             # the same kernels on float32 weights: two expert layers
             ("serve_afmoe_f32", serve_afmoe,
-             (afmoe_config((1, 4), "float32"), [3000, 700]), {})]
+             (afmoe_config((1, 4), "float32"), [3000, 700]), {}),
+            # the sampler alone at Trinity-Mini's batch and vocabulary:
+            # what a step pays for its first sampling row
+            ("sampler_gate", sampler_gate,
+             (32, afmoe_config()["vocab_size"]), {})]
     unknown = set(args.phase or ()) - {name for name, *_ in phases}
     if unknown:
         sys.exit(f"no such phase with --chips {args.chips}: "
@@ -1005,8 +1076,9 @@ def main(argv=None):
     lines = [run_phase(name, fn, *a, **kw) for name, fn, a, kw in phases
              if not args.phase or name in args.phase]
     for line in lines:
-        check(line["kernels"], f"phase {line['phase']}: no Pallas "
-              "tpu_custom_call in its compiled program")
+        # `sampler_gate` is XLA's alone and reports no kernels
+        check(line.get("kernels", True), f"phase {line['phase']}: no "
+              "Pallas tpu_custom_call in its compiled program")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": jax.device_count()}}), flush=True)

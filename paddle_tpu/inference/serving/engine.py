@@ -21,7 +21,10 @@ unified ragged step program:
     argmax, temperature, per-request top-k and top-p, with each draw
     keyed by ``fold_in(PRNGKey(request.seed), absolute_position)`` —
     deterministic under any schedule, chunking, batch packing, or
-    preemption;
+    preemption.  A step with no sampling row takes the argmax alone:
+    the filter and the draw sit under one ``lax.cond`` on the step's
+    own ``do_sample``/``temperature`` (``stats()['sampler_filter_steps']``
+    counts the steps that ran them);
   * the step loop never blocks the host: decode input ids are the
     previous step's device-side output array (an eager device scatter,
     no host read), and results drain lazily ``pipeline_depth - 1``
@@ -130,6 +133,19 @@ def _filter_and_draw(z, seeds, positions, do_sample, top_k, top_p,
     return jnp.where(use_sample, sampled, greedy).astype(jnp.int64)
 
 
+def _sample_rows(z, seeds, positions, do_sample, top_k, top_p,
+                 temperature):
+    """`_filter_and_draw` only when a row of the step samples: with
+    every row greedy (or at temperature 0) the softmax, the sorts, the
+    nucleus scatter and the draw cannot change a token, and the step
+    takes the argmax of the same float32 rows instead."""
+    return jax.lax.cond(
+        jnp.any(do_sample & (temperature.astype(jnp.float32) > 0)),
+        lambda: _filter_and_draw(z, seeds, positions, do_sample, top_k,
+                                 top_p, temperature),
+        lambda: jnp.argmax(z, axis=-1).astype(jnp.int64))
+
+
 def _ragged_sample_impl(logits, last_index, seeds, positions, do_sample,
                         top_k, top_p, temperature):
     """logits [1, T, V] (flat ragged step) -> next tokens, int64.
@@ -148,18 +164,20 @@ def _ragged_sample_impl(logits, last_index, seeds, positions, do_sample,
     Greedy rows take the argmax; sampling rows apply temperature ->
     top-k -> top-p (the dense baseline's filter order) and draw with a
     key folded from (seed, absolute position), so the result does not
-    depend on how the scheduler packed or when it ran this row."""
+    depend on how the scheduler packed or when it ran this row.  The
+    gather of the read rows is all a step without a sampling row pays
+    beside the argmax (`_sample_rows`)."""
     li = last_index.astype(jnp.int32)
     if li.ndim == 1:
         z = logits[0, li].astype(jnp.float32)
-        return _filter_and_draw(z, seeds, positions, do_sample, top_k,
-                                top_p, temperature)
+        return _sample_rows(z, seeds, positions, do_sample, top_k,
+                            top_p, temperature)
     S, C = li.shape
     z = logits[0, li.reshape(-1)].astype(jnp.float32)
     rep = lambda a: jnp.repeat(a, C, axis=0)  # noqa: E731
-    out = _filter_and_draw(z, rep(seeds), positions.reshape(-1),
-                           rep(do_sample), rep(top_k), rep(top_p),
-                           rep(temperature))
+    out = _sample_rows(z, rep(seeds), positions.reshape(-1),
+                       rep(do_sample), rep(top_k), rep(top_p),
+                       rep(temperature))
     return out.reshape(S, C)
 
 
@@ -275,11 +293,14 @@ class GenerationEngine:
                                      chunk_rows=chunk_pad)
         # cumulative, under their stats() names: what the steps carried
         # (decode rows, prompt tokens and the chunks they came in), the
-        # first chunks run for models with per-request state, and what
-        # the layer caches count as they stage (`stage_state`)
+        # first chunks run for models with per-request state, what
+        # the layer caches count as they stage (`stage_state`), and the
+        # steps packed with how many of them held a sampling row (the
+        # sampler's filter ran: `_sample_rows`)
         self._counters = dict.fromkeys((
             "decode_rows_carried", "prompt_tokens_carried",
-            "prefill_chunks", "state_resets"), 0)
+            "prefill_chunks", "state_resets", "sampler_steps",
+            "sampler_filter_steps"), 0)
         # how many layers read which group's blocks (the grouped path's
         # counters are host arithmetic from each row's position)
         self._window_layers = collections.Counter(
@@ -1231,7 +1252,9 @@ class GenerationEngine:
         return self._tensor(ids), args, spec_rows, chunk_row
 
     def _control_tensors(self, reqs, n):
-        """Per-row sampling controls; None entries are masked rows."""
+        """Per-row sampling controls; None entries are masked rows.
+        Counts the step, and whether its sampler's filter will run: the
+        predicate of `_sample_rows` on the same values."""
         seeds = np.zeros(n, np.int32)
         do_sample = np.zeros(n, bool)
         top_k = np.zeros(n, np.int32)
@@ -1245,6 +1268,13 @@ class GenerationEngine:
             top_k[i] = req.top_k
             top_p[i] = req.top_p
             temp[i] = req.temperature
+        filters = bool(np.any(do_sample & (temp > 0)))
+        self._counters["sampler_steps"] += 1
+        self._counters["sampler_filter_steps"] += filters
+        if obs.enabled():
+            obs.get_registry().counter(
+                "sampler.filter_steps" if filters
+                else "sampler.greedy_steps").inc()
         return tuple(self._tensor(a)
                      for a in (seeds, do_sample, top_k, top_p, temp))
 

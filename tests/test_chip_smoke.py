@@ -140,6 +140,16 @@ def test_serve_afmoe_tiny():
     assert c["step_program_compiles"] == 1 and out["kernels"] == {}
 
 
+def test_sampler_gate_tiny():
+    """Five rows of 301 logits: tokens of the gated sampler against the
+    ungated one, greedy and with one sampling row; no device, no time."""
+    c = chip_smoke.sampler_gate(5, 301)["checked"]
+    assert c["tokens_off_the_argmax"]["greedy"] == 0
+    assert set(c["device_ms"]) == {"greedy", "one_sampling_row",
+                                   "ungated_greedy"}
+    assert set(c["device_ms"].values()) == {None}
+
+
 def test_serve_tiny():
     out = chip_smoke.serve(GPT, [5, 40, 70, 90], new_tokens=6)
     c = out["checked"]

@@ -552,6 +552,128 @@ def test_ragged_sample_next_greedy_matches_argmax(columns):
     assert np.asarray(got).tolist() == want.tolist()
 
 
+def _sampler_case(mix, columns):
+    """Logits and per-row controls of one sampler call: ``mix`` picks
+    which rows sample, ``columns`` the ``[S, C]`` form of the index."""
+    import jax.numpy as jnp
+    vocab, rows, flat = 301, 5, 24
+    rng = np.random.RandomState(11)
+    logits = jnp.asarray(3.0 * rng.randn(1, flat, vocab).astype(np.float32))
+    last = rng.choice(flat - 2, size=rows, replace=False).astype(np.int32)
+    pos = np.arange(7, 7 + rows, dtype=np.int64)
+    if columns:
+        last = np.stack([last + j for j in range(columns)], axis=1)
+        pos = np.stack([pos + j for j in range(columns)], axis=1)
+    do_sample = {"greedy": np.zeros(rows, bool),
+                 "sampling": np.ones(rows, bool),
+                 "mixed": np.arange(rows) % 2 == 1,
+                 "temperature0": np.ones(rows, bool)}[mix]
+    temp = np.zeros(rows, np.float32) if mix == "temperature0" \
+        else np.linspace(0.6, 1.4, rows).astype(np.float32)
+    return (logits, jnp.asarray(last),
+            jnp.asarray(np.arange(100, 100 + rows, dtype=np.int32)),
+            jnp.asarray(pos), jnp.asarray(do_sample),
+            jnp.asarray(np.array([0, 5, 40, 1, 0], np.int32)),      # top_k
+            jnp.asarray(np.array([1.0, 0.9, 0.5, 1.0, 0.3], np.float32)),
+            jnp.asarray(temp))
+
+
+def _primitives(jaxpr):
+    """Names of every primitive under ``jaxpr``, sub-jaxprs included."""
+    import jax
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+@pytest.mark.parametrize("columns", [None, 3])
+def test_ragged_sample_greedy_branch_has_no_filter(columns):
+    """One ``cond`` in the sampler: the branch a step without a sampling
+    row takes holds the argmax and none of the filter or the draw; the
+    gather of the read rows stays outside it."""
+    import jax
+    from paddle_tpu.inference.serving.engine import _ragged_sample_impl
+    jaxpr = jax.make_jaxpr(_ragged_sample_impl)(
+        *_sampler_case("mixed", columns)).jaxpr
+    assert list(_primitives(jaxpr)).count("cond") == 1
+    (cond,) = (e for e in jaxpr.eqns if e.primitive.name == "cond")
+    greedy, sampling = (list(_primitives(b.jaxpr))
+                        for b in cond.params["branches"])
+    filter_ops = ("sort", "cumsum", "scatter", "random_bits")
+    assert "argmax" in greedy
+    assert not [n for n in greedy
+                if n.startswith(filter_ops) or n == "gather"]
+    for op in filter_ops:
+        assert any(n.startswith(op) for n in sampling), op
+    outside = [e.primitive.name for e in jaxpr.eqns]
+    assert "gather" in _primitives(jaxpr)
+    assert not [n for n in outside if n.startswith(filter_ops)]
+
+
+@pytest.mark.parametrize("columns", [None, 3])
+@pytest.mark.parametrize("mix", ["greedy", "sampling", "mixed",
+                                 "temperature0"])
+def test_ragged_sample_gate_matches_ungated(mix, columns):
+    """Tokens of the gated sampler are those of `_filter_and_draw` on
+    the same rows, for every mix of greedy and sampling rows."""
+    import jax.numpy as jnp
+    from paddle_tpu.inference.serving.engine import (_filter_and_draw,
+                                                     _ragged_sample_impl)
+    logits, last, seeds, pos, do_sample, top_k, top_p, temp = case = \
+        _sampler_case(mix, columns)
+    C = columns or 1
+    rep = lambda a: jnp.repeat(a, C, axis=0)  # noqa: E731
+    want = _filter_and_draw(
+        logits[0, last.reshape(-1)].astype(jnp.float32), rep(seeds),
+        pos.reshape(-1), rep(do_sample), rep(top_k), rep(top_p),
+        rep(temp)).reshape(last.shape)
+    got = _ragged_sample_impl(*case)
+    assert got.dtype == want.dtype and got.shape == last.shape
+    assert np.asarray(got).tolist() == np.asarray(want).tolist()
+    greedy = np.asarray(logits)[0][np.asarray(last)].argmax(-1)
+    differs = (np.asarray(got) != greedy).any()
+    assert differs == (mix in ("sampling", "mixed"))
+
+
+def test_engine_sampler_counters(gpt_mini):
+    """`sampler_steps` counts the steps packed, `sampler_filter_steps`
+    those that held a live sampling row: none of a greedy run, and of a
+    mixed run only the steps before the last sampling row finished."""
+    eng = GenerationEngine(gpt_mini, num_blocks=64, max_batch=3,
+                           max_model_len=64)
+    held = []
+    pack = eng._control_tensors
+
+    def recording(reqs, n):
+        held.append(any(r is not None and r.do_sample
+                        and r.temperature > 0 for r in reqs))
+        return pack(reqs, n)
+
+    eng._control_tensors = recording
+    try:
+        prompts = _prompts((5, 9, 3), seed=3)
+        eng.generate(prompts, max_new_tokens=6)
+        s = eng.stats()
+        assert s["sampler_steps"] == len(held) > 0
+        assert s["sampler_filter_steps"] == 0 and not any(held)
+
+        del held[:]
+        eng.add_request(prompts[0], max_new_tokens=14)
+        eng.add_request(prompts[1], max_new_tokens=4, do_sample=True,
+                        top_k=10, temperature=0.7, seed=5)
+        eng.add_request(prompts[2], max_new_tokens=14, do_sample=True,
+                        temperature=0.0)
+        while eng.has_unfinished():
+            eng.step()
+        d = {k: eng.stats()[k] - s[k]
+             for k in ("sampler_steps", "sampler_filter_steps")}
+        assert d["sampler_steps"] == len(held)
+        assert 0 < d["sampler_filter_steps"] == sum(held) < len(held)
+    finally:
+        eng.close()
+
+
 def test_top_p_sampling_deterministic_under_seed():
     from paddle_tpu.incubate.nn.functional import top_p_sampling
     rng = np.random.RandomState(9)
