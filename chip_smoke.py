@@ -777,17 +777,17 @@ def serve_sala(config, prompt_lens, new_tokens=8, engine=None):
 AFMOE_REL_L2 = {"bfloat16": (0.5, 3e-2), "float32": (1e-3, 1e-3)}
 
 
-def serve_afmoe(config, prompt_lens, new_tokens=8, engine=None):
-    """The AFMoE family at the published widths, a dense sliding, an
-    expert sliding and an expert full layer: prefill in chunks then
-    decode through the ``GenerationEngine`` against the plain
-    reference's full forward, on logits (the step compiled with one
-    more output, the logits row each request samples from, as
-    `serve_sala` does).  Both groups of block tables, the grouped
-    expert kernel and the ragged kernel's window and head-group forms
-    run; a prompt longer than the window makes its group release."""
+def _served_against_reference(family, config, prompt_lens, new_tokens,
+                              engine):
+    """Prefill in chunks then decode through the ``GenerationEngine``
+    against ``family``'s plain reference's full forward, on logits (the
+    step compiled with one more output, the logits row each request
+    samples from, as `serve_sala` does).  Returns the step's Mosaic
+    kernels, the relative L2 of every sampled row, how many greedy
+    tokens are the reference's argmax, the engine's stats and how often
+    the step compiled."""
     import jax.numpy as jnp
-    from benchmarks.families import _plain, afmoe as family
+    from benchmarks.families import _plain
     from paddle_tpu.core.dispatch import dispatch
     from paddle_tpu.inference.serving.engine import ragged_sample_next
     paddle.seed(SEED)
@@ -804,22 +804,23 @@ def serve_afmoe(config, prompt_lens, new_tokens=8, engine=None):
     def tapped(ids, seeds, *controls):
         with paddle.no_grad():
             logits = model(ids, cache=view, use_cache=False)
-            view.take_reports()          # the plan counters: not read here
             picked = dispatch(
                 "tap_rows", lambda z, i: z[0, i].astype(jnp.float32),
                 (logits, view.last_index), {}, differentiable=False)
-            return ragged_sample_next(logits, view.last_index, seeds,
-                                      view.sample_pos, *controls), picked
+            tok = ragged_sample_next(logits, view.last_index, seeds,
+                                     view.sample_pos, *controls)
+            # the plan counters leave the step as they do the engine's
+            return tok, picked, view.take_reports()
 
     step_fn = paddle.jit.to_static(tapped)
 
     def step(ids, *args):
-        tok, picked = step_fn(ids, *args)
+        tok, picked, reports = step_fn(ids, *args)
         where = np.asarray(view.sample_pos._value)
         for r, req in enumerate(eng._rows):
             if req is not None and where[r] > 0:
                 rows[(req.id, int(where[r]))] = (r, picked._value)
-        return tok
+        return tok, reports
 
     step._cache = step_fn._cache
     eng._step_fn = step
@@ -853,23 +854,53 @@ def serve_afmoe(config, prompt_lens, new_tokens=8, engine=None):
           and median <= median_limit,
           f"served logits differ from the reference by {sorted(rel)} "
           "(rel L2 a row)")
+    return kernels, stats, {
+        "requests": len(prompts), "prompt_lens": list(prompt_lens),
+        "positions": len(rel),
+        "worst_row_rel_l2_vs_reference": worst,
+        "median_row_rel_l2_vs_reference": median,
+        "rel_l2_tolerance_worst_median": [worst_limit, median_limit],
+        "greedy_tokens_the_reference_agrees_with": agree,
+        "step_program_compiles": len(step_fn._cache),
+        "probe_ok": _probed()}
+
+
+def serve_afmoe(config, prompt_lens, new_tokens=8, engine=None):
+    """The AFMoE family at the published widths, a dense sliding, an
+    expert sliding and an expert full layer, through the engine against
+    the plain reference (`_served_against_reference`).  Both groups of
+    block tables, the grouped expert kernel and the ragged kernel's
+    window and head-group forms run; a prompt longer than the window
+    makes its group release."""
+    from benchmarks.families import afmoe as family
+    kernels, stats, checked = _served_against_reference(
+        family, config, prompt_lens, new_tokens, engine)
     check(stats["window_blocks_released"] > 0,
           "no block was released: the contexts are inside the window")
-    return {"kernels": kernels,
-            "checked": {
-                "requests": len(prompts), "prompt_lens": list(prompt_lens),
-                "positions": len(rel),
-                "worst_row_rel_l2_vs_reference": worst,
-                "median_row_rel_l2_vs_reference": median,
-                "rel_l2_tolerance_worst_median": [worst_limit,
-                                                  median_limit],
-                "greedy_tokens_the_reference_agrees_with": agree,
-                "window_blocks_released": stats["window_blocks_released"],
-                "window_high_water": stats["window_high_water"],
-                "kv_blocks_read_window": stats["kv_blocks_read_window"],
-                "kv_blocks_context": stats["kv_blocks_context"],
-                "step_program_compiles": len(step_fn._cache),
-                "probe_ok": _probed()}}
+    return {"kernels": kernels, "checked": {**checked, **{k: stats[k] for k in (
+        "window_blocks_released", "window_high_water",
+        "kv_blocks_read_window", "kv_blocks_context")}}}
+
+
+def serve_qwen3_next(config, prompt_lens, new_tokens=8, engine=None):
+    """The Qwen3-Next family at the published widths, one period (delta,
+    delta, delta, attention) with a share of the experts held, through
+    the engine against the plain reference given the same share
+    (`_served_against_reference`).  Both gated delta rule kernels, the
+    state and convolution pools, the grouped expert kernel over the held
+    experts and the ragged kernel at head width 256 run; the prompts
+    cross chunk boundaries, and two requests share the step."""
+    from benchmarks.families import qwen3_next as family
+    kernels, stats, checked = _served_against_reference(
+        family, config, prompt_lens, new_tokens, engine)
+    check({"gated_delta_rule_fwd", "gated_delta_rule_step_fwd"}
+          <= set(kernels) or jax.devices()[0].platform != "tpu",
+          f"the gated delta rule kernels are not in the step: {kernels}")
+    check(0 < stats["moe_assignments"] < stats["moe_assignments_routed"],
+          "every routed assignment was dispatched here: no share is held")
+    return {"kernels": kernels, "checked": {**checked, **{k: stats[k] for k in (
+        "state_resets", "state_pool_bytes", "moe_assignments",
+        "moe_assignments_routed", "kv_blocks_read_full")}}}
 
 
 def _device_ms(fn, *args, calls=5):
@@ -950,6 +981,20 @@ def afmoe_config(layers=(0, 1, 4), dtype=None):
                                     for i in layers),
             "dtype": dtype or config["dtype"],
             "layer_types": [config["layer_types"][i] for i in layers]}
+
+
+def qwen3_next_config(layers=4, chips=32, dtype="float32"):
+    """The benchmark's Qwen3-Next file cut to one period of layers and
+    to one of ``chips`` chips' share of the experts (16 of 512), which
+    fits the chip in float32 (depth and the share are what a smoke may
+    cut; no width is)."""
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmarks", "configs",
+                           "Qwen3-Next-80B-A3B-Instruct.json")) as f:
+        config = json.load(f)
+    return {**config, "num_hidden_layers": layers, "dtype": dtype,
+            "num_experts": config["published_num_experts"] // chips,
+            "expert_shard": {"chips": chips, "index": 0}}
 
 
 def sala_config(layers=("minicpm4", "lightning-attn", "lightning-attn",
@@ -1065,6 +1110,9 @@ def main(argv=None):
             # the same kernels on float32 weights: two expert layers
             ("serve_afmoe_f32", serve_afmoe,
              (afmoe_config((1, 4), "float32"), [3000, 700]), {}),
+            # 2,500 tokens cross two chunk boundaries, 700 fit one chunk
+            ("serve_qwen3_next_f32", serve_qwen3_next,
+             (qwen3_next_config(), [2500, 700]), {}),
             # the sampler alone at Trinity-Mini's batch and vocabulary:
             # what a step pays for its first sampling row
             ("sampler_gate", sampler_gate,

@@ -18,9 +18,13 @@ the stable argsort gives tokens of one expert their arrival order):
     the k choices per token.
 
 `sigmoid_topk_router` is the sigmoid router with a selection-only bias,
-and `gated_experts` strings plan, scatter, the gated grouped kernel,
-the down projection and the combine together for a SwiGLU expert layer
-(`models/afmoe.py`).
+`softmax_topk_router` the softmax router with renormalised top-k, and
+`gated_experts` strings plan, scatter, the gated grouped kernel, the
+down projection and the combine together for a SwiGLU expert layer
+(`models/afmoe.py`, `models/qwen3_next.py`).  A layer that holds only
+the experts ``held = (lo, hi)`` of those the router chooses among plans
+its own: every other assignment goes where the rows that carry nothing
+go, and the result is the part that its experts give.
 
 Expert parallelism crosses the ``ep`` mesh axis with all-to-all.
 `ring_all_to_all_local` decomposes that collective into per-peer
@@ -43,7 +47,7 @@ from ...ops.pallas_tiles import group_segments, num_group_blocks
 __all__ = [
     "dropless_combine", "dropless_dispatch", "dropless_plan",
     "expert_imbalance", "gated_experts", "plan_counters",
-    "ring_all_to_all_local", "sigmoid_topk_router",
+    "ring_all_to_all_local", "sigmoid_topk_router", "softmax_topk_router",
 ]
 
 
@@ -69,11 +73,29 @@ def sigmoid_topk_router(logits, bias, top_k, route_scale=1.0,
     return idx.astype(jnp.int32), w * route_scale
 
 
+def softmax_topk_router(logits, top_k, norm_topk=True):
+    """A softmax router.  ``logits`` [N, E] float32; the chosen set is
+    the ``top_k`` largest of ``softmax(logits)`` (ties to the lower
+    index), a chosen expert's weight its probability, over the chosen
+    probabilities' sum with ``norm_topk``.  Returns ``(topk_idx,
+    topk_weight)`` [N, top_k], int32 and float32."""
+    w, idx = jax.lax.top_k(jax.nn.softmax(logits.astype(jnp.float32), -1),
+                           top_k)
+    if norm_topk:
+        w = w / w.sum(-1, keepdims=True)
+    return idx.astype(jnp.int32), w
+
+
 def dropless_plan(topk_idx, num_experts, block_rows, num_blocks=None,
-                  carried=None):
+                  carried=None, held=None):
     """Plan the grouped layout for top-k assignments — droplessly.
 
-    ``topk_idx``: [N, k] int expert choices.  ``num_blocks`` must be
+    ``topk_idx``: [N, k] int expert choices.  With ``held = (lo, hi)``
+    the choices are among more experts than this plan lays out: the
+    ``num_experts = hi - lo`` experts ``lo <= e < hi`` are planned as
+    groups ``e - lo`` and an assignment to any other expert is
+    dispatched nowhere, as a token's that carries nothing.
+    ``num_blocks`` must be
     the static `pallas_tiles.num_group_blocks(N * k, num_experts,
     block_rows)` (computed here when N is concrete).  ``carried`` [N]
     bool marks the tokens that carry something (the serving engine's
@@ -97,6 +119,10 @@ def dropless_plan(topk_idx, num_experts, block_rows, num_blocks=None,
     N, k = topk_idx.shape
     T = N * k
     e_flat = topk_idx.reshape(-1).astype(jnp.int32)
+    if held is not None:
+        lo, hi = held
+        e_flat = jnp.where((e_flat >= lo) & (e_flat < hi), e_flat - lo,
+                           num_experts)
     if carried is not None:
         e_flat = jnp.where(jnp.repeat(carried, k), e_flat, num_experts)
     # group `num_experts` collects what goes nowhere
@@ -139,30 +165,39 @@ def dropless_combine(y_rows, rows, topk_val):
         g.astype(jnp.float32)).astype(y_rows.dtype)
 
 
-def plan_counters(counts, block_rows):
+def plan_counters(counts, block_rows, routed):
     """What a plan dispatched, as int32 ``[assignments, experts
     touched, rows of the grouped buffer the kernel runs over (padding
-    included), the fullest expert's rows]``."""
+    included), the fullest expert's rows, assignments routed]``: the
+    first four of the experts planned here, the last (``routed``) over
+    all the experts the router chose among."""
     blocks = (counts + block_rows - 1) // block_rows
     return jnp.stack([counts.sum(), (counts > 0).sum(),
                       blocks.sum() * block_rows,
-                      counts.max()]).astype(jnp.int32)
+                      counts.max(), routed]).astype(jnp.int32)
 
 
 def gated_experts(x, topk_idx, topk_weight, w_gate_up, w_down,
-                  carried=None, act="silu", use_pallas=False):
+                  carried=None, act="silu", use_pallas=False, held=None):
     """The routed half of a gated expert layer on flat tokens: plan,
     scatter, ``act(x @ w_gate[e]) * (x @ w_up[e])`` then ``@ w_down[e]``
     through the grouped kernel (or its composite), weighted sum.
     ``w_gate_up`` [E, D, 2 I] and ``w_down`` [E, I, D] are read where
-    they lie.  Returns ``(y [N, D], plan_counters)``."""
+    they lie.  ``held = (lo, hi)``: the stacks are experts ``lo ... hi -
+    1`` of those ``topk_idx`` names (``E = hi - lo``), and ``y`` is the
+    part of the layer's result that they give; without it the stacks
+    are all the experts.  Returns ``(y [N, D], plan_counters)``."""
     from ...ops import pallas_grouped as pg
     N, k = topk_idx.shape
     E = w_gate_up.shape[0]
+    if held is not None and held[1] - held[0] != E:
+        raise ValueError(f"experts {held[0]} to {held[1]} held, stacks "
+                         f"of {E}")
     bm, nb, rows_total = pg.grouped_layout(N * k, E, x.dtype)
     use_pallas = use_pallas and bool(pg.gated_block_n(
         bm, x.shape[1], w_gate_up.shape[2] // 2, x.dtype))
-    rows, gid, counts = dropless_plan(topk_idx, E, bm, nb, carried)
+    rows, gid, counts = dropless_plan(topk_idx, E, bm, nb, carried, held)
+    routed = k * (N if carried is None else carried.sum())
     xd = dropless_dispatch(x, rows, k, rows_total)
     gated, down = (pg.grouped_gated_act, pg.grouped_linear_act) \
         if use_pallas else (pg.grouped_gated_act_ref,
@@ -170,7 +205,7 @@ def gated_experts(x, topk_idx, topk_weight, w_gate_up, w_down,
     h = gated(xd, w_gate_up, block_group=gid, act=act)
     y_rows = down(h, w_down, block_group=gid)
     return (dropless_combine(y_rows, rows, topk_weight),
-            plan_counters(counts, bm))
+            plan_counters(counts, bm, routed))
 
 
 def expert_imbalance(counts):

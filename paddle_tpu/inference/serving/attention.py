@@ -39,7 +39,7 @@ hands each layer one of three layer caches, chosen from the cache's
 attention; with grouped KV heads or a ``window`` in its spec, the decode
 rows and the chunk apart, over its group's table), ``SparseLayerCache`` (paged K/V with grouped heads plus the
 selector's pooled keys in a state slot) and ``RecurrentLayerCache`` (no
-K/V, one state a head in the request's slot).  ``kv_blocks_gather`` /
+K/V, the layer's named states in the request's slot).  ``kv_blocks_gather`` /
 ``kv_blocks_scatter`` move whole pool blocks to and from the host
 (tiering, disaggregation).
 """
@@ -310,24 +310,16 @@ def ragged_attention(q, k_pool, v_pool, block_tables, context_lens,
 # ---------------------------------------------------------------------
 # the model-facing cache adapter
 # ---------------------------------------------------------------------
-class RaggedLayerCache:
-    """One layer's view of the ragged mixed-batch step."""
+class _LayerCache:
+    """What every kind of layer cache gives a model's layer beside its
+    own state: which rows of the step carry a token, and a way to hand
+    the engine what the layer counted."""
 
-    __slots__ = ("_view", "_layer", "_window", "_grouped")
+    __slots__ = ("_view", "_layer")
 
     def __init__(self, view, layer):
         self._view = view
         self._layer = layer
-        spec = view.cache.layer_specs[layer]
-        self._window = spec.get("window")
-        #: query heads that share KV heads, or a window: the decode rows
-        #: and the chunk go through the kernel apart (`_attend_grouped`)
-        self._grouped = layer_is_grouped(spec)
-
-    @property
-    def lora(self):
-        """The multi-LoRA segment state (serving.lora), or None."""
-        return self._view.lora
 
     def carried_rows(self):
         """``[T]`` bool: the rows of the flat buffer that carry a token
@@ -342,6 +334,25 @@ class RaggedLayerCache:
         plan counters): it leaves the step's program with the sampled
         tokens and is read where they are."""
         self._view.reports.setdefault(name, []).append(value)
+
+
+class RaggedLayerCache(_LayerCache):
+    """One layer's view of the ragged mixed-batch step."""
+
+    __slots__ = ("_window", "_grouped")
+
+    def __init__(self, view, layer):
+        super().__init__(view, layer)
+        spec = view.cache.layer_specs[layer]
+        self._window = spec.get("window")
+        #: query heads that share KV heads, or a window: the decode rows
+        #: and the chunk go through the kernel apart (`_attend_grouped`)
+        self._grouped = layer_is_grouped(spec)
+
+    @property
+    def lora(self):
+        """The multi-LoRA segment state (serving.lora), or None."""
+        return self._view.lora
 
     def _attend_grouped(self, q, k, v):
         """Grouped KV heads, or a window: scatter, then the decode rows
@@ -638,15 +649,59 @@ def _lightning_update_impl(q, k, v, pool, dec_index, row_slots, meta, *,
     return _merge_rows(q0, chunk_out, dec_out, meta, dec_index)[None], pool
 
 
-class _StatefulLayerCache:
+def _gated_delta_update_impl(x, g, beta, conv_w, pool, conv_pool,
+                             dec_index, row_slots, meta, *, key_heads,
+                             value_heads, key_dim, value_dim, chunk_rows,
+                             use_pallas):
+    """One gated delta rule layer of the ragged step.  ``x`` [1, T,
+    channels] is ``[q; k; v]`` before the convolution, ``g`` and
+    ``beta`` [1, T, Hv] float32.  The decode rows and the prefill chunk
+    (``meta``: flat offset, valid rows, state slot, first-chunk flag)
+    each convolve after their request's last inputs (``conv_pool``) and
+    run their form of the rule against its state (``pool``), both in
+    place; a first chunk starts from zeros in both."""
+    from ...ops import pallas_gated_delta as pgd
+    x0, g0, b0 = x[0], g[0], beta[0]
+    T, width = x0.shape[0], conv_w.shape[0]
+    idx = jnp.minimum(dec_index, T - 1)
+    sl = lambda a: _chunk_rows(a, meta, chunk_rows)       # noqa: E731
+    heads = lambda y: pgd.split_heads(                    # noqa: E731
+        y, key_heads, value_heads, key_dim, value_dim)
+    # decode rows: one token after the slot's last inputs
+    yd, window = jax.vmap(
+        lambda prev, row: pgd.causal_conv(row[None], prev, conv_w))(
+        conv_pool[row_slots], x0[idx])
+    yd = yd[:, 0]
+    conv_pool = conv_pool.at[row_slots].set(
+        window[:, 1:].astype(conv_pool.dtype))
+    # the chunk: after the slot's last inputs, or after nothing
+    prev = jnp.where(meta[3] > 0, 0, 1).astype(conv_pool.dtype) \
+        * conv_pool[meta[2]]
+    yc, padded = pgd.causal_conv(sl(x0), prev, conv_w)
+    conv_pool = conv_pool.at[meta[2]].set(jax.lax.dynamic_slice_in_dim(
+        padded, meta[1], width - 1, 0).astype(conv_pool.dtype))
+    if use_pallas:
+        dec_out, pool = pgd.gated_delta_rule_step_fwd(
+            *heads(yd), g0[idx], b0[idx], pool, row_slots)
+        chunk_out, pool = pgd.gated_delta_rule_fwd(
+            *heads(yc), sl(g0), sl(b0), pool, meta[2], meta[1], meta[3])
+    else:
+        dec_out, pool = pgd.gated_delta_step_ref(
+            *heads(yd), g0[idx], b0[idx], pool, row_slots)
+        start = jnp.where(meta[3] > 0, 0.0, 1.0) * pool[meta[2]]
+        chunk_out, state = pgd.gated_delta_chunk_ref(
+            *heads(yc), sl(g0), sl(b0), start, meta[1])
+        pool = pool.at[meta[2]].set(state.astype(pool.dtype))
+    like = jnp.zeros((T, value_heads, value_dim), x.dtype)
+    out = _merge_rows(like, chunk_out, dec_out, meta, dec_index)
+    return out[None], pool, conv_pool
+
+
+class _StatefulLayerCache(_LayerCache):
     """One layer's view of the ragged step, for a layer that keeps
     per-request state in a slot pool."""
 
-    __slots__ = ("_view", "_layer")
-
-    def __init__(self, view, layer):
-        self._view = view
-        self._layer = layer
+    __slots__ = ()
 
 
 class SparseLayerCache(_StatefulLayerCache):
@@ -722,8 +777,10 @@ class SparseLayerCache(_StatefulLayerCache):
 
 
 class RecurrentLayerCache(_StatefulLayerCache):
-    """A lightning layer: no K/V, one state a head in the request's
-    slot."""
+    """A layer with a recurrence: no K/V, its named states in the
+    request's slot.  `update` is the lightning layer's form (one decayed
+    state a head), `delta_update` the gated delta rule's (a state a
+    value head and the convolution's last inputs)."""
 
     __slots__ = ()
 
@@ -732,7 +789,7 @@ class RecurrentLayerCache(_StatefulLayerCache):
         updated in place."""
         from ...ops.pallas_gate import pallas_enabled
         view = self._view
-        pool = view.cache.layer_state(self._layer)
+        pool = view.cache.layer_state(self._layer, "state")
         out, new_pool = dispatch(
             "lightning_state_update", _lightning_update_impl,
             (q, k, v, pool, view.dec_index, view.row_slots,
@@ -741,6 +798,32 @@ class RecurrentLayerCache(_StatefulLayerCache):
                  use_pallas=pallas_enabled("lightning_attention")),
             differentiable=False)
         pool._inplace_update(new_pool._value)
+        return out
+
+    def delta_update(self, x, g, beta, conv_weight, *, key_heads,
+                     value_heads, key_dim, value_dim):
+        """``x`` [1, T, channels] (``[q; k; v]`` before the
+        convolution), ``g`` and ``beta`` [1, T, Hv] float32 -> [1, T,
+        Hv, Dv]; the layer's ``delta`` and ``conv`` pools are updated in
+        place."""
+        from ...ops import pallas_gated_delta as pgd
+        from ...ops.pallas_gate import pallas_enabled
+        view = self._view
+        pool = view.cache.layer_state(self._layer, "delta")
+        conv_pool = view.cache.layer_state(self._layer, "conv")
+        out, new_pool, new_conv = dispatch(
+            "gated_delta_state_update", _gated_delta_update_impl,
+            (x, g, beta, conv_weight, pool, conv_pool, view.dec_index,
+             view.row_slots, view.chunk_meta),
+            dict(key_heads=key_heads, value_heads=value_heads,
+                 key_dim=key_dim, value_dim=value_dim,
+                 chunk_rows=view.chunk_rows,
+                 use_pallas=pgd.kernel_shapes_ok(
+                     view.chunk_rows, key_dim, value_dim, key_heads,
+                     value_heads) and pallas_enabled("gated_delta_rule")),
+            differentiable=False)
+        pool._inplace_update(new_pool._value)
+        conv_pool._inplace_update(new_conv._value)
         return out
 
 

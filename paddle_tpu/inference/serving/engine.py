@@ -1336,9 +1336,10 @@ class GenerationEngine:
     def _count_reports(self, reports):
         """A drained step's reports into the counters: the expert
         layers' plans (``moe_dispatch.plan_counters`` a layer)."""
-        moe = np.asarray(reports["moe"])                 # [layers, 4]
-        self._count({name: int(moe[:, n].sum()) for n, name in enumerate(
-            ("moe_assignments", "moe_experts_touched", "moe_plan_rows"))})
+        moe = np.asarray(reports["moe"])                 # [layers, 5]
+        self._count({name: int(moe[:, n].sum()) for n, name in (
+            (0, "moe_assignments"), (1, "moe_experts_touched"),
+            (2, "moe_plan_rows"), (4, "moe_assignments_routed"))})
         # the fullest expert of any layer of any step so far
         self._counters["moe_max_expert_rows"] = max(
             self._counters.get("moe_max_expert_rows", 0),

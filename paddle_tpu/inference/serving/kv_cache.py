@@ -44,8 +44,10 @@ corrupt a prefix another sequence still reads.
 **Per-layer kinds and state slots** (``layer_specs=``, a model's
 ``cache_spec()``): ``paged_kv`` layers share the block pool and the
 tables above (one geometry: KV heads and head dim, which need not be the
-query heads'); a ``recurrent`` layer keeps no K/V but one fixed-size
-state per live request, and a paged layer with ``sparse_sizes`` also
+query heads'); a ``recurrent`` layer keeps no K/V but its named
+fixed-size ``states`` per live request (``{name: {"shape", "dtype"}}``:
+a decayed or delta-rule state a head, a convolution's last inputs beside
+it), and a paged layer with ``sparse_sizes`` also
 keeps one mean-pooled key per ``stride`` tokens per request (the
 block-sparse selector's cache).  Both live in **slot pools**
 ``[state_slots + 1, ...]``: slot 0 is the pad slot, ``allocate`` hands a
@@ -431,16 +433,19 @@ class PagedKVCache:
         if stateful and not self.state_slots:
             raise ValueError("layers that keep per-request state need "
                              "state_slots (one per live request)")
-        self._state = {}        # model layer -> [slots + 1, *state_shape]
+        #: model layer -> {name: [slots + 1, *shape]}
+        self._state = {}
         self._compressed = {}   # model layer -> [slots + 1, Hkv, J, D]
         for i, spec in stateful:
             if spec["kind"] == "recurrent":
-                t = Tensor(jnp.zeros(
-                    (self.state_slots + 1,) + tuple(spec["state_shape"]),
-                    jnp.dtype(to_jax_dtype(spec.get("dtype", "float32")))),
-                    _internal=True, stop_gradient=True)
-                t.name = f"kv_cache.state.layer{i}"
-                self._state[i] = t
+                self._state[i] = {}
+                for name, state in spec["states"].items():
+                    t = Tensor(jnp.zeros(
+                        (self.state_slots + 1,) + tuple(state["shape"]),
+                        jnp.dtype(to_jax_dtype(state["dtype"]))),
+                        _internal=True, stop_gradient=True)
+                    t.name = f"kv_cache.{name}.layer{i}"
+                    self._state[i][name] = t
             else:
                 # one pooled key per `stride` tokens, to the longest
                 # context, in whole 128-lane tiles
@@ -540,7 +545,11 @@ class PagedKVCache:
     @property
     def state_pool_bytes(self):
         """Bytes of the recurrent layers' state slots."""
-        return sum(int(t._value.nbytes) for t in self._state.values())
+        return sum(int(t._value.nbytes) for t in self._state_tensors())
+
+    def _state_tensors(self):
+        return [t for named in self._state.values()
+                for t in named.values()]
 
     @property
     def compressed_pool_bytes(self):
@@ -597,9 +606,10 @@ class PagedKVCache:
             return None
         return self._scales[self._kv_index[layer]]
 
-    def layer_state(self, layer):
-        """A recurrent layer's state pool ``[slots + 1, *state_shape]``."""
-        return self._state[layer]
+    def layer_state(self, layer, name):
+        """One of a recurrent layer's state pools, ``[slots + 1,
+        *shape]``, by its name in the layer's spec."""
+        return self._state[layer][name]
 
     def layer_compressed(self, layer):
         """A sparse layer's pooled-key pool ``[slots + 1, Hkv, J, D]``."""
@@ -610,7 +620,7 @@ class PagedKVCache:
                     for i in g.layers]
         return ([t for kv in (self._pools + self._scales + windowed)
                  for t in kv]
-                + list(self._state.values())
+                + self._state_tensors()
                 + list(self._compressed.values()))
 
     # -- state slots -----------------------------------------------------

@@ -222,7 +222,10 @@ def preflight_check(compiled, program="<program>", named_buffers=None,
     step pipeline's in-flight buffers: each of the depth-1 extra
     un-synchronized steps keeps its outputs plus ``per_step_io_bytes``
     of feeds live, so the estimate covers the pipelined steady state,
-    not just one isolated step.
+    not just one isolated step.  An output that aliases a donated
+    argument (a serving step's KV and state pools) is the next step's
+    argument and exists once however many steps are in flight: only the
+    outputs that alias nothing are charged.
 
     Registered residents (register_resident) are charged into
     ``est.resident_bytes`` and named in ``est.buffers`` — except when
@@ -239,7 +242,8 @@ def preflight_check(compiled, program="<program>", named_buffers=None,
     if extra_steps:
         est.pipeline_depth = int(pipeline_depth)
         est.pipeline_bytes = extra_steps * (
-            est.output_bytes + int(per_step_io_bytes))
+            max(0, est.output_bytes - est.alias_bytes)
+            + int(per_step_io_bytes))
     skip = set(resident_skip_ids or ())
     for rname, rbytes, ids_fn in resident_items():
         est.buffers.append((rname, rbytes))

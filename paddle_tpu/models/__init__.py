@@ -16,6 +16,7 @@ from .moe_gpt import (MoEGPTConfig, MoEGPTModel, MoEGPTForCausalLM,
                       MoEGPTPretrainingCriterion)
 from .minicpm_sala import MiniCPMSALAConfig, MiniCPMSALAForCausalLM
 from .afmoe import AfmoeConfig, AfmoeForCausalLM
+from .qwen3_next import Qwen3NextConfig, Qwen3NextForCausalLM
 from .generation import GenerationMixin, generate
 
 __all__ = [
@@ -27,5 +28,6 @@ __all__ = [
     "MoEGPTConfig", "MoEGPTModel", "MoEGPTForCausalLM",
     "MoEGPTPretrainingCriterion", "MiniCPMSALAConfig",
     "MiniCPMSALAForCausalLM", "AfmoeConfig", "AfmoeForCausalLM",
+    "Qwen3NextConfig", "Qwen3NextForCausalLM",
     "GenerationMixin", "generate",
 ]

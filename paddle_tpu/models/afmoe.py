@@ -209,7 +209,8 @@ def _routed_impl(x, router, bias, gate_up, down, carried, *, top_k,
     idx, weight = md.sigmoid_topk_router(logits, bias, top_k, route_scale,
                                          route_norm)
     return md.gated_experts(x, idx, weight, gate_up, down, carried,
-                            use_pallas=use_pallas)
+                            use_pallas=use_pallas,
+                            held=(0, gate_up.shape[0]))
 
 
 class AfmoeExperts(nn.Layer):

@@ -277,11 +277,10 @@ class MiniCPMSALAForCausalLM(nn.Layer, GenerationMixin):
                              "block_size": sizes.block,
                              "sparse_sizes": sizes})
             else:
-                spec.append({"kind": "recurrent",
-                             "state_shape": (cfg.lightning_nh,
-                                             cfg.lightning_head_dim,
-                                             cfg.lightning_head_dim),
-                             "dtype": "float32"})
+                spec.append({"kind": "recurrent", "states": {"state": {
+                    "shape": (cfg.lightning_nh, cfg.lightning_head_dim,
+                              cfg.lightning_head_dim),
+                    "dtype": "float32"}}})
         return spec
 
     def forward(self, input_ids, cache=None, use_cache=False):

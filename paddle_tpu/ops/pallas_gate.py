@@ -245,6 +245,19 @@ def _probe_lightning_attention():
     jax.block_until_ready(fn(q, pool))
 
 
+def _probe_gated_delta_rule():
+    from . import pallas_gated_delta as pgd
+    q = jnp.zeros((128, 2, 128), jnp.bfloat16)
+    v = jnp.zeros((128, 4, 128), jnp.bfloat16)
+    g = jnp.zeros((128, 4), jnp.float32)
+    pool = jnp.zeros((3, 4, 128, 128), jnp.float32)
+    fn = jax.jit(lambda q, v, g, pool: pgd.gated_delta_rule_step_fwd(
+        q[:2], q[:2], v[:2], g[:2], g[:2],
+        pgd.gated_delta_rule_fwd(q, q, v, g, g, pool, 1, 100, 1)[1],
+        jnp.array([1, 2], jnp.int32)))
+    jax.block_until_ready(fn(q, v, g, pool))
+
+
 def _probe_sparse_select():
     from . import pallas_sparse as pls
     sizes = pls.SparseSizes()
@@ -265,6 +278,7 @@ _PROBES = {
     "layer_norm_residual": _probe_layer_norm_residual,
     "layer_norm_residual_dropout": _probe_layer_norm_residual_dropout,
     "lightning_attention": _probe_lightning_attention,
+    "gated_delta_rule": _probe_gated_delta_rule,
     "sparse_select": _probe_sparse_select,
     "grouped_matmul": _probe_grouped_matmul,
     "lora_sgmv": _probe_lora_sgmv,

@@ -10,7 +10,10 @@ four described chips), ``serve`` (the GPT engine's unified step),
 ``serve_afmoe`` (the engine's step for `benchmarks/configs/Trinity-
 Mini.json` at the cell's geometry, depth cut to a dense, a sliding
 expert and a full expert layer: both groups of block tables, the
-grouped expert kernel at 128 x 2048 x 1024, the 200,192-word head).
+grouped expert kernel at 128 x 2048 x 1024, the 200,192-word head),
+``serve_qwen3_next`` (the same for `benchmarks/configs/Qwen3-Next-80B-
+A3B-Instruct.json`, depth cut to one period: both gated delta rule
+kernels, the held experts at 128 x 2048 x 512, attention at width 256).
 Each prints its compile seconds, the Mosaic kernels and collectives in
 the compiled text, and ``memory_analysis()`` against the chip's 16 GB.
 
@@ -41,8 +44,8 @@ from paddle_tpu.distributed.auto_parallel.sharding import (  # noqa: E402
     BERT_RULES, MeshPlan, clear_mesh_plan, set_mesh_plan)
 from paddle_tpu.models import BertConfig, GPTConfig  # noqa: E402
 from paddle_tpu.ops import (pallas_fused, pallas_gate,  # noqa: E402
-                            pallas_grouped, pallas_kernels,
-                            pallas_ragged, pallas_tiles)
+                            pallas_gated_delta, pallas_grouped,
+                            pallas_kernels, pallas_ragged, pallas_tiles)
 
 HBM_BYTES = 16e9
 BATCH, SEQ = 16, 512
@@ -56,7 +59,7 @@ def open_gate():
     pallas_gate.pallas_enabled = lambda name, manual=False: (
         manual or not pallas_gate._auto_partitioned())
     for mod in (pallas_kernels, pallas_fused, pallas_ragged,
-                pallas_grouped, pallas_tiles):
+                pallas_grouped, pallas_gated_delta, pallas_tiles):
         mod._interpret = lambda: False
 
 
@@ -167,43 +170,61 @@ def check_serve(topo):
     engine.close()
 
 
-def check_serve_afmoe(topo):
-    """`check_serve` for the AFMoE family at the benchmark cell's sizes
-    (one CPU step at real widths first: a minute or two)."""
+def _check_serve_cell(topo, name, family, config, traffic, cut, stacks):
+    """`check_serve` for a family at its benchmark cell's sizes (one CPU
+    step at real widths first: a minute or two).  ``cut`` lays the cut
+    in depth over the config file; ``stacks`` is the regular expression
+    of an expert stack's shape, which the step may not move."""
     import json
+    import re
     pallas_gate.pallas_enabled = lambda name, manual=False: False
-    from benchmarks.families import afmoe
     from paddle_tpu.inference.serving import GenerationEngine
-    with open(os.path.join(ROOT, "benchmarks/configs/Trinity-Mini.json")) as f:
+    with open(os.path.join(ROOT, "benchmarks/configs", config)) as f:
         cfg = json.load(f)
-    with open(os.path.join(
-            ROOT, "benchmarks/traffic/mixedlen-closed32.json")) as f:
+    with open(os.path.join(ROOT, "benchmarks/traffic", traffic)) as f:
         traffic = json.load(f)
-    kinds = cfg["layer_types"]
-    cfg.update(num_hidden_layers=3, layer_types=[kinds[0], kinds[1],
-                                                 kinds[-1]])
+    cfg.update(cut(cfg))
     paddle.seed(cs.SEED)
-    engine = GenerationEngine(afmoe.build(cfg), **traffic["engine"])
+    engine = GenerationEngine(family.build(cfg), **traffic["engine"])
     engine.add_request(list(range(1, 1301)), max_new_tokens=2)
     engine.step()
     (entry,) = engine._step_fn._cache.values()
     stats = engine.stats()
-    print(f"serve_afmoe: token_budget {engine.token_budget} table_width "
+    print(f"{name}: token_budget {engine.token_budget} table_width "
           f"{engine.cache.table_width} pool {stats['full_pool_bytes']/1e9:.2f}G"
           f" + {stats['window_pool_bytes']/1e9:.2f}G "
-          f"{stats['window_groups']}", flush=True)
+          f"{stats['window_groups']} state "
+          f"{stats['state_pool_bytes']/1e9:.2f}G", flush=True)
     open_gate()
     chip = SingleDeviceSharding(topo.devices[0])
-    text = report("serve_afmoe", jax.jit(
+    text = report(name, jax.jit(
         lambda *a: entry["pure_fn"](*a), donate_argnums=(2,)).lower(
         *_on(chip, entry["avals"])))
-    import re
     moved = [line.strip()[:160] for line in text.splitlines()
-             if re.search(r"bf16\[12[89],(?:2048|1024),(?:2048|1024)\]", line)
+             if re.search(stacks, line)
              and re.search(r" (?:copy|pad|concatenate|transpose)\(", line)]
     assert not moved, f"an expert stack is moved in the step: {moved}"
     engine.close()
     return text
+
+
+def check_serve_afmoe(topo):
+    from benchmarks.families import afmoe
+    return _check_serve_cell(
+        topo, "serve_afmoe", afmoe, "Trinity-Mini.json",
+        "mixedlen-closed32.json",
+        lambda cfg: dict(num_hidden_layers=3, layer_types=[
+            cfg["layer_types"][i] for i in (0, 1, -1)]),
+        r"bf16\[12[89],(?:2048|1024),(?:2048|1024)\]")
+
+
+def check_serve_qwen3_next(topo):
+    from benchmarks.families import qwen3_next
+    return _check_serve_cell(
+        topo, "serve_qwen3_next", qwen3_next,
+        "Qwen3-Next-80B-A3B-Instruct.json", "longctx24k-closed32.json",
+        lambda cfg: dict(num_hidden_layers=4),
+        r"bf16\[128,(?:2048|512),(?:2048|1024)\]")
 
 
 def main(names):
@@ -219,6 +240,8 @@ def main(names):
         check_serve(topo)
     if "serve_afmoe" in names:
         check_serve_afmoe(topo)
+    if "serve_qwen3_next" in names:
+        check_serve_qwen3_next(topo)
     open_gate()
     for name in names:
         if name == "static":
@@ -227,7 +250,7 @@ def main(names):
             check_static(topo, loop=True)
         elif name == "mesh":
             check_mesh(topo)
-        elif name not in ("serve", "serve_afmoe"):
+        elif name not in ("serve", "serve_afmoe", "serve_qwen3_next"):
             raise SystemExit(f"unknown program {name!r}")
     print("AOT_SMOKE_OK", flush=True)
 

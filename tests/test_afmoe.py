@@ -150,6 +150,33 @@ def test_router_bias_moves_the_choice_and_not_the_weight():
                                np.asarray(s[:2]), rtol=1e-6)
 
 
+def test_the_layer_holds_every_expert_through_the_held_path(model):
+    """``AfmoeMoE`` calls the expert layer with all 16 experts held: the
+    same plan as with no share stated, and every routed assignment is
+    dispatched here."""
+    layer = model.model.layers[1].mlp
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(12, 64)),
+                    jnp.float32)
+    args = (x, layer.router.weight.value(), layer.expert_bias.value(),
+            layer.experts.gate_up.value(), layer.experts.down.value(),
+            jnp.ones(12, bool))
+    from paddle_tpu.models.afmoe import _routed_impl
+    y, counted = _routed_impl(*args, top_k=4, route_scale=2.826,
+                              route_norm=True, use_pallas=False)
+    assert counted.tolist()[0] == counted.tolist()[4] == 12 * 4
+    idx, weight = md.sigmoid_topk_router(
+        jnp.dot(x, args[1], precision="highest"), args[2], 4, 2.826, True)
+    plain, _ = md.gated_experts(x, idx, weight, args[3], args[4], args[5])
+    assert (np.asarray(y) == np.asarray(plain)).all()
+    half, c = md.gated_experts(x, idx, weight, args[3][:8], args[4][:8],
+                               args[5], held=(0, 8))
+    other, _ = md.gated_experts(x, idx, weight, args[3][8:], args[4][8:],
+                                args[5], held=(8, 16))
+    assert 0 < int(c[0]) < int(c[4]) == 48
+    np.testing.assert_allclose(np.asarray(half + other), np.asarray(y),
+                               rtol=1e-5, atol=1e-7)
+
+
 def test_router_ties_go_to_the_lower_index():
     idx, _ = md.sigmoid_topk_router(jnp.zeros((1, 6)), None, 3)
     assert idx.tolist() == [[0, 1, 2]]
@@ -177,7 +204,7 @@ def test_rows_that_carry_nothing_are_dispatched_nowhere():
     y = md.dropless_combine(xd, jnp.asarray(rows.reshape(-1)),
                             jnp.ones((10, 2)))
     assert np.asarray(y)[:, 0].tolist() == [2, 0, 0, 0, 0, 2, 0, 0, 0, 0]
-    assert md.plan_counters(counts, 8).tolist()[:2] == [
+    assert md.plan_counters(counts, 8, 4).tolist()[:2] == [
         4, int((counts > 0).sum())]
 
 

@@ -140,6 +140,33 @@ def test_serve_afmoe_tiny():
     assert c["step_program_compiles"] == 1 and out["kernels"] == {}
 
 
+def test_serve_qwen3_next_tiny():
+    """Float32 at width 64, one period, a quarter of 16 experts held:
+    the served rows equal the reference of the same share to rounding,
+    through both pools of the delta layers."""
+    config = {**chip_smoke.qwen3_next_config(chips=4), "vocab_size": 97,
+              "hidden_size": 64, "num_attention_heads": 4,
+              "num_key_value_heads": 2, "head_dim": 16,
+              "linear_key_head_dim": 16, "linear_num_key_heads": 2,
+              "linear_num_value_heads": 4, "linear_value_head_dim": 16,
+              "moe_intermediate_size": 32,
+              "shared_expert_intermediate_size": 32, "num_experts": 4,
+              "published_num_experts": 16, "num_experts_per_tok": 4,
+              "kv_block_size": 8}
+    assert config["num_hidden_layers"] == 4 and config["dtype"] == "float32"
+    assert chip_smoke.qwen3_next_config()["num_experts"] == 16
+    out = chip_smoke.serve_qwen3_next(config, [70, 12], new_tokens=4,
+                                      engine={
+        "max_batch": 2, "block_size": 8, "num_blocks": 32,
+        "max_model_len": 128, "prefill_chunk": 16})
+    c = out["checked"]
+    assert c["positions"] == 8 and c["state_resets"] == 2
+    assert c["worst_row_rel_l2_vs_reference"] < 1e-5
+    assert c["greedy_tokens_the_reference_agrees_with"] == 8
+    assert 0 < c["moe_assignments"] < c["moe_assignments_routed"]
+    assert c["step_program_compiles"] == 1 and out["kernels"] == {}
+
+
 def test_sampler_gate_tiny():
     """Five rows of 301 logits: tokens of the gated sampler against the
     ungated one, greedy and with one sampling row; no device, no time."""

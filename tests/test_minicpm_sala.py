@@ -151,7 +151,8 @@ def test_a_bfloat16_state_fails_the_tolerance(model, prompts, monkeypatch):
     spec = model.cache_spec()
     for s in spec:
         if s["kind"] == "recurrent":
-            s["dtype"] = "bfloat16"
+            s["states"] = {"state": {**s["states"]["state"],
+                                     "dtype": "bfloat16"}}
     monkeypatch.setattr(model, "cache_spec", lambda: spec)
     ids, outs, rows, _ = served_logits(model, prompts[:1])
     assert worst_row(model, ids, outs, prompts[:1], rows) > 3 * TOL
@@ -187,7 +188,7 @@ def test_state_slots_are_freed_and_zeroed_on_reuse(model, prompts):
     eng = GenerationEngine(model, **{**ENGINE, "max_batch": 1})
     outs = eng.generate(prompts[1:], max_new_tokens=6)
     assert eng.cache.free_state_slots == 1 and not eng.cache._slot_of
-    state = eng.cache.layer_state(1)._value
+    state = eng.cache.layer_state(1, "state")._value
     assert float(jnp.abs(state[1]).max()) > 0     # the slot was used
     eng.close()
     for prompt, out in zip(prompts[1:], outs):

@@ -137,6 +137,57 @@ def test_dropless_plan_deterministic():
         assert (np.asarray(u) == np.asarray(v)).all()
 
 
+@pytest.mark.parametrize("held", [(0, 8), (0, 2), (2, 4), (6, 8)])
+def test_dropless_plan_of_the_held_experts(held):
+    """A plan that is told which experts it holds lays out those alone,
+    as groups ``e - lo``; every other assignment gets the row past the
+    buffer's end, as a token's that carries nothing."""
+    rng = np.random.RandomState(7)
+    topk = np.asarray(rng.randint(0, 8, size=(40, 3)), np.int32)
+    lo, hi = held
+    bm, nb, R = pg.grouped_layout(topk.size, hi - lo, jnp.float32)
+    rows, gid, counts = md.dropless_plan(jnp.asarray(topk), hi - lo, bm,
+                                         nb, held=held)
+    rows, gid = np.asarray(rows).reshape(topk.shape), np.asarray(gid)
+    inside = (topk >= lo) & (topk < hi)
+    assert np.asarray(counts).tolist() == [
+        int((topk == e).sum()) for e in range(lo, hi)]
+    assert (rows[~inside] == R).all() and (rows[inside] < R).all()
+    # a held assignment's row lies in a block of its expert's group
+    assert (gid[rows[inside] // bm] == topk[inside] - lo).all()
+    assert len(set(rows[inside].tolist())) == int(inside.sum())
+    if held == (0, 8):                  # every expert held: the old plan
+        plain = md.dropless_plan(jnp.asarray(topk), 8, bm, nb)
+        assert (np.asarray(plain[0]).reshape(topk.shape) == rows).all()
+
+
+def test_gated_experts_shares_add_up():
+    """The parts of the result that the holders of experts 0-1, 2-5 and
+    6-7 give sum to the layer's with every expert held, and the counters
+    say what each dispatched beside what was routed."""
+    rng = np.random.RandomState(8)
+    N, D, W, E, k = 24, 16, 8, 8, 3
+    x = jnp.asarray(rng.randn(N, D), jnp.float32)
+    idx = jnp.asarray(np.stack([rng.permutation(E)[:k] for _ in range(N)]),
+                      jnp.int32)
+    weight = jnp.asarray(rng.rand(N, k), jnp.float32)
+    gate_up = jnp.asarray(rng.randn(E, D, 2 * W) * 0.3, jnp.float32)
+    down = jnp.asarray(rng.randn(E, W, D) * 0.3, jnp.float32)
+    whole, counted = md.gated_experts(x, idx, weight, gate_up, down)
+    assert counted.tolist()[0] == counted.tolist()[4] == N * k
+    total, dispatched = 0.0, 0
+    for lo, hi in ((0, 2), (2, 6), (6, 8)):
+        part, c = md.gated_experts(x, idx, weight, gate_up[lo:hi],
+                                   down[lo:hi], held=(lo, hi))
+        total, dispatched = total + part, dispatched + int(c[0])
+        assert int(c[4]) == N * k and int(c[1]) <= hi - lo
+    assert dispatched == N * k
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               rtol=1e-5, atol=1e-6)
+    with pytest.raises(ValueError, match="held, stacks"):
+        md.gated_experts(x, idx, weight, gate_up, down, held=(0, 4))
+
+
 def test_expert_imbalance_gauge():
     assert float(md.expert_imbalance(jnp.asarray([4, 4, 4, 4]))) \
         == pytest.approx(1.0)

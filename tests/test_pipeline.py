@@ -310,6 +310,26 @@ def test_preflight_accounts_for_depth(monkeypatch):
     assert est.pipeline_bytes == 0
 
 
+def test_preflight_charges_a_donated_output_once(monkeypatch):
+    """A serving step's pools come back as outputs that alias the
+    donated arguments: the next step in flight reads the same buffers,
+    so depth charges only what aliases nothing."""
+    from paddle_tpu.memory import guard
+    from paddle_tpu.memory.estimator import MemoryEstimate
+
+    def fake_analyze(compiled, program=None, named_buffers=None):
+        return MemoryEstimate(program=program or "p",
+                              argument_bytes=1000, output_bytes=600,
+                              temp_bytes=100, alias_bytes=550)
+
+    monkeypatch.setenv(guard.ENV_MEMORY_GUARD, "on")
+    monkeypatch.setattr(guard, "analyze_compiled", fake_analyze)
+    est = guard.preflight_check(None, program="p", budget=2000,
+                                pipeline_depth=3, per_step_io_bytes=40)
+    assert est.pipeline_bytes == 2 * (600 - 550 + 40)
+    assert est.total_bytes == 1000 + 600 + 100 - 550 + 180
+
+
 # -- persistent compile cache --------------------------------------------
 @pytest.mark.parametrize("env_dir", [True, False])
 def test_compile_cache_persists_to_dir(tmp_path, monkeypatch, env_dir):

@@ -31,6 +31,7 @@ from jax.sharding import SingleDeviceSharding
 
 from benchmarks import span_reduce
 import paddle_tpu.ops.pallas_fused as pf
+import paddle_tpu.ops.pallas_gated_delta as pgd
 import paddle_tpu.ops.pallas_grouped as pgm
 import paddle_tpu.ops.pallas_kernels as pk
 import paddle_tpu.ops.pallas_lightning as pll
@@ -60,7 +61,7 @@ def one_chip(topo):
     from jax.experimental.compilation_cache import compilation_cache
     mp = pytest.MonkeyPatch()
     # each kernel module binds _interpret by name at import
-    for mod in (pk, pf, pr, pgm, pt, pll, pls):
+    for mod in (pk, pf, pr, pgm, pt, pll, pls, pgd):
         mp.setattr(mod, "_interpret", lambda: False)
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -418,6 +419,93 @@ def test_trinity_kernel_compiles_for_v5e(one_chip, name):
                if 'custom_call_target="tpu_custom_call"' in line)
     moved = [m.group(0) for m in re.finditer(
         r"bf16\[12[89],(?:2048|1024),(?:2048|1024)\](?:\{[^}]*\})? "
+        r"(?:copy|pad|concatenate|transpose)\(", text)]
+    assert not moved, moved
+
+
+# -- Qwen3-Next's engine step (benchmarks/traffic/longctx24k-closed32) ---
+# 32 rows, a 1,024-token chunk; delta layers of 16 key / 32 value heads
+# of 128 with 33 state slots; attention of 16 query / 2 KV heads of 256
+# over 13,312 blocks of 64 tokens (416 table slots); 128 held experts of
+# 2048 x 512, top-10 of 512 over the 1,520-row budget
+QN_HK, QN_HV, QN_D, QN_SLOTS = 16, 32, 128, 33
+
+
+def _delta_chunk():
+    return (pgd.gated_delta_rule_fwd,
+            [((1024, QN_HK, QN_D), bf16)] * 2
+            + [((1024, QN_HV, QN_D), bf16)] + [((1024, QN_HV), f32)] * 2
+            + [((QN_SLOTS, QN_HV, QN_D, QN_D), f32)] + [((), i32)] * 3)
+
+
+def _delta_step():
+    return (pgd.gated_delta_rule_step_fwd,
+            [((TRI_ROWS, QN_HK, QN_D), bf16)] * 2
+            + [((TRI_ROWS, QN_HV, QN_D), bf16)]
+            + [((TRI_ROWS, QN_HV), f32)] * 2
+            + [((QN_SLOTS, QN_HV, QN_D, QN_D), f32), ((TRI_ROWS,), i32)])
+
+
+def _held_experts():
+    from paddle_tpu.distributed.auto_parallel import moe_dispatch as md
+    return (lambda x, idx, w, gate_up, down, carried: md.gated_experts(
+                x, idx, w, gate_up, down, carried, use_pallas=True,
+                held=(0, 128)),
+            [((TRI_BUDGET, 2048), bf16), ((TRI_BUDGET, 10), i32),
+             ((TRI_BUDGET, 10), f32), ((128, 2048, 1024), bf16),
+             ((128, 512, 2048), bf16), ((TRI_BUDGET,), jnp.bool_)])
+
+
+def _wide_decode():
+    from paddle_tpu.inference.serving.attention import (
+        grouped_decode_attention)
+    pool = ((13313, 2, 64, 256), bf16)
+    return (functools.partial(grouped_decode_attention, use_pallas=True,
+                              block_q=16),
+            [((TRI_ROWS, 16, 256), bf16), pool, pool,
+             ((TRI_ROWS, 2, 416), i32), ((TRI_ROWS, 2), i32)])
+
+
+def _wide_chunk():
+    from paddle_tpu.inference.serving.attention import (
+        grouped_chunk_attention)
+    pool = ((13313, 2, 64, 256), bf16)
+    return (functools.partial(grouped_chunk_attention, window=None,
+                              chunk_bq=128, use_pallas=True),
+            [((1024, 16, 256), bf16), pool, pool, ((416,), i32)]
+            + [((), i32)] * 3)
+
+
+QWEN3_NEXT_CASES = {
+    "delta_chunk": (_delta_chunk, ["gated_delta_rule_fwd"]),
+    "delta_step": (_delta_step, ["gated_delta_rule_step_fwd"]),
+    "held_experts_128x2048x512": (_held_experts,
+                                  ["grouped_matmul_fwd"] * 2),
+    "decode_rows_width_256": (_wide_decode, ["ragged_attention_fwd"]),
+    "chunk_width_256": (_wide_chunk, ["ragged_attention_fwd"]),
+}
+
+
+@pytest.mark.parametrize("name", list(QWEN3_NEXT_CASES))
+def test_qwen3_next_kernel_compiles_for_v5e(one_chip, name):
+    """The two gated delta rule kernels, the grouped expert kernel over
+    the held experts and the ragged kernel at head width 256 with head
+    groups of 8, at the cell's sizes, each under the instruction name
+    the benchmark's readers match; the held stacks go to the kernel as
+    they lie."""
+    build, want = QWEN3_NEXT_CASES[name]
+    fn, args = build()
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in args]
+    with jax.enable_x64(False):
+        text = jax.jit(fn).lower(*avals).compile().as_text()
+    calls = [span_reduce._INSTRUCTION.match(
+        line.strip().removeprefix("ROOT ")).group(1)
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls == want, calls
+    moved = [m.group(0) for m in re.finditer(
+        r"bf16\[128,(?:2048|512),(?:2048|1024)\](?:\{[^}]*\})? "
         r"(?:copy|pad|concatenate|transpose)\(", text)]
     assert not moved, moved
 
