@@ -1,6 +1,6 @@
 """Prove that the trainer and the serving engine start on the chip.
 
-    python chip_smoke.py            # one TPU chip: eleven phases
+    python chip_smoke.py            # one TPU chip: thirteen phases
     python chip_smoke.py --chips 4  # four chips: the sharded phase only
     python chip_smoke.py --phase attention_dropout   # that phase alone
 
@@ -967,6 +967,132 @@ def sampler_gate(rows, vocab):
                         "device_ms": ms}}
 
 
+#: what a v5e's HBM delivers (`benchmarks/peaks.json`), for
+#: `ragged_walk`'s floor
+HBM_BYTES_PER_S = 819e9
+
+#: `ragged_walk`'s calls at the serving cells' geometry: (name, form,
+#: query heads a KV head, KV heads, head width, block size, table
+#: width, window, chunk tokens).  GPT-2 makes one call a layer for a
+#: 256-token chunk and 31 decode rows; the grouped engines one for
+#: their 32 decode rows and one for a 1,024-token chunk
+#: (`attention._grouped_attend_impl`).
+RAGGED_WALK_CALLS = (
+    ("gpt2-large.step", "mixed", 1, 20, 64, 16, 64, None, 256),
+    ("Trinity-Mini.decode.window", "decode", 8, 4, 128, 64, 34, 2048, 0),
+    ("Trinity-Mini.decode.full", "decode", 8, 4, 128, 64, 224, None, 0),
+    ("Trinity-Mini.chunk.window", "chunk", 8, 4, 128, 64, 50, 2048, 1024),
+    ("Trinity-Mini.chunk.full", "chunk", 8, 4, 128, 64, 224, None, 1024),
+    ("Qwen3-Next.decode", "decode", 8, 2, 256, 64, 416, None, 0),
+    ("Qwen3-Next.chunk", "chunk", 8, 2, 256, 64, 416, None, 1024),
+)
+
+
+def _ragged_walk_call(form, group, kv_heads, d, bs, width, window, chunk,
+                      fill, rows=32, chunk_bq=128):
+    """One attention call of a serving step as a function of (q, k_pool,
+    v_pool, use_pallas), its arguments, and the K/V blocks its rows have
+    to read (all KV heads), for tables filled to ``fill`` of their
+    width.  Every row owns its blocks; block 0 is the null block."""
+    import jax.numpy as jnp
+    from paddle_tpu.inference.serving import attention as att
+    from paddle_tpu.ops import pallas_ragged as pr
+    ctx = max(int(fill * width) * bs - bs // 2, 1)      # ends mid-block
+    tables = 1 + np.arange(rows * width, dtype=np.int32).reshape(rows, width)
+    pool = (rows * width + 1, kv_heads, bs, d)
+    key = jax.random.PRNGKey(SEED)
+    kk, kv, kq = jax.random.split(key, 3)
+    k_pool = jax.random.normal(kk, pool, jnp.bfloat16)
+    v_pool = jax.random.normal(kv, pool, jnp.bfloat16)
+    span = ctx if window is None else min(ctx, window)
+
+    def blocks(first, last):        # table slots that hold keys first..last
+        return last // bs - first // bs + 1
+
+    if form == "decode":
+        q = jax.random.normal(kq, (rows, group * kv_heads, d), jnp.bfloat16)
+        tab = jnp.asarray(np.broadcast_to(
+            tables[:, None], (rows, kv_heads, width)))
+        lens = jnp.full((rows, kv_heads), ctx, jnp.int32)
+        bq = att.decode_block_q(group, jnp.bfloat16)
+
+        def fn(q, kp, vp, use_pallas):
+            return att.grouped_decode_attention(
+                q, kp, vp, tab, lens, use_pallas, window=window, block_q=bq)
+        need = rows * kv_heads * blocks(ctx - span, ctx - 1)
+    elif form == "chunk":
+        take = min(chunk, ctx)
+        q = jax.random.normal(kq, (chunk, group * kv_heads, d),
+                              jnp.bfloat16)
+        tab = jnp.asarray(tables[0])
+
+        def fn(q, kp, vp, use_pallas):
+            return att.grouped_chunk_attention(
+                q, kp, vp, tab, jnp.int32(ctx), jnp.int32(ctx - take),
+                jnp.int32(take), window=window, chunk_bq=chunk_bq,
+                use_pallas=use_pallas)
+        need = 0
+        for first in range(ctx - take, ctx, chunk_bq):
+            last = min(first + chunk_bq, ctx) - 1
+            low = 0 if window is None else max(first - window + 1, 0)
+            need += kv_heads * blocks(low, last)
+    else:
+        # the ungrouped engine's call: a chunk of one sequence in
+        # q-blocks of `ragged_q_block` rows, then the decode rows
+        bq = pr.ragged_q_block(jnp.bfloat16)
+        take = min(chunk, ctx)
+        lens = [ctx] * rows
+        sid, qs, qv, _, total = pr.ragged_segments(
+            [take] + [1] * (rows - 1), lens, bq)
+        q = jax.random.normal(kq, (total, kv_heads, d), jnp.bfloat16)
+        tab, cl = jnp.asarray(tables), jnp.asarray(lens, jnp.int32)
+        sid, qs, qv = jnp.asarray(sid), jnp.asarray(qs), jnp.asarray(qv)
+
+        def fn(q, kp, vp, use_pallas):
+            return att._ragged_attention_impl(
+                q[None], kp, vp, tab, cl, sid, qs, qv, block_q=bq,
+                scale=1.0 / math.sqrt(d), use_pallas=use_pallas)[0]
+        need = kv_heads * ((rows - 1) * blocks(0, ctx - 1) + sum(
+            blocks(0, min(first + bq, ctx) - 1)
+            for first in range(ctx - take, ctx, bq)))
+    return fn, (q, k_pool, v_pool), need
+
+
+def ragged_walk(calls, fills=(0.25, 1.0), **sizes):
+    """The ragged attention kernel alone, one call of a serving step at
+    a time (``calls``: `RAGGED_WALK_CALLS`' form), over block tables
+    filled to each of ``fills``: the kernel against the XLA fallback at
+    the first fill, then its device time, and the share of that time
+    that the bytes of the K/V blocks its rows need would take at the
+    HBM's rate."""
+    checked, kernels = {}, Counter()
+    for name, *call in calls:
+        _, _, kv_heads, d, bs, *_ = call
+        for n, fill in enumerate(fills):
+            fn, args, need = _ragged_walk_call(*call, fill, **sizes)
+            run = jax.jit(lambda q, kp, vp: fn(q, kp, vp, True))
+            compiled = run.lower(*args).compile()
+            kernels.update(mosaic_kernels(compiled.as_text()))
+            got = jax.block_until_ready(compiled(*args))
+            entry = {"kv_blocks": need}
+            if n == 0:
+                ref = jax.jit(lambda q, kp, vp: fn(q, kp, vp, False))(*args)
+                entry["rel_l2_vs_fallback"] = _rel_l2(got, ref)
+                check(entry["rel_l2_vs_fallback"] < BF16_REL_L2,
+                      f"{name}: kernel against fallback "
+                      f"{entry['rel_l2_vs_fallback']:.3g}")
+                del ref
+            ms = _device_ms(compiled, *args)
+            if ms is not None:
+                floor_us = need * 2 * bs * d * 2 / HBM_BYTES_PER_S * 1e6
+                entry.update(us=round(ms * 1e3, 1),
+                             floor_us=round(floor_us, 1),
+                             floor_share=round(floor_us / (ms * 1e3), 4))
+            checked[f"{name}@{fill}"] = entry
+            del fn, args, run, compiled, got
+    return {"kernels": dict(kernels), "checked": checked}
+
+
 def afmoe_config(layers=(0, 1, 4), dtype=None):
     """The benchmark's Trinity-Mini file with the layer list cut to a
     dense sliding, an expert sliding and an expert full layer (depth is
@@ -1116,7 +1242,9 @@ def main(argv=None):
             # the sampler alone at Trinity-Mini's batch and vocabulary:
             # what a step pays for its first sampling row
             ("sampler_gate", sampler_gate,
-             (32, afmoe_config()["vocab_size"]), {})]
+             (32, afmoe_config()["vocab_size"]), {}),
+            # the ragged kernel alone, a serving step's call at a time
+            ("ragged_walk", ragged_walk, (RAGGED_WALK_CALLS,), {})]
     unknown = set(args.phase or ()) - {name for name, *_ in phases}
     if unknown:
         sys.exit(f"no such phase with --chips {args.chips}: "

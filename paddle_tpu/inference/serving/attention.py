@@ -271,6 +271,9 @@ def _use_pallas_ragged(head_dim, block_size, dtype, block_q,
         return False
     if head_dim > 256 or block_size % 8 != 0:
         return False
+    from ...ops.pallas_ragged import pool_copyable
+    if not pool_copyable(head_dim, block_size):
+        return False
     from ...ops.pallas_kernels import _min_rows
     # block_q tiles the QUERY buffer, whose dtype is the compute
     # precision — an int8 pool does not force 32-row q blocks

@@ -882,8 +882,12 @@ class GenerationEngine:
         grouped layers' block counters, host arithmetic from each row's
         position: K/V blocks (all KV heads) the step's decode rows and
         the chunk's q-blocks read in the windowed and in the full
-        layers, and what the windowed layers would have read with no
-        window."""
+        layers, what the windowed layers would have read with no
+        window, and the table slots their calls are handed
+        (``kv_table_slots``: the width of the group's table a q-block,
+        for a windowed layer's decode rows what `RaggedLayerCache` cuts
+        it to): the blocks read over it is the share of a table that the
+        kernel's walk has to visit."""
         cache, bs = self.cache, self.cache.block_size
         spans = [(cache.length(r.id) - 1, 1, r.id) for r in decodes]
         reads = [(p, p) for p, _, _ in spans]    # (first, last) token
@@ -896,9 +900,16 @@ class GenerationEngine:
         released = sum(cache.write_window(rid, start, n)
                        for start, n, rid in spans)
         step = dict.fromkeys(("kv_blocks_read_window", "kv_blocks_read_full",
-                              "kv_blocks_context"), 0)
+                              "kv_blocks_context", "kv_table_slots"), 0)
         step["window_blocks_released"] = released
+        widths = {g.window: g.table_width for g in cache.window_groups}
         for window, layers in self._window_layers.items():
+            width = widths.get(window, cache.table_width)
+            dec_width = width if window is None else min(
+                width, window // bs + 2)
+            step["kv_table_slots"] += layers * (
+                len(decodes) * dec_width
+                + (len(reads) - len(decodes)) * width)
             for first, last in reads:
                 whole = last // bs + 1
                 if window is None:

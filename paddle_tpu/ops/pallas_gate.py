@@ -205,7 +205,9 @@ def _probe_ragged_attention():
     nqb = 3                       # one 2-block prefill + one decode
     q = jnp.zeros((nqb * block_q, 2, 64), jnp.float32)
     pool = jnp.zeros((4, 2, 16, 64), jnp.float32)
-    bt = jnp.array([[1, 2], [3, 0]], jnp.int32)
+    # a table wider than either context: the walk inside the program
+    # has slots to leave out
+    bt = jnp.array([[1, 2, 0, 0], [3, 0, 0, 0]], jnp.int32)
     cl = jnp.array([20, 5], jnp.int32)
     sid = jnp.array([0, 0, 1], jnp.int32)
     qs = jnp.array([4, 4 + block_q, 4], jnp.int32)
@@ -222,7 +224,9 @@ def _probe_ragged_attention_int8():
     q = jnp.zeros((nqb * block_q, 2, 64), jnp.float32)
     pool = jnp.zeros((4, 2, 16, 64), jnp.int8)
     scales = jnp.ones((4, 16, pr.KV_SCALE_LANES), jnp.float32)
-    bt = jnp.array([[1, 2], [3, 0]], jnp.int32)
+    # a table wider than either context: the walk inside the program
+    # has slots to leave out
+    bt = jnp.array([[1, 2, 0, 0], [3, 0, 0, 0]], jnp.int32)
     cl = jnp.array([20, 5], jnp.int32)
     sid = jnp.array([0, 0, 1], jnp.int32)
     qs = jnp.array([4, 4 + block_q, 4], jnp.int32)
@@ -303,11 +307,11 @@ def _static_diagnose(kernel):
         return diags
     if kernel == "ragged_attention":
         return list(tiling.audit_ragged_attention(
-            2, 64, 16, num_q_blocks=3, num_blocks=4, table_width=2,
+            2, 64, 16, num_q_blocks=3, num_blocks=4, table_width=4,
             dtype=jnp.float32))
     if kernel == "ragged_attention_int8":
         return list(tiling.audit_ragged_attention(
-            2, 64, 16, num_q_blocks=3, num_blocks=4, table_width=2,
+            2, 64, 16, num_q_blocks=3, num_blocks=4, table_width=4,
             dtype=jnp.float32, kv_dtype=jnp.int8))
     if kernel in ("layer_norm_residual", "layer_norm_residual_dropout"):
         diags = []

@@ -16,17 +16,43 @@ per-q-block scalar arrays describe the ragged layout:
                  i.e. ``context_len - query_len + i_local * block_q``
     q_valids[i]  valid rows in the block (trailing rows are padding)
 
-K/V live in the PR-5 paged pool ``[num_blocks, H, block_size, D]``;
-``block_tables [S, W]`` / ``context_lens [S]`` are scalar-prefetched
-(they drive the K/V BlockSpec index maps, so each program streams
-exactly the block its sequence owns at table slot ``w``), and the
-grid is
+K/V live in the PR-5 paged pool ``[num_blocks, H, block_size, D]``,
+which stays in HBM (``memory_space=pl.ANY``); ``block_tables [S, W]`` /
+``context_lens [S]`` and the three descriptors are scalar-prefetched,
+and the grid is
 
-    (num_q_blocks, num_heads, W)     w innermost, sequential
+    (num_q_blocks, num_heads)
 
-so the online-softmax state (acc/m/l) in VMEM scratch survives the
-walk over a sequence's KV blocks.  Causal masking happens inside each
-ragged segment: row ``r`` of q-block ``i`` sees KV position ``c`` iff
+one program a q-block and head.  The walk over the sequence's block
+table runs *inside* the program (several pages a step, fetched by the
+kernel's own double-buffered copies: the form of the Ragged Paged
+Attention kernel, PAPERS.md): from the scalars it computes the table
+slots ``[lo, hi)`` that hold a key one of its rows can see,
+
+    lo = block of its first token's window start (0 with no window)
+    hi = min(ceil(context_len / block_size),
+             1 + block of the q-block's last token)
+
+and a ``lax.fori_loop`` takes them ``kv_step`` slots a step: the blocks
+``pool[block_tables[seq, w], h]`` of a step come by
+``pltpu.make_async_copy`` into one half of a VMEM buffer ``[2, kv_step,
+block_size, D]`` while the other half is computed on.  A slot outside
+``[lo, hi)`` is never read, whatever the table's width: a program costs
+what its context costs, and a null segment walks nothing.  The
+online-softmax state (acc/m/l, VMEM scratch) is carried over the steps;
+the score block of a step is ``block_q x (kv_step * block_size)``.
+
+``kv_step`` follows from the call's shapes (`_kv_step`): 512 keys a step
+for a decode row's 16-row q-block, halved while the score block's float32
+temporaries and the buffers pass a fixed VMEM budget (a 1,024-row chunk
+q-block takes 256 keys).  A head narrower than 128 lanes cannot be cut
+out of an HBM array, so such a pool is viewed as ``[num_blocks, H,
+block_size / p, p * D]`` with ``p = 128 / D`` keys a row
+(`_lane_parts`), and the program reads the keys of a step part by part;
+the mask knows their positions.
+
+Causal masking happens inside each ragged segment: row ``r`` of q-block
+``i`` sees KV position ``c`` iff
 
     r < q_valids[i]  and  c <= q_starts[i] + r  and  c < context_len
 
@@ -46,12 +72,11 @@ they leave as it was):
                   the query heads that share one: a KV block is read
                   once for all of them (a prefill chunk with grouped KV
                   heads; with ``block_tokens = 1``, a decode row's
-                  heads).  A KV block wholly after the q-block's last
-                  token is skipped.
+                  heads).  The walk ends at the q-block's last
+                  token's block.
     window        row at position ``p`` sees ``c`` only if ``c > p -
-                  window``; a KV block wholly before the q-block's first
-                  token's window is skipped, and the first one read is
-                  masked inside.
+                  window``; the walk starts at the block of the
+                  q-block's first token's window start, masked inside.
 
 Gated through ``pallas_gate`` ("ragged_attention" probe);
 `ragged_block_plan` exports the exact specs for
@@ -71,7 +96,7 @@ from .pallas_tiles import (_NEG_INF, _STAT_LANES, _demote_f64,
                            _interpret, _kernel_span, _lanes, _min_rows,
                            _x32, softmax_scratch)
 
-__all__ = ["ragged_paged_attention", "ragged_block_plan",
+__all__ = ["ragged_paged_attention", "ragged_block_plan", "pool_copyable",
            "ragged_q_block", "ragged_segments", "KV_SCALE_LANES"]
 
 #: lane width of the per-slot KV dequant scale tables
@@ -131,56 +156,192 @@ def ragged_segments(query_lens, context_lens, block_q,
             np.asarray(offsets, np.int32), off)
 
 
+#: keys one step of the walk brings for the smallest q-block (a decode
+#: row's): `_kv_step` turns it into table slots
+_STEP_KEYS = 512
+#: VMEM that a step's score block (its float32 temporaries) and the K/V
+#: buffers beside it may take; a larger q-block gets a narrower step
+_STEP_VMEM_BYTES = 6 * 2 ** 20
+
+
+def _kv_step(block_q, block_size, head_dim, kv_itemsize, table_width):
+    """Table slots a step of the walk brings: `_STEP_KEYS` keys, no
+    more than the table holds, halved until the ``block_q x keys``
+    score block (scores, probabilities and the mask beside them, f32)
+    and the buffers (two K and two V buffers in the pool's type, one
+    widened copy of each) fit `_STEP_VMEM_BYTES`.  Sixteen decode rows
+    take 512 keys a step; a 1,024-row chunk q-block 256."""
+    step = max(1, min(_STEP_KEYS // block_size, table_width))
+
+    def vmem(step):
+        keys = step * block_size
+        return (3 * block_q * keys * 4
+                + keys * head_dim * (4 * kv_itemsize + 2 * 4))
+
+    while step > 1 and vmem(step) > _STEP_VMEM_BYTES:
+        step //= 2
+    return step
+
+
+#: lanes of a vector register row: the narrowest window of an HBM
+#: array that a copy may name
+_LANES = 128
+
+
+def _lane_parts(head_dim, block_size):
+    """Keys that share a 128-lane row of the pool as the kernel sees it.
+
+    An HBM window narrower than 128 lanes cannot be copied (Mosaic: a
+    slice "must be aligned to tiling (128)"), so a pool of narrow heads
+    is handed over as ``[num_blocks, H, block_size / parts, parts * D]``:
+    row ``t`` of a block holds keys ``t * parts ... t * parts + parts -
+    1`` side by side.  1 for a head width that fills whole rows."""
+    if head_dim < _LANES and _LANES % head_dim == 0 \
+            and block_size % (_LANES // head_dim) == 0:
+        return _LANES // head_dim
+    return 1
+
+
+def pool_copyable(head_dim, block_size):
+    """Whether the walk's copies lower on the chip for a pool of this
+    head width and block size: whole 128-lane rows a head, or whole keys
+    a row (`_lane_parts`) and then whole 8-row tiles a block."""
+    if head_dim % _LANES == 0:
+        return True
+    parts = _lane_parts(head_dim, block_size)
+    return parts > 1 and (block_size // parts) % 8 == 0
+
+
 def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
-                      q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-                      acc_ref, m_ref, l_ref, *, block_size, block_q,
-                      scale, w_last, window=None, block_tokens=None):
-    """One (q-block, head, table-slot) program over the paged pool.
+                      q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
+                      acc_ref, m_ref, l_ref, k_buf, v_buf, ks_buf, vs_buf,
+                      sems, *, block_size, block_q, kv_step, scale,
+                      parts=1, window=None, block_tokens=None):
+    """One (q-block, head) program: the walk over the sequence's block
+    table runs inside it.
 
     Scalar-prefetched ``seq_ids`` route each q-block to its sequence's
     block table; the null segment (``seq_ids == num_seqs``) reads
-    ``context_len 0`` from the padded tail of ``cl_ref`` so its guard
-    never fires and the emit writes zeros.
+    ``context_len 0`` from the padded tail of ``cl_ref``, walks nothing
+    and emits zeros.
 
-    ``ks_ref``/``vs_ref`` are the int8 variant's per-slot dequant scale
-    blocks ((1, block_size, KV_SCALE_LANES) f32, walked by the SAME
-    block-table index map as k/v) or None on the float path; dequant
-    happens on the VMEM-resident tile inside the running-softmax loop —
-    the int8 bytes are all that crosses HBM.
+    The pools stay in HBM.  A step of the walk copies ``kv_step``
+    consecutive table slots' K and V blocks into one half of the double
+    buffers while the other half is computed on; only slots in ``[lo,
+    hi)`` (the first block the first token's window reaches, the last
+    block that holds a key a row can see) are ever copied.  The last
+    step's slots past ``hi`` keep what the buffer held, masked like any
+    key past the context; their V rows are zeroed first, since ``0 x
+    NaN`` is not 0.
+
+    ``ks_hbm``/``vs_hbm`` are the int8 variant's per-slot dequant scale
+    tables (copied by the SAME walk into ``ks_buf``/``vs_buf``) or None
+    on the float path; dequant happens on the VMEM-resident tiles
+    inside the running-softmax loop — the int8 bytes are all that
+    crosses HBM.
     """
     i = pl.program_id(0)
-    w = pl.program_id(2)
+    h = pl.program_id(1)
     sid = sid_ref[i]
     ctx = cl_ref[sid]
     qs = qs_ref[i]
     qv = qv_ref[i]
+    keys = kv_step * block_size
+    part_keys = keys // parts
+    head_dim = q_ref.shape[-1]
+    int8_kv = ks_hbm is not None
 
-    @pl.when(w == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def unpack(buf, scales, b):
+        """Buffer half ``b`` as a (keys, D) float32 matrix.  With
+        ``parts`` keys a row the keys come part by part: entry ``c *
+        part_keys + r`` is key ``r * parts + c`` of the step."""
+        x = buf[b].astype(jnp.float32)
+        x = x.reshape(part_keys, x.shape[-1])           # (rows, lanes)
+        cut = [x] if parts == 1 else [
+            x[:, c * head_dim:(c + 1) * head_dim] for c in range(parts)]
+        if scales is not None:
+            # per-slot dequant; the scale tables come in the same order
+            sc = scales[b]                      # (step, parts, rows, 128)
+            cut = [x_c * sc[:, c].reshape(part_keys, sc.shape[-1])[:, :1]
+                   for c, x_c in enumerate(cut)]
+        return cut[0] if parts == 1 else jnp.concatenate(cut, axis=0)
 
-    live = w * block_size < ctx
-    if block_tokens is not None:
-        # nothing after the q-block's last token
-        live &= w * block_size <= qs + (block_tokens - 1)
+    # nothing past the context, nothing after the q-block's last token
+    last = qs + ((block_tokens or block_q) - 1)
+    hi = jnp.minimum(jax.lax.div(ctx + (block_size - 1), block_size),
+                     jax.lax.div(last, block_size) + 1)
+    lo = 0
     if window is not None:
         # nothing wholly before the first token's window
-        live &= (w + 1) * block_size > qs - (window - 1)
+        lo = jax.lax.div(jnp.maximum(qs - (window - 1), 0), block_size)
+    steps = jax.lax.div(jnp.maximum(hi - lo, 0) + (kv_step - 1), kv_step)
 
-    @pl.when(live)
-    def _block():
+    # (table in HBM, its buffer, whether a block has a head axis)
+    walked = [(k_hbm, k_buf, True), (v_hbm, v_buf, True)]
+    if int8_kv:
+        walked += [(ks_hbm, ks_buf, False), (vs_hbm, vs_buf, False)]
+
+    def copies(g, j, b):
+        """Table slot ``lo + g * kv_step + j`` into row ``j`` of buffer
+        half ``b``."""
+        blk = bt_ref[sid, lo + g * kv_step + j]
+        return [pltpu.make_async_copy(
+            src.at[blk, h] if per_head else src.at[blk], dst.at[b, j],
+            sems.at[n, b]) for n, (src, dst, per_head) in enumerate(walked)]
+
+    def live_slots(g):
+        return jnp.minimum(hi - lo - g * kv_step, kv_step)
+
+    def start(g, b):
+        def one(j, c):
+            for dma in copies(g, j, b):
+                dma.start()
+            return c
+        jax.lax.fori_loop(0, live_slots(g), one, 0)
+
+    def wait(g, b):
+        def one(j, c):
+            for dma in copies(g, j, b):
+                dma.wait()
+            return c
+
+        def dead(j, c):
+            # an int8 V is finite as it lies; its scale may not be
+            zeroed = vs_buf if int8_kv else v_buf
+            zeroed[b, j] = jnp.zeros(zeroed.shape[2:], zeroed.dtype)
+            return c
+        n = live_slots(g)
+        jax.lax.fori_loop(0, n, one, 0)
+        jax.lax.fori_loop(n, kv_step, dead, 0)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(steps > 0)
+    def _first():
+        start(0, 0)
+
+    def step(g, c):
+        b = jax.lax.rem(g, 2)
+
+        @pl.when(g + 1 < steps)
+        def _next():
+            start(g + 1, 1 - b)
+
+        wait(g, b)
         q = q_ref[0].astype(jnp.float32)                # (bq, D)
-        k = k_ref[0, 0].astype(jnp.float32)             # (bs, D)
-        if ks_ref is not None:
-            k = k * ks_ref[0, :, :1]                    # per-slot dequant
+        k = unpack(k_buf, ks_buf, b)                    # (keys, D)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, bs)
+            preferred_element_type=jnp.float32) * scale  # (bq, keys)
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        col = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-               + w * block_size)
+        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        if parts > 1:
+            # the keys' own order back (see `unpack`)
+            part = jax.lax.div(col, part_keys)
+            col = (col - part * part_keys) * parts + part
+        col = col + (lo + g * kv_step) * block_size
         if block_tokens is not None:
             # head groups: row r is token r % block_tokens (a power of
             # two) of the block
@@ -197,42 +358,56 @@ def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = _lanes(alpha * l_ref[:, :1]
                             + jnp.sum(p, axis=-1, keepdims=True))
-        v = v_ref[0, 0].astype(jnp.float32)             # (bs, D)
-        if vs_ref is not None:
-            v = v * vs_ref[0, :, :1]                    # per-slot dequant
+        v = unpack(v_buf, vs_buf, b)                    # (keys, D)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = _lanes(m_new)
+        return c
 
-    @pl.when(w == w_last)
-    def _emit():
-        l = l_ref[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out = acc_ref[...] / l_safe
-        # masked/null rows -> zeros.  Broadcast the f32 stat, never the
-        # (bq, 1) predicate: Mosaic lowers a bool broadcast_in_dim
-        # through an integer select/compare whose width follows the x64
-        # mode at LOWERING time (outside _x32) and aborts on i64
-        # ("bitwidth_ <= 32"); compare at full shape instead.
-        out = jnp.where(jnp.broadcast_to(l, out.shape) > 0.0, out, 0.0)
-        o_ref[...] = out[None].astype(o_ref.dtype)
+    jax.lax.fori_loop(0, steps, step, 0)
+
+    l = l_ref[:, :1]
+    l_safe = jnp.where(l == 0.0, 1.0, l)
+    out = acc_ref[...] / l_safe
+    # masked/null rows -> zeros.  Broadcast the f32 stat, never the
+    # (bq, 1) predicate: Mosaic lowers a bool broadcast_in_dim
+    # through an integer select/compare whose width follows the x64
+    # mode at LOWERING time (outside _x32) and aborts on i64
+    # ("bitwidth_ <= 32"); compare at full shape instead.
+    out = jnp.where(jnp.broadcast_to(l, out.shape) > 0.0, out, 0.0)
+    o_ref[...] = out[None].astype(o_ref.dtype)
 
 
 def _ragged_attn_kernel(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
-                        q_ref, k_ref, v_ref, o_ref,
-                        acc_ref, m_ref, l_ref, **kw):
+                        q_ref, k_hbm, v_hbm, o_ref,
+                        acc_ref, m_ref, l_ref, k_buf, v_buf, sems, **kw):
     _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
-                      q_ref, k_ref, v_ref, None, None, o_ref,
-                      acc_ref, m_ref, l_ref, **kw)
+                      q_ref, k_hbm, v_hbm, None, None, o_ref,
+                      acc_ref, m_ref, l_ref, k_buf, v_buf, None, None,
+                      sems, **kw)
 
 
 def _ragged_attn_int8_kernel(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
-                             q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-                             acc_ref, m_ref, l_ref, **kw):
+                             q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
+                             acc_ref, m_ref, l_ref, k_buf, v_buf, ks_buf,
+                             vs_buf, sems, **kw):
     _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
-                      q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-                      acc_ref, m_ref, l_ref, **kw)
+                      q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
+                      acc_ref, m_ref, l_ref, k_buf, v_buf, ks_buf, vs_buf,
+                      sems, **kw)
+
+
+def _walk_scratch(kv_step, block_size, head_dim, kv_dtype, int8_kv):
+    """The walk's VMEM: two halves of ``kv_step`` K and V blocks (and,
+    for an int8 pool, of their scale blocks) as the kernel sees them
+    (`_lane_parts`), as (shape, dtype)."""
+    parts = _lane_parts(head_dim, block_size)
+    rows = block_size // parts
+    bufs = [((2, kv_step, rows, parts * head_dim), kv_dtype)] * 2
+    if int8_kv:
+        bufs += [((2, kv_step, parts, rows, _LANES), jnp.float32)] * 2
+    return bufs
 
 
 @_x32
@@ -286,10 +461,18 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     S, W = block_tables.shape
     if scale is None:
         scale = 1.0 / (D ** 0.5)
+    kv_step = _kv_step(block_q, block_size, D,
+                       jnp.dtype(k_pool.dtype).itemsize, W)
+    parts = _lane_parts(D, block_size)
+    if parts > 1:
+        options["parts"] = parts
+        rows = block_size // parts
+        k_pool = k_pool.reshape(num_blocks, H, rows, parts * D)
+        v_pool = v_pool.reshape(num_blocks, H, rows, parts * D)
 
     qt = jnp.swapaxes(q, 0, 1)                          # [H, T, D]
-    # null segment: seq_ids == S indexes the appended zero row / zero
-    # context so the kernel's guard skips every KV block
+    # null segment: seq_ids == S indexes the appended zero context, so
+    # the walk has no step
     bt = jnp.concatenate(
         [block_tables.astype(jnp.int32),
          jnp.zeros((1, W), jnp.int32)], axis=0)          # [S+1, W]
@@ -301,41 +484,45 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     qv = q_valids.astype(jnp.int32)
 
     q_spec = pl.BlockSpec(
-        (1, block_q, D),
-        lambda i, h, w, bt, cl, sid, qs, qv: (h, i, 0))
-    pool_spec = pl.BlockSpec(
-        (1, 1, block_size, D),
-        lambda i, h, w, bt, cl, sid, qs, qv: (bt[sid[i], w], h, 0, 0))
-    in_specs = [q_spec, pool_spec, pool_spec]
+        (1, block_q, D), lambda i, h, bt, cl, sid, qs, qv: (h, i, 0))
+    # the pools (and the scale tables) stay where they are: the program
+    # copies the blocks its table names
+    hbm_spec = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [q_spec, hbm_spec, hbm_spec]
     operands = [qt, k_pool, v_pool]
     kernel = _ragged_attn_kernel
     name = "ragged_attention"
     if int8_kv:
-        # the scale blocks ride the same block-table walk as k/v; both
-        # trailing dims cover the full scale array so the spec is legal
-        scale_spec = pl.BlockSpec(
-            (1, block_size, KV_SCALE_LANES),
-            lambda i, h, w, bt, cl, sid, qs, qv: (bt[sid[i], w], 0, 0))
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
+        in_specs += [hbm_spec, hbm_spec]
+        # [nb, bs, 1] -> [nb, parts, bs / parts, 128]: key t * parts + c
+        # of a block at [c, t], the order `unpack` reads, over a whole
+        # row of lanes (the least an HBM window may span, and what the
+        # one lane takes in HBM's tiles anyway)
+        operands += [
+            jnp.broadcast_to(jnp.swapaxes(
+                t[..., :1].astype(jnp.float32).reshape(
+                    num_blocks, block_size // parts, parts, 1), 1, 2),
+                (num_blocks, parts, block_size // parts, _LANES))
+            for t in (k_scales, v_scales)]
         kernel = _ragged_attn_int8_kernel
         name = "ragged_attention_int8"
+    buffers = _walk_scratch(kv_step, block_size, D, k_pool.dtype, int8_kv)
 
     with _kernel_span(name, "fwd") as kernel_name:
         out = pl.pallas_call(
             functools.partial(
-                kernel, block_size=block_size,
-                block_q=block_q, scale=float(scale), w_last=W - 1,
-                **options),
+                kernel, block_size=block_size, block_q=block_q,
+                kv_step=kv_step, scale=float(scale), **options),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=5,
-                grid=(nqb, H, W),
+                grid=(nqb, H),
                 in_specs=in_specs,
                 out_specs=pl.BlockSpec(
                     (1, block_q, D),
-                    lambda i, h, w, bt, cl, sid, qs, qv: (h, i, 0)),
-                scratch_shapes=softmax_scratch(block_q, D),
+                    lambda i, h, bt, cl, sid, qs, qv: (h, i, 0)),
+                scratch_shapes=softmax_scratch(block_q, D) + [
+                    pltpu.VMEM(shape, dtype) for shape, dtype in buffers
+                ] + [pltpu.SemaphoreType.DMA((len(buffers), 2))],
             ),
             out_shape=jax.ShapeDtypeStruct((H, T, D), q.dtype),
             interpret=_interpret(),
@@ -352,38 +539,50 @@ def ragged_block_plan(num_heads, head_dim, block_size, num_q_blocks=4,
     context lens, segment descriptors) live whole in SMEM, have no
     BlockSpec to audit, and are omitted.
 
+    The pools stay in HBM: their entries give the window one async copy
+    of the walk names (a table slot's block of one head, as the kernel
+    sees the pool: `_lane_parts`), and ``scratch`` holds the two halves
+    of ``kv_step`` such windows that the copies fill, after the
+    softmax's accumulators.
+
     ``kv_dtype=int8`` exports the int8-pool variant: int8 k/v blocks
-    plus the two (1, block_size, KV_SCALE_LANES) f32 per-slot scale
-    operands; q/out stay ``dtype`` (the compute precision).
+    plus the two f32 per-slot scale tables, a whole row of lanes wide;
+    q/out stay ``dtype`` (the compute precision).
     """
     dtype = jnp.dtype(dtype)
     f32 = jnp.dtype(jnp.float32)
     kvdt = jnp.dtype(kv_dtype) if kv_dtype is not None else dtype
+    int8_kv = kvdt == jnp.dtype(jnp.int8)
     if block_q is None:
         block_q = ragged_q_block(dtype)
     D = head_dim
     T = num_q_blocks * block_q
-    pool = (num_blocks, num_heads, block_size, D)
+    kv_step = _kv_step(block_q, block_size, D, kvdt.itemsize, table_width)
+    parts = _lane_parts(D, block_size)
+    rows = block_size // parts
+    pool = (num_blocks, num_heads, rows, parts * D)
     operands = [
         ("q", (1, block_q, D), (num_heads, T, D), dtype),
-        ("k_pool", (1, 1, block_size, D), pool, kvdt),
-        ("v_pool", (1, 1, block_size, D), pool, kvdt),
+        ("k_pool", (1, 1, rows, parts * D), pool, kvdt),
+        ("v_pool", (1, 1, rows, parts * D), pool, kvdt),
     ]
-    if kvdt == jnp.dtype(jnp.int8):
-        scales = (num_blocks, block_size, KV_SCALE_LANES)
+    if int8_kv:
+        scales = (num_blocks, parts, rows, _LANES)
         operands += [
-            ("k_scales", (1, block_size, KV_SCALE_LANES), scales, f32),
-            ("v_scales", (1, block_size, KV_SCALE_LANES), scales, f32),
+            ("k_scales", (1, parts, rows, _LANES), scales, f32),
+            ("v_scales", (1, parts, rows, _LANES), scales, f32),
         ]
     operands.append(("out", (1, block_q, D), (num_heads, T, D), dtype))
     return {
-        "grid": (num_q_blocks, num_heads, table_width),
+        "grid": (num_q_blocks, num_heads),
         "block_q": block_q,
+        "kv_step": kv_step,
         "kv_dtype": str(kvdt),
         "operands": operands,
         "scratch": (
             ((block_q, D), f32),
             ((block_q, _STAT_LANES), f32),
             ((block_q, _STAT_LANES), f32),
-        ),
+        ) + tuple((shape, jnp.dtype(dt)) for shape, dt in _walk_scratch(
+            kv_step, block_size, D, kvdt, int8_kv)),
     }

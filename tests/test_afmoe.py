@@ -105,6 +105,9 @@ def test_engine_matches_the_reference(model, num_blocks, preempted):
     assert group["blocks_released"] > 20 and group["blocks_in_use"] == 0
     assert stats["prefix_bypassed_window"] >= 5
     assert 0 < stats["kv_blocks_read_window"] < stats["kv_blocks_context"]
+    # every block read is a slot of a table the kernel was handed
+    assert (stats["kv_blocks_read_window"] + stats["kv_blocks_read_full"]
+            <= stats["kv_table_slots"])
     assert stats["moe_assignments"] == 4 * 4 * (
         stats["decode_rows_carried"] + stats["prompt_tokens_carried"])
     assert stats["moe_assignments"] <= stats["moe_plan_rows"]

@@ -177,6 +177,25 @@ def test_sampler_gate_tiny():
     assert set(c["device_ms"].values()) == {None}
 
 
+def test_ragged_walk_tiny():
+    """The three forms of a step's attention call at width 32, three
+    rows and tables of four blocks: the kernel (interpreted) against
+    the fallback, the blocks counted; no device, no time."""
+    calls = (("t.step", "mixed", 1, 2, 32, 16, 4, None, 32),
+             ("t.decode", "decode", 2, 2, 32, 16, 4, 24, 0),
+             ("t.chunk", "chunk", 2, 2, 32, 16, 4, None, 32))
+    c = chip_smoke.ragged_walk(calls, rows=3, chunk_bq=16)["checked"]
+    assert len(c) == 6 and all("us" not in e for e in c.values())
+    assert all(e["rel_l2_vs_fallback"] < chip_smoke.BF16_REL_L2
+               for k, e in c.items() if k.endswith("@0.25"))
+    # full tables: 56 keys of 64.  Two decode rows read four blocks of
+    # two KV heads, the chunk's two q-blocks three (the first ends at
+    # key 39) and four; the windowed rows keys 32-55: two blocks
+    assert c["t.step@1.0"]["kv_blocks"] == 2 * (2 * 4 + 3 + 4)
+    assert c["t.decode@1.0"]["kv_blocks"] == 3 * 2 * 2
+    assert c["t.chunk@1.0"]["kv_blocks"] == 2 * (3 + 4)
+
+
 def test_serve_tiny():
     out = chip_smoke.serve(GPT, [5, 40, 70, 90], new_tokens=6)
     c = out["checked"]

@@ -1,7 +1,10 @@
 """Pallas kernel parity vs XLA reference compositions (interpret mode on
 CPU; same code compiles via Mosaic on TPU)."""
+import functools
+
 import numpy as np
 import pytest
+
 import jax
 import jax.numpy as jnp
 
@@ -470,9 +473,10 @@ def test_xent_edge_mask_is_elided_when_aligned():
 # Ragged mixed prefill+decode attention (pallas_ragged)
 # ---------------------------------------------------------------------
 def _ragged_case(query_lens, context_lens, dtype, seed=30, H=4, D=32,
-                 bs=16, W=4, pad_blocks=0):
+                 bs=16, W=4, pad_blocks=0, int8=False):
     """Build a ragged batch + paged pool and return (kernel, fallback)
-    outputs at the given dtype."""
+    outputs at the given dtype (``int8``: an int8 pool with per-slot
+    scales under queries of ``dtype``)."""
     from paddle_tpu.inference.serving.attention import _ragged_ref
     from paddle_tpu.ops import pallas_ragged as pr
 
@@ -485,13 +489,21 @@ def _ragged_case(query_lens, context_lens, dtype, seed=30, H=4, D=32,
                                            block_q, num_q_blocks=nqb,
                                            num_seqs=S)
     nb = S * W + 1
-    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    kq, kk, kv, ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(kq, (nqb * block_q, H, D),
                           jnp.float32).astype(dtype)
-    k_pool = jax.random.normal(kk, (nb, H, bs, D),
-                               jnp.float32).astype(dtype)
-    v_pool = jax.random.normal(kv, (nb, H, bs, D),
-                               jnp.float32).astype(dtype)
+    scales = {}
+    if int8:
+        k_pool, v_pool = (jax.random.randint(
+            k, (nb, H, bs, D), -127, 128, jnp.int32).astype(jnp.int8)
+            for k in (kk, kv))
+        for name, k in zip(("k_scales", "v_scales"), jax.random.split(ks)):
+            scales[name] = jax.random.uniform(
+                k, (nb, bs, pr.KV_SCALE_LANES), jnp.float32, 0.002, 0.02)
+    else:
+        k_pool, v_pool = (jax.random.normal(
+            k, (nb, H, bs, D), jnp.float32).astype(dtype)
+            for k in (kk, kv))
     tables = np.zeros((S, W), np.int32)
     for s, ctx in enumerate(context_lens):
         for w in range(-(-int(ctx) // bs)):
@@ -501,22 +513,97 @@ def _ragged_case(query_lens, context_lens, dtype, seed=30, H=4, D=32,
     sid, qs, qv = jnp.asarray(sid), jnp.asarray(qs), jnp.asarray(qv)
     scale = 1.0 / D ** 0.5
     out = pr.ragged_paged_attention(q, k_pool, v_pool, bt, cl, sid, qs,
-                                    qv, block_q=block_q, scale=scale)
+                                    qv, block_q=block_q, scale=scale,
+                                    **scales)
     ref = _ragged_ref(q, k_pool, v_pool, bt, cl, sid, qs, qv, block_q,
-                      scale)
+                      scale, **scales)
     return np.asarray(out, np.float32), np.asarray(ref, np.float32)
 
 
+def _grouped_case(form, dtype, contexts, seed=31, kv_heads=2, group=2,
+                  D=32, bs=16, W=8, window=None):
+    """The grouped engines' calls (`serving.attention`), kernel against
+    fallback: ``decode`` rows of one token whose ``group`` heads go as
+    the rows of a q-block (``block_tokens`` 1), a ``chunk`` of 48 tokens
+    in q-blocks of 16 tokens x ``group`` heads, or ``selected`` decode
+    rows (MiniCPM-SALA's form) over tables that hold a choice of the
+    context's blocks in an order of their own."""
+    from paddle_tpu.inference.serving import attention as att
+    S = len(contexts)
+    rng = np.random.default_rng(seed)
+    nb = S * W + 1
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    k_pool, v_pool = (jax.random.normal(
+        k, (nb, kv_heads, bs, D), jnp.float32).astype(dtype)
+        for k in (kk, kv))
+    tables = 1 + np.arange(S * W, dtype=np.int32).reshape(S, W)
+    if form == "chunk":
+        chunk, valid, ctx = 48, 40, int(contexts[0])
+        q = jax.random.normal(kq, (chunk, group * kv_heads, D),
+                              jnp.float32).astype(dtype)
+        return [np.asarray(att.grouped_chunk_attention(
+            q, k_pool, v_pool, jnp.asarray(tables[0]), jnp.int32(ctx),
+            jnp.int32(ctx - valid), jnp.int32(valid), window=window,
+            chunk_bq=16, use_pallas=use), np.float32)
+            for use in (True, False)]
+    q = jax.random.normal(kq, (S, group * kv_heads, D),
+                          jnp.float32).astype(dtype)
+    sel = np.broadcast_to(tables[:, None], (S, kv_heads, W)).copy()
+    ctx = np.broadcast_to(np.asarray(contexts, np.int32)[:, None],
+                          (S, kv_heads)).copy()
+    options = dict(window=window,
+                   block_q=att.decode_block_q(group, dtype))
+    if form == "selected":
+        # each (row, KV head) keeps a shuffled half of its blocks, the
+        # last one part full: no prefix of the context
+        options = {}
+        for s in range(S):
+            for h in range(kv_heads):
+                sel[s, h] = rng.permutation(sel[s, h])
+                ctx[s, h] = (W // 2) * bs - int(rng.integers(0, bs))
+    return [np.asarray(att.grouped_decode_attention(
+        q, k_pool, v_pool, jnp.asarray(sel), jnp.asarray(ctx), use,
+        **options), np.float32) for use in (True, False)]
+
+
+_case = functools.partial
 _RAGGED_CASES = {
     # every row a single-token decode step (the PR-5 steady state)
-    "pure_decode": ([1, 1, 1], [60, 17, 5]),
+    "pure_decode": _case(_ragged_case, [1, 1, 1], [60, 17, 5]),
     # one prompt prefilled whole (query == context, multiple q-blocks)
-    "pure_prefill": ([20], [20]),
+    "pure_prefill": _case(_ragged_case, [20], [20]),
     # prefill chunk + two decode rows in ONE batch
-    "mixed": ([12, 1, 1], [30, 25, 9]),
+    "mixed": _case(_ragged_case, [12, 1, 1], [30, 25, 9]),
     # chunk starting mid-prompt exactly at a q-block boundary
     # (query_len a multiple of block_q, base context > 0)
-    "chunk_boundary": ([16, 1], [48, 33]),
+    "chunk_boundary": _case(_ragged_case, [16, 1], [48, 33]),
+    # the walk's ends (blocks of 16, 32 table slots a step): a context
+    # that ends mid-block, at a block's end, at a step's end (512 keys)
+    # and one block past it
+    "walk_ends": _case(_ragged_case, [1, 1, 1, 1], [505, 496, 512, 528],
+                       W=40),
+    # a chunk whose q-blocks end in different steps of the walk
+    "walk_chunk": _case(_ragged_case, [40, 1], [530, 3], W=40),
+    # a table four times wider than the longest context
+    "wide_table": _case(_ragged_case, [1, 9, 1], [60, 33, 64], W=16),
+    # 64-wide heads: two keys a 128-lane row of the pool (GPT-2's form)
+    "packed_lanes": _case(_ragged_case, [20, 1, 1], [52, 41, 16], D=64),
+    # the int8 pool: per-slot scales walked with the table
+    "int8_pool": _case(_ragged_case, [12, 1, 1], [30, 25, 9], int8=True),
+    "int8_pool_packed": _case(_ragged_case, [1, 1], [530, 64], int8=True,
+                              D=64, W=40),
+    # grouped KV heads: decode rows (block_tokens 1) and a chunk's head
+    # groups (block_tokens 16), with no window and with one whose first
+    # live block lies inside the table (and the context past one step)
+    "grouped_decode": _case(_grouped_case, "decode", contexts=[100, 7, 128]),
+    "grouped_decode_window": _case(_grouped_case, "decode", W=40,
+                                   contexts=[600, 530, 20], window=70),
+    "grouped_chunk": _case(_grouped_case, "chunk", contexts=[117]),
+    "grouped_chunk_window": _case(_grouped_case, "chunk", contexts=[600],
+                                  W=40, window=70),
+    # MiniCPM-SALA's decode rows: a selected table, no prefix
+    "selected_table": _case(_grouped_case, "selected", group=8,
+                            contexts=[0, 0, 0]),
 }
 
 
@@ -525,10 +612,58 @@ _RAGGED_CASES = {
 def test_ragged_attention_kernel_matches_fallback(case, dtype):
     """Ragged mixed-batch kernel vs the pure-XLA segment-gather
     fallback, at the paged-attention parity tolerance for f32."""
-    qls, ctxs = _RAGGED_CASES[case]
-    out, ref = _ragged_case(qls, ctxs, dtype)
+    out, ref = _RAGGED_CASES[case](dtype=dtype)
+    assert np.abs(ref).max() > 0.1
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(out, ref, atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("window, block_tokens", [(None, None), (40, 1),
+                                                  (40, 16), (None, 16)])
+def test_ragged_attention_reads_no_dead_block(window, block_tokens):
+    """Every pool block that no live table slot names is NaN, and every
+    dead slot (past the context, after a q-block's last token, before
+    its window) names such a block: the output is finite and equals the
+    fallback's over a pool whose dead blocks hold zeros."""
+    from paddle_tpu.inference.serving.attention import _ragged_ref
+    from paddle_tpu.ops import pallas_ragged as pr
+    H, D, bs, W, block_q = 2, 32, 16, 40, 16
+    if block_tokens == 1:       # decode rows, the walk past one step
+        contexts, lens = [600, 37, 513], [1, 1, 1]
+    else:                       # a chunk of three q-blocks and a row
+        contexts, lens = [570, 9], [40, 1]
+    tokens = block_tokens or block_q
+    sid, qs, qv, _, _ = pr.ragged_segments(lens, contexts, tokens)
+    nqb, S = len(sid), len(contexts)
+    tables = 1 + np.arange(S * W, dtype=np.int32).reshape(S, W)
+    live = np.zeros((S, W), bool)
+    for i in range(nqb):
+        last = qs[i] + qv[i] - 1
+        first = 0 if window is None else max(qs[i] - window + 1, 0)
+        live[sid[i], first // bs:last // bs + 1] = True
+    dead = S * W + 1                                    # the NaN block
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(5), 3)
+    pools = [np.array(jax.random.normal(k, (S * W + 2, H, bs, D),
+                                        jnp.float32))
+             for k in (kk, kv)]
+    for pool in pools:
+        pool[0] = pool[dead] = np.nan
+        pool[tables[~live]] = np.nan
+    q = jax.random.normal(kq, (nqb * block_q, H, D), jnp.float32)
+    args = (jnp.asarray(contexts, jnp.int32), jnp.asarray(sid),
+            jnp.asarray(qs), jnp.asarray(qv))
+    options = dict(window=window, block_tokens=block_tokens)
+    out = pr.ragged_paged_attention(
+        q, *(jnp.asarray(p) for p in pools),
+        jnp.asarray(np.where(live, tables, dead)), *args,
+        block_q=block_q, scale=D ** -0.5, **options)
+    ref = _ragged_ref(
+        q, *(jnp.asarray(np.nan_to_num(p)) for p in pools),
+        jnp.asarray(tables), *args, block_q, D ** -0.5, **options)
+    out = np.asarray(out)
+    assert np.isfinite(out).all()
+    assert live.sum() < S * W / 2 and np.abs(out).max() > 0.1
+    np.testing.assert_allclose(out, np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
 def test_ragged_attention_null_segments_emit_zeros():

@@ -170,7 +170,7 @@ def test_engine_matches_the_reference(model):
     carried = stats["decode_rows_carried"] + stats["prompt_tokens_carried"]
     assert stats["moe_assignments"] == stats["moe_assignments_routed"] \
         == 4 * 4 * carried
-    assert stats["kv_blocks_read_full"] > 0
+    assert 0 < stats["kv_blocks_read_full"] <= stats["kv_table_slots"]
     assert stats["kv_blocks_read_window"] == 0
 
 

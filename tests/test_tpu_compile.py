@@ -167,6 +167,41 @@ def _ragged(kv_dtype):
     return pr.ragged_paged_attention, args
 
 
+def _ragged_gpt2_large():
+    """`gpt2-large.decode-closed32`: 20 heads of 64, blocks of 16, a
+    table of 64 slots, 47 q-blocks of 16 rows a step."""
+    heads, d, bs, width, seqs, nqb = 20, 64, 16, 64, 32, 47
+    pool = ((2049, heads, bs, d), bf16)
+    return (pr.ragged_paged_attention,
+            [((nqb * 16, heads, d), bf16), pool, pool,
+             ((seqs, width), i32), ((seqs,), i32), ((nqb,), i32),
+             ((nqb,), i32), ((nqb,), i32)])
+
+
+def _ragged_grouped(form, d, kv_heads, width, num_blocks, window=None):
+    """The grouped cells' two calls a layer as the engine makes them
+    (`attention._grouped_attend_impl`): 32 decode rows whose 8 query
+    heads a KV head go as the rows of a 16-row q-block, or a 1,024-token
+    chunk in q-blocks of 128 tokens x 8 heads, over blocks of 64."""
+    from paddle_tpu.inference.serving import attention as att
+    seqs, group, bs, chunk = 32, 8, 64, 1024
+    pool = ((num_blocks, kv_heads, bs, d), bf16)
+    if form == "decode":
+        def fn(q, kp, vp, tables, ctx):
+            return att.grouped_decode_attention(
+                q, kp, vp, tables, ctx, True, window=window,
+                block_q=att.decode_block_q(group, bf16))
+        return fn, [((seqs, group * kv_heads, d), bf16), pool, pool,
+                    ((seqs, kv_heads, width), i32), ((seqs, kv_heads), i32)]
+
+    def fn(q, kp, vp, table, ctx, start, valid):
+        return att.grouped_chunk_attention(
+            q, kp, vp, table, ctx[0], start[0], valid[0], window=window,
+            chunk_bq=128, use_pallas=True)
+    return fn, [((chunk, group * kv_heads, d), bf16), pool, pool,
+                ((width,), i32), ((1,), i32), ((1,), i32), ((1,), i32)]
+
+
 SMOKE_CASES = {
     "flash_fwd_16x512x12x64": lambda: _flash("fwd"),
     "flash_bwd_16x512x12x64": lambda: _flash("bwd"),
@@ -184,6 +219,18 @@ SMOKE_CASES = {
     "softmax_xent_8192x30522": _xent,
     "ragged_attention_gpt_bf16": lambda: _ragged(bf16),
     "ragged_attention_gpt_int8kv": lambda: _ragged(i8),
+    # the serving cells' forms of the in-program walk
+    "ragged_attention_gpt2_large": _ragged_gpt2_large,
+    **{f"ragged_attention_trinity_{form}_{name}":
+       functools.partial(_ragged_grouped, form, 128, 4, width, blocks,
+                         window)
+       for form in ("decode", "chunk")
+       for name, width, blocks, window in (
+           ("window", 34 if form == "decode" else 50, 1601, 2048),
+           ("full", 224, 7169, None))},
+    **{f"ragged_attention_qwen3_next_{form}":
+       functools.partial(_ragged_grouped, form, 256, 2, 416, 13313)
+       for form in ("decode", "chunk")},
 }
 
 
