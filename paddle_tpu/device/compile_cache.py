@@ -12,7 +12,10 @@ this module sets no directory.  Otherwise the cache goes to one fixed,
 git-ignored directory in the checkout (``DEFAULT_CACHE_DIR``) — the
 path is part of the cache key, so it never carries a pid, a time or a
 temp name.  ``jax_enable_compilation_cache=False`` (the test suite sets
-it) keeps the cache off altogether.
+it) keeps the cache off altogether.  JAX leaves an instruction's
+metadata out of the key; a step program whose metadata is read
+(``observability.note_program``: its block scopes) compiles through
+``compile_keyed_by_metadata``, which puts it in.
 
 The in-process layer above it is the Executor's program-fingerprint
 -keyed executable cache (``static/executor.py``): a structurally
@@ -31,7 +34,7 @@ import os
 import threading
 
 __all__ = ["DEFAULT_CACHE_DIR", "ensure_compile_cache",
-           "record_compile_metrics"]
+           "compile_keyed_by_metadata", "record_compile_metrics"]
 
 DEFAULT_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
@@ -73,6 +76,24 @@ def ensure_compile_cache():
                 compilation_cache.reset_cache()
             _applied = True
     return jax.config.jax_compilation_cache_dir
+
+
+def compile_keyed_by_metadata(lowered):
+    """``lowered.compile()`` with the instructions' metadata (``op_name``
+    and with it the block scopes, source lines) in the persistent
+    cache's key, for a step program whose map is kept
+    (``observability.note_program``).  By default JAX leaves it out, so
+    a tree that only moved a scope would be handed the older tree's
+    executable, and read the older tree's map off it.  The many small
+    host-path programs keep the default and hit across trees."""
+    import jax
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update(flag, before)
 
 
 def record_compile_metrics(ms, kind="compile"):

@@ -53,6 +53,7 @@ import numpy as np
 
 from ...core.dispatch import dispatch
 from ...core.tensor import Tensor
+from ...observability import block
 
 __all__ = ["kv_cache_scatter", "kv_cache_scatter_quant",
            "ragged_attention", "RaggedCacheView",
@@ -119,14 +120,15 @@ def _kv_scatter_impl(k_pool, v_pool, k_new, v_new, slots):
     block — duplicate pad writes race benignly, block 0 is never read
     unmasked)."""
     nb, H, bs, D = k_pool.shape
-    blk = slots // bs
-    off = slots % bs
-    flat_k = k_new.reshape(-1, H, D).astype(k_pool.dtype)
-    flat_v = v_new.reshape(-1, H, D).astype(v_pool.dtype)
-    # advanced indices (blk, off) separated by the ":" slice put the
-    # gathered dim first: target shape [T, H, D] == flat layout
-    return (k_pool.at[blk, :, off, :].set(flat_k),
-            v_pool.at[blk, :, off, :].set(flat_v))
+    with block("kv_write"):
+        blk = slots // bs
+        off = slots % bs
+        flat_k = k_new.reshape(-1, H, D).astype(k_pool.dtype)
+        flat_v = v_new.reshape(-1, H, D).astype(v_pool.dtype)
+        # advanced indices (blk, off) separated by the ":" slice put the
+        # gathered dim first: target shape [T, H, D] == flat layout
+        return (k_pool.at[blk, :, off, :].set(flat_k),
+                v_pool.at[blk, :, off, :].set(flat_v))
 
 
 def kv_cache_scatter(k_pool, v_pool, k_new, v_new, slot_mapping):
@@ -158,14 +160,15 @@ def _kv_scatter_quant_impl(k_pool, v_pool, k_scales, v_scales,
     up over many decode steps never re-scales already-written slots."""
     nb, H, bs, D = k_pool.shape
     lanes = k_scales.shape[-1]
-    blk = slots // bs
-    off = slots % bs
-    qk, sk = _quantize_tokens(k_new.reshape(-1, H, D), lanes)
-    qv, sv = _quantize_tokens(v_new.reshape(-1, H, D), lanes)
-    return (k_pool.at[blk, :, off, :].set(qk),
-            v_pool.at[blk, :, off, :].set(qv),
-            k_scales.at[blk, off, :].set(sk),
-            v_scales.at[blk, off, :].set(sv))
+    with block("kv_write"):
+        blk = slots // bs
+        off = slots % bs
+        qk, sk = _quantize_tokens(k_new.reshape(-1, H, D), lanes)
+        qv, sv = _quantize_tokens(v_new.reshape(-1, H, D), lanes)
+        return (k_pool.at[blk, :, off, :].set(qk),
+                v_pool.at[blk, :, off, :].set(qv),
+                k_scales.at[blk, off, :].set(sk),
+                v_scales.at[blk, off, :].set(sv))
 
 
 def kv_cache_scatter_quant(k_pool, v_pool, k_scales, v_scales,
@@ -303,11 +306,13 @@ def ragged_attention(q, k_pool, v_pool, block_tables, context_lens,
             seq_ids, q_starts, q_valids)
     if k_scales is not None:
         args += (k_scales, v_scales)
-    return dispatch("ragged_paged_attention", _ragged_attention_impl,
-                    args,
-                    dict(block_q=int(block_q), scale=float(scale),
-                         use_pallas=use_pallas),
-                    differentiable=False)
+    # chunk and decode rows in one call: the blocks call it "decode"
+    with block("attention/decode"):
+        return dispatch("ragged_paged_attention", _ragged_attention_impl,
+                        args,
+                        dict(block_q=int(block_q), scale=float(scale),
+                             use_pallas=use_pallas),
+                        differentiable=False)
 
 
 # ---------------------------------------------------------------------
@@ -541,22 +546,24 @@ def _grouped_attend_impl(q, k, v, k_pool, v_pool, slots, tables, base,
     T, H, D = q0.shape
     S, kv_heads = tables.shape[0], k_pool.shape[1]
     tables = tables.astype(jnp.int32)
-    qd = q0[jnp.minimum(dec_index, T - 1)]                   # [S, H, D]
-    ctx = jnp.where(row_pos >= 0, row_pos + 1 - base, 0)
-    dec_out = grouped_decode_attention(
-        qd, k_pool, v_pool,
-        jnp.broadcast_to(tables[:, None, :dec_width],
-                         (S, kv_heads, dec_width)),
-        jnp.broadcast_to(ctx[:, None], (S, kv_heads)), pallas_rows,
-        window=window, block_q=dec_rows)
+    with block("attention/decode"):
+        qd = q0[jnp.minimum(dec_index, T - 1)]               # [S, H, D]
+        ctx = jnp.where(row_pos >= 0, row_pos + 1 - base, 0)
+        dec_out = grouped_decode_attention(
+            qd, k_pool, v_pool,
+            jnp.broadcast_to(tables[:, None, :dec_width],
+                             (S, kv_heads, dec_width)),
+            jnp.broadcast_to(ctx[:, None], (S, kv_heads)), pallas_rows,
+            window=window, block_q=dec_rows)
 
     def chunk(_):
-        row = jnp.minimum(meta[4], S - 1)
-        start = meta[5] - base[row]
-        return grouped_chunk_attention(
-            _chunk_rows(q0, meta, chunk_rows), k_pool, v_pool, tables[row],
-            start + meta[1], start, meta[1], window=window,
-            chunk_bq=chunk_bq, use_pallas=pallas_chunk)
+        with block("attention/chunk"):
+            row = jnp.minimum(meta[4], S - 1)
+            start = meta[5] - base[row]
+            return grouped_chunk_attention(
+                _chunk_rows(q0, meta, chunk_rows), k_pool, v_pool,
+                tables[row], start + meta[1], start, meta[1],
+                window=window, chunk_bq=chunk_bq, use_pallas=pallas_chunk)
 
     chunk_out = jax.lax.cond(
         meta[1] > 0, chunk,
@@ -593,30 +600,33 @@ def _sparse_attend_impl(q, k, v, k_pool, v_pool, ck_pool, slots, tables,
     S, W = tables.shape
     tables_ext = jnp.concatenate(
         [tables.astype(jnp.int32), jnp.zeros((1, W), jnp.int32)], axis=0)
-    ck_pool = pls.compress_keys(k_pool, ck_pool, tables_ext, ck_seq, ck_j,
-                                ck_slot, sizes)
+    with block("kv_write"):              # the pooled keys' writes
+        ck_pool = pls.compress_keys(k_pool, ck_pool, tables_ext, ck_seq,
+                                    ck_j, ck_slot, sizes)
     q0 = q[0]
     T, H, D = q0.shape
     kv_heads, bs = k_pool.shape[1], k_pool.shape[2]
-    qd = q0[jnp.minimum(dec_index, T - 1)]                   # [S, H, D]
-    scores = pls.sparse_select_scores(qd, ck_pool, row_slots, row_pos,
-                                      sizes, use_pallas=pallas_select)
-    sel_tables, sel_ctx = pls.selected_tables(scores, row_pos, tables,
-                                              sizes, sel_width)
-    dec_out = grouped_decode_attention(qd, k_pool, v_pool, sel_tables,
-                                       sel_ctx, pallas_attn)
+    with block("attention/decode"):
+        qd = q0[jnp.minimum(dec_index, T - 1)]               # [S, H, D]
+        scores = pls.sparse_select_scores(qd, ck_pool, row_slots, row_pos,
+                                          sizes, use_pallas=pallas_select)
+        sel_tables, sel_ctx = pls.selected_tables(scores, row_pos, tables,
+                                                  sizes, sel_width)
+        dec_out = grouped_decode_attention(qd, k_pool, v_pool, sel_tables,
+                                           sel_ctx, pallas_attn)
 
     def chunk(_):
-        qc = _chunk_rows(q0, meta, chunk_rows)
-        r = jnp.arange(chunk_rows, dtype=jnp.int32)
-        t = jnp.where(r < meta[1], meta[5] + r, -1)
-        table = tables_ext[meta[4]]
-        gather = lambda pool: jnp.swapaxes(               # noqa: E731
-            pool[table], 1, 2).reshape(W * bs, kv_heads, D)
-        out = pls.sparse_block_attention(
-            qc.reshape(chunk_rows, kv_heads, H // kv_heads, D), t,
-            gather(k_pool), gather(v_pool), ck_pool[meta[2]], sizes)
-        return out.reshape(chunk_rows, H, D)
+        with block("attention/chunk"):
+            qc = _chunk_rows(q0, meta, chunk_rows)
+            r = jnp.arange(chunk_rows, dtype=jnp.int32)
+            t = jnp.where(r < meta[1], meta[5] + r, -1)
+            table = tables_ext[meta[4]]
+            gather = lambda pool: jnp.swapaxes(           # noqa: E731
+                pool[table], 1, 2).reshape(W * bs, kv_heads, D)
+            out = pls.sparse_block_attention(
+                qc.reshape(chunk_rows, kv_heads, H // kv_heads, D), t,
+                gather(k_pool), gather(v_pool), ck_pool[meta[2]], sizes)
+            return out.reshape(chunk_rows, H, D)
 
     chunk_out = jax.lax.cond(
         meta[1] > 0, chunk,

@@ -184,10 +184,11 @@ def _ragged_sample_impl(logits, last_index, seeds, positions, do_sample,
 def ragged_sample_next(logits, last_index, seeds, positions, do_sample,
                        top_k, top_p, temperature):
     """Next-token selection over the flat ragged step's logits."""
-    return dispatch("ragged_sample_next", _ragged_sample_impl,
-                    (logits, last_index, seeds, positions, do_sample,
-                     top_k, top_p, temperature), {},
-                    differentiable=False)
+    with obs.block("sampler"):
+        return dispatch("ragged_sample_next", _ragged_sample_impl,
+                        (logits, last_index, seeds, positions, do_sample,
+                         top_k, top_p, temperature), {},
+                        differentiable=False)
 
 
 # ---------------------------------------------------------------------
@@ -308,6 +309,10 @@ class GenerationEngine:
             if s["kind"] == "paged_kv") if self._view.grouped else {}
         self._step_reports = None
         self._step_fn = paddle.jit.to_static(self._ragged_step)
+        # a device trace knows the step as jit_engine_step, and
+        # observability.program_blocks() as "engine:step"
+        self._step_fn.program_label = "engine:step"
+        self._packed_context = 0
 
         # fault-tolerance knobs: a per-step wall-clock deadline (the
         # decode watchdog) and an admission queue-depth bound (load
@@ -934,7 +939,11 @@ class GenerationEngine:
                           **decode_attrs):
         """`_checked_dispatch` under its spans: ``engine:dispatch`` on
         the profiler's clock, and the timeline's ``decode`` /
-        ``prefill:chunk`` (what the step carried) inside it."""
+        ``prefill:chunk`` (what the step carried) inside it.  The
+        boundary span says what the step carries: its decode rows, its
+        chunk's tokens and first position (0 without one), and the
+        context its rows attend to, summed (the packer's
+        ``context_lens``)."""
         self._counters["decode_rows_carried"] += len(decodes)
         if chunk is not None:
             self._counters["prompt_tokens_carried"] += chunk.length
@@ -943,7 +952,9 @@ class GenerationEngine:
             stack.enter_context(obs.span(
                 "engine:dispatch", boundary=True, step=self._step_idx,
                 decode_rows=len(decodes),
-                chunk_tokens=chunk.length if chunk is not None else 0))
+                chunk_tokens=chunk.length if chunk is not None else 0,
+                chunk_start=chunk.start if chunk is not None else 0,
+                context_tokens=self._packed_context))
             if decodes:
                 stack.enter_context(obs.span(
                     "decode", cat="decode", step=self._step_idx,
@@ -1038,6 +1049,7 @@ class GenerationEngine:
                 rows_reqs.append((r, req))
             flat += nseg * BQ
 
+        self._packed_context = int(ctx.sum())
         self._view.set_inputs(slots, tables, ctx, positions, seq_ids,
                               q_starts, q_valids, last_index,
                               sample_pos)
@@ -1253,6 +1265,7 @@ class GenerationEngine:
                 chunk_row = (r, req)
             flat += nseg * BQ
 
+        self._packed_context = int(ctx.sum())
         self._view.set_inputs(slots, tables, ctx, positions, seq_ids,
                               q_starts, q_valids, last_index,
                               sample_pos)

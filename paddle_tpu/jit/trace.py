@@ -19,6 +19,7 @@ Mechanics per call signature (cache key = pytree structure + shapes/dtypes):
 from __future__ import annotations
 
 import functools
+import re
 from typing import Any, Callable
 
 import numpy as np
@@ -130,6 +131,14 @@ def _bind_args(args, kwargs, tensor_vals):
 
 class TracedFunction:
     """The callable returned by paddle.jit.to_static."""
+
+    #: set on a step program: what ``observability.program_blocks()``
+    #: calls its programs (a map of each is kept where it compiles),
+    #: and (non-word characters as ``_``) the name of their modules in
+    #: a device trace: the serving engine's step is ``engine:step`` /
+    #: ``jit_engine_step``.  None: no map is kept, and the module is
+    #: ``jit_<the function's name>``
+    program_label = None
 
     def __init__(self, fn, input_spec=None, jit_kwargs=None):
         from .dy2static import convert_function
@@ -341,19 +350,27 @@ class TracedFunction:
             rw_vals = concrete_values(rw_state)
             arg_vals = tuple(jax.device_put(v, sh) for v, sh in
                              zip(arg_vals, arg_shardings))
-        jitted = jax.jit(pure_fn, **jit_kwargs)
         label = f"jit:{getattr(self._orig_fn, '__qualname__', self._fn)}"
+        # the module's name is what a device trace knows the program by
+        pure_fn.__name__ = re.sub(r"\W", "_", self.program_label or getattr(
+            self._orig_fn, "__name__", "pure_fn"))
+        jitted = jax.jit(pure_fn, **jit_kwargs)
         flow = obs.next_flow_id()
-        from ..device.compile_cache import (ensure_compile_cache,
+        from ..device.compile_cache import (compile_keyed_by_metadata,
+                                            ensure_compile_cache,
                                             record_compile_metrics)
         ensure_compile_cache()
         import time as _time
         t0 = _time.perf_counter()
         with obs.span("compile:" + label, cat="compile", flow_out=flow,
                       n_state=len(state)):
-            compiled = jitted.lower(arg_vals, ro_vals, rw_vals).compile()
+            lowered = jitted.lower(arg_vals, ro_vals, rw_vals)
+            compiled = compile_keyed_by_metadata(lowered) \
+                if self.program_label else lowered.compile()
         record_compile_metrics((_time.perf_counter() - t0) * 1e3,
                                kind="to_static")
+        if self.program_label:
+            obs.note_program(self.program_label, compiled)
         # memory guard pre-flight: hold the fresh executable to the HBM
         # budget before its first dispatch (raises HbmBudgetError).  The
         # async window keeps up to depth-1 extra steps' args/outputs

@@ -42,6 +42,7 @@ from .. import nn
 from ..core.dispatch import dispatch
 from ..nn import functional as F
 from ..nn import initializer as I
+from ..observability import block
 from .generation import GenerationMixin
 from .llama import apply_rotary_pos_emb
 from .minicpm_sala import rope_tables
@@ -195,7 +196,9 @@ class AfmoeMLP(nn.Layer):
         self.down_proj = _linear(cfg, width, cfg.hidden_size)
 
     def forward(self, x):
-        return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
+        with block("ffn"):
+            return self.down_proj(
+                F.silu(self.gate_proj(x)) * self.up_proj(x))
 
 
 def _routed_impl(x, router, bias, gate_up, down, carried, *, top_k,
@@ -279,11 +282,14 @@ class AfmoeLayer(nn.Layer):
         self.post_mlp_layernorm = nn.RMSNorm(cfg.hidden_size, epsilon=eps)
 
     def forward(self, x, positions, cache=None):
-        h = x + self.post_attention_layernorm(
-            self.self_attn(self.input_layernorm(x), positions, cache))
-        u = self.pre_mlp_layernorm(h)
-        m = self.mlp(u, cache) if self.routed else self.mlp(u)
-        return h + self.post_mlp_layernorm(m)
+        with block("attention"):
+            h = x + self.post_attention_layernorm(
+                self.self_attn(self.input_layernorm(x), positions, cache))
+        # the routed layer's shared expert is an "ffn" inside
+        with block("experts" if self.routed else "ffn"):
+            u = self.pre_mlp_layernorm(h)
+            m = self.mlp(u, cache) if self.routed else self.mlp(u)
+            return h + self.post_mlp_layernorm(m)
 
 
 class AfmoeModel(nn.Layer):
@@ -305,10 +311,12 @@ class AfmoeModel(nn.Layer):
         b, s = input_ids.shape
         positions = cache.position_ids if cache is not None \
             else paddle.arange(0, s, dtype="int64")
-        x = self.embed_tokens(input_ids) * self._embed_scale
+        with block("embed"):
+            x = self.embed_tokens(input_ids) * self._embed_scale
         for i, layer in enumerate(self.layers):
             x = layer(x, positions, None if cache is None else cache[i])
-        return self.norm(x)
+        with block("head"):
+            return self.norm(x)
 
 
 class AfmoeForCausalLM(nn.Layer, GenerationMixin):
@@ -341,4 +349,6 @@ class AfmoeForCausalLM(nn.Layer, GenerationMixin):
             raise NotImplementedError(
                 "AFMoE decodes through the serving engine's cache "
                 "(GenerationEngine), not a concatenated one")
-        return self.lm_head(self.model(input_ids, cache))
+        hidden = self.model(input_ids, cache)
+        with block("head"):
+            return self.lm_head(hidden)

@@ -11,6 +11,7 @@ import paddle_tpu as paddle
 from .. import nn
 from ..nn import functional as F
 from ..nn import initializer as I
+from ..observability import block
 
 
 @dataclass
@@ -63,11 +64,13 @@ class BertEmbeddings(nn.Layer):
 
     def forward(self, input_ids, token_type_ids=None):
         b, s = input_ids.shape
-        pos = paddle.arange(s, dtype="int64")
-        x = self.word_embeddings(input_ids) + self.position_embeddings(pos)
-        if token_type_ids is not None:
-            x = x + self.token_type_embeddings(token_type_ids)
-        return self.dropout(self.layer_norm(x))
+        with block("embed"):
+            pos = paddle.arange(s, dtype="int64")
+            x = self.word_embeddings(input_ids) \
+                + self.position_embeddings(pos)
+            if token_type_ids is not None:
+                x = x + self.token_type_embeddings(token_type_ids)
+            return self.dropout(self.layer_norm(x))
 
 
 class BertSelfAttention(nn.Layer):
@@ -109,10 +112,12 @@ class BertLayer(nn.Layer):
         # LN kernel; fc1's bias+gelu fold into the matmul epilogue (both
         # TPU-gated)
         p = self.hidden_drop_p
-        x = self.ln1.forward_fused(self.attention(x, attn_mask), x, p)
-        h = F.linear_act(x, self.fc1.weight, self.fc1.bias,
-                         act="gelu_tanh")
-        x = self.ln2.forward_fused(self.fc2(h), x, p)
+        with block("attention"):
+            x = self.ln1.forward_fused(self.attention(x, attn_mask), x, p)
+        with block("ffn"):
+            h = F.linear_act(x, self.fc1.weight, self.fc1.bias,
+                             act="gelu_tanh")
+            x = self.ln2.forward_fused(self.fc2(h), x, p)
         return x
 
 
@@ -160,18 +165,19 @@ class TiedMLMHead(nn.Layer):
                                epsilon=cfg.layer_norm_eps)
 
     def forward(self, hidden, word_embedding_weight, labels=None):
-        hidden = self.ln(F.linear_act(
-            hidden, self.transform.weight, self.transform.bias,
-            act="gelu_tanh"))
-        logits = paddle.matmul(hidden, word_embedding_weight,
-                               transpose_y=True)
-        if labels is None:
-            return logits
-        v = logits.shape[-1]
-        loss = F.cross_entropy(paddle.reshape(logits, [-1, v]),
-                               paddle.reshape(labels, [-1]),
-                               ignore_index=-100, reduction="mean")
-        return loss, logits
+        with block("head"):
+            hidden = self.ln(F.linear_act(
+                hidden, self.transform.weight, self.transform.bias,
+                act="gelu_tanh"))
+            logits = paddle.matmul(hidden, word_embedding_weight,
+                                   transpose_y=True)
+            if labels is None:
+                return logits
+            v = logits.shape[-1]
+            loss = F.cross_entropy(paddle.reshape(logits, [-1, v]),
+                                   paddle.reshape(labels, [-1]),
+                                   ignore_index=-100, reduction="mean")
+            return loss, logits
 
 
 class BertForMaskedLM(nn.Layer):

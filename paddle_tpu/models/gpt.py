@@ -13,6 +13,7 @@ from dataclasses import dataclass
 import paddle_tpu as paddle
 from .. import nn
 from ..nn import functional as F
+from ..observability import block
 from .generation import GenerationMixin
 
 
@@ -124,14 +125,16 @@ class GPTBlock(nn.Layer):
 
     def forward(self, x, cache=None, use_cache=False):
         lora = getattr(cache, "lora", None) if cache is not None else None
-        if use_cache:
-            a, new_cache = self.attn(self.ln_1(x), cache, True)
+        new_cache = None
+        with block("attention"):
+            if use_cache:
+                a, new_cache = self.attn(self.ln_1(x), cache, True)
+            else:
+                a = self.attn(self.ln_1(x), cache)
             x = x + self.dropout(a)
+        with block("ffn"):
             x = x + self.dropout(self.mlp(self.ln_2(x), lora=lora))
-            return x, new_cache
-        x = x + self.dropout(self.attn(self.ln_1(x), cache))
-        x = x + self.dropout(self.mlp(self.ln_2(x), lora=lora))
-        return x
+        return (x, new_cache) if use_cache else x
 
 
 class GPTModel(nn.Layer):
@@ -156,7 +159,8 @@ class GPTModel(nn.Layer):
         else:
             past = 0 if cache is None else cache[0][0].shape[1]
             pos = paddle.arange(past, past + s, dtype="int64")
-        x = self.wte(input_ids) + self.wpe(pos)
+        with block("embed"):
+            x = self.wte(input_ids) + self.wpe(pos)
         drop_active = (self.training
                        and self.config.hidden_dropout_prob > 0)
         # the memory guard's ladder can flip recompute on globally
@@ -168,7 +172,8 @@ class GPTModel(nn.Layer):
             from ..nn.layer import scanned
             x = scanned.scan_layer_stack(self.h, x,
                                          remat=use_remat)
-            return self.ln_f(x)
+            with block("head"):
+                return self.ln_f(x)
         if (self.config.use_scan_layers and drop_active
                 and not getattr(self, "_scan_fallback_warned", False)):
             self._scan_fallback_warned = True
@@ -190,7 +195,8 @@ class GPTModel(nn.Layer):
                 # a supplied cache participates even when the caller
                 # doesn't want an updated one back
                 x = blk(x, layer_cache)
-        x = self.ln_f(x)
+        with block("head"):
+            x = self.ln_f(x)
         if use_cache:
             return x, new_caches
         return x
@@ -226,11 +232,12 @@ class GPTForCausalLM(nn.Layer, GenerationMixin):
         else:
             hidden = self.gpt(input_ids, cache)
             new_cache = None
-        if self.lm_head is not None:
-            logits = self.lm_head(hidden)
-        else:
-            logits = paddle.matmul(hidden, self.gpt.wte.weight,
-                                   transpose_y=True)
+        with block("head"):
+            if self.lm_head is not None:
+                logits = self.lm_head(hidden)
+            else:
+                logits = paddle.matmul(hidden, self.gpt.wte.weight,
+                                       transpose_y=True)
         if use_cache:
             return logits, new_cache
         return logits
@@ -241,6 +248,7 @@ class GPTPretrainingCriterion(nn.Layer):
 
     def forward(self, logits, labels):
         b, s, v = logits.shape
-        logits = paddle.reshape(logits[:, :-1, :], [-1, v])
-        labels = paddle.reshape(labels[:, 1:], [-1])
-        return F.cross_entropy(logits, labels, reduction="mean")
+        with block("head"):
+            logits = paddle.reshape(logits[:, :-1, :], [-1, v])
+            labels = paddle.reshape(labels[:, 1:], [-1])
+            return F.cross_entropy(logits, labels, reduction="mean")

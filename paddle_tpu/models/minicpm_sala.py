@@ -35,6 +35,7 @@ import paddle_tpu as paddle
 from .. import nn
 from ..core.dispatch import dispatch
 from ..nn import functional as F
+from ..observability import block
 from ..ops import pallas_lightning as pll
 from ..ops import pallas_sparse as pls
 from .generation import GenerationMixin
@@ -222,9 +223,12 @@ class MiniCPMSALALayer(nn.Layer):
         self.mlp = LlamaMLP(cfg)
 
     def forward(self, x, positions, cache=None):
-        x = x + self.mixer(self.input_layernorm(x), positions,
-                           cache) * self.scale
-        return x + self.mlp(self.post_attention_layernorm(x)) * self.scale
+        with block("recurrent" if self.kind == LIGHTNING else "attention"):
+            x = x + self.mixer(self.input_layernorm(x), positions,
+                               cache) * self.scale
+        with block("ffn"):
+            return x + self.mlp(
+                self.post_attention_layernorm(x)) * self.scale
 
 
 class MiniCPMSALAModel(nn.Layer):
@@ -247,10 +251,12 @@ class MiniCPMSALAModel(nn.Layer):
             positions = cache.position_ids
         else:
             positions = paddle.arange(0, s, dtype="int64")
-        x = self.embed_tokens(input_ids) * self.config.scale_emb
+        with block("embed"):
+            x = self.embed_tokens(input_ids) * self.config.scale_emb
         for i, layer in enumerate(self.layers):
             x = layer(x, positions, None if cache is None else cache[i])
-        return self.norm(x)
+        with block("head"):
+            return self.norm(x)
 
 
 class MiniCPMSALAForCausalLM(nn.Layer, GenerationMixin):
@@ -289,4 +295,5 @@ class MiniCPMSALAForCausalLM(nn.Layer, GenerationMixin):
                 "MiniCPM-SALA decodes through the serving engine's "
                 "cache (GenerationEngine), not a concatenated one")
         hidden = self.model(input_ids, cache)
-        return self.lm_head(hidden) * self._logit_scale
+        with block("head"):
+            return self.lm_head(hidden) * self._logit_scale

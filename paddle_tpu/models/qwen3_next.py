@@ -50,6 +50,7 @@ from .. import nn
 from ..core.dispatch import dispatch
 from ..nn import functional as F
 from ..nn import initializer as I
+from ..observability import block
 from ..ops import pallas_gated_delta as pgd
 from .afmoe import AfmoeMLP, _dense_attention_impl, _linear, _normal
 from .generation import GenerationMixin
@@ -369,8 +370,9 @@ class Qwen3NextMoE(nn.Layer):
             differentiable=False)
         if cache is not None:
             cache.report("moe", counters)
-        shared = F.sigmoid(self.shared_expert_gate(x)) \
-            * self.shared_expert(x)
+        with block("ffn"):
+            shared = F.sigmoid(self.shared_expert_gate(x)) \
+                * self.shared_expert(x)
         return shared + paddle.reshape(routed, shape)
 
 
@@ -389,8 +391,11 @@ class Qwen3NextLayer(nn.Layer):
 
     def forward(self, x, positions, cache=None):
         mixer = self.self_attn if self.attention else self.linear_attn
-        h = x + mixer(self.input_layernorm(x), positions, cache)
-        return h + self.mlp(self.post_attention_layernorm(h), cache)
+        with block("attention" if self.attention else "recurrent"):
+            h = x + mixer(self.input_layernorm(x), positions, cache)
+        # the shared expert is an "ffn" inside
+        with block("experts"):
+            return h + self.mlp(self.post_attention_layernorm(h), cache)
 
 
 class Qwen3NextModel(nn.Layer):
@@ -409,10 +414,12 @@ class Qwen3NextModel(nn.Layer):
         b, s = input_ids.shape
         positions = cache.position_ids if cache is not None \
             else paddle.arange(0, s, dtype="int64")
-        x = self.embed_tokens(input_ids)
+        with block("embed"):
+            x = self.embed_tokens(input_ids)
         for i, layer in enumerate(self.layers):
             x = layer(x, positions, None if cache is None else cache[i])
-        return self.norm(x)
+        with block("head"):
+            return self.norm(x)
 
 
 class Qwen3NextForCausalLM(nn.Layer, GenerationMixin):
@@ -455,4 +462,6 @@ class Qwen3NextForCausalLM(nn.Layer, GenerationMixin):
             raise NotImplementedError(
                 "Qwen3-Next decodes through the serving engine's cache "
                 "(GenerationEngine), not a concatenated one")
-        return self.lm_head(self.model(input_ids, cache))
+        hidden = self.model(input_ids, cache)
+        with block("head"):
+            return self.lm_head(hidden)

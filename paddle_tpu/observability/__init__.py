@@ -20,6 +20,12 @@ global read), ``PADDLE_TPU_OBS_DIR`` (export directory),
 ``PADDLE_TPU_OBS_CAPACITY`` (event-buffer bound, default 65536).
 ``paddle.profiler`` is a thin shim over this core.
 
+Beside them, ungated (``blocks.py``): **block scopes** (``block(name)``
+over the closed vocabulary ``BLOCKS``) that put a model's blocks into a
+compiled step's ``op_name`` metadata, and ``program_blocks()``, the map
+from each noted step program's instructions to their block, which a
+device trace is joined with by instruction name.
+
 Imports nothing from the rest of paddle_tpu, so every layer can
 instrument itself without import cycles.
 """
@@ -30,6 +36,10 @@ from .timeline import (  # noqa: F401
 )
 from .registry import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, get_registry,
+)
+from .blocks import (  # noqa: F401
+    BLOCKS, block, name_scope, note_program, parse_hlo_blocks,
+    program_blocks, scope_path, write_blocks,
 )
 from .export import (  # noqa: F401
     CATEGORY_LANES, chrome_trace, collective_overlap_stats,
@@ -46,4 +56,6 @@ __all__ = [
     "CATEGORY_LANES", "chrome_trace", "collective_overlap_stats",
     "export_chrome_trace", "export_jsonl", "lint_summary_table",
     "load_jsonl", "summary", "phase_breakdown", "pipeline_stats",
+    "BLOCKS", "block", "name_scope", "scope_path", "note_program",
+    "program_blocks", "parse_hlo_blocks", "write_blocks",
 ]

@@ -83,12 +83,15 @@ def _kernel_span(name: str, direction: str):
     """Names one pallas_call for the device trace.
 
     Every pallas_call is built inside ``with _kernel_span(name,
-    direction) as kernel_name`` and passes ``name=kernel_name``: the
-    ``jax.named_scope("<name>.<direction>")`` puts the kernel into the
-    op's ``op_name`` path, and ``<name>_<direction>`` names the Mosaic
-    kernel itself.  A profiler trace then tells ``ragged_attention`` from
-    ``layer_norm`` whatever the dispatcher's jitted functions are called
-    (``benchmarks/span_reduce.py`` holds the rule that reads the names).
+    direction) as kernel_name`` and passes ``name=kernel_name``:
+    ``<name>_<direction>`` names the Mosaic kernel itself, which is the
+    name of its HLO instruction and so of its event in a profiler trace
+    (``benchmarks/span_reduce.py`` holds the rule that reads it), and
+    the ``jax.named_scope("<name>.<direction>")`` puts the kernel into
+    the op's ``op_name`` path, which no trace carries: it is in the
+    compiled step's text (``compiled.as_text()``), inside the model's
+    block the call sits in (``observability/blocks.py``, whose map
+    keeps the block and not yet this scope: nothing reads it).
     Nothing is timed here: in a compiled step this runs at trace time.
     """
     with jax.named_scope(f"{name}.{direction}"):

@@ -22,6 +22,7 @@ stop after the ``on_trace_ready`` handler has consumed them.
 """
 from __future__ import annotations
 
+import glob
 import os
 import time
 from enum import Enum
@@ -156,13 +157,28 @@ class Profiler:
         self._last_step_t = time.perf_counter()
         self._maybe_toggle()
 
+    def _stop_trace(self):
+        """End the XPlane capture and write ``blocks.json`` beside it:
+        ``observability.program_blocks()``, the map from the traced
+        programs' instruction names to the model's blocks (the trace
+        itself carries the names alone)."""
+        try:
+            jax.profiler.stop_trace()
+        except Exception:
+            pass
+        self._active = False
+        runs = glob.glob(os.path.join(self._log_dir, "plugins", "profile",
+                                      "*"))
+        if runs:
+            try:
+                _obs.write_blocks(os.path.join(
+                    max(runs, key=os.path.getmtime), "blocks.json"))
+            except OSError:
+                pass
+
     def stop(self):
         if self._active:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
-            self._active = False
+            self._stop_trace()
         if self._on_trace_ready:
             self._on_trace_ready(self)
         # the handler has consumed the session's events; release the
@@ -202,11 +218,7 @@ class Profiler:
             except Exception:
                 pass
         elif not should_record and self._active:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
-            self._active = False
+            self._stop_trace()
 
     def summary(self, sorted_by=None, op_detail=True, thread_sep=False,
                 time_unit="ms", views=None):

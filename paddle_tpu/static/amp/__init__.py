@@ -86,7 +86,9 @@ def _rewrite_program(program, white, black, low):
         def eff_dtype(t):
             return eff.get(id(t), jnp.dtype(t._value.dtype))
 
-        def casted(src, dtype):
+        def casted(src, dtype, scope):
+            # the cast is recorded in the name scope of the op that
+            # first asks for it
             key = (id(src), str(dtype))
             cv = cast_cache.get(key)
             if cv is None:
@@ -98,7 +100,7 @@ def _rewrite_program(program, white, black, low):
                     stop_gradient=getattr(src, "stop_gradient", True))
                 new_ops.append(OpDesc(
                     "cast", lambda v, _d=dtype: v.astype(_d),
-                    [src], {}, [cv]))
+                    [src], {}, [cv], scope=scope))
                 cast_cache[key] = cv
                 eff[id(cv)] = jnp.dtype(dtype)
             return cv
@@ -111,7 +113,7 @@ def _rewrite_program(program, white, black, low):
                 target = f32
             if target is not None:
                 op.inputs = [
-                    casted(i, target)
+                    casted(i, target, op.scope)
                     if (isinstance(i, Tensor)
                         and eff_dtype(i) in (f32, lowd)
                         and eff_dtype(i) != target)

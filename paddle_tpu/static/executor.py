@@ -13,7 +13,9 @@ and cached — XLA performs scheduling, fusion, and memory planning.  Repeat
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
+import itertools
 import time
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence
@@ -58,20 +60,27 @@ def run_program_ops(ops, env, capture_value, op_override=None):
     ``op_override(op, in_vals)`` — optional per-op interception (the
     collective-overlap router swaps eligible TP matmuls for their
     decomposed shard_map form); returning ``NotImplemented`` falls
-    through to the op's recorded impl."""
-    for op in ops:
-        in_vals = [env[i.name] if isinstance(i, Variable)
-                   else capture_value(i) for i in op.inputs]
-        out = NotImplemented
-        if op_override is not None:
-            out = op_override(op, in_vals)
-        if out is NotImplemented:
-            out = op.impl(*in_vals)
-        if isinstance(out, (tuple, list)):
-            for var, v in zip(op.outputs, out):
-                env[var.name] = v
-        else:
-            env[op.outputs[0].name] = out
+    through to the op's recorded impl.
+
+    Each run of consecutive ops recorded under one name scope
+    (``OpDesc.scope``: ``static.name_scope``, ``observability.block``)
+    is evaluated under ``jax.named_scope`` of that path, so a compiled
+    step's instructions carry it in their ``op_name``."""
+    for scope, run in itertools.groupby(ops, key=lambda op: op.scope):
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
+            for op in run:
+                in_vals = [env[i.name] if isinstance(i, Variable)
+                           else capture_value(i) for i in op.inputs]
+                out = NotImplemented
+                if op_override is not None:
+                    out = op_override(op, in_vals)
+                if out is NotImplemented:
+                    out = op.impl(*in_vals)
+                if isinstance(out, (tuple, list)):
+                    for var, v in zip(op.outputs, out):
+                        env[var.name] = v
+                else:
+                    env[op.outputs[0].name] = out
     return env
 
 
@@ -490,9 +499,10 @@ class Executor:
                 # lr + step ride as arguments so LRScheduler.step()
                 # and Adam bias correction (1 - beta**step) evolve
                 # across calls of the cached executable
-                new_params, new_opt = optimizer._static_update(
-                    param_vals, grads, opt_vals, trainable, lr=lr,
-                    step=step)
+                with obs.block("optimizer"):
+                    new_params, new_opt = optimizer._static_update(
+                        param_vals, grads, opt_vals, trainable, lr=lr,
+                        step=step)
                 return (tuple(env[v.name] for v in fetch_vars),
                         tuple(new_params), tuple(new_opt),
                         tuple(env[v.name] for v in rng_final_vars))
@@ -576,6 +586,9 @@ class Executor:
                            int(np.prod(t._value.shape))
                            * t._value.dtype.itemsize)
                           for t in trainable + frozen]
+        # the module's name in a device trace (``jit_exe_step``), where
+        # ``observability.program_blocks()`` is joined with it
+        pure.__name__ = "exe_step"
         jitted = jax.jit(pure, donate_argnums=(1, 2) if donate else (),
                          **jit_shardings)
 
@@ -644,18 +657,20 @@ class Executor:
         def compile_step():
             # deferred: a run_steps-only caller (bench fused loop) must
             # not pay the single-step XLA compile it never invokes
-            from ..device.compile_cache import (ensure_compile_cache,
-                                                record_compile_metrics)
+            from ..device.compile_cache import (
+                compile_keyed_by_metadata, ensure_compile_cache,
+                record_compile_metrics)
             ensure_compile_cache()
             t0 = time.perf_counter()
             with obs.span("compile:" + entry["program_label"],
                           cat="compile", flow_out=entry["flow"],
                           ops=len(block.ops)):
-                compiled = jitted.lower(feed_avals, param_avals,
-                                        opt_avals, rng_avals, lr_aval,
-                                        step_aval).compile()
+                compiled = compile_keyed_by_metadata(jitted.lower(
+                    feed_avals, param_avals, opt_avals, rng_avals,
+                    lr_aval, step_aval))
             record_compile_metrics((time.perf_counter() - t0) * 1e3,
                                    kind="executor")
+            obs.note_program("exe:step", compiled)
             # pre-flight: hold the executable to the HBM budget BEFORE
             # the first dispatch (raises HbmBudgetError when over).
             # per-step feed bytes × (depth-1) extra in-flight steps ride

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from ..core.dispatch import get_dispatch_state
 from ..core.dtypes import convert_dtype, to_jax_dtype
 from ..core.tensor import Tensor
+from .. import observability as _obs
 
 __all__ = ["Program", "Block", "Variable", "OpDesc", "program_guard",
            "default_main_program", "default_startup_program",
@@ -61,14 +62,19 @@ class Variable(Tensor):
 
 
 class OpDesc:
-    __slots__ = ("type", "impl", "inputs", "attrs", "outputs")
+    __slots__ = ("type", "impl", "inputs", "attrs", "outputs", "scope")
 
-    def __init__(self, type, impl, inputs, attrs, outputs):
+    def __init__(self, type, impl, inputs, attrs, outputs, scope=None):
         self.type = type
         self.impl = impl          # pure-JAX callable
         self.inputs = inputs      # list of Variable | Tensor (captured const)
         self.attrs = attrs
         self.outputs = outputs    # list of Variable
+        # the name scopes open where the op is recorded
+        # (``static.name_scope``, ``observability.block``), "/"-joined,
+        # unless a copy or a rewrite hands over another op's: the
+        # Executor's walker re-enters them as ``jax.named_scope``
+        self.scope = _obs.scope_path() if scope is None else scope
 
     def __repr__(self):
         ins = ", ".join(getattr(i, "name", "<const>") for i in self.inputs)
@@ -164,7 +170,8 @@ class Program:
                         # consumes no randomness
                         return _infer(*vs, **_at), key
                 nb.ops.append(OpDesc(op.type, impl, list(op.inputs),
-                                     dict(op.attrs), list(op.outputs)))
+                                     dict(op.attrs), list(op.outputs),
+                                     scope=op.scope))
             p.blocks.append(nb)
         p.current_block_idx = min(self.current_block_idx,
                                   len(p.blocks) - 1)
@@ -318,9 +325,11 @@ def program_guard(main_program, startup_program=None):
         _startup_program = prev_startup
 
 
-@contextlib.contextmanager
 def name_scope(prefix):
-    yield
+    """paddle.static.name_scope: the ops recorded inside carry
+    ``prefix`` in ``OpDesc.scope``, and the compiled step in their
+    ``op_name`` metadata (``observability/blocks.py``)."""
+    return _obs.name_scope(prefix)
 
 
 def data(name, shape, dtype="float32", lod_level=0):
