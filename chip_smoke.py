@@ -92,6 +92,30 @@ def mosaic_kernels(hlo_text):
     return dict(found)
 
 
+def pool_sized_moves(hlo_text, elements):
+    """The instructions of a compiled program that relay a K/V pool:
+    opcode ``copy``, ``copy-start`` or ``transpose``
+    (`observability.parse_hlo_blocks`' opcodes, the ones
+    ``layout_copy_ms.serve`` sums) whose result holds an array of at
+    least ``elements`` elements, as ``"block: line"``.  Since PR 38 a
+    serving step has none: the scatter writes the pool as it lies and
+    the ragged kernel reads it so."""
+    table = obs.parse_hlo_blocks(hlo_text)["instructions"]
+    moves = []
+    for line in hlo_text.splitlines():
+        name = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*)", line)
+        entry = name and table.get(name.group(1))
+        if not entry or entry["opcode"] not in ("copy", "copy-start",
+                                                "transpose"):
+            continue
+        result = name.group(2).split(f" {entry['opcode']}(")[0]
+        if any(math.prod(int(n) for n in dims.split(",")) >= elements
+               for dims in re.findall(r"\[([\d,]+)\]", result)):
+            moves.append(f"{entry['block'] or 'no block'}: "
+                         f"{line.strip()[:160]}")
+    return moves
+
+
 def _rel_l2(got, ref):
     got = np.asarray(got, np.float64)
     ref = np.asarray(ref, np.float64)
@@ -903,15 +927,20 @@ def serve_qwen3_next(config, prompt_lens, new_tokens=8, engine=None):
         "moe_assignments_routed", "kv_blocks_read_full")}}}
 
 
-def _device_ms(fn, *args, calls=5):
+def _device_ms(fn, *args, calls=5, donated=()):
     """Median device milliseconds of one call of the compiled ``fn``
     (already run once), from the profiler's ``XLA Modules`` line; None
-    where the trace holds no TPU plane (the CPU rehearsal)."""
+    where the trace holds no TPU plane (the CPU rehearsal).  Arguments
+    ``donated`` are replaced by the results after the first, call after
+    call."""
     from jax.profiler import ProfileData
+    args = list(args)
     with tempfile.TemporaryDirectory() as trace_dir:
         with jax.profiler.trace(trace_dir):
             for _ in range(calls):
-                jax.block_until_ready(fn(*args))
+                out = jax.block_until_ready(fn(*args))
+                for n, i in enumerate(donated):
+                    args[i] = out[1 + n]
         (path,) = glob.glob(os.path.join(
             trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
         ms = [e.duration_ns / 1e6
@@ -991,15 +1020,21 @@ RAGGED_WALK_CALLS = (
 def _ragged_walk_call(form, group, kv_heads, d, bs, width, window, chunk,
                       fill, rows=32, chunk_bq=128):
     """One attention call of a serving step as a function of (q, k_pool,
-    v_pool, use_pallas), its arguments, and the K/V blocks its rows have
+    v_pool, use_pallas), its arguments, the K/V blocks its rows have
     to read (all KV heads), for tables filled to ``fill`` of their
-    width.  Every row owns its blocks; block 0 is the null block."""
+    width, and what the step's scatter writes before it (new K, new V
+    and their flat slots: the chunk's tokens into row 0's last blocks,
+    a token a decode row at its context's end).  Every row owns its
+    blocks; block 0 is the null block.  The pools have the layout of the
+    tree this file runs in: a parent of PR 38 keeps ``[nb, H, bs, D]``."""
     import jax.numpy as jnp
     from paddle_tpu.inference.serving import attention as att
     from paddle_tpu.ops import pallas_ragged as pr
     ctx = max(int(fill * width) * bs - bs // 2, 1)      # ends mid-block
     tables = 1 + np.arange(rows * width, dtype=np.int32).reshape(rows, width)
-    pool = (rows * width + 1, kv_heads, bs, d)
+    pool = (rows * width + 1, bs, kv_heads * d)
+    if hasattr(pr, "_lane_parts"):                      # before PR 38
+        pool = (rows * width + 1, kv_heads, bs, d)
     key = jax.random.PRNGKey(SEED)
     kk, kv, kq = jax.random.split(key, 3)
     k_pool = jax.random.normal(kk, pool, jnp.bfloat16)
@@ -1055,21 +1090,36 @@ def _ragged_walk_call(form, group, kv_heads, d, bs, width, window, chunk,
         need = kv_heads * ((rows - 1) * blocks(0, ctx - 1) + sum(
             blocks(0, min(first + bq, ctx) - 1)
             for first in range(ctx - take, ctx, bq)))
-    return fn, (q, k_pool, v_pool), need
+    # the step's scatter: every token of the budget, as the engine's
+    # one scatter a layer writes them
+    slot = lambda r, t: int(tables[r, t // bs]) * bs + t % bs  # noqa: E731
+    new = min(chunk or 1024, ctx)
+    slots = np.asarray([slot(0, t) for t in range(ctx - new, ctx)]
+                       + [slot(r, ctx - 1) for r in range(1, rows)],
+                       np.int32)
+    fresh = jax.random.normal(key, (2, 1, len(slots), kv_heads, d),
+                              jnp.bfloat16)
+    return (fn, (q, k_pool, v_pool), need,
+            (fresh[0], fresh[1], jnp.asarray(slots)))
 
 
 def ragged_walk(calls, fills=(0.25, 1.0), **sizes):
     """The ragged attention kernel alone, one call of a serving step at
     a time (``calls``: `RAGGED_WALK_CALLS`' form), over block tables
     filled to each of ``fills``: the kernel against the XLA fallback at
-    the first fill, then its device time, and the share of that time
+    the first fill, then its device time, the share of that time
     that the bytes of the K/V blocks its rows need would take at the
-    HBM's rate."""
+    HBM's rate, and the device time of the step's scatter followed by
+    the kernel on donated pools (``scatter_kernel_us``: what a layer of
+    a step pays, copies between the two layouts included where a tree
+    has two)."""
+    from paddle_tpu.inference.serving import attention as att
     checked, kernels = {}, Counter()
     for name, *call in calls:
         _, _, kv_heads, d, bs, *_ = call
         for n, fill in enumerate(fills):
-            fn, args, need = _ragged_walk_call(*call, fill, **sizes)
+            fn, args, need, written = _ragged_walk_call(*call, fill,
+                                                        **sizes)
             run = jax.jit(lambda q, kp, vp: fn(q, kp, vp, True))
             compiled = run.lower(*args).compile()
             kernels.update(mosaic_kernels(compiled.as_text()))
@@ -1088,8 +1138,20 @@ def ragged_walk(calls, fills=(0.25, 1.0), **sizes):
                 entry.update(us=round(ms * 1e3, 1),
                              floor_us=round(floor_us, 1),
                              floor_share=round(floor_us / (ms * 1e3), 4))
+            del compiled, got
+
+            def layer(q, kp, vp, kn, vn, slots):
+                kp, vp = att._kv_scatter_impl(kp, vp, kn, vn, slots)
+                return fn(q, kp, vp, True), kp, vp
+            both = jax.jit(layer, donate_argnums=(1, 2)).lower(
+                *args, *written).compile()
+            out = jax.block_until_ready(both(*args, *written))
+            ms = _device_ms(both, args[0], *out[1:], *written,
+                            donated=(1, 2))
+            if ms is not None:
+                entry["scatter_kernel_us"] = round(ms * 1e3, 1)
             checked[f"{name}@{fill}"] = entry
-            del fn, args, run, compiled, got
+            del fn, args, run, both, out, written
     return {"kernels": dict(kernels), "checked": checked}
 
 

@@ -6,13 +6,17 @@ work identically in eager mode, under ``jit.to_static`` replay, and in
 the engine's compiled step function:
 
   ``kv_cache_scatter``         write this step's freshly projected K/V
-  ``kv_cache_scatter_quant``   into the flat block pool at
-                               ``slot_mapping`` (functional
-                               ``.at[].set`` — the engine's to_static
-                               step donates the pool, so the compiled
-                               update is in-place at 1x memory); the
-                               int8 form quantizes per token and writes
-                               the per-slot scale tables beside it
+  ``kv_cache_scatter_quant``   into the block pool ``[num_blocks,
+                               block_size, H * D]`` at ``slot_mapping``:
+                               a token is one row of the pool, so the
+                               functional ``.at[slots].set`` is a row
+                               scatter over the pool as it lies — the
+                               engine's to_static step donates the pool,
+                               and the compiled update is in place at 1x
+                               memory with no copy of a pool before or
+                               after it; the int8 form quantizes per
+                               token and writes the per-slot scale
+                               tables beside it
   ``ragged_attention``         every scheduled token of a step — one
                                prefill chunk and the decode rows — in
                                one flat buffer of block-aligned
@@ -24,7 +28,8 @@ the engine's compiled step function:
                                declines) ``_ragged_ref`` below executes
                                the IDENTICAL semantics, so tier-1 CPU
                                tests exercise the same math the TPU
-                               serves.
+                               serves.  Both read the pool in the
+                               layout the scatter wrote.
 
 ``_ragged_ref`` replicates ``_sdpa_ref``'s numerics op-for-op (f32 score
 einsum, -1e30 mask, f32 softmax, ``any_visible`` zeroing, f32 output
@@ -71,10 +76,10 @@ _NEG_INF = -1e30
 def kv_blocks_gather(cache, blocks):
     """Dispatch device gathers of whole pool blocks across all layers
     of a PagedKVCache: ``(k, v, k_scales, v_scales)`` lists (per layer)
-    of ``[nb, H, bs, D]`` / ``[nb, bs, lanes]`` device arrays, in
-    ``blocks`` order.  The gathers are async — the caller decides when
-    (and whether) to sync them to host, so spills/exports overlap with
-    compute.  Scale tables ride along for int8 pools (None otherwise):
+    of ``[nb, bs, H * D]`` / ``[nb, bs, lanes]`` device arrays (a block
+    as it lies in the pool), in ``blocks`` order.  The gathers are async
+    — the caller decides when (and whether) to sync them to host, so
+    spills/exports overlap with compute.  Scale tables ride along for int8 pools (None otherwise):
     block bytes without their dequant scales are garbage."""
     import numpy as np
     idx = jnp.asarray(np.asarray(blocks, np.int32))
@@ -88,7 +93,7 @@ def kv_blocks_gather(cache, blocks):
 def kv_blocks_scatter(cache, blocks, k_parts, v_parts, ks_parts=None,
                       vs_parts=None):
     """Device-put host block bytes into pool blocks (promotion /
-    import): per-layer ``[nb, H, bs, D]`` host arrays land in
+    import): per-layer ``[nb, bs, H * D]`` host arrays land in
     ``blocks`` via one ``.at[idx].set`` per layer per side, through
     ``_inplace_update`` so compiled step functions see the new
     buffers.  Returns the updated pool values for pipeline-window
@@ -115,20 +120,23 @@ def kv_blocks_scatter(cache, blocks, k_parts, v_parts, ks_parts=None,
 # scatter: new K/V -> pool slots
 # ---------------------------------------------------------------------
 def _kv_scatter_impl(k_pool, v_pool, k_new, v_new, slots):
-    """k_pool/v_pool: [nb, H, bs, D]; k_new/v_new: [B, S, H, D];
+    """k_pool/v_pool: [nb, bs, H * D]; k_new/v_new: [B, S, H, D];
     slots: [B*S] int32 flat pool slots (pad tokens -> slot 0, the pad
     block — duplicate pad writes race benignly, block 0 is never read
-    unmasked)."""
-    nb, H, bs, D = k_pool.shape
+    unmasked).  A token's heads are one row of the pool, so the write is
+    a row scatter over the pool as it lies: in place on a donated pool,
+    with no copy into a layout of the scatter's own."""
     with block("kv_write"):
-        blk = slots // bs
-        off = slots % bs
-        flat_k = k_new.reshape(-1, H, D).astype(k_pool.dtype)
-        flat_v = v_new.reshape(-1, H, D).astype(v_pool.dtype)
-        # advanced indices (blk, off) separated by the ":" slice put the
-        # gathered dim first: target shape [T, H, D] == flat layout
-        return (k_pool.at[blk, :, off, :].set(flat_k),
-                v_pool.at[blk, :, off, :].set(flat_v))
+        return (_scatter_rows(k_pool, k_new, slots),
+                _scatter_rows(v_pool, v_new, slots))
+
+
+def _scatter_rows(pool, new, slots):
+    """Rows ``new`` [..., H, D] (or [T, lanes]) into flat slots of
+    ``pool`` [nb, bs, lanes]."""
+    nb, bs, lanes = pool.shape
+    return pool.reshape(nb * bs, lanes).at[slots].set(
+        new.reshape(-1, lanes).astype(pool.dtype)).reshape(pool.shape)
 
 
 def kv_cache_scatter(k_pool, v_pool, k_new, v_new, slot_mapping):
@@ -158,17 +166,15 @@ def _kv_scatter_quant_impl(k_pool, v_pool, k_scales, v_scales,
     independently and write its dequant scale into the per-slot tables
     ``[nb, bs, lanes]`` next to the int8 block data.  A block filling
     up over many decode steps never re-scales already-written slots."""
-    nb, H, bs, D = k_pool.shape
+    H, D = k_new.shape[-2:]
     lanes = k_scales.shape[-1]
     with block("kv_write"):
-        blk = slots // bs
-        off = slots % bs
         qk, sk = _quantize_tokens(k_new.reshape(-1, H, D), lanes)
         qv, sv = _quantize_tokens(v_new.reshape(-1, H, D), lanes)
-        return (k_pool.at[blk, :, off, :].set(qk),
-                v_pool.at[blk, :, off, :].set(qv),
-                k_scales.at[blk, off, :].set(sk),
-                v_scales.at[blk, off, :].set(sv))
+        return (_scatter_rows(k_pool, qk, slots),
+                _scatter_rows(v_pool, qv, slots),
+                _scatter_rows(k_scales, sk, slots),
+                _scatter_rows(v_scales, sv, slots))
 
 
 def kv_cache_scatter_quant(k_pool, v_pool, k_scales, v_scales,
@@ -186,9 +192,10 @@ def kv_cache_scatter_quant(k_pool, v_pool, k_scales, v_scales,
 def _ragged_ref(q, k_pool, v_pool, block_tables, context_lens, seq_ids,
                 q_starts, q_valids, block_q, scale,
                 k_scales=None, v_scales=None, window=None,
-                block_tokens=None):
+                block_tokens=None, head_ids=None):
     """Pure-XLA segment-gather fallback for `ragged_paged_attention`
-    (``window`` and ``block_tokens`` as there).
+    (``window``, ``block_tokens`` and ``head_ids`` as there), over the
+    same pool ``[nb, bs, H * D]``.
 
     q: [T, H, D] flat block-aligned ragged queries (see
     ops/pallas_ragged.py for the seq_ids/q_starts/q_valids layout;
@@ -203,7 +210,7 @@ def _ragged_ref(q, k_pool, v_pool, block_tables, context_lens, seq_ids,
     so the two paths agree bitwise.
     """
     T, H, D = q.shape
-    nb, _, bs, _ = k_pool.shape
+    nb, bs, lanes = k_pool.shape
     S, W = block_tables.shape
     nqb = T // block_q
     # null-segment row: zero table (pad block) + zero context
@@ -213,15 +220,23 @@ def _ragged_ref(q, k_pool, v_pool, block_tables, context_lens, seq_ids,
                           jnp.zeros((1,), jnp.int32)], axis=0)
     sid = seq_ids.astype(jnp.int32)
     bt_q = bt[sid]                                 # [nqb, W]
-    k = k_pool[bt_q]                               # [nqb, W, H, bs, D]
-    v = v_pool[bt_q]
-    if k_scales is not None:
-        # per-slot dequant: [nqb, W, bs, 1] broadcast over H (axis 2)
-        # and D; mirrors the kernel's `k * ks_ref[0, :, :1]`
-        k = k.astype(jnp.float32) * k_scales[bt_q][:, :, None, :, :1]
-        v = v.astype(jnp.float32) * v_scales[bt_q][:, :, None, :, :1]
-    k = jnp.moveaxis(k, 2, 1).reshape(nqb, H, W * bs, D)
-    v = jnp.moveaxis(v, 2, 1).reshape(nqb, H, W * bs, D)
+
+    def gather(pool, scales):
+        """The q-blocks' keys, [nqb, H, W * bs, D]."""
+        if head_ids is None:
+            x = pool[bt_q].reshape(nqb, W * bs, H, D)
+        else:                      # a q-block's own head of the pool
+            x = pool.reshape(nb, bs, lanes // D, D)[
+                bt_q, :, head_ids.astype(jnp.int32)[:, None]]
+            x = x.reshape(nqb, W * bs, 1, D)
+        if scales is not None:
+            # per-slot dequant, broadcast over heads and lanes; mirrors
+            # the kernel's dequant of a window before the dots
+            x = x.astype(jnp.float32) * scales[bt_q][..., :1].reshape(
+                nqb, W * bs, 1, 1)
+        return jnp.swapaxes(x, 1, 2)
+
+    k, v = gather(k_pool, k_scales), gather(v_pool, v_scales)
     qt = jnp.swapaxes(q.reshape(nqb, block_q, H, D), 1, 2)
     scores = jnp.einsum("nhqd,nhkd->nhqk", qt, k,
                         preferred_element_type=jnp.float32) * scale
@@ -249,23 +264,24 @@ def _ragged_ref(q, k_pool, v_pool, block_tables, context_lens, seq_ids,
 def _ragged_attention_impl(q, k_pool, v_pool, block_tables,
                            context_lens, seq_ids, q_starts, q_valids,
                            *scales, block_q, scale, use_pallas,
-                           window=None, block_tokens=None):
+                           window=None, block_tokens=None, head_ids=None):
     ks, vs = scales if scales else (None, None)
     if use_pallas:
         from ...ops.pallas_ragged import ragged_paged_attention as _krn
         out = _krn(q[0], k_pool, v_pool, block_tables, context_lens,
                    seq_ids, q_starts, q_valids, block_q=block_q,
                    scale=scale, k_scales=ks, v_scales=vs, window=window,
-                   block_tokens=block_tokens)
+                   block_tokens=block_tokens, head_ids=head_ids)
     else:
         out = _ragged_ref(q[0], k_pool, v_pool, block_tables,
                           context_lens, seq_ids, q_starts, q_valids,
                           block_q, scale, k_scales=ks, v_scales=vs,
-                          window=window, block_tokens=block_tokens)
+                          window=window, block_tokens=block_tokens,
+                          head_ids=head_ids)
     return out[None]
 
 
-def _use_pallas_ragged(head_dim, block_size, dtype, block_q,
+def _use_pallas_ragged(head_dim, kv_heads, block_size, dtype, block_q,
                        q_dtype=None):
     jd = jnp.dtype(dtype)
     int8_kv = jd == jnp.dtype(jnp.int8)
@@ -275,7 +291,7 @@ def _use_pallas_ragged(head_dim, block_size, dtype, block_q,
     if head_dim > 256 or block_size % 8 != 0:
         return False
     from ...ops.pallas_ragged import pool_copyable
-    if not pool_copyable(head_dim, block_size):
+    if not pool_copyable(head_dim, kv_heads):
         return False
     from ...ops.pallas_kernels import _min_rows
     # block_q tiles the QUERY buffer, whose dtype is the compute
@@ -300,8 +316,8 @@ def ragged_attention(q, k_pool, v_pool, block_tables, context_lens,
         scale = 1.0 / math.sqrt(head_dim)
     kv = k_pool._value if isinstance(k_pool, Tensor) else k_pool
     qv_ = q._value if isinstance(q, Tensor) else q
-    use_pallas = _use_pallas_ragged(head_dim, kv.shape[2], kv.dtype,
-                                    int(block_q), qv_.dtype)
+    use_pallas = _use_pallas_ragged(head_dim, qv_.shape[2], kv.shape[1],
+                                    kv.dtype, int(block_q), qv_.dtype)
     args = (q, k_pool, v_pool, block_tables, context_lens,
             seq_ids, q_starts, q_valids)
     if k_scales is not None:
@@ -374,10 +390,11 @@ class RaggedLayerCache(_LayerCache):
         slots, tables, base = view.group_inputs(
             cache.layer_group(self._layer))
         kv, qv_ = k_pool._value, q._value
-        group = qv_.shape[2] // kv.shape[1]
+        kv_heads, head_dim = cache.num_heads, cache.head_dim
+        group = qv_.shape[2] // kv_heads
         dec_rows = decode_block_q(group, qv_.dtype)
         chunk_bq = view.chunk_block_q
-        bs = kv.shape[2]
+        bs = cache.block_size
         dec_width = tables.shape[1] if self._window is None else min(
             tables.shape[1], self._window // bs + 2)
         out, new_k, new_v = dispatch(
@@ -388,9 +405,10 @@ class RaggedLayerCache(_LayerCache):
                  chunk_bq=chunk_bq, dec_width=int(dec_width),
                  dec_rows=dec_rows,
                  pallas_rows=_use_pallas_ragged(
-                     kv.shape[3], bs, kv.dtype, dec_rows, qv_.dtype),
+                     head_dim, kv_heads, bs, kv.dtype, dec_rows,
+                     qv_.dtype),
                  pallas_chunk=_use_pallas_ragged(
-                     kv.shape[3], bs, kv.dtype, group * chunk_bq,
+                     head_dim, kv_heads, bs, kv.dtype, group * chunk_bq,
                      qv_.dtype)),
             differentiable=False)
         k_pool._inplace_update(new_k._value)
@@ -453,26 +471,25 @@ def grouped_decode_attention(q, k_pool, v_pool, sel_tables, sel_ctx,
                              use_pallas, window=None, block_q=None):
     """Decode rows with grouped KV heads over a block table of their
     own, through the ragged kernel: ``q`` [S, H, D] (one token a row,
-    heads grouped by KV head), pools [nb, Hkv, bs, D], ``sel_tables``
+    heads grouped by KV head), pools [nb, bs, Hkv * D], ``sel_tables``
     [S, Hkv, W], ``sel_ctx`` [S, Hkv] (0: an idle row).
 
     The kernel learns grouped heads by layout: a (row, KV head) pair is
-    a sequence of its own whose q-block holds the group's ``G`` query
-    heads as rows, all at the same position (``q_starts = ctx - 1``
-    lets every row see the whole context), and the pool is viewed as
-    ``[nb * Hkv, 1, bs, D]`` so that a table entry names a block *and*
-    a KV head.  Sixteen MXU rows a token where one head a row gives
-    one.
+    a sequence of its own, with its own table, whose q-block holds the
+    group's ``G`` query heads as rows, all at the same position
+    (``q_starts = ctx - 1`` lets every row see the whole context), and
+    the q-block names the KV head whose lanes of the pool it reads
+    (``head_ids``).  Sixteen MXU rows a token where one head a row
+    gives one.
 
     With ``block_q`` (at least ``G``: `decode_block_q`) the q-block is
     padded to that many rows and the kernel is told that its rows are
     one token (``block_tokens = 1``), which a ``window`` needs: the
     rows then share the position that the window is counted from."""
     S, H, D = q.shape
-    nb, kv_heads, bs, _ = k_pool.shape
+    kv_heads = sel_tables.shape[1]
     G, n = H // kv_heads, S * kv_heads
-    heads = jnp.arange(kv_heads, dtype=jnp.int32)[None, :, None]
-    tables = (sel_tables * kv_heads + heads).reshape(n, -1)
+    tables = sel_tables.reshape(n, -1)
     ctx = sel_ctx.reshape(n).astype(jnp.int32)
     seq = jnp.where(ctx > 0, jnp.arange(n, dtype=jnp.int32), n)
     if block_q is None:
@@ -487,10 +504,10 @@ def grouped_decode_attention(q, k_pool, v_pool, sel_tables, sel_ctx,
         qg = jnp.pad(q.reshape(n, G, D), ((0, 0), (0, rows - G), (0, 0))) \
             .reshape(1, n * rows, 1, D)
     out = _ragged_attention_impl(
-        qg, k_pool.reshape(nb * kv_heads, 1, bs, D),
-        v_pool.reshape(nb * kv_heads, 1, bs, D), tables, ctx, seq,
+        qg, k_pool, v_pool, tables, ctx, seq,
         jnp.maximum(ctx - 1, 0), jnp.full((n,), valid, jnp.int32),
         block_q=rows, scale=1.0 / math.sqrt(D), use_pallas=use_pallas,
+        head_ids=jnp.tile(jnp.arange(kv_heads, dtype=jnp.int32), S),
         **options)
     return out.reshape(n, rows, D)[:, :G].reshape(S, H, D)
 
@@ -504,7 +521,7 @@ def grouped_chunk_attention(q, k_pool, v_pool, table, context, start,
     of one KV head, so the kernel's head axis is the KV heads and a
     K/V block is read once for the ``G`` heads that share it."""
     C, H, D = q.shape
-    kv_heads = k_pool.shape[1]
+    kv_heads = k_pool.shape[2] // D
     G, nqb = H // kv_heads, C // chunk_bq
     # [C, H, D] -> rows ordered (q-block, head of the group, token)
     qg = q.reshape(nqb, chunk_bq, kv_heads, G, D).transpose(0, 3, 1, 2, 4) \
@@ -544,7 +561,7 @@ def _grouped_attend_impl(q, k, v, k_pool, v_pool, slots, tables, base,
     k_pool, v_pool = _kv_scatter_impl(k_pool, v_pool, k, v, slots)
     q0 = q[0]
     T, H, D = q0.shape
-    S, kv_heads = tables.shape[0], k_pool.shape[1]
+    S, kv_heads = tables.shape[0], k_pool.shape[2] // D
     tables = tables.astype(jnp.int32)
     with block("attention/decode"):
         qd = q0[jnp.minimum(dec_index, T - 1)]               # [S, H, D]
@@ -605,7 +622,7 @@ def _sparse_attend_impl(q, k, v, k_pool, v_pool, ck_pool, slots, tables,
                                     ck_j, ck_slot, sizes)
     q0 = q[0]
     T, H, D = q0.shape
-    kv_heads, bs = k_pool.shape[1], k_pool.shape[2]
+    kv_heads, bs = k_pool.shape[2] // D, k_pool.shape[1]
     with block("attention/decode"):
         qd = q0[jnp.minimum(dec_index, T - 1)]               # [S, H, D]
         scores = pls.sparse_select_scores(qd, ck_pool, row_slots, row_pos,
@@ -621,8 +638,8 @@ def _sparse_attend_impl(q, k, v, k_pool, v_pool, ck_pool, slots, tables,
             r = jnp.arange(chunk_rows, dtype=jnp.int32)
             t = jnp.where(r < meta[1], meta[5] + r, -1)
             table = tables_ext[meta[4]]
-            gather = lambda pool: jnp.swapaxes(           # noqa: E731
-                pool[table], 1, 2).reshape(W * bs, kv_heads, D)
+            gather = lambda pool: pool[table].reshape(    # noqa: E731
+                W * bs, kv_heads, D)
             out = pls.sparse_block_attention(
                 qc.reshape(chunk_rows, kv_heads, H // kv_heads, D), t,
                 gather(k_pool), gather(v_pool), ck_pool[meta[2]], sizes)
@@ -769,7 +786,7 @@ class SparseLayerCache(_StatefulLayerCache):
         k_pool, v_pool = cache.layer_pools(self._layer)
         ck_pool = cache.layer_compressed(self._layer)
         kv, qv_ = k_pool._value, q._value
-        group = qv_.shape[2] // kv.shape[1]
+        group = qv_.shape[2] // cache.num_heads
         out, new_k, new_v, new_ck = dispatch(
             "sparse_paged_attention", _sparse_attend_impl,
             (q, k, v, k_pool, v_pool, ck_pool, view.slot_mapping,
@@ -780,7 +797,8 @@ class SparseLayerCache(_StatefulLayerCache):
                  sel_width=sizes.table_width(
                      cache.table_width * cache.block_size),
                  pallas_attn=_use_pallas_ragged(
-                     kv.shape[3], kv.shape[2], kv.dtype, group, qv_.dtype),
+                     cache.head_dim, cache.num_heads, cache.block_size,
+                     kv.dtype, group, qv_.dtype),
                  pallas_select=pallas_enabled("sparse_select")),
             differentiable=False)
         k_pool._inplace_update(new_k._value)

@@ -7,13 +7,27 @@ models/generation.py — every length compiles its own executable and a
 long sequence pins worst-case memory), K/V live in a pool of fixed-size
 blocks
 
-    k_pool[layer]: [num_blocks, num_heads, block_size, head_dim]
+    k_pool[layer]: [num_blocks, block_size, num_heads * head_dim]
 
 and each sequence owns an ordered list of block ids (its *block table*).
 Token `i` of a sequence lives at flat slot ``table[i // bs] * bs +
 i % bs``.  Appending a token never moves data; freeing a sequence
 returns whole blocks to the pool; admission control is a free-list
 length check.
+
+**One layout.**  A token's KV heads lie side by side in one row and a
+block's tokens in consecutive rows, so flat slot ``s`` is row ``s`` of
+the pool seen as ``[num_blocks * block_size, num_heads * head_dim]``.
+The step's scatter writes rows of that view in place
+(`attention._kv_scatter_impl`), the ragged kernel copies ``[block_size,
+lanes]`` lane windows out of the same array (`ops/pallas_ragged.py`),
+and `_ragged_ref` gathers from it: nothing between them relays a pool.
+The windowed groups' pools, the int8 pools (their scale tables
+``[num_blocks, block_size, lanes]`` were always token-major) and a
+block on its way to the host or to another pool (``[block_size,
+num_heads * head_dim]``: `kv_blocks_gather` / `kv_blocks_scatter`, the
+host ring of tiering.py, `HandoffPayload`, transport.py's frames) all
+keep this one format.
 
 Block 0 is reserved as the *pad block*: padded batch rows scatter their
 garbage K/V there and padded block-table entries point at it — it is
@@ -366,8 +380,8 @@ class PagedKVCache:
         self.prefix_cache = (prefix_cache_enabled()
                              if prefix_cache is None else bool(prefix_cache))
 
-        shape = (self.num_blocks, self.num_heads, self.block_size,
-                 self.head_dim)
+        shape = (self.num_blocks, self.block_size,
+                 self.num_heads * self.head_dim)
         self._pools = []  # [(k_tensor, v_tensor)] per layer
         self._scales = []  # [(k_scale, v_scale)] per layer (int8 only)
         for i in range(self.num_layers):

@@ -94,7 +94,8 @@ def _observe_dma(direction, nbytes, elapsed_s):
 
 class HandoffPayload:
     """One sequence's paged KV state as host bytes: per-layer stacked
-    block data ``[nb, H, bs, D]`` (+ scale tables ``[nb, bs, lanes]``
+    block data ``[nb, bs, H * D]`` (blocks as they lie in the pool:
+    ``kv_cache.py``'s one format; + scale tables ``[nb, bs, lanes]``
     for int8 pools) in table order.  Produced by
     ``PagedKVCache.export_sequence`` and consumed block-granularly by
     ``import_sequence`` on another pool."""
@@ -103,7 +104,7 @@ class HandoffPayload:
                  "block_size", "kv_dtype", "nbytes")
 
     def __init__(self, k, v, k_scales, v_scales, block_size, kv_dtype):
-        self.k = k                    # [layers] of [nb, H, bs, D]
+        self.k = k                    # [layers] of [nb, bs, H * D]
         self.v = v
         self.k_scales = k_scales      # [layers] of [nb, bs, lanes]|None
         self.v_scales = v_scales
@@ -132,8 +133,9 @@ class HostKVPool:
         self.block_size = int(block_size)
         self.scale_lanes = int(scale_lanes)
         self.num_slots = int(num_slots)
-        shape = (self.num_slots, int(num_heads), self.block_size,
-                 int(head_dim))
+        # a slot holds a block as it lies in the pool
+        shape = (self.num_slots, self.block_size,
+                 int(num_heads) * int(head_dim))
         # one pinned (preallocated, reused in place) array per layer
         # per side; slots are recycled through the free list, so the
         # ring never grows past the budget
@@ -177,7 +179,7 @@ class HostKVPool:
 
     def write(self, slot, k_parts, v_parts, ks_parts=None,
               vs_parts=None):
-        """Land one block's host bytes: per-layer [H, bs, D] arrays
+        """Land one block's host bytes: per-layer [bs, H * D] arrays
         (+ [bs, lanes] scales) copied into the pinned ring slot."""
         for i in range(self.num_layers):
             np.copyto(self._k[i][slot], k_parts[i], casting="no")

@@ -204,7 +204,7 @@ def _probe_ragged_attention():
     block_q = pr.ragged_q_block(jnp.float32)
     nqb = 3                       # one 2-block prefill + one decode
     q = jnp.zeros((nqb * block_q, 2, 64), jnp.float32)
-    pool = jnp.zeros((4, 2, 16, 64), jnp.float32)
+    pool = jnp.zeros((4, 16, 2 * 64), jnp.float32)
     # a table wider than either context: the walk inside the program
     # has slots to leave out
     bt = jnp.array([[1, 2, 0, 0], [3, 0, 0, 0]], jnp.int32)
@@ -222,7 +222,7 @@ def _probe_ragged_attention_int8():
     block_q = pr.ragged_q_block(jnp.float32)
     nqb = 3                       # one 2-block prefill + one decode
     q = jnp.zeros((nqb * block_q, 2, 64), jnp.float32)
-    pool = jnp.zeros((4, 2, 16, 64), jnp.int8)
+    pool = jnp.zeros((4, 16, 2 * 64), jnp.int8)
     scales = jnp.ones((4, 16, pr.KV_SCALE_LANES), jnp.float32)
     # a table wider than either context: the walk inside the program
     # has slots to leave out

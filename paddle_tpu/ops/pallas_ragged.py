@@ -16,40 +16,43 @@ per-q-block scalar arrays describe the ragged layout:
                  i.e. ``context_len - query_len + i_local * block_q``
     q_valids[i]  valid rows in the block (trailing rows are padding)
 
-K/V live in the PR-5 paged pool ``[num_blocks, H, block_size, D]``,
-which stays in HBM (``memory_space=pl.ANY``); ``block_tables [S, W]`` /
-``context_lens [S]`` and the three descriptors are scalar-prefetched,
-and the grid is
+K/V live in the paged pool ``[num_blocks, block_size, H * D]`` (a
+token's heads side by side in one row, a block's tokens in consecutive
+rows: the layout the step's scatter writes, `serving/kv_cache.py`), which
+stays in HBM as it lies (``memory_space=pl.ANY``); ``block_tables [S,
+W]`` / ``context_lens [S]`` and the three descriptors are
+scalar-prefetched, and the grid is
 
-    (num_q_blocks, num_heads)
+    (num_q_blocks, lane windows of a row)
 
-one program a q-block and head.  The walk over the sequence's block
-table runs *inside* the program (several pages a step, fetched by the
-kernel's own double-buffered copies: the form of the Ragged Paged
-Attention kernel, PAPERS.md): from the scalars it computes the table
-slots ``[lo, hi)`` that hold a key one of its rows can see,
+one program a q-block and *lane window*: the ``D`` lanes of one head
+where ``D`` is whole 128-lane tiles, else the 128 lanes that ``128 / D``
+neighbouring heads share (an HBM window narrower than 128 lanes cannot
+be copied), whose heads the one program computes, each on its static
+lane slice.  The walk over the sequence's block table runs *inside* the
+program (several pages a step, fetched by the kernel's own
+double-buffered copies: the form of the Ragged Paged Attention kernel,
+PAPERS.md): from the scalars it computes the table slots ``[lo, hi)``
+that hold a key one of its rows can see,
 
     lo = block of its first token's window start (0 with no window)
     hi = min(ceil(context_len / block_size),
              1 + block of the q-block's last token)
 
-and a ``lax.fori_loop`` takes them ``kv_step`` slots a step: the blocks
-``pool[block_tables[seq, w], h]`` of a step come by
+and a ``lax.fori_loop`` takes them ``kv_step`` slots a step: the windows
+``pool[block_tables[seq, w], :, lane0:lane0 + lanes]`` of a step come by
 ``pltpu.make_async_copy`` into one half of a VMEM buffer ``[2, kv_step,
-block_size, D]`` while the other half is computed on.  A slot outside
-``[lo, hi)`` is never read, whatever the table's width: a program costs
-what its context costs, and a null segment walks nothing.  The
-online-softmax state (acc/m/l, VMEM scratch) is carried over the steps;
-the score block of a step is ``block_q x (kv_step * block_size)``.
+block_size, lanes]`` while the other half is computed on.  A slot
+outside ``[lo, hi)`` is never read, whatever the table's width: a
+program costs what its context costs, and a null segment walks nothing.
+The online-softmax state (acc/m/l, VMEM scratch) is carried over the
+steps; the score block of a step is ``block_q x (kv_step *
+block_size)``, keys in their own order.
 
 ``kv_step`` follows from the call's shapes (`_kv_step`): 512 keys a step
 for a decode row's 16-row q-block, halved while the score block's float32
 temporaries and the buffers pass a fixed VMEM budget (a 1,024-row chunk
-q-block takes 256 keys).  A head narrower than 128 lanes cannot be cut
-out of an HBM array, so such a pool is viewed as ``[num_blocks, H,
-block_size / p, p * D]`` with ``p = 128 / D`` keys a row
-(`_lane_parts`), and the program reads the keys of a step part by part;
-the mask knows their positions.
+q-block takes 256 keys).
 
 Causal masking happens inside each ragged segment: row ``r`` of q-block
 ``i`` sees KV position ``c`` iff
@@ -77,6 +80,12 @@ they leave as it was):
     window        row at position ``p`` sees ``c`` only if ``c > p -
                   window``; the walk starts at the block of the
                   q-block's first token's window start, masked inside.
+
+A third input, ``head_ids`` [num_q_blocks], gives each q-block the one
+head of the pool it reads (``q`` is then ``[T, 1, D]``): the grouped
+decode rows, where a (row, KV head) pair is a sequence with a table of
+its own.  A narrow head then picks its slice of the window by its place
+in it.
 
 Gated through ``pallas_gate`` ("ragged_attention" probe);
 `ragged_block_plan` exports the exact specs for
@@ -188,51 +197,55 @@ def _kv_step(block_q, block_size, head_dim, kv_itemsize, table_width):
 _LANES = 128
 
 
-def _lane_parts(head_dim, block_size):
-    """Keys that share a 128-lane row of the pool as the kernel sees it.
-
-    An HBM window narrower than 128 lanes cannot be copied (Mosaic: a
-    slice "must be aligned to tiling (128)"), so a pool of narrow heads
-    is handed over as ``[num_blocks, H, block_size / parts, parts * D]``:
-    row ``t`` of a block holds keys ``t * parts ... t * parts + parts -
-    1`` side by side.  1 for a head width that fills whole rows."""
-    if head_dim < _LANES and _LANES % head_dim == 0 \
-            and block_size % (_LANES // head_dim) == 0:
-        return _LANES // head_dim
-    return 1
+def _lane_window(head_dim):
+    """Lanes of the window of a pool row that one copy of the walk
+    names: the head's own ``head_dim`` where that is whole 128-lane
+    tiles, else the 128 lanes that ``128 / head_dim`` neighbouring
+    heads share (an HBM window narrower than 128 lanes cannot be
+    copied: Mosaic, a slice "must be aligned to tiling (128)")."""
+    return head_dim if head_dim % _LANES == 0 else _LANES
 
 
-def pool_copyable(head_dim, block_size):
-    """Whether the walk's copies lower on the chip for a pool of this
-    head width and block size: whole 128-lane rows a head, or whole keys
-    a row (`_lane_parts`) and then whole 8-row tiles a block."""
+def pool_copyable(head_dim, num_kv_heads):
+    """Whether the walk's copies lower on the chip for a pool whose rows
+    hold ``num_kv_heads`` heads of ``head_dim`` lanes side by side: a
+    head is whole 128-lane tiles, or a whole number of heads fills a
+    tile and the row is whole tiles."""
     if head_dim % _LANES == 0:
         return True
-    parts = _lane_parts(head_dim, block_size)
-    return parts > 1 and (block_size // parts) % 8 == 0
+    return (_LANES % head_dim == 0
+            and (num_kv_heads * head_dim) % _LANES == 0)
 
 
-def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
+def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref, hd_ref,
                       q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
                       acc_ref, m_ref, l_ref, k_buf, v_buf, ks_buf, vs_buf,
                       sems, *, block_size, block_q, kv_step, scale,
-                      parts=1, window=None, block_tokens=None):
-    """One (q-block, head) program: the walk over the sequence's block
-    table runs inside it.
+                      window=None, block_tokens=None):
+    """One (q-block, lane window) program: the walk over the sequence's
+    block table runs inside it.
 
     Scalar-prefetched ``seq_ids`` route each q-block to its sequence's
     block table; the null segment (``seq_ids == num_seqs``) reads
     ``context_len 0`` from the padded tail of ``cl_ref``, walks nothing
     and emits zeros.
 
-    The pools stay in HBM.  A step of the walk copies ``kv_step``
-    consecutive table slots' K and V blocks into one half of the double
-    buffers while the other half is computed on; only slots in ``[lo,
-    hi)`` (the first block the first token's window reaches, the last
-    block that holds a key a row can see) are ever copied.  The last
-    step's slots past ``hi`` keep what the buffer held, masked like any
-    key past the context; their V rows are zeroed first, since ``0 x
-    NaN`` is not 0.
+    The pools stay in HBM as they lie, ``[num_blocks, block_size, H *
+    D]``.  A step of the walk copies the program's lane window of
+    ``kv_step`` consecutive table slots' K and V blocks (``[block_size,
+    lanes]`` each) into one half of the double buffers while the other
+    half is computed on; only slots in ``[lo, hi)`` (the first block the
+    first token's window reaches, the last block that holds a key a row
+    can see) are ever copied.  The last step's slots past ``hi`` keep
+    what the buffer held, masked like any key past the context; their V
+    rows are zeroed first, since ``0 x NaN`` is not 0.
+
+    The program's heads are the ``q_ref.shape[0]`` heads from ``hd_ref[i]``
+    (a q-block's own KV head: the grouped decode rows) or from its place
+    on the grid's head axis.  Heads narrower than 128 lanes share a
+    window: a program of the whole window's heads computes each on its
+    static lane slice; a program of one head picks its slice by the
+    head's place in the window.
 
     ``ks_hbm``/``vs_hbm`` are the int8 variant's per-slot dequant scale
     tables (copied by the SAME walk into ``ks_buf``/``vs_buf``) or None
@@ -241,30 +254,38 @@ def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
     crosses HBM.
     """
     i = pl.program_id(0)
-    h = pl.program_id(1)
+    heads, _, head_dim = q_ref.shape
+    lanes = k_buf.shape[-1]
+    share = lanes // head_dim             # heads a lane window holds
+    head = pl.program_id(1) * heads if hd_ref is None else hd_ref[i]
+    lane0 = pl.multiple_of(jax.lax.div(head, share) * lanes, lanes)
     sid = sid_ref[i]
     ctx = cl_ref[sid]
     qs = qs_ref[i]
     qv = qv_ref[i]
     keys = kv_step * block_size
-    part_keys = keys // parts
-    head_dim = q_ref.shape[-1]
     int8_kv = ks_hbm is not None
 
     def unpack(buf, scales, b):
-        """Buffer half ``b`` as a (keys, D) float32 matrix.  With
-        ``parts`` keys a row the keys come part by part: entry ``c *
-        part_keys + r`` is key ``r * parts + c`` of the step."""
-        x = buf[b].astype(jnp.float32)
-        x = x.reshape(part_keys, x.shape[-1])           # (rows, lanes)
-        cut = [x] if parts == 1 else [
-            x[:, c * head_dim:(c + 1) * head_dim] for c in range(parts)]
-        if scales is not None:
-            # per-slot dequant; the scale tables come in the same order
-            sc = scales[b]                      # (step, parts, rows, 128)
-            cut = [x_c * sc[:, c].reshape(part_keys, sc.shape[-1])[:, :1]
-                   for c, x_c in enumerate(cut)]
-        return cut[0] if parts == 1 else jnp.concatenate(cut, axis=0)
+        """Buffer half ``b`` as a (keys, lanes) float32 matrix, keys in
+        their own order."""
+        x = buf[b].astype(jnp.float32).reshape(keys, lanes)
+        if scales is not None:            # per-slot dequant
+            x = x * scales[b].reshape(keys, _LANES)[:, :1]
+        return x
+
+    def head_lanes(x, c):
+        """The program's head ``c`` of a window's (keys, lanes)."""
+        if share == 1:
+            return x
+        cut = [x[:, s * head_dim:(s + 1) * head_dim] for s in range(share)]
+        if heads == share:                # the window's heads in order
+            return cut[c]
+        place = jnp.full(cut[0].shape, jax.lax.rem(head, share), jnp.int32)
+        out = cut[0]
+        for s in range(1, share):
+            out = jnp.where(place == s, cut[s], out)
+        return out
 
     # nothing past the context, nothing after the q-block's last token
     last = qs + ((block_tokens or block_q) - 1)
@@ -276,7 +297,7 @@ def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
         lo = jax.lax.div(jnp.maximum(qs - (window - 1), 0), block_size)
     steps = jax.lax.div(jnp.maximum(hi - lo, 0) + (kv_step - 1), kv_step)
 
-    # (table in HBM, its buffer, whether a block has a head axis)
+    # (table in HBM, its buffer, whether a row holds heads side by side)
     walked = [(k_hbm, k_buf, True), (v_hbm, v_buf, True)]
     if int8_kv:
         walked += [(ks_hbm, ks_buf, False), (vs_hbm, vs_buf, False)]
@@ -286,7 +307,8 @@ def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
         half ``b``."""
         blk = bt_ref[sid, lo + g * kv_step + j]
         return [pltpu.make_async_copy(
-            src.at[blk, h] if per_head else src.at[blk], dst.at[b, j],
+            src.at[blk, :, pl.ds(lane0, lanes)] if per_head
+            else src.at[blk], dst.at[b, j],
             sems.at[n, b]) for n, (src, dst, per_head) in enumerate(walked)]
 
     def live_slots(g):
@@ -330,17 +352,8 @@ def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
             start(g + 1, 1 - b)
 
         wait(g, b)
-        q = q_ref[0].astype(jnp.float32)                # (bq, D)
-        k = unpack(k_buf, ks_buf, b)                    # (keys, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, keys)
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if parts > 1:
-            # the keys' own order back (see `unpack`)
-            part = jax.lax.div(col, part_keys)
-            col = (col - part * part_keys) * parts + part
+        row = jax.lax.broadcasted_iota(jnp.int32, (block_q, keys), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (block_q, keys), 1)
         col = col + (lo + g * kv_step) * block_size
         if block_tokens is not None:
             # head groups: row r is token r % block_tokens (a power of
@@ -351,18 +364,25 @@ def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
         mask = (row < qv) & (col <= row + qs) & (col < ctx)
         if window is not None:
             mask &= col > row + (qs - window)
-        s = jnp.where(mask, s, _NEG_INF)
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = _lanes(alpha * l_ref[:, :1]
-                            + jnp.sum(p, axis=-1, keepdims=True))
-        v = unpack(v_buf, vs_buf, b)                    # (keys, D)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = _lanes(m_new)
+        k_win = unpack(k_buf, ks_buf, b)                # (keys, lanes)
+        v_win = unpack(v_buf, vs_buf, b)
+        for n in range(heads):
+            rows = slice(n * block_q, (n + 1) * block_q)
+            q = q_ref[n].astype(jnp.float32)            # (bq, D)
+            s = jax.lax.dot_general(
+                q, head_lanes(k_win, n), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (bq, keys)
+            s = jnp.where(mask, s, _NEG_INF)
+            m_prev = m_ref[rows, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[rows] = _lanes(alpha * l_ref[rows, :1]
+                                 + jnp.sum(p, axis=-1, keepdims=True))
+            acc_ref[rows] = acc_ref[rows] * alpha + jax.lax.dot_general(
+                p, head_lanes(v_win, n), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[rows] = _lanes(m_new)
         return c
 
     jax.lax.fori_loop(0, steps, step, 0)
@@ -376,37 +396,32 @@ def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
     # mode at LOWERING time (outside _x32) and aborts on i64
     # ("bitwidth_ <= 32"); compare at full shape instead.
     out = jnp.where(jnp.broadcast_to(l, out.shape) > 0.0, out, 0.0)
-    o_ref[...] = out[None].astype(o_ref.dtype)
+    o_ref[...] = out.reshape(o_ref.shape).astype(o_ref.dtype)
 
 
-def _ragged_attn_kernel(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
-                        q_ref, k_hbm, v_hbm, o_ref,
-                        acc_ref, m_ref, l_ref, k_buf, v_buf, sems, **kw):
-    _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
-                      q_ref, k_hbm, v_hbm, None, None, o_ref,
-                      acc_ref, m_ref, l_ref, k_buf, v_buf, None, None,
-                      sems, **kw)
+def _ragged_attn_kernel(*refs, scalars, int8_kv, **kw):
+    """The body's references by name: ``scalars`` scalar-prefetched
+    arrays (the sixth, a q-block's own head, only for the grouped decode
+    rows), then q, the pools, an int8 pool's scale tables, the output,
+    the softmax's scratch and the walk's buffers."""
+    prefetched = list(refs[:scalars]) + [None] * (6 - scalars)
+    rest = list(refs[scalars:])
+    q_ref, k_hbm, v_hbm = rest[:3]
+    ks_hbm, vs_hbm = rest[3:5] if int8_kv else (None, None)
+    rest = rest[5 if int8_kv else 3:]
+    o_ref, acc_ref, m_ref, l_ref, k_buf, v_buf = rest[:6]
+    ks_buf, vs_buf = rest[6:8] if int8_kv else (None, None)
+    _ragged_attn_body(*prefetched, q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm,
+                      o_ref, acc_ref, m_ref, l_ref, k_buf, v_buf, ks_buf,
+                      vs_buf, rest[-1], **kw)
 
 
-def _ragged_attn_int8_kernel(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
-                             q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
-                             acc_ref, m_ref, l_ref, k_buf, v_buf, ks_buf,
-                             vs_buf, sems, **kw):
-    _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref,
-                      q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
-                      acc_ref, m_ref, l_ref, k_buf, v_buf, ks_buf, vs_buf,
-                      sems, **kw)
-
-
-def _walk_scratch(kv_step, block_size, head_dim, kv_dtype, int8_kv):
-    """The walk's VMEM: two halves of ``kv_step`` K and V blocks (and,
-    for an int8 pool, of their scale blocks) as the kernel sees them
-    (`_lane_parts`), as (shape, dtype)."""
-    parts = _lane_parts(head_dim, block_size)
-    rows = block_size // parts
-    bufs = [((2, kv_step, rows, parts * head_dim), kv_dtype)] * 2
+def _walk_scratch(kv_step, block_size, lanes, kv_dtype, int8_kv):
+    """The walk's VMEM: two halves of ``kv_step`` K and V windows (and,
+    for an int8 pool, of their scale blocks), as (shape, dtype)."""
+    bufs = [((2, kv_step, block_size, lanes), kv_dtype)] * 2
     if int8_kv:
-        bufs += [((2, kv_step, parts, rows, _LANES), jnp.float32)] * 2
+        bufs += [((2, kv_step, block_size, _LANES), jnp.float32)] * 2
     return bufs
 
 
@@ -414,11 +429,12 @@ def _walk_scratch(kv_step, block_size, head_dim, kv_dtype, int8_kv):
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
                            seq_ids, q_starts, q_valids, block_q=None,
                            scale=None, k_scales=None, v_scales=None,
-                           window=None, block_tokens=None):
+                           window=None, block_tokens=None, head_ids=None):
     """Mixed prefill+decode attention over the paged KV pool.
 
     q: [T, H, D] flat block-aligned ragged queries (T % block_q == 0);
-    k_pool/v_pool: [num_blocks, H, block_size, D];
+    k_pool/v_pool: [num_blocks, block_size, H * D] (a token's heads side
+    by side in a row);
     block_tables: [S, W] int32; context_lens: [S] int32;
     seq_ids/q_starts/q_valids: [T // block_q] int32 (see module doc;
     ``seq_ids == S`` marks a null/pad q-block).  Returns [T, H, D].
@@ -431,7 +447,9 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
 
     ``window`` and ``block_tokens`` (static; see the module doc) bound
     what a row sees and lay head groups into a q-block; ``None`` for
-    both is the program without them.
+    both is the program without them.  ``head_ids`` [T // block_q]
+    int32 gives each q-block the one head of the pool that it reads
+    (``q`` is then [T, 1, D], the pool's heads ``lanes / D``).
     """
     q, k_pool, v_pool = _demote_f64(q, k_pool, v_pool)
     int8_kv = jnp.dtype(k_pool.dtype) == jnp.dtype(jnp.int8)
@@ -457,18 +475,21 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     if seq_ids.shape[0] != nqb:
         raise ValueError(f"{seq_ids.shape[0]} segment descriptors for "
                          f"{nqb} q-blocks")
-    num_blocks, _, block_size, _ = k_pool.shape
+    num_blocks, block_size, row_lanes = k_pool.shape
+    lanes = _lane_window(D)
+    if row_lanes % lanes or lanes % D or H != (
+            row_lanes // D if head_ids is None else 1):
+        raise ValueError(f"q {q.shape} does not match a pool of "
+                         f"{row_lanes}-lane rows read in windows of "
+                         f"{lanes}")
     S, W = block_tables.shape
     if scale is None:
         scale = 1.0 / (D ** 0.5)
-    kv_step = _kv_step(block_q, block_size, D,
+    # a program computes the heads of its lane window, or the one head
+    # its q-block names
+    heads = lanes // D if head_ids is None else 1
+    kv_step = _kv_step(block_q, block_size, lanes,
                        jnp.dtype(k_pool.dtype).itemsize, W)
-    parts = _lane_parts(D, block_size)
-    if parts > 1:
-        options["parts"] = parts
-        rows = block_size // parts
-        k_pool = k_pool.reshape(num_blocks, H, rows, parts * D)
-        v_pool = v_pool.reshape(num_blocks, H, rows, parts * D)
 
     qt = jnp.swapaxes(q, 0, 1)                          # [H, T, D]
     # null segment: seq_ids == S indexes the appended zero context, so
@@ -479,55 +500,49 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     cl = jnp.concatenate(
         [context_lens.astype(jnp.int32),
          jnp.zeros((1,), jnp.int32)], axis=0)            # [S+1]
-    sid = seq_ids.astype(jnp.int32)
-    qs = q_starts.astype(jnp.int32)
-    qv = q_valids.astype(jnp.int32)
+    prefetched = [bt, cl, seq_ids.astype(jnp.int32),
+                  q_starts.astype(jnp.int32), q_valids.astype(jnp.int32)]
+    if head_ids is not None:
+        prefetched.append(head_ids.astype(jnp.int32))
 
-    q_spec = pl.BlockSpec(
-        (1, block_q, D), lambda i, h, bt, cl, sid, qs, qv: (h, i, 0))
+    q_spec = pl.BlockSpec((heads, block_q, D), lambda i, h, *_: (h, i, 0))
     # the pools (and the scale tables) stay where they are: the program
-    # copies the blocks its table names
+    # copies the windows of the blocks its table names
     hbm_spec = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [q_spec, hbm_spec, hbm_spec]
     operands = [qt, k_pool, v_pool]
-    kernel = _ragged_attn_kernel
     name = "ragged_attention"
     if int8_kv:
         in_specs += [hbm_spec, hbm_spec]
-        # [nb, bs, 1] -> [nb, parts, bs / parts, 128]: key t * parts + c
-        # of a block at [c, t], the order `unpack` reads, over a whole
-        # row of lanes (the least an HBM window may span, and what the
-        # one lane takes in HBM's tiles anyway)
+        # the one lane over a whole row of lanes: the least an HBM
+        # window may span, and what it takes in HBM's tiles anyway
         operands += [
-            jnp.broadcast_to(jnp.swapaxes(
-                t[..., :1].astype(jnp.float32).reshape(
-                    num_blocks, block_size // parts, parts, 1), 1, 2),
-                (num_blocks, parts, block_size // parts, _LANES))
+            jnp.broadcast_to(t[..., :1].astype(jnp.float32),
+                             (num_blocks, block_size, _LANES))
             for t in (k_scales, v_scales)]
-        kernel = _ragged_attn_int8_kernel
         name = "ragged_attention_int8"
-    buffers = _walk_scratch(kv_step, block_size, D, k_pool.dtype, int8_kv)
+    buffers = _walk_scratch(kv_step, block_size, lanes, k_pool.dtype,
+                            int8_kv)
 
     with _kernel_span(name, "fwd") as kernel_name:
         out = pl.pallas_call(
             functools.partial(
-                kernel, block_size=block_size, block_q=block_q,
+                _ragged_attn_kernel, scalars=len(prefetched),
+                int8_kv=int8_kv, block_size=block_size, block_q=block_q,
                 kv_step=kv_step, scale=float(scale), **options),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=5,
-                grid=(nqb, H),
+                num_scalar_prefetch=len(prefetched),
+                grid=(nqb, H // heads),
                 in_specs=in_specs,
-                out_specs=pl.BlockSpec(
-                    (1, block_q, D),
-                    lambda i, h, bt, cl, sid, qs, qv: (h, i, 0)),
-                scratch_shapes=softmax_scratch(block_q, D) + [
+                out_specs=q_spec,
+                scratch_shapes=softmax_scratch(heads * block_q, D) + [
                     pltpu.VMEM(shape, dtype) for shape, dtype in buffers
                 ] + [pltpu.SemaphoreType.DMA((len(buffers), 2))],
             ),
             out_shape=jax.ShapeDtypeStruct((H, T, D), q.dtype),
             interpret=_interpret(),
             name=kernel_name,
-        )(bt, cl, sid, qs, qv, *operands)
+        )(*prefetched, *operands)
     return jnp.swapaxes(out, 0, 1)                      # [T, H, D]
 
 
@@ -539,11 +554,12 @@ def ragged_block_plan(num_heads, head_dim, block_size, num_q_blocks=4,
     context lens, segment descriptors) live whole in SMEM, have no
     BlockSpec to audit, and are omitted.
 
-    The pools stay in HBM: their entries give the window one async copy
-    of the walk names (a table slot's block of one head, as the kernel
-    sees the pool: `_lane_parts`), and ``scratch`` holds the two halves
-    of ``kv_step`` such windows that the copies fill, after the
-    softmax's accumulators.
+    The pools stay in HBM as they lie, ``[num_blocks, block_size, H *
+    D]``: their entries give the window one async copy of the walk
+    names (a table slot's block, the lanes of one head or of the heads
+    that share 128), and ``scratch`` holds the two halves of ``kv_step``
+    such windows that the copies fill, after the softmax's accumulators
+    (a program computes the heads of its window).
 
     ``kv_dtype=int8`` exports the int8-pool variant: int8 k/v blocks
     plus the two f32 per-slot scale tables, a whole row of lanes wide;
@@ -557,32 +573,33 @@ def ragged_block_plan(num_heads, head_dim, block_size, num_q_blocks=4,
         block_q = ragged_q_block(dtype)
     D = head_dim
     T = num_q_blocks * block_q
-    kv_step = _kv_step(block_q, block_size, D, kvdt.itemsize, table_width)
-    parts = _lane_parts(D, block_size)
-    rows = block_size // parts
-    pool = (num_blocks, num_heads, rows, parts * D)
+    lanes = _lane_window(D)
+    heads = lanes // D                  # a program's: its window's
+    kv_step = _kv_step(block_q, block_size, lanes, kvdt.itemsize,
+                       table_width)
+    pool = (num_blocks, block_size, num_heads * D)
     operands = [
-        ("q", (1, block_q, D), (num_heads, T, D), dtype),
-        ("k_pool", (1, 1, rows, parts * D), pool, kvdt),
-        ("v_pool", (1, 1, rows, parts * D), pool, kvdt),
+        ("q", (heads, block_q, D), (num_heads, T, D), dtype),
+        ("k_pool", (1, block_size, lanes), pool, kvdt),
+        ("v_pool", (1, block_size, lanes), pool, kvdt),
     ]
     if int8_kv:
-        scales = (num_blocks, parts, rows, _LANES)
+        scales = (num_blocks, block_size, _LANES)
         operands += [
-            ("k_scales", (1, parts, rows, _LANES), scales, f32),
-            ("v_scales", (1, parts, rows, _LANES), scales, f32),
+            ("k_scales", (1, block_size, _LANES), scales, f32),
+            ("v_scales", (1, block_size, _LANES), scales, f32),
         ]
-    operands.append(("out", (1, block_q, D), (num_heads, T, D), dtype))
+    operands.append(("out", (heads, block_q, D), (num_heads, T, D), dtype))
     return {
-        "grid": (num_q_blocks, num_heads),
+        "grid": (num_q_blocks, num_heads // heads),
         "block_q": block_q,
         "kv_step": kv_step,
         "kv_dtype": str(kvdt),
         "operands": operands,
         "scratch": (
-            ((block_q, D), f32),
-            ((block_q, _STAT_LANES), f32),
-            ((block_q, _STAT_LANES), f32),
+            ((heads * block_q, D), f32),
+            ((heads * block_q, _STAT_LANES), f32),
+            ((heads * block_q, _STAT_LANES), f32),
         ) + tuple((shape, jnp.dtype(dt)) for shape, dt in _walk_scratch(
-            kv_step, block_size, D, kvdt, int8_kv)),
+            kv_step, block_size, lanes, kvdt, int8_kv)),
     }

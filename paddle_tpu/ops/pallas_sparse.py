@@ -98,13 +98,14 @@ def compress_keys(k_pool, ck_pool, tables, seq, j, slot, sizes):
     (rows of ``tables`` [S + 1, W]: the last row is the null sequence)
     into slots ``slot`` [N] of ``ck_pool`` [slots, Hkv, J, D]: the mean,
     in float32, of ``kernel`` keys gathered from ``k_pool`` [blocks,
-    Hkv, block, D] through the block table.  Entries with ``slot`` 0
+    block, Hkv * D] through the block table.  Entries with ``slot`` 0
     land in the pad slot."""
     pos = sizes.stride * j[:, None] + jnp.arange(sizes.kernel)[None, :]
     blk = jnp.take_along_axis(tables[seq], pos // sizes.block, axis=1)
-    keys = k_pool[blk, :, pos % sizes.block, :]          # [N, kernel, Hkv, D]
+    keys = k_pool[blk, pos % sizes.block]             # [N, kernel, Hkv * D]
     mean = keys.astype(jnp.float32).mean(1).astype(ck_pool.dtype)
-    return ck_pool.at[slot, :, j, :].set(mean)
+    return ck_pool.at[slot, :, j, :].set(
+        mean.reshape(mean.shape[0], ck_pool.shape[1], ck_pool.shape[3]))
 
 
 def compress_dense(k, sizes):

@@ -13,7 +13,12 @@ expert and a full expert layer: both groups of block tables, the
 grouped expert kernel at 128 x 2048 x 1024, the 200,192-word head),
 ``serve_qwen3_next`` (the same for `benchmarks/configs/Qwen3-Next-80B-
 A3B-Instruct.json`, depth cut to one period: both gated delta rule
-kernels, the held experts at 128 x 2048 x 512, attention at width 256).
+kernels, the held experts at 128 x 2048 x 512, attention at width 256),
+``serve_sala`` (the same for `benchmarks/configs/MiniCPM-SALA.json`,
+depth cut to two sparse and two lightning layers: the selected tables a
+KV head, the pooled keys, the sparse chunk's gather).  The four engine
+steps also assert that no instruction copies or transposes a whole K/V
+pool (`assert_pools_stay`).
 Each prints its compile seconds, the Mosaic kernels and collectives in
 the compiled text, and ``memory_analysis()`` against the chip's 16 GB.
 
@@ -45,7 +50,8 @@ from paddle_tpu.distributed.auto_parallel.sharding import (  # noqa: E402
 from paddle_tpu.models import BertConfig, GPTConfig  # noqa: E402
 from paddle_tpu.ops import (pallas_fused, pallas_gate,  # noqa: E402
                             pallas_gated_delta, pallas_grouped,
-                            pallas_kernels, pallas_ragged, pallas_tiles)
+                            pallas_kernels, pallas_lightning,
+                            pallas_ragged, pallas_sparse, pallas_tiles)
 
 HBM_BYTES = 16e9
 BATCH, SEQ = 16, 512
@@ -59,7 +65,8 @@ def open_gate():
     pallas_gate.pallas_enabled = lambda name, manual=False: (
         manual or not pallas_gate._auto_partitioned())
     for mod in (pallas_kernels, pallas_fused, pallas_ragged,
-                pallas_grouped, pallas_gated_delta, pallas_tiles):
+                pallas_grouped, pallas_gated_delta, pallas_lightning,
+                pallas_sparse, pallas_tiles):
         mod._interpret = lambda: False
 
 
@@ -85,6 +92,18 @@ def report(name, lowered, kernels=True):
     assert ("tpu_custom_call" in text) == kernels, (
         f"{name}: Mosaic kernels {'missing' if kernels else 'present'}")
     return text
+
+
+def assert_pools_stay(name, engine, text):
+    """No instruction of the step copies or transposes an array as
+    large as a layer's K/V pool (the smallest group's): the scatter
+    writes the pool as it lies and the ragged kernel reads it so."""
+    pool = min(int(t._value.size) for layer in engine.cache._layer_pool
+               for t in engine.cache.layer_pools(layer))
+    moves = cs.pool_sized_moves(text, pool)
+    assert not moves, f"{name}: a K/V pool is relaid in the step: {moves}"
+    print(f"{name}: no copy or transpose of {pool} elements (a pool) "
+          f"or more", flush=True)
 
 
 def _on(sharding, avals):
@@ -164,9 +183,10 @@ def check_serve(topo):
     chip = SingleDeviceSharding(topo.devices[0])
     # a fresh function object: jax keeps the first trace of `pure_fn`
     # for these shapes and would hand back the CPU-branch jaxpr
-    report("serve", jax.jit(
+    text = report("serve", jax.jit(
         lambda *a: entry["pure_fn"](*a), donate_argnums=(2,)).lower(
         *_on(chip, entry["avals"])))
+    assert_pools_stay("serve", engine, text)
     engine.close()
 
 
@@ -201,9 +221,10 @@ def _check_serve_cell(topo, name, family, config, traffic, cut, stacks):
         lambda *a: entry["pure_fn"](*a), donate_argnums=(2,)).lower(
         *_on(chip, entry["avals"])))
     moved = [line.strip()[:160] for line in text.splitlines()
-             if re.search(stacks, line)
+             if stacks and re.search(stacks, line)
              and re.search(r" (?:copy|pad|concatenate|transpose)\(", line)]
     assert not moved, f"an expert stack is moved in the step: {moved}"
+    assert_pools_stay(name, engine, text)
     engine.close()
     return text
 
@@ -227,6 +248,16 @@ def check_serve_qwen3_next(topo):
         r"bf16\[128,(?:2048|512),(?:2048|1024)\]")
 
 
+def check_serve_sala(topo):
+    from benchmarks.families import minicpm_sala
+    return _check_serve_cell(
+        topo, "serve_sala", minicpm_sala, "MiniCPM-SALA.json",
+        "longdoc-closed32.json",
+        lambda cfg: dict(num_hidden_layers=4, mixer_types=[
+            "minicpm4", "lightning-attn", "lightning-attn", "minicpm4"]),
+        None)
+
+
 def main(names):
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -242,6 +273,8 @@ def main(names):
         check_serve_afmoe(topo)
     if "serve_qwen3_next" in names:
         check_serve_qwen3_next(topo)
+    if "serve_sala" in names:
+        check_serve_sala(topo)
     open_gate()
     for name in names:
         if name == "static":
@@ -250,7 +283,8 @@ def main(names):
             check_static(topo, loop=True)
         elif name == "mesh":
             check_mesh(topo)
-        elif name not in ("serve", "serve_afmoe", "serve_qwen3_next"):
+        elif name not in ("serve", "serve_afmoe", "serve_qwen3_next",
+                          "serve_sala"):
             raise SystemExit(f"unknown program {name!r}")
     print("AOT_SMOKE_OK", flush=True)
 

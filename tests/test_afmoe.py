@@ -249,7 +249,9 @@ def test_grouped_kernel_reads_the_stack_where_it_lies():
 
 # (e) the ragged kernel's window and head groups -------------------------
 def _pool(rng, blocks, heads, bs, d):
-    return (jnp.asarray(rng.normal(size=(blocks, heads, bs, d)),
+    """K and V pools as the engine keeps them: a token's heads side by
+    side in a row, whole 128-lane windows a row."""
+    return (jnp.asarray(rng.normal(size=(blocks, bs, heads * d)),
                         jnp.float32) for _ in range(2))
 
 
@@ -271,10 +273,10 @@ def _dense(q, k, v, window):
 @pytest.mark.parametrize("use_pallas", [False, True])
 def test_chunk_and_decode_rows_with_head_groups(window, use_pallas):
     rng = np.random.default_rng(11)
-    bs, d, hkv, H = 8, 16, 2, 4
+    bs, d, hkv, H = 8, 64, 2, 4
     k_pool, v_pool = _pool(rng, 12, hkv, bs, d)
     table = jnp.asarray([3, 7, 1, 9, 5], jnp.int32)      # 40 positions
-    flat = lambda p: jnp.swapaxes(p[table], 1, 2).reshape(-1, hkv, d)  # noqa: E731
+    flat = lambda p: p[table].reshape(-1, hkv, d)  # noqa: E731
     k, v = flat(k_pool), flat(v_pool)
     # a chunk of 16 rows, 13 of them real, ending at position 36
     q = jnp.asarray(rng.normal(size=(16, H, d)), jnp.float32)
@@ -302,7 +304,7 @@ def test_chunk_and_decode_rows_with_head_groups(window, use_pallas):
 
 def test_window_none_is_todays_kernel_bit_for_bit():
     rng = np.random.default_rng(5)
-    bs, d, H, bq = 8, 16, 2, 8
+    bs, d, H, bq = 8, 64, 2, 8
     k_pool, v_pool = _pool(rng, 9, H, bs, d)
     q = jnp.asarray(rng.normal(size=(3 * bq, H, d)), jnp.float32)
     args = (q, k_pool, v_pool,

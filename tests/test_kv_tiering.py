@@ -54,7 +54,9 @@ def _cache(dtype="float32", num_blocks=8, **kw):
 def _pattern(cache, n_blocks, seed):
     """Deterministic per-layer K/V (and scale) payloads for n blocks."""
     rng = np.random.RandomState(seed)
-    shape = (n_blocks, cache.num_heads, cache.block_size, cache.head_dim)
+    # blocks as they lie in the pool: [bs, H * D]
+    shape = (n_blocks, cache.block_size,
+             cache.num_heads * cache.head_dim)
     if cache.quantized:
         k = [rng.randint(-127, 128, size=shape).astype(np.int8)
              for _ in range(cache.num_layers)]
@@ -144,6 +146,65 @@ def test_spill_evict_promote_bit_identical_f32():
 
 def test_spill_evict_promote_bit_identical_int8():
     _spill_roundtrip("int8")
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+def test_exported_block_serves_the_same_tokens(dtype):
+    """A sequence written by the step's scatter, exported block by
+    block with `kv_blocks_gather` and imported into other blocks of
+    another pool with `kv_blocks_scatter`: a block travels as it lies
+    (``[block_size, H * D]``, a token a row), and attention over the
+    second pool gives every decode and prefill row what it gave over
+    the first."""
+    import jax.numpy as jnp
+    from paddle_tpu.inference.serving import kv_blocks_gather
+    from paddle_tpu.inference.serving.attention import (
+        _kv_scatter_impl, _kv_scatter_quant_impl, _ragged_ref)
+    from paddle_tpu.ops.pallas_ragged import ragged_segments
+    rng = np.random.RandomState(4)
+    H, D, bs, n = 2, 4, 4, 14
+    a, b = _cache(dtype=dtype), _cache(dtype=dtype, num_blocks=12)
+    assert a.allocate("s", n)
+    assert b.allocate("other", 9) and b.allocate("s", n)
+    assert a._tables["s"] != b._tables["s"]
+    new = [jnp.asarray(rng.randn(1, n, H, D), jnp.float32) for _ in "kv"]
+    slots = jnp.asarray(a.slot_mapping("s", 0, n), jnp.int32)
+    for layer in range(a.num_layers):
+        pools = [t._value for t in a.layer_pools(layer)]
+        if a.quantized:
+            scales = [t._value for t in a.layer_scales(layer)]
+            written = _kv_scatter_quant_impl(*pools, *scales, *new, slots)
+        else:
+            written = _kv_scatter_impl(*pools, *new, slots)
+        for t, value in zip(a.layer_pools(layer)
+                            + (a.layer_scales(layer) or ()), written):
+            t._inplace_update(value)
+    # token 5 of the sequence is row 5 % bs of its block, heads side by
+    # side, in the pool and on the host alike
+    k, v, ks, vs = kv_blocks_gather(a, a._tables["s"])
+    assert k[0].shape == (len(a._tables["s"]), bs, H * D)
+    if not a.quantized:
+        np.testing.assert_array_equal(
+            np.asarray(k[0])[5 // bs, 5 % bs],
+            np.asarray(new[0])[0, 5].reshape(H * D))
+    host = [part and [np.asarray(x) for x in part]
+            for part in (k, v, ks, vs)]
+    kv_blocks_scatter(b, b._tables["s"], *host)
+
+    # the last three tokens as a prefill chunk, then one decode row
+    sid, qs, qv, _, rows = ragged_segments([3], [n], 8)
+    q = jnp.asarray(rng.randn(rows, H, D), jnp.float32)
+
+    def attend(cache):
+        kp, vp = (t._value for t in cache.layer_pools(0))
+        sc = cache.layer_scales(0)
+        return np.asarray(_ragged_ref(
+            q, kp, vp, jnp.asarray([cache.block_table("s")], jnp.int32),
+            jnp.asarray([n], jnp.int32), jnp.asarray(sid), jnp.asarray(qs),
+            jnp.asarray(qv), 8, 0.5,
+            k_scales=sc and sc[0]._value, v_scales=sc and sc[1]._value))
+    assert np.abs(attend(a)[:3]).max() > 0.05
+    np.testing.assert_array_equal(attend(a), attend(b))
 
 
 def test_host_tier_is_host_line_item_not_device_charge():

@@ -262,7 +262,7 @@ def test_gpt_takes_its_geometry_from_the_cache_spec():
                                  "head_dim": 8}] * 2
     eng = GenerationEngine(gpt, max_batch=2, num_blocks=8, block_size=8)
     k, _ = eng.cache.layer_pools(1)
-    assert k.shape == [9, 4, 8, 8] and eng.cache.state_slots == 0
+    assert k.shape == [9, 8, 4 * 8] and eng.cache.state_slots == 0
     assert eng.cache.prefix_cache
     eng.close()
 
@@ -394,7 +394,7 @@ def test_grouped_decode_reads_the_selected_blocks_only():
     five: against plain attention over exactly those tokens."""
     rng = np.random.default_rng(9)
     q = jnp.asarray(rng.standard_normal((2, 8, 16)), jnp.float32)
-    pools = [jnp.asarray(rng.standard_normal((5, 2, 4, 16)), jnp.float32)
+    pools = [jnp.asarray(rng.standard_normal((5, 4, 2 * 16)), jnp.float32)
              for _ in range(2)]
     tables = jnp.array([[[3, 1], [4, 2]], [[2, 0], [0, 0]]], jnp.int32)
     ctx = jnp.array([[6, 7], [3, 0]], jnp.int32)     # row 1, head 1 idles
@@ -406,7 +406,8 @@ def test_grouped_decode_reads_the_selected_blocks_only():
             if not n:
                 assert not out[r, 4 * g:4 * g + 4].any()
                 continue
-            k, v = (np.concatenate([np.asarray(p[b, g]) for b in
+            k, v = (np.concatenate([np.asarray(p[b, :, 16 * g:16 * g + 16])
+                                    for b in
                                     np.asarray(tables[r, g])])[:n]
                     for p in pools)
             s = np.asarray(q[r, 4 * g:4 * g + 4]) @ k.T / 4.0

@@ -473,10 +473,10 @@ def test_xent_edge_mask_is_elided_when_aligned():
 # Ragged mixed prefill+decode attention (pallas_ragged)
 # ---------------------------------------------------------------------
 def _ragged_case(query_lens, context_lens, dtype, seed=30, H=4, D=32,
-                 bs=16, W=4, pad_blocks=0, int8=False):
-    """Build a ragged batch + paged pool and return (kernel, fallback)
-    outputs at the given dtype (``int8``: an int8 pool with per-slot
-    scales under queries of ``dtype``)."""
+                 bs=16, W=4, pad_blocks=0, int8=False, window=None):
+    """Build a ragged batch + paged pool ``[nb, bs, H * D]`` and return
+    (kernel, fallback) outputs at the given dtype (``int8``: an int8
+    pool with per-slot scales under queries of ``dtype``)."""
     from paddle_tpu.inference.serving.attention import _ragged_ref
     from paddle_tpu.ops import pallas_ragged as pr
 
@@ -495,14 +495,14 @@ def _ragged_case(query_lens, context_lens, dtype, seed=30, H=4, D=32,
     scales = {}
     if int8:
         k_pool, v_pool = (jax.random.randint(
-            k, (nb, H, bs, D), -127, 128, jnp.int32).astype(jnp.int8)
+            k, (nb, bs, H * D), -127, 128, jnp.int32).astype(jnp.int8)
             for k in (kk, kv))
         for name, k in zip(("k_scales", "v_scales"), jax.random.split(ks)):
             scales[name] = jax.random.uniform(
                 k, (nb, bs, pr.KV_SCALE_LANES), jnp.float32, 0.002, 0.02)
     else:
         k_pool, v_pool = (jax.random.normal(
-            k, (nb, H, bs, D), jnp.float32).astype(dtype)
+            k, (nb, bs, H * D), jnp.float32).astype(dtype)
             for k in (kk, kv))
     tables = np.zeros((S, W), np.int32)
     for s, ctx in enumerate(context_lens):
@@ -514,27 +514,29 @@ def _ragged_case(query_lens, context_lens, dtype, seed=30, H=4, D=32,
     scale = 1.0 / D ** 0.5
     out = pr.ragged_paged_attention(q, k_pool, v_pool, bt, cl, sid, qs,
                                     qv, block_q=block_q, scale=scale,
-                                    **scales)
+                                    window=window, **scales)
     ref = _ragged_ref(q, k_pool, v_pool, bt, cl, sid, qs, qv, block_q,
-                      scale, **scales)
+                      scale, window=window, **scales)
     return np.asarray(out, np.float32), np.asarray(ref, np.float32)
 
 
 def _grouped_case(form, dtype, contexts, seed=31, kv_heads=2, group=2,
-                  D=32, bs=16, W=8, window=None):
+                  D=64, bs=16, W=8, window=None):
     """The grouped engines' calls (`serving.attention`), kernel against
     fallback: ``decode`` rows of one token whose ``group`` heads go as
     the rows of a q-block (``block_tokens`` 1), a ``chunk`` of 48 tokens
     in q-blocks of 16 tokens x ``group`` heads, or ``selected`` decode
     rows (MiniCPM-SALA's form) over tables that hold a choice of the
-    context's blocks in an order of their own."""
+    context's blocks in an order of their own.  At the default width
+    the two KV heads share one 128-lane window of a pool row: a decode
+    row's program picks its head's half, a chunk's computes both."""
     from paddle_tpu.inference.serving import attention as att
     S = len(contexts)
     rng = np.random.default_rng(seed)
     nb = S * W + 1
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
     k_pool, v_pool = (jax.random.normal(
-        k, (nb, kv_heads, bs, D), jnp.float32).astype(dtype)
+        k, (nb, bs, kv_heads * D), jnp.float32).astype(dtype)
         for k in (kk, kv))
     tables = 1 + np.arange(S * W, dtype=np.int32).reshape(S, W)
     if form == "chunk":
@@ -586,12 +588,25 @@ _RAGGED_CASES = {
     "walk_chunk": _case(_ragged_case, [40, 1], [530, 3], W=40),
     # a table four times wider than the longest context
     "wide_table": _case(_ragged_case, [1, 9, 1], [60, 33, 64], W=16),
-    # 64-wide heads: two keys a 128-lane row of the pool (GPT-2's form)
+    # 64-wide heads: two heads share a 128-lane window of a pool row
+    # (GPT-2's form), and a program computes both
     "packed_lanes": _case(_ragged_case, [20, 1, 1], [52, 41, 16], D=64),
+    # one window alone: an even and an odd head side by side
+    "shared_window": _case(_ragged_case, [20, 1, 1], [52, 41, 16], D=64,
+                           H=2),
+    "shared_window_windowed": _case(_ragged_case, [40, 1], [530, 70],
+                                    D=64, H=2, W=40, window=50),
+    # heads of whole 128-lane tiles: a window a head
+    "wide_heads_128": _case(_ragged_case, [20, 1, 1], [52, 41, 16], D=128,
+                            H=2),
+    "wide_heads_256": _case(_ragged_case, [12, 1], [30, 9], D=256, H=2,
+                            bs=8, W=8),
     # the int8 pool: per-slot scales walked with the table
     "int8_pool": _case(_ragged_case, [12, 1, 1], [30, 25, 9], int8=True),
     "int8_pool_packed": _case(_ragged_case, [1, 1], [530, 64], int8=True,
                               D=64, W=40),
+    "int8_pool_wide": _case(_ragged_case, [12, 1, 1], [30, 25, 9],
+                            int8=True, D=128, H=2),
     # grouped KV heads: decode rows (block_tokens 1) and a chunk's head
     # groups (block_tokens 16), with no window and with one whose first
     # live block lies inside the table (and the context past one step)
@@ -601,9 +616,27 @@ _RAGGED_CASES = {
     "grouped_chunk": _case(_grouped_case, "chunk", contexts=[117]),
     "grouped_chunk_window": _case(_grouped_case, "chunk", contexts=[600],
                                   W=40, window=70),
-    # MiniCPM-SALA's decode rows: a selected table, no prefix
+    # the same at the cells' head widths, and at a width where the two
+    # KV heads share a window and a decode row's program picks its half
+    "grouped_decode_128": _case(_grouped_case, "decode", D=128,
+                                contexts=[100, 7, 128]),
+    "grouped_decode_256_window": _case(_grouped_case, "decode", D=256,
+                                       W=40, contexts=[600, 530, 20],
+                                       window=70),
+    "grouped_chunk_128_window": _case(_grouped_case, "chunk", D=128,
+                                      contexts=[600], W=40, window=70),
+    "grouped_chunk_256": _case(_grouped_case, "chunk", D=256,
+                               contexts=[117]),
+    # four KV heads a window
+    "grouped_decode_32": _case(_grouped_case, "decode", D=32, kv_heads=4,
+                               contexts=[100, 7, 128]),
+    "grouped_chunk_32": _case(_grouped_case, "chunk", D=32, kv_heads=4,
+                              contexts=[117]),
+    # MiniCPM-SALA's decode rows: a selected table a KV head, no prefix
     "selected_table": _case(_grouped_case, "selected", group=8,
                             contexts=[0, 0, 0]),
+    "selected_table_128": _case(_grouped_case, "selected", group=8, D=128,
+                                contexts=[0, 0, 0]),
 }
 
 
@@ -627,7 +660,7 @@ def test_ragged_attention_reads_no_dead_block(window, block_tokens):
     fallback's over a pool whose dead blocks hold zeros."""
     from paddle_tpu.inference.serving.attention import _ragged_ref
     from paddle_tpu.ops import pallas_ragged as pr
-    H, D, bs, W, block_q = 2, 32, 16, 40, 16
+    H, D, bs, W, block_q = 4, 32, 16, 40, 16
     if block_tokens == 1:       # decode rows, the walk past one step
         contexts, lens = [600, 37, 513], [1, 1, 1]
     else:                       # a chunk of three q-blocks and a row
@@ -643,7 +676,7 @@ def test_ragged_attention_reads_no_dead_block(window, block_tokens):
         live[sid[i], first // bs:last // bs + 1] = True
     dead = S * W + 1                                    # the NaN block
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(5), 3)
-    pools = [np.array(jax.random.normal(k, (S * W + 2, H, bs, D),
+    pools = [np.array(jax.random.normal(k, (S * W + 2, bs, H * D),
                                         jnp.float32))
              for k in (kk, kv)]
     for pool in pools:
@@ -677,6 +710,48 @@ def test_ragged_attention_null_segments_emit_zeros():
     # pad segments and must come back as exact zeros
     assert out.shape[0] == 3 * block_q
     assert float(np.abs(out[block_q:]).sum()) == 0.0
+
+
+@pytest.mark.parametrize("head_dim, kv_heads, ok", [
+    (64, 20, True),      # GPT-2: two heads a 128-lane window
+    (64, 3, False),      # the last window would pass the row's end
+    (32, 4, True), (32, 2, False),
+    (128, 1, True), (128, 4, True), (256, 2, True),
+    (96, 4, False),      # neither whole tiles nor a divisor of one
+])
+def test_pool_copyable_is_the_rule_of_the_lane_window(head_dim, kv_heads,
+                                                      ok):
+    """What the walk's copies can name in a pool row of ``H * D`` lanes:
+    a head of whole 128-lane tiles, or whole heads a tile and whole
+    tiles a row; a call outside the rule is refused by its shapes."""
+    from paddle_tpu.ops import pallas_ragged as pr
+    assert pr.pool_copyable(head_dim, kv_heads) is ok
+    if ok or head_dim == 96:
+        return
+    q = jnp.zeros((8, kv_heads, head_dim), jnp.float32)
+    pool = jnp.zeros((3, 8, kv_heads * head_dim), jnp.float32)
+    one = jnp.zeros((1,), jnp.int32)
+    with pytest.raises(ValueError, match="lane"):
+        pr.ragged_paged_attention(q, pool, pool, jnp.zeros((1, 2), jnp.int32),
+                                  one, one, one, one, block_q=8)
+
+
+def test_ragged_attention_refuses_a_pool_of_another_width():
+    """``q``'s heads times its width are the pool's row, unless each
+    q-block names its one head (``head_ids``, ``q`` [T, 1, D])."""
+    from paddle_tpu.ops import pallas_ragged as pr
+    pool = jnp.zeros((3, 8, 4 * 64), jnp.float32)
+    one = jnp.zeros((1,), jnp.int32)
+    table = jnp.zeros((1, 2), jnp.int32)
+    for heads, ids in ((2, None), (4, one)):
+        q = jnp.zeros((8, heads, 64), jnp.float32)
+        with pytest.raises(ValueError, match="lane"):
+            pr.ragged_paged_attention(q, pool, pool, table, one, one, one,
+                                      one, block_q=8, head_ids=ids)
+    out = pr.ragged_paged_attention(
+        jnp.zeros((8, 1, 64), jnp.float32), pool, pool, table, one, one,
+        one, one, block_q=8, head_ids=one + 3)
+    assert out.shape == (8, 1, 64)
 
 
 def test_ragged_segments_layout():

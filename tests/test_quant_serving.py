@@ -131,9 +131,9 @@ def _ragged_case(seed=0):
     H, D, bs, W, S, NB = 4, 64, 16, 4, 3, 16
     bq = pr.ragged_q_block(jnp.float32)
     q = jnp.asarray(rng.normal(size=(3 * bq, H, D)).astype(np.float32))
-    kp = jnp.asarray(rng.integers(-127, 128, size=(NB, H, bs, D)),
+    kp = jnp.asarray(rng.integers(-127, 128, size=(NB, bs, H * D)),
                      jnp.int8)
-    vp = jnp.asarray(rng.integers(-127, 128, size=(NB, H, bs, D)),
+    vp = jnp.asarray(rng.integers(-127, 128, size=(NB, bs, H * D)),
                      jnp.int8)
     lanes = pr.KV_SCALE_LANES
     ks = jnp.asarray(rng.uniform(0.01, 0.1, size=(NB, bs, lanes))
@@ -150,8 +150,8 @@ def _ragged_case(seed=0):
 
 def test_int8_ragged_kernel_bit_matches_float_kernel_on_dequant():
     bq, q, kp, vp, ks, vs, bt, cl, sid, qs, qv = _ragged_case()
-    kf = kp.astype(jnp.float32) * ks[:, None, :, :1]
-    vf = vp.astype(jnp.float32) * vs[:, None, :, :1]
+    kf = kp.astype(jnp.float32) * ks[..., :1]
+    vf = vp.astype(jnp.float32) * vs[..., :1]
     out_i8 = pr.ragged_paged_attention(q, kp, vp, bt, cl, sid, qs, qv,
                                        k_scales=ks, v_scales=vs)
     out_f = pr.ragged_paged_attention(q, kf, vf, bt, cl, sid, qs, qv)
@@ -160,8 +160,8 @@ def test_int8_ragged_kernel_bit_matches_float_kernel_on_dequant():
 
 def test_int8_ragged_fallback_bit_matches_float_fallback_on_dequant():
     bq, q, kp, vp, ks, vs, bt, cl, sid, qs, qv = _ragged_case(1)
-    kf = kp.astype(jnp.float32) * ks[:, None, :, :1]
-    vf = vp.astype(jnp.float32) * vs[:, None, :, :1]
+    kf = kp.astype(jnp.float32) * ks[..., :1]
+    vf = vp.astype(jnp.float32) * vs[..., :1]
     scale = float(q.shape[-1]) ** -0.5
     ref = jax.jit(functools.partial(_ragged_ref, block_q=bq,
                                     scale=scale))
@@ -191,7 +191,7 @@ def test_scatter_quant_deterministic_and_bounded():
     error."""
     rng = np.random.default_rng(3)
     NB, H, bs, D, lanes = 4, 2, 4, 8, pr.KV_SCALE_LANES
-    kp = jnp.zeros((NB, H, bs, D), jnp.int8)
+    kp = jnp.zeros((NB, bs, H * D), jnp.int8)
     ks = jnp.zeros((NB, bs, lanes), jnp.float32)
     new = jnp.asarray(rng.normal(size=(5, H, D)).astype(np.float32))
     slots = jnp.asarray([4, 5, 6, 7, 8], jnp.int32)
@@ -203,7 +203,7 @@ def test_scatter_quant_deterministic_and_bounded():
     qk, sk = np.asarray(qk), np.asarray(sk)
     assert np.abs(qk).max() <= 127
     for i, s in enumerate([4, 5, 6, 7]):
-        tok = qk[s // bs, :, s % bs, :].astype(np.float32) \
+        tok = qk[s // bs, s % bs].reshape(H, D).astype(np.float32) \
             * sk[s // bs, s % bs, 0]
         np.testing.assert_allclose(tok, np.asarray(new[i]),
                                    atol=np.abs(np.asarray(new[i])).max()

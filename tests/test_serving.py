@@ -168,7 +168,7 @@ def test_paged_attention_matches_dense():
     vd = rng.randn(len(ctxs), max(ctxs), H, D).astype(np.float32)
     q = rng.randn(len(ctxs), 1, H, D).astype(np.float32)
     # scatter the dense K/V into a pool via per-sequence block tables
-    kp = np.zeros((16, H, bs, D), np.float32)
+    kp = np.zeros((16, bs, H * D), np.float32)
     vp = np.zeros_like(kp)
     tables = np.zeros((len(ctxs), W), np.int32)
     nxt = 1
@@ -178,8 +178,8 @@ def test_paged_attention_matches_dense():
                 tables[i, t // bs] = nxt
                 nxt += 1
             blk, off = tables[i, t // bs], t % bs
-            kp[blk, :, off] = kd[i, t]
-            vp[blk, :, off] = vd[i, t]
+            kp[blk, off] = kd[i, t].reshape(-1)
+            vp[blk, off] = vd[i, t].reshape(-1)
     # one token a sequence: each fills row 0 of a q-block of its own
     sid, qs, qv, offsets, rows = ragged_segments([1] * len(ctxs), ctxs,
                                                  block_q)

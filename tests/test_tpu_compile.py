@@ -155,7 +155,7 @@ def _ragged(kv_dtype):
     nqb, width = tokens // bq, 1024 // bs
     # 0.3 of a 16 GB chip over 12 layers of 12x64 bf16 K+V blocks
     nb = int(0.3 * 16e9) // (2 * 12 * H * bs * D * 2) + 1
-    pool = ((nb, H, bs, D), kv_dtype)
+    pool = ((nb, bs, H * D), kv_dtype)
     args = [((tokens, H, D), bf16), pool, pool, ((seqs, width), i32),
             ((seqs,), i32), ((nqb,), i32), ((nqb,), i32), ((nqb,), i32)]
     if kv_dtype == i8:
@@ -171,7 +171,7 @@ def _ragged_gpt2_large():
     """`gpt2-large.decode-closed32`: 20 heads of 64, blocks of 16, a
     table of 64 slots, 47 q-blocks of 16 rows a step."""
     heads, d, bs, width, seqs, nqb = 20, 64, 16, 64, 32, 47
-    pool = ((2049, heads, bs, d), bf16)
+    pool = ((2049, bs, heads * d), bf16)
     return (pr.ragged_paged_attention,
             [((nqb * 16, heads, d), bf16), pool, pool,
              ((seqs, width), i32), ((seqs,), i32), ((nqb,), i32),
@@ -185,7 +185,7 @@ def _ragged_grouped(form, d, kv_heads, width, num_blocks, window=None):
     chunk in q-blocks of 128 tokens x 8 heads, over blocks of 64."""
     from paddle_tpu.inference.serving import attention as att
     seqs, group, bs, chunk = 32, 8, 64, 1024
-    pool = ((num_blocks, kv_heads, bs, d), bf16)
+    pool = ((num_blocks, bs, kv_heads * d), bf16)
     if form == "decode":
         def fn(q, kp, vp, tables, ctx):
             return att.grouped_decode_attention(
@@ -362,7 +362,7 @@ def _sparse_decode():
     from paddle_tpu.inference.serving.attention import (
         grouped_decode_attention)
     width = pls.SparseSizes().table_width(20480)
-    pool = ((10241, 2, 64, SALA_D), bf16)
+    pool = ((10241, 64, 2 * SALA_D), bf16)
     return (functools.partial(grouped_decode_attention, use_pallas=True),
             [((SALA_ROWS, SALA_H, SALA_D), bf16), pool, pool,
              ((SALA_ROWS, 2, width), i32), ((SALA_ROWS, 2), i32)])
@@ -414,7 +414,7 @@ def _trinity_experts():
 def _trinity_decode(window, blocks, width):
     from paddle_tpu.inference.serving.attention import (
         grouped_decode_attention)
-    pool = ((blocks + 1, TRI_KV, 64, TRI_D), bf16)
+    pool = ((blocks + 1, 64, TRI_KV * TRI_D), bf16)
     return (functools.partial(grouped_decode_attention, use_pallas=True,
                               window=window, block_q=16),
             [((TRI_ROWS, TRI_H, TRI_D), bf16), pool, pool,
@@ -424,7 +424,7 @@ def _trinity_decode(window, blocks, width):
 def _trinity_chunk(window, blocks, width):
     from paddle_tpu.inference.serving.attention import (
         grouped_chunk_attention)
-    pool = ((blocks + 1, TRI_KV, 64, TRI_D), bf16)
+    pool = ((blocks + 1, 64, TRI_KV * TRI_D), bf16)
     return (functools.partial(grouped_chunk_attention, window=window,
                               chunk_bq=128, use_pallas=True),
             [((1024, TRI_H, TRI_D), bf16), pool, pool, ((width,), i32)]
@@ -506,7 +506,7 @@ def _held_experts():
 def _wide_decode():
     from paddle_tpu.inference.serving.attention import (
         grouped_decode_attention)
-    pool = ((13313, 2, 64, 256), bf16)
+    pool = ((13313, 64, 2 * 256), bf16)
     return (functools.partial(grouped_decode_attention, use_pallas=True,
                               block_q=16),
             [((TRI_ROWS, 16, 256), bf16), pool, pool,
@@ -516,7 +516,7 @@ def _wide_decode():
 def _wide_chunk():
     from paddle_tpu.inference.serving.attention import (
         grouped_chunk_attention)
-    pool = ((13313, 2, 64, 256), bf16)
+    pool = ((13313, 64, 2 * 256), bf16)
     return (functools.partial(grouped_chunk_attention, window=None,
                               chunk_bq=128, use_pallas=True),
             [((1024, 16, 256), bf16), pool, pool, ((416,), i32)]
@@ -555,6 +555,100 @@ def test_qwen3_next_kernel_compiles_for_v5e(one_chip, name):
         r"bf16\[128,(?:2048|512),(?:2048|1024)\](?:\{[^}]*\})? "
         r"(?:copy|pad|concatenate|transpose)\(", text)]
     assert not moved, moved
+
+
+# -- a layer's scatter and attention on donated pools, a serving cell ---
+# The pools are [num_blocks, block_size, H_kv * D]: the scatter writes
+# rows of them in place and the ragged kernel copies lane windows out of
+# the same arrays, so no step relays a pool (PERF.md section 6, PR 38).
+def _pool_layer(form, d, kv_heads, width, blocks, window=None, group=8):
+    """(function, arguments, elements of a pool) of `_kv_scatter_impl`
+    then one attention call of a step, as the engine makes them:
+    ``mixed`` (GPT-2's one call: 47 q-blocks of 16 rows), ``decode`` (32
+    rows whose ``group`` heads a KV head are a q-block's rows, a table a
+    (row, KV head) pair) or ``chunk`` (1,024 tokens in head groups)."""
+    from paddle_tpu.inference.serving import attention as att
+    seqs, bs = 32, 16 if form == "mixed" else 64
+    tokens = 752 if form == "mixed" else 1520
+    pool = ((blocks, bs, kv_heads * d), bf16)
+    new = ((1, tokens, kv_heads, d), bf16)
+    if form == "mixed":
+        def attend(q, kp, vp, bt, cl, sid, qs, qv):
+            return pr.ragged_paged_attention(q, kp, vp, bt, cl, sid, qs, qv)
+        rest = [((seqs, width), i32), ((seqs,), i32)] + [((47,), i32)] * 3
+        q = ((tokens, kv_heads, d), bf16)
+    elif form == "decode":
+        def attend(q, kp, vp, tables, ctx):
+            return att.grouped_decode_attention(
+                q, kp, vp, tables, ctx, True, window=window,
+                block_q=att.decode_block_q(group, bf16))
+        rest = [((seqs, kv_heads, width), i32), ((seqs, kv_heads), i32)]
+        q = ((seqs, group * kv_heads, d), bf16)
+    else:
+        def attend(q, kp, vp, table, ctx, start, valid):
+            return att.grouped_chunk_attention(
+                q, kp, vp, table, ctx, start, valid, window=window,
+                chunk_bq=128, use_pallas=True)
+        rest = [((width,), i32)] + [((), i32)] * 3
+        q = ((1024, group * kv_heads, d), bf16)
+
+    def layer(q, kp, vp, kn, vn, slots, *rest):
+        kp, vp = att._kv_scatter_impl(kp, vp, kn, vn, slots)
+        return attend(q, kp, vp, *rest), kp, vp
+    return (layer, [q, pool, pool, new, new, ((tokens,), i32)] + rest,
+            blocks * bs * kv_heads * d)
+
+
+POOL_LAYER_CASES = {
+    "gpt2_large_mixed": ("mixed", 64, 20, 64, 2049),
+    "trinity_full_decode": ("decode", 128, 4, 224, 7169),
+    "trinity_full_chunk": ("chunk", 128, 4, 224, 7169),
+    "trinity_window_decode": ("decode", 128, 4, 34, 1601, 2048),
+    "trinity_window_chunk": ("chunk", 128, 4, 50, 1601, 2048),
+    # two KV heads of 128, a selected table of 128 blocks, 16 heads a pair
+    "minicpm_sala_selected_decode": ("decode", 128, 2, 128, 10241, None,
+                                     16),
+    "qwen3_next_decode": ("decode", 256, 2, 416, 13313),
+    "qwen3_next_chunk": ("chunk", 256, 2, 416, 13313),
+}
+
+
+@pytest.mark.parametrize("case", list(POOL_LAYER_CASES))
+def test_scatter_and_ragged_kernel_leave_the_pools_where_they_lie(
+        one_chip, case):
+    """`_kv_scatter_impl` then the ragged call on donated pools, at each
+    serving cell's geometry: the call keeps its name, the module aliases
+    both pools to its results, and the optimised text holds no copy and
+    no transpose of a whole pool."""
+    import chip_smoke
+    fn, args, elements = _pool_layer(*POOL_LAYER_CASES[case])
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in args]
+    with jax.enable_x64(False):
+        text = jax.jit(fn, donate_argnums=(1, 2)).lower(
+            *avals).compile().as_text()
+    assert chip_smoke.mosaic_kernels(text) == {"ragged_attention_fwd": 1}
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    assert alias and "{1}: (1," in alias.group(1) \
+        and "{2}: (2," in alias.group(1), text[:400]
+    moves = chip_smoke.pool_sized_moves(text, elements)
+    assert not moves, moves
+
+
+def test_pool_sized_moves_sees_a_relaid_pool(one_chip):
+    """The guard's own control: the scatter into a head-major pool
+    ``[nb, H, bs, D]`` (the layout before PR 38) makes XLA copy the
+    whole donated pool into the scatter's layout and back."""
+    import chip_smoke
+
+    def scatter(pool, new, slots):
+        return pool.at[slots // 16, :, slots % 16, :].set(new)
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((2049, 20, 16, 64), bf16), ((752, 20, 64), bf16), ((752,), i32))]
+    with jax.enable_x64(False):
+        text = jax.jit(scatter, donate_argnums=(0,)).lower(
+            *avals).compile().as_text()
+    assert chip_smoke.pool_sized_moves(text, 2049 * 20 * 16 * 64)
 
 
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
