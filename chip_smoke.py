@@ -1155,6 +1155,145 @@ def ragged_walk(calls, fills=(0.25, 1.0), **sizes):
     return {"kernels": dict(kernels), "checked": checked}
 
 
+#: `latent_walk`'s geometry: the LongCat-Flash cell's (32 rows, tables
+#: of 272 blocks of 64 tokens, 64 heads, a latent row of 512 + 64 lanes
+#: in five 128-lane tiles, 128 + 64 query lanes a head, values of 128)
+LATENT_WALK = dict(rows=32, width=272, bs=64, heads=64, rank=512, rope=64,
+                   nope=128, value=128, chunk=1024)
+
+
+def latent_walk(fills=(0.25, 1.0), rows=32, width=272, bs=64, heads=64,
+                rank=512, rope=64, nope=128, value=128, chunk=1024):
+    """The latent attention kernel alone at the LongCat-Flash cell's
+    shapes, over tables filled to each of ``fills``: the decode rows'
+    call and a 1,024-token chunk at the table's end in the absorbed form
+    (``latent_attention_fwd``) against `attention._latent_ref`, their
+    device times, the scatter then the decode call on a donated pool,
+    and the chunk in the other form, the context's latents up-projected
+    a head (``W_UK``, ``W_UV``) and attended at widths ``nope + rope`` /
+    ``value`` by XLA, for the same chunk: the like-for-like pair the
+    chunk's form is chosen by."""
+    import jax.numpy as jnp
+    from paddle_tpu.inference.serving import attention as att
+    lanes = -(-(rank + rope) // 128) * 128
+    tables = 1 + np.arange(rows * width, dtype=np.int32).reshape(rows, width)
+    kp, kq, kw, kn = jax.random.split(jax.random.PRNGKey(SEED), 4)
+    w_ukv = (0.05 * jax.random.normal(
+        kw, (rank, heads, nope + value), jnp.float32)).astype(jnp.bfloat16)
+    scale = (nope + rope) ** -0.5
+    ct = att.latent_chunk_tokens(heads, chunk)
+    checked, kernels = {}, Counter()
+    for fill in fills:
+        # fresh each fill: the last call of a fill donates it
+        pool = jnp.pad(jax.random.normal(
+            kp, (rows * width + 1, bs, rank + rope), jnp.bfloat16),
+            ((0, 0), (0, 0), (0, lanes - rank - rope)))
+        ctx = max(int(fill * width) * bs - bs // 2, chunk)
+        used = -(-ctx // bs)
+        full = jnp.asarray(tables)
+        cut = jnp.asarray(tables[:, :used])
+
+        def decode(q, pool, tab, use_pallas):
+            c = jnp.full((rows,), ctx, jnp.int32)
+            return att._latent_call(
+                use_pallas, q, pool, tab, c, jnp.arange(rows, dtype=jnp.int32),
+                c - 1, jnp.ones((rows,), jnp.int32), block_q=heads,
+                block_tokens=1, value_lanes=rank, scale=scale)
+
+        def absorbed(q, pool, tab, use_pallas):
+            nqb = chunk // ct
+            first = jnp.arange(nqb, dtype=jnp.int32) * ct
+            return att._latent_call(
+                use_pallas, q, pool, tab[:1], jnp.full((1,), ctx, jnp.int32),
+                jnp.zeros((nqb,), jnp.int32), ctx - chunk + first,
+                jnp.full((nqb,), ct, jnp.int32), block_q=heads * ct,
+                block_tokens=ct, value_lanes=rank, scale=scale)
+
+        def projected(q, pool, tab):
+            """The chunk's queries ``[C, H, nope + rope]`` against the
+            context's keys and values up-projected a head."""
+            rows_ = pool[tab[0]].reshape(-1, lanes)[:ctx]
+            c, kr = rows_[:, :rank], rows_[:, rank:rank + rope]
+            kv = jnp.einsum("sc,chd->shd", c, w_ukv,
+                            preferred_element_type=jnp.float32) \
+                .astype(jnp.bfloat16)
+            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+                kr[:, None], (ctx, heads, rope))], -1)
+            v = kv[..., nope:]
+            pos = jnp.arange(ctx)
+
+            def block(args):
+                qb, tb = args
+                s = jnp.einsum("qhd,shd->hqs", qb, k,
+                               preferred_element_type=jnp.float32) * scale
+                p = jax.nn.softmax(jnp.where(pos[None, None, :]
+                                             <= tb[None, :, None], s,
+                                             -1e30), -1)
+                return jnp.einsum("hqs,shv->qhv", p.astype(jnp.bfloat16), v,
+                                  preferred_element_type=jnp.float32)
+            qb = min(128, chunk)
+            t = ctx - chunk + jnp.arange(chunk)
+            return jax.lax.map(block, (q.reshape(-1, qb, heads, nope + rope),
+                                       t.reshape(-1, qb))).reshape(
+                chunk, heads, value).astype(jnp.bfloat16)
+
+        qd = jax.random.normal(kq, (rows * heads, lanes), jnp.bfloat16)
+        qc = jax.random.normal(kq, (chunk * heads, lanes), jnp.bfloat16)
+        qp = jax.random.normal(kn, (chunk, heads, nope + rope), jnp.bfloat16)
+        need = {"decode": rows * used,
+                "chunk": sum(min(used, (a + ct - 1) // bs + 1)
+                             for a in range(ctx - chunk, ctx, ct))}
+        for form, fn, q in (("decode", decode, qd),
+                            ("chunk", absorbed, qc)):
+            compiled = jax.jit(lambda q, p, t: fn(q, p, t, True)).lower(
+                q, pool, full).compile()
+            kernels.update(mosaic_kernels(compiled.as_text()))
+            got = jax.block_until_ready(compiled(q, pool, full))
+            entry = {"latent_blocks": need[form]}
+            if fill == fills[0]:
+                ref = jax.jit(lambda q, p, t: fn(q, p, t, False))(q, pool,
+                                                                  cut)
+                entry["rel_l2_vs_jnp"] = _rel_l2(got, ref)
+                check(entry["rel_l2_vs_jnp"] < BF16_REL_L2,
+                      f"latent {form}: kernel against its jnp form "
+                      f"{entry['rel_l2_vs_jnp']:.3g}")
+                del ref
+            ms = _device_ms(compiled, q, pool, full)
+            if ms is not None:
+                floor_us = need[form] * bs * (rank + rope) * 2 \
+                    / HBM_BYTES_PER_S * 1e6
+                entry.update(us=round(ms * 1e3, 1),
+                             floor_us=round(floor_us, 1))
+            checked[f"latent.{form}@{fill}"] = entry
+            del compiled, got
+        # the other form of the chunk, like for like
+        proj = jax.jit(projected).lower(qp, pool, full).compile()
+        jax.block_until_ready(proj(qp, pool, full))
+        ms = _device_ms(proj, qp, pool, full)
+        checked[f"projected.chunk@{fill}"] = {
+            "us": None if ms is None else round(ms * 1e3, 1)}
+        # the step's scatter (a chunk and 31 decode rows) then the
+        # decode rows' call, on the donated pool
+        slot = lambda r, t: int(tables[r, t // bs]) * bs + t % bs  # noqa: E731
+        slots = jnp.asarray([slot(0, t) for t in range(ctx - chunk, ctx)]
+                            + [slot(r, ctx - 1) for r in range(1, rows)],
+                            jnp.int32)
+        new = jax.random.normal(kn, (len(slots), lanes), jnp.bfloat16)
+
+        def layer(q, pool, new, slots):
+            pool = att._scatter_rows(pool, new, slots)
+            return decode(q, pool, full, True), pool
+        both = jax.jit(layer, donate_argnums=(1,)).lower(
+            qd, pool, new, slots).compile()
+        out = jax.block_until_ready(both(qd, pool, new, slots))
+        ms = _device_ms(both, qd, out[1], new, slots, donated=(1,))
+        if ms is not None:
+            checked[f"latent.decode@{fill}"]["scatter_kernel_us"] = round(
+                ms * 1e3, 1)
+        del both, out, proj, pool
+    return {"kernels": dict(kernels), "checked": checked}
+
+
 def afmoe_config(layers=(0, 1, 4), dtype=None):
     """The benchmark's Trinity-Mini file with the layer list cut to a
     dense sliding, an expert sliding and an expert full layer (depth is
@@ -1306,7 +1445,10 @@ def main(argv=None):
             ("sampler_gate", sampler_gate,
              (32, afmoe_config()["vocab_size"]), {}),
             # the ragged kernel alone, a serving step's call at a time
-            ("ragged_walk", ragged_walk, (RAGGED_WALK_CALLS,), {})]
+            ("ragged_walk", ragged_walk, (RAGGED_WALK_CALLS,), {}),
+            # the latent kernel alone and the chunk's other form, at the
+            # LongCat-Flash cell's shapes
+            ("latent_walk", latent_walk, (), LATENT_WALK)]
     unknown = set(args.phase or ()) - {name for name, *_ in phases}
     if unknown:
         sys.exit(f"no such phase with --chips {args.chips}: "

@@ -18,13 +18,17 @@ the stable argsort gives tokens of one expert their arrival order):
     the k choices per token.
 
 `sigmoid_topk_router` is the sigmoid router with a selection-only bias,
-`softmax_topk_router` the softmax router with renormalised top-k, and
-`gated_experts` strings plan, scatter, the gated grouped kernel, the
-down projection and the combine together for a SwiGLU expert layer
-(`models/afmoe.py`, `models/qwen3_next.py`).  A layer that holds only
-the experts ``held = (lo, hi)`` of those the router chooses among plans
-its own: every other assignment goes where the rows that carry nothing
-go, and the result is the part that its experts give.
+`softmax_topk_router` the softmax router with renormalised top-k,
+`softmax_bias_topk_router` a softmax router with a selection-only bias
+and a fixed scale, and `gated_experts` strings plan, scatter, the gated
+grouped kernel, the down projection and the combine together for a
+SwiGLU expert layer (`models/afmoe.py`, `models/qwen3_next.py`,
+`models/longcat_flash.py`).  A layer that holds only the experts ``held
+= (lo, hi)`` of those the router chooses among plans its own: every
+other assignment goes where the rows that carry nothing go, and the
+result is the part that its experts give.  Choices at or past
+``identity`` name *zero-compute experts*: the identity, with no weights
+and no plan row, whose weighted input joins the combine.
 
 Expert parallelism crosses the ``ep`` mesh axis with all-to-all.
 `ring_all_to_all_local` decomposes that collective into per-peer
@@ -47,7 +51,8 @@ from ...ops.pallas_tiles import group_segments, num_group_blocks
 __all__ = [
     "dropless_combine", "dropless_dispatch", "dropless_plan",
     "expert_imbalance", "gated_experts", "plan_counters",
-    "ring_all_to_all_local", "sigmoid_topk_router", "softmax_topk_router",
+    "ring_all_to_all_local", "sigmoid_topk_router",
+    "softmax_bias_topk_router", "softmax_topk_router",
 ]
 
 
@@ -84,6 +89,18 @@ def softmax_topk_router(logits, top_k, norm_topk=True):
     if norm_topk:
         w = w / w.sum(-1, keepdims=True)
     return idx.astype(jnp.int32), w
+
+
+def softmax_bias_topk_router(logits, bias, top_k, scale=1.0):
+    """A softmax router with a selection-only bias and no
+    renormalisation.  ``logits`` [N, E] float32, ``bias`` [E]; the
+    chosen set is the ``top_k`` largest of ``softmax(logits) + bias``
+    (ties to the lower index), a chosen expert's weight its probability,
+    bias left out, times ``scale``.  Returns ``(topk_idx, topk_weight)``
+    [N, top_k], int32 and float32."""
+    p = jax.nn.softmax(logits.astype(jnp.float32), -1)
+    _, idx = jax.lax.top_k(p + bias.astype(jnp.float32), top_k)
+    return idx.astype(jnp.int32), scale * jnp.take_along_axis(p, idx, -1)
 
 
 def dropless_plan(topk_idx, num_experts, block_rows, num_blocks=None,
@@ -165,20 +182,24 @@ def dropless_combine(y_rows, rows, topk_val):
         g.astype(jnp.float32)).astype(y_rows.dtype)
 
 
-def plan_counters(counts, block_rows, routed):
+def plan_counters(counts, block_rows, routed, zero=None):
     """What a plan dispatched, as int32 ``[assignments, experts
     touched, rows of the grouped buffer the kernel runs over (padding
     included), the fullest expert's rows, assignments routed]``: the
-    first four of the experts planned here, the last (``routed``) over
-    all the experts the router chose among."""
+    first four of the experts planned here, the fifth (``routed``) over
+    all the experts the router chose among; with ``zero`` a sixth, the
+    assignments to zero-compute experts."""
     blocks = (counts + block_rows - 1) // block_rows
-    return jnp.stack([counts.sum(), (counts > 0).sum(),
-                      blocks.sum() * block_rows,
-                      counts.max(), routed]).astype(jnp.int32)
+    parts = [counts.sum(), (counts > 0).sum(), blocks.sum() * block_rows,
+             counts.max(), routed]
+    if zero is not None:
+        parts.append(zero)
+    return jnp.stack(parts).astype(jnp.int32)
 
 
 def gated_experts(x, topk_idx, topk_weight, w_gate_up, w_down,
-                  carried=None, act="silu", use_pallas=False, held=None):
+                  carried=None, act="silu", use_pallas=False, held=None,
+                  identity=None):
     """The routed half of a gated expert layer on flat tokens: plan,
     scatter, ``act(x @ w_gate[e]) * (x @ w_up[e])`` then ``@ w_down[e]``
     through the grouped kernel (or its composite), weighted sum.
@@ -186,7 +207,10 @@ def gated_experts(x, topk_idx, topk_weight, w_gate_up, w_down,
     they lie.  ``held = (lo, hi)``: the stacks are experts ``lo ... hi -
     1`` of those ``topk_idx`` names (``E = hi - lo``), and ``y`` is the
     part of the layer's result that they give; without it the stacks
-    are all the experts.  Returns ``(y [N, D], plan_counters)``."""
+    are all the experts.  ``identity``: choices at or past it are
+    zero-compute experts, planned nowhere; ``y`` then also holds their
+    ``weight * x`` over the carried tokens, and the counters their
+    count.  Returns ``(y [N, D], plan_counters)``."""
     from ...ops import pallas_grouped as pg
     N, k = topk_idx.shape
     E = w_gate_up.shape[0]
@@ -204,8 +228,16 @@ def gated_experts(x, topk_idx, topk_weight, w_gate_up, w_down,
                             pg.grouped_linear_act_ref)
     h = gated(xd, w_gate_up, block_group=gid, act=act)
     y_rows = down(h, w_down, block_group=gid)
-    return (dropless_combine(y_rows, rows, topk_weight),
-            plan_counters(counts, bm, routed))
+    y = dropless_combine(y_rows, rows, topk_weight)
+    if identity is None:
+        return y, plan_counters(counts, bm, routed)
+    zero = topk_idx >= identity
+    if carried is not None:
+        zero &= carried[:, None]
+    kept = jnp.where(zero, topk_weight.astype(jnp.float32), 0.0).sum(-1)
+    y = (y.astype(jnp.float32) + kept[:, None] * x.astype(jnp.float32)) \
+        .astype(y.dtype)
+    return y, plan_counters(counts, bm, routed, zero.sum())
 
 
 def expert_imbalance(counts):

@@ -39,10 +39,13 @@ to the dense-cache path.
 ``RaggedCacheView`` adapts a PagedKVCache to the model's ``cache``
 argument (``models/gpt.py`` detects it by its ``attend`` /
 ``position_ids`` attributes) and owns the per-step driving Tensors.  It
-hands each layer one of three layer caches, chosen from the cache's
+hands each layer one of four layer caches, chosen from the cache's
 ``layer_specs``: ``RaggedLayerCache`` (paged K/V, scatter then ragged
 attention; with grouped KV heads or a ``window`` in its spec, the decode
-rows and the chunk apart, over its group's table), ``SparseLayerCache`` (paged K/V with grouped heads plus the
+rows and the chunk apart, over its group's table), ``LatentLayerCache``
+(latent attention: one row a token for all heads, scatter then the
+absorbed form through ``ops/pallas_latent``, the decode rows and the
+chunk apart), ``SparseLayerCache`` (paged K/V with grouped heads plus the
 selector's pooled keys in a state slot) and ``RecurrentLayerCache`` (no
 K/V, the layer's named states in the request's slot).  ``kv_blocks_gather`` /
 ``kv_blocks_scatter`` move whole pool blocks to and from the host
@@ -50,6 +53,7 @@ K/V, the layer's named states in the request's slot).  ``kv_blocks_gather`` /
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -63,7 +67,8 @@ from ...observability import block
 __all__ = ["kv_cache_scatter", "kv_cache_scatter_quant",
            "ragged_attention", "RaggedCacheView",
            "grouped_chunk_attention",
-           "RaggedLayerCache", "SparseLayerCache", "RecurrentLayerCache",
+           "RaggedLayerCache", "LatentLayerCache", "SparseLayerCache",
+           "RecurrentLayerCache", "latent_chunk_tokens",
            "grouped_decode_attention", "kv_blocks_gather",
            "kv_blocks_scatter"]
 
@@ -447,6 +452,158 @@ class RaggedLayerCache(_LayerCache):
                                 view.context_lens, view.seq_ids,
                                 view.q_starts, view.q_valids,
                                 view.block_q)
+
+
+# ---------------------------------------------------------------------
+# latent attention: one row a token for every head
+# ---------------------------------------------------------------------
+#: rows of a chunk's q-block in the latent kernel: its heads times
+#: `latent_chunk_tokens` tokens
+_LATENT_CHUNK_ROWS = 1024
+
+
+def latent_chunk_tokens(heads, chunk_rows):
+    """Tokens of a chunk's q-block in the latent kernel: all ``heads``
+    over the most tokens, a power of two that divides ``chunk_rows``,
+    that keep the q-block at `_LATENT_CHUNK_ROWS` rows (16 at 64
+    heads)."""
+    tokens = 1
+    while (2 * tokens * heads <= _LATENT_CHUNK_ROWS
+           and chunk_rows % (2 * tokens) == 0):
+        tokens *= 2
+    return tokens
+
+
+def _latent_ref(q, pool, block_tables, context_lens, seq_ids, q_starts,
+                q_valids, *, block_q, block_tokens, value_lanes, scale):
+    """Pure-XLA gather form of `pallas_latent.latent_attention`, the
+    same arguments and semantics: each q-block's table gathered whole,
+    float32 scores, ``-1e30`` mask, float32 softmax, a row that sees
+    nothing emits zeros."""
+    T, width = q.shape
+    nb, bs, _ = pool.shape
+    S, W = block_tables.shape
+    nqb = T // block_q
+    bt = jnp.concatenate([block_tables.astype(jnp.int32),
+                          jnp.zeros((1, W), jnp.int32)], axis=0)
+    cl = jnp.concatenate([context_lens.astype(jnp.int32),
+                          jnp.zeros((1,), jnp.int32)], axis=0)
+    sid = seq_ids.astype(jnp.int32)
+    rows = pool[bt[sid]].reshape(nqb, W * bs, width)
+    scores = jnp.einsum("nqc,nkc->nqk", q.reshape(nqb, block_q, width),
+                        rows, preferred_element_type=jnp.float32) * scale
+    token = jnp.arange(block_q, dtype=jnp.int32) % block_tokens
+    col = jnp.arange(W * bs, dtype=jnp.int32)
+    pos = q_starts.astype(jnp.int32)[:, None] + token[None, :]
+    visible = ((token[None, :, None]
+                < q_valids.astype(jnp.int32)[:, None, None])
+               & (col[None, None, :] <= pos[:, :, None])
+               & (col[None, None, :] < cl[sid][:, None, None]))
+    scores = jnp.where(visible, scores, jnp.asarray(_NEG_INF, scores.dtype))
+    probs = jax.nn.softmax(scores, axis=-1)
+    probs = jnp.where(jnp.any(visible, axis=-1, keepdims=True), probs, 0.0)
+    out = jnp.einsum("nqk,nkc->nqc", probs.astype(pool.dtype),
+                     rows[..., :value_lanes],
+                     preferred_element_type=jnp.float32)
+    return out.reshape(T, value_lanes).astype(q.dtype)
+
+
+def _latent_call(use_pallas, *args, **kw):
+    if use_pallas:
+        from ...ops.pallas_latent import latent_attention
+        return latent_attention(*args, **kw)
+    return _latent_ref(*args, **kw)
+
+
+def _latent_attend_impl(q, rows, pool, slots, tables, dec_index, row_pos,
+                        meta, *, chunk_rows, chunk_tokens, value_lanes,
+                        scale, use_pallas):
+    """One latent layer of the ragged step.  ``q`` [1, T, H, width] (the
+    absorbed queries), ``rows`` [1, T, width] (this step's latent rows).
+    Scatter the rows; the decode rows read their table through the
+    latent kernel, a row's heads as the rows of one q-block; the chunk
+    (skipped when the step carries none) in q-blocks of all its heads
+    over ``chunk_tokens`` tokens.  Returns ``([1, T, H, value_lanes],
+    pool)``.  The pool's rows are whole 128-lane tiles: rows and queries
+    are padded with zero lanes to its width."""
+    lanes = pool.shape[-1]
+
+    def padded(x):
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1)
+                       + [(0, lanes - x.shape[-1])])
+    with block("kv_write"):
+        pool = _scatter_rows(pool, padded(rows), slots)
+    q0 = padded(q[0])
+    T, H, width = q0.shape
+    S = tables.shape[0]
+    tables = tables.astype(jnp.int32)
+    call = functools.partial(_latent_call, use_pallas, value_lanes=value_lanes,
+                             scale=scale)
+    with block("attention/decode"):
+        dec_rows = decode_block_q(H, q0.dtype)
+        qd = jnp.pad(q0[jnp.minimum(dec_index, T - 1)],
+                     ((0, 0), (0, dec_rows - H), (0, 0)))
+        ctx = jnp.where(row_pos >= 0, row_pos + 1, 0).astype(jnp.int32)
+        dec_out = call(
+            qd.reshape(S * dec_rows, width), pool, tables, ctx,
+            jnp.where(ctx > 0, jnp.arange(S, dtype=jnp.int32), S),
+            jnp.maximum(ctx - 1, 0), jnp.ones((S,), jnp.int32),
+            block_q=dec_rows, block_tokens=1)
+        dec_out = dec_out.reshape(S, dec_rows, value_lanes)[:, :H]
+
+    def chunk(_):
+        with block("attention/chunk"):
+            ct = chunk_tokens
+            nqb = chunk_rows // ct
+            # [C, H, width] -> rows ordered (q-block, head, token)
+            qc = _chunk_rows(q0, meta, chunk_rows).reshape(
+                nqb, ct, H, width).transpose(0, 2, 1, 3)
+            first = jnp.arange(nqb, dtype=jnp.int32) * ct
+            valids = jnp.clip(meta[1] - first, 0, ct).astype(jnp.int32)
+            out = call(
+                qc.reshape(nqb * H * ct, width), pool,
+                tables[jnp.minimum(meta[4], S - 1)][None],
+                jnp.reshape(meta[5] + meta[1], (1,)),
+                jnp.where(valids > 0, 0, 1).astype(jnp.int32),
+                (meta[5] + first).astype(jnp.int32), valids,
+                block_q=H * ct, block_tokens=ct)
+            return out.reshape(nqb, H, ct, value_lanes) \
+                .transpose(0, 2, 1, 3).reshape(chunk_rows, H, value_lanes)
+
+    chunk_out = jax.lax.cond(
+        meta[1] > 0, chunk,
+        lambda _: jnp.zeros((chunk_rows, H, value_lanes), q.dtype), None)
+    like = jnp.zeros((T, H, value_lanes), q.dtype)
+    return _merge_rows(like, chunk_out, dec_out, meta, dec_index)[None], pool
+
+
+class LatentLayerCache(_LayerCache):
+    """A latent attention layer: one row a token for all its heads in
+    the block pool, read in the absorbed form."""
+
+    __slots__ = ()
+
+    def attend(self, q, rows, scale):
+        """``q`` [1, T, H, width] absorbed queries, ``rows`` [1, T,
+        width] this step's latent rows -> [1, T, H, value_lanes]; the
+        layer's pool is updated in place."""
+        from ...ops.pallas_gate import pallas_enabled
+        view = self._view
+        cache = view.cache
+        (pool,) = cache.layer_pools(self._layer)
+        spec = cache.layer_specs[self._layer]
+        out, new_pool = dispatch(
+            "latent_paged_attention", _latent_attend_impl,
+            (q, rows, pool, view.slot_mapping, view.block_tables,
+             view.dec_index, view.row_pos, view.chunk_meta),
+            dict(chunk_rows=view.chunk_rows,
+                 chunk_tokens=view.latent_chunk_tokens,
+                 value_lanes=int(spec["value_lanes"]), scale=float(scale),
+                 use_pallas=pool._value.dtype in (jnp.bfloat16, jnp.float32)
+                 and pallas_enabled("latent_attention")),
+            differentiable=False)
+        pool._inplace_update(new_pool._value)
+        return out
 
 
 # ---------------------------------------------------------------------
@@ -907,12 +1064,18 @@ class RaggedCacheView:
         #: staged beside the full group's (`set_group_inputs`)
         self._group_inputs = {}
         #: layers whose decode rows and chunk go through the kernel
-        #: apart; they read the per-row arrays of `stage_state`
-        self.grouped = any(spec["kind"] == "paged_kv"
-                           and layer_is_grouped(spec)
-                           for spec in cache.layer_specs)
+        #: apart (grouped K/V heads, a window, latent rows); they read
+        #: the per-row arrays of `stage_state`
+        self.grouped = cache.latent or any(
+            spec["kind"] == "paged_kv" and layer_is_grouped(spec)
+            for spec in cache.layer_specs)
         #: tokens of a q-block of the chunk's head-group form
         self.chunk_block_q = math.gcd(int(chunk_rows or block_q), 128)
+        #: tokens of a chunk's q-block in the latent kernel
+        self.latent_chunk_tokens = latent_chunk_tokens(
+            max([int(s["query_heads"]) for s in cache.layer_specs
+                 if s["kind"] == "latent"] or [1]),
+            int(chunk_rows or block_q))
         self._zero_base = None
         sparse = [spec["sparse_sizes"] for spec in cache.layer_specs
                   if spec.get("sparse_sizes")]
@@ -923,6 +1086,7 @@ class RaggedCacheView:
         self._sparse = (sparse[0], len(sparse)) if sparse else None
         self._layers = [
             RecurrentLayerCache(self, i) if spec["kind"] == "recurrent"
+            else LatentLayerCache(self, i) if spec["kind"] == "latent"
             else SparseLayerCache(self, i) if spec.get("sparse_sizes")
             else RaggedLayerCache(self, i)
             for i, spec in enumerate(cache.layer_specs)]

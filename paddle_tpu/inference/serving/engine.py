@@ -307,6 +307,11 @@ class GenerationEngine:
         self._window_layers = collections.Counter(
             s.get("window") for s in self.cache.layer_specs
             if s["kind"] == "paged_kv") if self._view.grouped else {}
+        # the latent layers and their query heads (the latent kernel's
+        # work counters are host arithmetic from each row's position)
+        latent = [int(s["query_heads"]) for s in self.cache.layer_specs
+                  if s["kind"] == "latent"]
+        self._latent = (len(latent), max(latent)) if latent else None
         self._step_reports = None
         self._step_fn = paddle.jit.to_static(self._ragged_step)
         # a device trace knows the step as jit_engine_step, and
@@ -892,7 +897,11 @@ class GenerationEngine:
         (``kv_table_slots``: the width of the group's table a q-block,
         for a windowed layer's decode rows what `RaggedLayerCache` cuts
         it to): the blocks read over it is the share of a table that the
-        kernel's walk has to visit."""
+        kernel's walk has to visit.  With latent layers, their kernel's
+        work: the latent blocks its walks fetch (a decode row's whole
+        context, a chunk q-block's up to its last token, once for all
+        heads) and the (query head row, visible key) pairs it computes
+        on (``latent_blocks_read``, ``latent_pairs``)."""
         cache, bs = self.cache, self.cache.block_size
         spans = [(cache.length(r.id) - 1, 1, r.id) for r in decodes]
         reads = [(p, p) for p, _, _ in spans]    # (first, last) token
@@ -923,6 +932,20 @@ class GenerationEngine:
                     step["kv_blocks_context"] += layers * whole
                     step["kv_blocks_read_window"] += layers * (
                         whole - max(0, first - window + 1) // bs)
+        if self._latent:
+            layers, heads = self._latent
+            ctxs = [p + 1 for p, _, _ in spans[:len(decodes)]]
+            blocks = sum(-(-c // bs) for c in ctxs)
+            pairs = sum(ctxs)
+            if chunk is not None:
+                _, start, n = chunk
+                ct = self._view.latent_chunk_tokens
+                ctx = -(-(start + n) // bs)
+                blocks += sum(min(ctx, (a + ct - 1) // bs + 1)
+                              for a in range(start, start + n, ct))
+                pairs += n * start + n * (n + 1) // 2
+            step["latent_blocks_read"] = layers * blocks
+            step["latent_pairs"] = layers * heads * pairs
         self._count(step)
 
     def _count(self, step):
@@ -1359,11 +1382,13 @@ class GenerationEngine:
 
     def _count_reports(self, reports):
         """A drained step's reports into the counters: the expert
-        layers' plans (``moe_dispatch.plan_counters`` a layer)."""
-        moe = np.asarray(reports["moe"])                 # [layers, 5]
+        layers' plans (``moe_dispatch.plan_counters`` a layer; a sixth
+        column counts the zero-compute experts' assignments)."""
+        moe = np.asarray(reports["moe"])            # [layers, 5 or 6]
         self._count({name: int(moe[:, n].sum()) for n, name in (
             (0, "moe_assignments"), (1, "moe_experts_touched"),
-            (2, "moe_plan_rows"), (4, "moe_assignments_routed"))})
+            (2, "moe_plan_rows"), (4, "moe_assignments_routed"),
+            (5, "moe_zero_assignments")) if n < moe.shape[1]})
         # the fullest expert of any layer of any step so far
         self._counters["moe_max_expert_rows"] = max(
             self._counters.get("moe_max_expert_rows", 0),

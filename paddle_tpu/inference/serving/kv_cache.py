@@ -61,7 +61,11 @@ tables above (one geometry: KV heads and head dim, which need not be the
 query heads'); a ``recurrent`` layer keeps no K/V but its named
 fixed-size ``states`` per live request (``{name: {"shape", "dtype"}}``:
 a decayed or delta-rule state a head, a convolution's last inputs beside
-it), and a paged layer with ``sparse_sizes`` also
+it), a ``latent`` layer (latent attention, MLA) keeps ONE row of
+``width`` lanes a token for all its heads in the same blocks and tables
+(one pool a layer, ``[num_blocks, block_size, lanes]`` with ``width``
+rounded up to whole 128-lane tiles, no V pool: the values are the row's
+first lanes), and a paged layer with ``sparse_sizes`` also
 keeps one mean-pooled key per ``stride`` tokens per request (the
 block-sparse selector's cache).  Both live in **slot pools**
 ``[state_slots + 1, ...]``: slot 0 is the pad slot, ``allocate`` hands a
@@ -317,8 +321,20 @@ class PagedKVCache:
                             "head_dim": head_dim}] * int(num_layers)
         self.layer_specs = [dict(s) for s in layer_specs]
         paged = [(i, s) for i, s in enumerate(self.layer_specs)
-                 if s["kind"] == "paged_kv"]
-        geometry = {(int(s["num_kv_heads"]), int(s["head_dim"]))
+                 if s["kind"] in ("paged_kv", "latent")]
+        if len({s["kind"] for _, s in paged}) > 1:
+            raise ValueError("a model's paged layers are all paged K/V or "
+                             "all latent rows, not both")
+        #: latent layers: one pool a layer, a row of ``width`` lanes a
+        #: token for every head (it is the keys, and its first lanes the
+        #: values)
+        self.latent = any(s["kind"] == "latent" for _, s in paged)
+        # a latent row in whole 128-lane tiles: HBM lays a row out so
+        # anyway, and the kernel's copy of a block cannot name fewer
+        # lanes than the row holds there (the tail lanes stay zero)
+        geometry = {(1, -(-int(s["width"]) // 128) * 128)
+                    if s["kind"] == "latent"
+                    else (int(s["num_kv_heads"]), int(s["head_dim"]))
                     for _, s in paged}
         if len(geometry) != 1:
             by_group = sorted({(s.get("window"), int(s["num_kv_heads"]),
@@ -359,10 +375,16 @@ class PagedKVCache:
         #: (amax over its (H, D) slice), so a block filling up across
         #: decode steps never re-scales already-written slots.
         self.quantized = self._jdtype == jnp.dtype(jnp.int8)
+        if self.latent and (self.quantized or windows):
+            raise NotImplementedError(
+                "a latent pool is kept in the model's type and seen whole: "
+                "no int8 scale tables, no window")
         self.scale_lanes = KV_SCALE_LANES if self.quantized else 0
+        #: pools a layer: K and V, or the latent row alone
+        self.sides = 1 if self.latent else 2
         # byte charge follows the ELEMENT dtype (int8 = 1 byte) plus the
         # scale-table overhead, so a fixed HBM budget admits ~2x blocks
-        self.bytes_per_block = (2 * self.num_layers * self.num_heads
+        self.bytes_per_block = (self.sides * self.num_layers * self.num_heads
                                 * self.block_size * self.head_dim
                                 * self._jdtype.itemsize
                                 + 2 * self.num_layers * self.block_size
@@ -382,11 +404,15 @@ class PagedKVCache:
 
         shape = (self.num_blocks, self.block_size,
                  self.num_heads * self.head_dim)
-        self._pools = []  # [(k_tensor, v_tensor)] per layer
+        self._pools = []  # [(k_tensor, v_tensor)] per layer; latent: (row,)
         self._scales = []  # [(k_scale, v_scale)] per layer (int8 only)
         for i in range(self.num_layers):
             k = Tensor(jnp.zeros(shape, self._jdtype), _internal=True,
                        stop_gradient=True)
+            if self.latent:
+                k.name = f"kv_cache.latent.layer{i}"
+                self._pools.append((k,))
+                continue
             k.name = f"kv_cache.k.layer{i}"
             v = Tensor(jnp.zeros(shape, self._jdtype), _internal=True,
                        stop_gradient=True)
@@ -496,8 +522,10 @@ class PagedKVCache:
         # -- host tier (tiering.py) --------------------------------------
         # evicted-but-indexed blocks spill into a bounded host ring; a
         # chain hash lives in EXACTLY one tier (_by_hash xor _host_of)
-        if self.window_groups:
-            tiering = False    # nothing is indexed, so nothing spills
+        if self.window_groups or self.latent:
+            # a windowed model indexes nothing, so nothing spills; the
+            # host ring holds K and V blocks, not latent rows
+            tiering = False
         if tiering is None:
             tiering = kv_tiering_enabled() and kv_host_budget() is not None
         if host_budget is None:
@@ -1174,12 +1202,9 @@ class PagedKVCache:
         """Device-side block copy, all layers (the COW split).  Int8
         pools copy the per-slot scale rows alongside the data — a split
         block with stale scales would dequantize to garbage."""
-        for k, v in self._pools:
-            k._inplace_update(k._value.at[dst].set(k._value[src]))
-            v._inplace_update(v._value.at[dst].set(v._value[src]))
-        for ks, vs in self._scales:
-            ks._inplace_update(ks._value.at[dst].set(ks._value[src]))
-            vs._inplace_update(vs._value.at[dst].set(vs._value[src]))
+        for pools in self._pools + self._scales:
+            for t in pools:
+                t._inplace_update(t._value.at[dst].set(t._value[src]))
 
     def append(self, seq_id, num_tokens=1):
         """Extend a sequence by ``num_tokens`` slots (decode).  Returns
@@ -1305,12 +1330,12 @@ class PagedKVCache:
 
     # -- cross-pool transfer (disaggregated prefill -> decode) -----------
     def _no_state_transfer(self):
-        if self.state_slots or self.window_groups:
+        if self.state_slots or self.window_groups or self.latent:
             raise NotImplementedError(
                 "a sequence with per-request state (recurrent layers, "
-                "pooled keys) or a windowed group's blocks cannot move "
-                "between pools yet: the handoff payload carries the "
-                "full group's K/V blocks only")
+                "pooled keys), a windowed group's blocks or latent rows "
+                "cannot move between pools yet: the handoff payload "
+                "carries the full group's K/V blocks only")
 
     def export_sequence(self, seq_id):
         """The sequence's paged KV state as a host-side

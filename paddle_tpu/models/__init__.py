@@ -17,6 +17,7 @@ from .moe_gpt import (MoEGPTConfig, MoEGPTModel, MoEGPTForCausalLM,
 from .minicpm_sala import MiniCPMSALAConfig, MiniCPMSALAForCausalLM
 from .afmoe import AfmoeConfig, AfmoeForCausalLM
 from .qwen3_next import Qwen3NextConfig, Qwen3NextForCausalLM
+from .longcat_flash import LongcatFlashConfig, LongcatFlashForCausalLM
 from .generation import GenerationMixin, generate
 
 __all__ = [
@@ -29,5 +30,6 @@ __all__ = [
     "MoEGPTPretrainingCriterion", "MiniCPMSALAConfig",
     "MiniCPMSALAForCausalLM", "AfmoeConfig", "AfmoeForCausalLM",
     "Qwen3NextConfig", "Qwen3NextForCausalLM",
+    "LongcatFlashConfig", "LongcatFlashForCausalLM",
     "GenerationMixin", "generate",
 ]

@@ -237,6 +237,20 @@ def _probe_ragged_attention_int8():
     jax.block_until_ready(fn(q, pool, pool, scales, scales))
 
 
+def _probe_latent_attention():
+    from . import pallas_latent as plat
+    block_q = 16                  # a decode row's heads, one token
+    q = jnp.zeros((2 * block_q, 256), jnp.bfloat16)
+    pool = jnp.zeros((4, 16, 256), jnp.bfloat16)    # rows of two tiles
+    bt = jnp.array([[1, 2, 0, 0], [3, 0, 0, 0]], jnp.int32)
+    fn = jax.jit(lambda q, pool: plat.latent_attention(
+        q, pool, bt, jnp.array([20, 5], jnp.int32),
+        jnp.array([0, 1], jnp.int32), jnp.array([19, 4], jnp.int32),
+        jnp.array([1, 1], jnp.int32), block_q=block_q, block_tokens=1,
+        value_lanes=128, scale=0.1))
+    jax.block_until_ready(fn(q, pool))
+
+
 def _probe_lightning_attention():
     from . import pallas_lightning as pll
     slopes = tuple(float(x) for x in pll.decay_slopes(4))
@@ -281,6 +295,7 @@ _PROBES = {
     "layer_norm": _probe_layer_norm,
     "layer_norm_residual": _probe_layer_norm_residual,
     "layer_norm_residual_dropout": _probe_layer_norm_residual_dropout,
+    "latent_attention": _probe_latent_attention,
     "lightning_attention": _probe_lightning_attention,
     "gated_delta_rule": _probe_gated_delta_rule,
     "sparse_select": _probe_sparse_select,

@@ -217,6 +217,71 @@ def pool_copyable(head_dim, num_kv_heads):
             and (num_kv_heads * head_dim) % _LANES == 0)
 
 
+def walk(bt_ref, sid, lo, hi, kv_step, walked, zeroed, sems, lane0, lanes,
+         compute):
+    """The walk over sequence ``sid``'s block table inside a program:
+    table slots ``[lo, hi)``, ``kv_step`` of them a step, each step's
+    blocks brought by the program's own async copies into one half of
+    its double buffers while ``compute(g, b)`` works on step ``g`` in
+    half ``b``.  ``walked`` is ``[(array in HBM, its VMEM buffer [2,
+    kv_step, block_size, ...], whether a copy takes the lane window
+    ``[lane0, lane0 + lanes)`` of a row or the whole block)]``, one DMA
+    semaphore pair each in ``sems``.  The last step's slots past ``hi``
+    keep what the buffer held; ``zeroed`` is zeroed there first, since
+    ``0 x NaN`` is not 0.  The latent kernel (`pallas_latent`) walks
+    with it too."""
+    steps = jax.lax.div(jnp.maximum(hi - lo, 0) + (kv_step - 1), kv_step)
+
+    def copies(g, j, b):
+        """Table slot ``lo + g * kv_step + j`` into row ``j`` of buffer
+        half ``b``."""
+        blk = bt_ref[sid, lo + g * kv_step + j]
+        return [pltpu.make_async_copy(
+            src.at[blk, :, pl.ds(lane0, lanes)] if per_head
+            else src.at[blk], dst.at[b, j],
+            sems.at[n, b]) for n, (src, dst, per_head) in enumerate(walked)]
+
+    def live_slots(g):
+        return jnp.minimum(hi - lo - g * kv_step, kv_step)
+
+    def start(g, b):
+        def one(j, c):
+            for dma in copies(g, j, b):
+                dma.start()
+            return c
+        jax.lax.fori_loop(0, live_slots(g), one, 0)
+
+    def wait(g, b):
+        def one(j, c):
+            for dma in copies(g, j, b):
+                dma.wait()
+            return c
+
+        def dead(j, c):
+            zeroed[b, j] = jnp.zeros(zeroed.shape[2:], zeroed.dtype)
+            return c
+        n = live_slots(g)
+        jax.lax.fori_loop(0, n, one, 0)
+        jax.lax.fori_loop(n, kv_step, dead, 0)
+
+    @pl.when(steps > 0)
+    def _first():
+        start(0, 0)
+
+    def step(g, c):
+        b = jax.lax.rem(g, 2)
+
+        @pl.when(g + 1 < steps)
+        def _next():
+            start(g + 1, 1 - b)
+
+        wait(g, b)
+        compute(g, b)
+        return c
+
+    jax.lax.fori_loop(0, steps, step, 0)
+
+
 def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref, hd_ref,
                       q_ref, k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref,
                       acc_ref, m_ref, l_ref, k_buf, v_buf, ks_buf, vs_buf,
@@ -295,63 +360,17 @@ def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref, hd_ref,
     if window is not None:
         # nothing wholly before the first token's window
         lo = jax.lax.div(jnp.maximum(qs - (window - 1), 0), block_size)
-    steps = jax.lax.div(jnp.maximum(hi - lo, 0) + (kv_step - 1), kv_step)
 
     # (table in HBM, its buffer, whether a row holds heads side by side)
     walked = [(k_hbm, k_buf, True), (v_hbm, v_buf, True)]
     if int8_kv:
         walked += [(ks_hbm, ks_buf, False), (vs_hbm, vs_buf, False)]
 
-    def copies(g, j, b):
-        """Table slot ``lo + g * kv_step + j`` into row ``j`` of buffer
-        half ``b``."""
-        blk = bt_ref[sid, lo + g * kv_step + j]
-        return [pltpu.make_async_copy(
-            src.at[blk, :, pl.ds(lane0, lanes)] if per_head
-            else src.at[blk], dst.at[b, j],
-            sems.at[n, b]) for n, (src, dst, per_head) in enumerate(walked)]
-
-    def live_slots(g):
-        return jnp.minimum(hi - lo - g * kv_step, kv_step)
-
-    def start(g, b):
-        def one(j, c):
-            for dma in copies(g, j, b):
-                dma.start()
-            return c
-        jax.lax.fori_loop(0, live_slots(g), one, 0)
-
-    def wait(g, b):
-        def one(j, c):
-            for dma in copies(g, j, b):
-                dma.wait()
-            return c
-
-        def dead(j, c):
-            # an int8 V is finite as it lies; its scale may not be
-            zeroed = vs_buf if int8_kv else v_buf
-            zeroed[b, j] = jnp.zeros(zeroed.shape[2:], zeroed.dtype)
-            return c
-        n = live_slots(g)
-        jax.lax.fori_loop(0, n, one, 0)
-        jax.lax.fori_loop(n, kv_step, dead, 0)
-
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(steps > 0)
-    def _first():
-        start(0, 0)
-
-    def step(g, c):
-        b = jax.lax.rem(g, 2)
-
-        @pl.when(g + 1 < steps)
-        def _next():
-            start(g + 1, 1 - b)
-
-        wait(g, b)
+    def compute(g, b):
         row = jax.lax.broadcasted_iota(jnp.int32, (block_q, keys), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (block_q, keys), 1)
         col = col + (lo + g * kv_step) * block_size
@@ -383,9 +402,10 @@ def _ragged_attn_body(bt_ref, cl_ref, sid_ref, qs_ref, qv_ref, hd_ref,
                 p, head_lanes(v_win, n), (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             m_ref[rows] = _lanes(m_new)
-        return c
 
-    jax.lax.fori_loop(0, steps, step, 0)
+    # an int8 V is finite as it lies; its scale may not be
+    walk(bt_ref, sid, lo, hi, kv_step, walked, vs_buf if int8_kv else v_buf,
+         sems, lane0, lanes, compute)
 
     l = l_ref[:, :1]
     l_safe = jnp.where(l == 0.0, 1.0, l)

@@ -14,6 +14,10 @@ grouped expert kernel at 128 x 2048 x 1024, the 200,192-word head),
 ``serve_qwen3_next`` (the same for `benchmarks/configs/Qwen3-Next-80B-
 A3B-Instruct.json`, depth cut to one period: both gated delta rule
 kernels, the held experts at 128 x 2048 x 512, attention at width 256),
+``serve_longcat`` (the same for `benchmarks/configs/LongCat-Flash-
+Omni.json`, depth cut to one double layer: two latent layers over the
+latent pool, the held experts at 8 x 6144 x 2048 beside the zero
+experts, the dense MLPs at 12,288),
 ``serve_sala`` (the same for `benchmarks/configs/MiniCPM-SALA.json`,
 depth cut to two sparse and two lightning layers: the selected tables a
 KV head, the pooled keys, the sparse chunk's gather).  The four engine
@@ -50,8 +54,9 @@ from paddle_tpu.distributed.auto_parallel.sharding import (  # noqa: E402
 from paddle_tpu.models import BertConfig, GPTConfig  # noqa: E402
 from paddle_tpu.ops import (pallas_fused, pallas_gate,  # noqa: E402
                             pallas_gated_delta, pallas_grouped,
-                            pallas_kernels, pallas_lightning,
-                            pallas_ragged, pallas_sparse, pallas_tiles)
+                            pallas_kernels, pallas_latent,
+                            pallas_lightning, pallas_ragged,
+                            pallas_sparse, pallas_tiles)
 
 HBM_BYTES = 16e9
 BATCH, SEQ = 16, 512
@@ -66,7 +71,7 @@ def open_gate():
         manual or not pallas_gate._auto_partitioned())
     for mod in (pallas_kernels, pallas_fused, pallas_ragged,
                 pallas_grouped, pallas_gated_delta, pallas_lightning,
-                pallas_sparse, pallas_tiles):
+                pallas_sparse, pallas_tiles, pallas_latent):
         mod._interpret = lambda: False
 
 
@@ -248,6 +253,26 @@ def check_serve_qwen3_next(topo):
         r"bf16\[128,(?:2048|512),(?:2048|1024)\]")
 
 
+def check_serve_longcat(topo):
+    from benchmarks.families import longcat_flash
+    from paddle_tpu.inference.serving import attention
+    # the one CPU step only discovers the step's state: the latent
+    # layers' gather form would hold a [64, 1024, 17408] float32 score
+    # block of a 1,024-token chunk on the CPU, so it stands aside there
+    # (the chip's program, traced again with the gate open, is the
+    # kernel's)
+    real = attention._latent_ref
+    attention._latent_ref = lambda q, *a, value_lanes, **kw: jnp.zeros(
+        (q.shape[0], value_lanes), q.dtype)
+    try:
+        return _check_serve_cell(
+            topo, "serve_longcat", longcat_flash, "LongCat-Flash-Omni.json",
+            "longctx16k-closed32.json", lambda cfg: dict(num_layers=1),
+            r"bf16\[8,(?:6144|2048),(?:6144|4096)\]")
+    finally:
+        attention._latent_ref = real
+
+
 def check_serve_sala(topo):
     from benchmarks.families import minicpm_sala
     return _check_serve_cell(
@@ -275,6 +300,8 @@ def main(names):
         check_serve_qwen3_next(topo)
     if "serve_sala" in names:
         check_serve_sala(topo)
+    if "serve_longcat" in names:
+        check_serve_longcat(topo)
     open_gate()
     for name in names:
         if name == "static":
@@ -284,7 +311,7 @@ def main(names):
         elif name == "mesh":
             check_mesh(topo)
         elif name not in ("serve", "serve_afmoe", "serve_qwen3_next",
-                          "serve_sala"):
+                          "serve_sala", "serve_longcat"):
             raise SystemExit(f"unknown program {name!r}")
     print("AOT_SMOKE_OK", flush=True)
 

@@ -46,9 +46,10 @@ def _tiny_afmoe(request):
     ``tests/test_benchmark.py``) runs every cell of the manifest from
     its own ``TINY`` table, keyed by family, and a PR that adds a family
     may not edit that file, nor ``benchmarks/conftest.py``: hand the
-    table its ``afmoe`` and ``qwen3_next`` entries from here
-    (``benchmarks/tests/test_trinity_mini_cell.py`` and
-    ``test_qwen3_next_cell.py`` hold them).  The next ``benchmark`` PR
+    table its ``afmoe``, ``qwen3_next`` and ``longcat_flash`` entries
+    from here (``benchmarks/tests/test_trinity_mini_cell.py``,
+    ``test_qwen3_next_cell.py`` and ``test_longcat_flash_cell.py`` hold
+    them).  The next ``benchmark`` PR
     moves the entries into the table and this goes (PERF.md section
     7)."""
     table = getattr(request.module, "TINY", None)
@@ -58,3 +59,6 @@ def _tiny_afmoe(request):
     if isinstance(table, dict) and "qwen3_next" not in table:
         from benchmarks.tests.test_qwen3_next_cell import TINY_QWEN3_NEXT
         table["qwen3_next"] = TINY_QWEN3_NEXT
+    if isinstance(table, dict) and "longcat_flash" not in table:
+        from benchmarks.tests.test_longcat_flash_cell import TINY_LONGCAT
+        table["longcat_flash"] = TINY_LONGCAT

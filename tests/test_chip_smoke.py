@@ -199,6 +199,26 @@ def test_ragged_walk_tiny():
     assert c["t.chunk@1.0"]["kv_blocks"] == 2 * (3 + 4)
 
 
+def test_latent_walk_tiny():
+    """The latent kernel's decode and chunk calls (interpreted) against
+    their jnp form at four heads, a 32 + 16-lane row in a 128-lane tile,
+    three rows and tables of four blocks, the blocks counted, the
+    scatter and the decode call on a donated pool and the chunk's
+    projected form run; no device, no time."""
+    c = chip_smoke.latent_walk(rows=3, width=4, bs=16, heads=4, rank=32,
+                               rope=16, nope=16, value=16,
+                               chunk=32)["checked"]
+    assert len(c) == 6 and all("us" not in e or e["us"] is None
+                               for e in c.values())
+    assert all(e["rel_l2_vs_jnp"] < chip_smoke.BF16_REL_L2
+               for k, e in c.items() if k.startswith("latent.")
+               and k.endswith("@0.25"))
+    # full tables: 56 keys of 64; three decode rows read four blocks
+    # each, the chunk's one q-block of 32 tokens (keys 24-55) four
+    assert c["latent.decode@1.0"]["latent_blocks"] == 3 * 4
+    assert c["latent.chunk@1.0"]["latent_blocks"] == 4
+
+
 def test_serve_tiny():
     out = chip_smoke.serve(GPT, [5, 40, 70, 90], new_tokens=6)
     c = out["checked"]

@@ -34,6 +34,7 @@ import paddle_tpu.ops.pallas_fused as pf
 import paddle_tpu.ops.pallas_gated_delta as pgd
 import paddle_tpu.ops.pallas_grouped as pgm
 import paddle_tpu.ops.pallas_kernels as pk
+import paddle_tpu.ops.pallas_latent as plat
 import paddle_tpu.ops.pallas_lightning as pll
 import paddle_tpu.ops.pallas_ragged as pr
 import paddle_tpu.ops.pallas_sparse as pls
@@ -61,7 +62,7 @@ def one_chip(topo):
     from jax.experimental.compilation_cache import compilation_cache
     mp = pytest.MonkeyPatch()
     # each kernel module binds _interpret by name at import
-    for mod in (pk, pf, pr, pgm, pt, pll, pls, pgd):
+    for mod in (pk, pf, pr, pgm, pt, pll, pls, pgd, plat):
         mp.setattr(mod, "_interpret", lambda: False)
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -632,6 +633,38 @@ def test_scatter_and_ragged_kernel_leave_the_pools_where_they_lie(
     assert alias and "{1}: (1," in alias.group(1) \
         and "{2}: (2," in alias.group(1), text[:400]
     moves = chip_smoke.pool_sized_moves(text, elements)
+    assert not moves, moves
+
+
+def test_latent_scatter_and_kernel_leave_the_pool_where_it_lies(one_chip):
+    """One latent layer of the LongCat-Flash cell's step on a donated
+    pool at its geometry (8,705 blocks of 64 rows of 512 + 64 lanes in
+    five 128-lane tiles, 32
+    rows, tables of 272 blocks, a 1,024-token chunk, 64 heads): the
+    scatter, the decode rows' and the chunk's ``latent_attention_fwd``
+    calls; the pool is aliased to its result and no instruction copies
+    or transposes it whole."""
+    import chip_smoke
+    from paddle_tpu.inference.serving import attention as att
+    T, S, H, width, blocks = 1520, 32, 64, 576, 8705
+
+    def layer(q, rows, pool, slots, tables, dec_index, row_pos, meta):
+        return att._latent_attend_impl(
+            q, rows, pool, slots, tables, dec_index, row_pos, meta,
+            chunk_rows=1024, chunk_tokens=att.latent_chunk_tokens(H, 1024),
+            value_lanes=512, scale=192 ** -0.5, use_pallas=True)
+    args = [((1, T, H, width), bf16), ((1, T, width), bf16),
+            ((blocks, 64, 640), bf16), ((T,), i32), ((S, 272), i32),
+            ((S,), i32), ((S,), i32), ((6,), i32)]
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in args]
+    with jax.enable_x64(False):
+        text = jax.jit(layer, donate_argnums=(2,)).lower(
+            *avals).compile().as_text()
+    assert chip_smoke.mosaic_kernels(text) == {"latent_attention_fwd": 2}
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+    assert alias and "{1}: (2," in alias.group(1), text[:400]
+    moves = chip_smoke.pool_sized_moves(text, blocks * 64 * 640)
     assert not moves, moves
 
 
