@@ -1,6 +1,6 @@
 """Prove that the trainer and the serving engine start on the chip.
 
-    python chip_smoke.py            # one TPU chip: thirteen phases
+    python chip_smoke.py            # one TPU chip: fifteen phases
     python chip_smoke.py --chips 4  # four chips: the sharded phase only
     python chip_smoke.py --phase attention_dropout   # that phase alone
 
@@ -19,8 +19,9 @@ Every phase is a function of the model configuration and sizes:
 chip before chip time is spent.  The command line takes no size.
 
 This is a start-up proof, not a benchmark: the seconds it prints are
-wall time of whole phases, compilation included (`sampler_gate` alone
-reads device times of single programs from a profiler trace).
+wall time of whole phases, compilation included (`sampler_gate`,
+`ragged_walk`, `latent_walk` and `moe_dispatch` alone read device times
+of single programs from a profiler trace).
 """
 import argparse
 import contextlib
@@ -1294,6 +1295,160 @@ def latent_walk(fills=(0.25, 1.0), rows=32, width=272, bs=64, heads=64,
     return {"kernels": dict(kernels), "checked": checked}
 
 
+#: `moe_dispatch`' expert layers, the three MoE cells' at their
+#: published widths over the engine's 1,520-row budget: (name, hidden,
+#: router outputs, experts held, top-k, expert width, router, the
+#: std of the router's bias as the cell's file draws it, first
+#: zero-compute expert)
+MOE_DISPATCH_LAYERS = (
+    ("Trinity-Mini", 2048, 128, 128, 8, 1024, "sigmoid", 0.05, None),
+    ("Qwen3-Next", 2048, 512, 128, 10, 512, "softmax", 0.0, None),
+    ("LongCat-Flash", 6144, 768, 8, 12, 2048, "softmax_bias", 0.0002, 512),
+)
+
+
+def _scatter_dispatch(x, rows, top_k, padded_rows):
+    """The dispatch the gathers replaced: every token repeated ``top_k``
+    times, then scattered whole into a zeroed buffer."""
+    import jax.numpy as jnp
+    return jnp.zeros((padded_rows, x.shape[1]), x.dtype).at[rows].set(
+        jnp.repeat(x, top_k, axis=0), mode="drop")
+
+
+def _einsum_combine(y_rows, rows, topk_val):
+    """The combine the reduction replaced: the rows widened to float32
+    and contracted with the weights over ``k``."""
+    import jax.numpy as jnp
+    N, k = topk_val.shape
+    g = y_rows.at[rows].get(mode="fill", fill_value=0).reshape(N, k, -1)
+    return jnp.einsum("nk,nkd->nd", topk_val.astype(jnp.float32),
+                      g.astype(jnp.float32)).astype(y_rows.dtype)
+
+
+def moe_dispatch(layers, tokens=1520, carried=1056):
+    """An expert layer of each MoE cell, split: the router, the plan,
+    the dispatch, the grouped kernel pair and the combine, each its own
+    program, and the whole layer; the dispatch and the combine (and so
+    the layer) in the scatter-and-einsum form and in the gather form
+    `moe_dispatch.gated_experts` runs.  ``tokens`` rows of which the
+    first ``carried`` carry something (a chunk step: 1,024 chunk tokens
+    and 32 decode rows).  Checks that the two forms fill the grouped
+    buffer bit for bit alike and agree on the combine and the layer;
+    reports device µs of each program and the dispatch's bytes (the
+    buffer written, each source row read once) over its time as a share
+    of the HBM's rate."""
+    import jax.numpy as jnp
+    from paddle_tpu.distributed.auto_parallel import moe_dispatch as md
+    from paddle_tpu.ops import pallas_grouped as pg
+    checked, kernels = {}, Counter()
+    for (name, hidden, routed, held, k, width, router, bias_std,
+         identity) in layers:
+        ks = jax.random.split(jax.random.PRNGKey(SEED), 5)
+        x = jax.random.normal(ks[0], (tokens, hidden), jnp.bfloat16)
+        w_router = jax.random.normal(ks[1], (hidden, routed),
+                                     jnp.float32) * hidden ** -0.5
+        bias = bias_std * jax.random.normal(ks[2], (routed,), jnp.float32)
+        gate_up = (0.02 * jax.random.normal(
+            ks[3], (held, hidden, 2 * width), jnp.float32)) \
+            .astype(jnp.bfloat16)
+        down = (0.02 * jax.random.normal(
+            ks[4], (held, width, hidden), jnp.float32)).astype(jnp.bfloat16)
+        live = jnp.arange(tokens) < carried
+        bm, nb, R = pg.grouped_layout(tokens * k, held, jnp.bfloat16)
+        use_pallas = bool(pg.gated_block_n(bm, hidden, width, jnp.bfloat16))
+        gated, lin = (pg.grouped_gated_act, pg.grouped_linear_act) \
+            if use_pallas else (pg.grouped_gated_act_ref,
+                                pg.grouped_linear_act_ref)
+
+        def route(x, w_router, bias):
+            logits = jnp.dot(x.astype(jnp.float32), w_router,
+                             precision="highest")
+            if router == "sigmoid":
+                return md.sigmoid_topk_router(logits, bias, k, 2.826)
+            if router == "softmax":
+                return md.softmax_topk_router(logits, k)
+            return md.softmax_bias_topk_router(logits, bias, k, 6.0)
+
+        def plan(idx, live):
+            return md.dropless_plan(idx, held, bm, nb, live, (0, held))[:2]
+
+        def pair(xd, gid, gate_up, down):
+            return lin(gated(xd, gate_up, block_group=gid, act="silu"),
+                       down, block_group=gid)
+
+        def zero_term(y, idx, w, x, live):
+            if identity is None:
+                return y
+            kept = jnp.where((idx >= identity) & live[:, None], w,
+                             0.0).sum(-1)
+            return (y.astype(jnp.float32) + kept[:, None]
+                    * x.astype(jnp.float32)).astype(y.dtype)
+
+        def layer_scatter(x, w_router, bias, gate_up, down, live):
+            idx, w = route(x, w_router, bias)
+            rows, gid, _ = md.dropless_plan(idx, held, bm, nb, live,
+                                            (0, held))
+            y_rows = pair(_scatter_dispatch(x, rows, k, R), gid, gate_up,
+                          down)
+            return zero_term(_einsum_combine(y_rows, rows, w), idx, w, x,
+                             live)
+
+        def layer_gather(x, w_router, bias, gate_up, down, live):
+            idx, w = route(x, w_router, bias)
+            return md.gated_experts(x, idx, w, gate_up, down, live,
+                                    use_pallas=use_pallas, held=(0, held),
+                                    identity=identity)[0]
+
+        entry = {"buffer_rows": R, "block_rows": bm}
+        timed = {}
+
+        def run(label, fn, *args):
+            compiled = jax.jit(fn).lower(*args).compile()
+            kernels.update(mosaic_kernels(compiled.as_text()))
+            out = jax.block_until_ready(compiled(*args))
+            timed[label] = _device_ms(compiled, *args)
+            return out
+
+        idx, w = run("router", route, x, w_router, bias)
+        rows, gid = run("plan", plan, idx, live)
+        old = run("dispatch.scatter", lambda x, r: _scatter_dispatch(
+            x, r, k, R), x, rows)
+        new = run("dispatch.gather", lambda x, r: md.dropless_dispatch(
+            x, r, k, R), x, rows)
+        check((np.asarray(old.view(jnp.uint16))
+               == np.asarray(new.view(jnp.uint16))).all(),
+              f"{name}: the gathered buffer differs from the scattered")
+        y_rows = run("kernels", pair, new, gid, gate_up, down)
+        del old, new
+        c_old = run("combine.einsum", _einsum_combine, y_rows, rows, w)
+        c_new = run("combine.reduce", md.dropless_combine, y_rows, rows, w)
+        entry["combine_rel_l2"] = _rel_l2(c_new, c_old)
+        check(entry["combine_rel_l2"] < 1e-3,
+              f"{name}: combines differ by {entry['combine_rel_l2']:.3g}")
+        del y_rows, c_old, c_new
+        args = (x, w_router, bias, gate_up, down, live)
+        l_old = run("layer.scatter", layer_scatter, *args)
+        l_new = run("layer.gather", layer_gather, *args)
+        entry["layer_rel_l2"] = _rel_l2(l_new, l_old)
+        check(entry["layer_rel_l2"] < 1e-3,
+              f"{name}: layers differ by {entry['layer_rel_l2']:.3g}")
+        if timed["dispatch.gather"] is not None:
+            entry["us"] = {n: round(ms * 1e3, 1) for n, ms in timed.items()}
+            # the least either form moves: the buffer written once, the
+            # rows of the tokens that reach a held expert read once, and
+            # the one zero row
+            sources = int((np.asarray(rows).reshape(tokens, k) < R)
+                          .any(1).sum())
+            moved = (R + sources + 1) * hidden * 2
+            entry["dispatch_hbm_share"] = {
+                form: round(moved / (timed[f"dispatch.{form}"] * 1e-3)
+                            / HBM_BYTES_PER_S, 4)
+                for form in ("scatter", "gather")}
+        checked[name] = entry
+        del x, gate_up, down, l_old, l_new, args
+    return {"kernels": dict(kernels), "checked": checked}
+
+
 def afmoe_config(layers=(0, 1, 4), dtype=None):
     """The benchmark's Trinity-Mini file with the layer list cut to a
     dense sliding, an expert sliding and an expert full layer (depth is
@@ -1448,7 +1603,10 @@ def main(argv=None):
             ("ragged_walk", ragged_walk, (RAGGED_WALK_CALLS,), {}),
             # the latent kernel alone and the chunk's other form, at the
             # LongCat-Flash cell's shapes
-            ("latent_walk", latent_walk, (), LATENT_WALK)]
+            ("latent_walk", latent_walk, (), LATENT_WALK),
+            # an expert layer of each MoE cell split into its programs,
+            # the scatter form beside the gathers
+            ("moe_dispatch", moe_dispatch, (MOE_DISPATCH_LAYERS,), {})]
     unknown = set(args.phase or ()) - {name for name, *_ in phases}
     if unknown:
         sys.exit(f"no such phase with --chips {args.chips}: "

@@ -13,14 +13,20 @@ the stable argsort gives tokens of one expert their arrival order):
 
   * `dropless_plan`   — top-k assignments -> (row of each assignment,
     block_group descriptor for the kernel, per-expert counts);
-  * `dropless_dispatch` — scatter tokens into the grouped buffer;
+  * `dropless_dispatch` — gather tokens into the grouped buffer;
   * `dropless_combine`  — gather expert outputs back and weighted-sum
     the k choices per token.
+
+Rows move by gathers of whole rows, never by a scatter of them: the
+buffer's row ``r`` reads its token through the int32 inverse of the
+plan's rows (a padding row reads a zero row appended to ``x``), and the
+combine reads each choice's row in the buffer's dtype and sums the k of
+a token in float32.
 
 `sigmoid_topk_router` is the sigmoid router with a selection-only bias,
 `softmax_topk_router` the softmax router with renormalised top-k,
 `softmax_bias_topk_router` a softmax router with a selection-only bias
-and a fixed scale, and `gated_experts` strings plan, scatter, the gated
+and a fixed scale, and `gated_experts` strings plan, dispatch, the gated
 grouped kernel, the down projection and the combine together for a
 SwiGLU expert layer (`models/afmoe.py`, `models/qwen3_next.py`,
 `models/longcat_flash.py`).  A layer that holds only the experts ``held
@@ -122,9 +128,9 @@ def dropless_plan(topk_idx, num_experts, block_rows, num_blocks=None,
 
     Returns ``(rows, block_group, counts)``:
       * ``rows``: [N * k] int32 — the grouped-buffer row of flat
-        assignment ``n * k + j`` (rows are unique: scatter is exact);
-        an assignment of a token that carries nothing gets the row
-        past the buffer's end (dropped on scatter, zero on gather);
+        assignment ``n * k + j`` (rows are unique); an assignment of a
+        token that carries nothing gets the row past the buffer's end
+        (read as zero by the combine);
       * ``block_group``: [num_blocks] int32 kernel descriptor
         (``num_experts`` = null block);
       * ``counts``: [num_experts] int32 tokens per expert (the
@@ -159,27 +165,31 @@ def dropless_plan(topk_idx, num_experts, block_rows, num_blocks=None,
 
 
 def dropless_dispatch(x, rows, top_k, padded_rows):
-    """Scatter [N, D] tokens into the [padded_rows, D] grouped buffer:
-    assignment ``n * k + j`` lands whole at ``rows[n * k + j]``;
-    padding rows stay zero (the grouped kernel's contract), and a row
-    past the buffer's end (a token that carries nothing) is dropped."""
-    N, D = x.shape
-    xr = jnp.repeat(x, top_k, axis=0)                   # [N*k, D]
-    return jnp.zeros((padded_rows, D), x.dtype).at[rows].set(
-        xr, mode="drop")
+    """The [padded_rows, D] grouped buffer of [N, D] tokens: assignment
+    ``n * k + j`` whole at ``rows[n * k + j]``; padding rows zero (the
+    grouped kernel's contract), a row past the buffer's end (a token
+    that carries nothing) nowhere.  The rows move by one gather, through
+    the int32 inverse of ``rows``: a row no assignment lands on reads
+    past ``x``'s end, a zero row appended (a fill mode would add a
+    select over the whole buffer)."""
+    N = x.shape[0]
+    src = jnp.full((padded_rows,), N, jnp.int32).at[rows].set(
+        jnp.arange(rows.shape[0], dtype=jnp.int32) // top_k, mode="drop")
+    x0 = jnp.concatenate([x, jnp.zeros((1,) + x.shape[1:], x.dtype)])
+    return x0.at[src].get(mode="promise_in_bounds")
 
 
 def dropless_combine(y_rows, rows, topk_val):
     """Gather expert outputs back and weighted-sum the k choices:
-    ``y[n] = sum_j topk_val[n, j] * y_rows[rows[n*k+j]]`` (f32
-    accumulation, cast back to the buffer dtype); a row past the
-    buffer's end reads as zero."""
+    ``y[n] = sum_j topk_val[n, j] * y_rows[rows[n*k+j]]``, the rows read
+    in the buffer's dtype and summed over ``j`` in float32 (an
+    elementwise product and a reduction, no contraction), cast back to
+    the buffer dtype; a row past the buffer's end reads as zero."""
     N, k = topk_val.shape
     g = y_rows.at[rows].get(mode="fill", fill_value=0) \
         .reshape(N, k, y_rows.shape[-1])
-    return jnp.einsum(
-        "nk,nkd->nd", topk_val.astype(jnp.float32),
-        g.astype(jnp.float32)).astype(y_rows.dtype)
+    return (topk_val.astype(jnp.float32)[..., None] * g).sum(1) \
+        .astype(y_rows.dtype)
 
 
 def plan_counters(counts, block_rows, routed, zero=None):
@@ -201,7 +211,7 @@ def gated_experts(x, topk_idx, topk_weight, w_gate_up, w_down,
                   carried=None, act="silu", use_pallas=False, held=None,
                   identity=None):
     """The routed half of a gated expert layer on flat tokens: plan,
-    scatter, ``act(x @ w_gate[e]) * (x @ w_up[e])`` then ``@ w_down[e]``
+    dispatch, ``act(x @ w_gate[e]) * (x @ w_up[e])`` then ``@ w_down[e]``
     through the grouped kernel (or its composite), weighted sum.
     ``w_gate_up`` [E, D, 2 I] and ``w_down`` [E, I, D] are read where
     they lie.  ``held = (lo, hi)``: the stacks are experts ``lo ... hi -

@@ -219,6 +219,21 @@ def test_latent_walk_tiny():
     assert c["latent.chunk@1.0"]["latent_blocks"] == 4
 
 
+def test_moe_dispatch_tiny():
+    """An expert layer of each kind at width 128, 40 rows of which 30
+    carry something: the gathered buffer bit for bit the scattered one,
+    the combines and the layers of the two forms alike, the kernels
+    interpreted; no device, no time."""
+    layers = (("t.sigmoid", 128, 16, 16, 8, 128, "sigmoid", 0.05, None),
+              ("t.held", 128, 32, 8, 1, 128, "softmax", 0.0, None),
+              ("t.zero", 128, 24, 4, 12, 128, "softmax_bias", 0.01, 16))
+    c = chip_smoke.moe_dispatch(layers, tokens=40, carried=30)["checked"]
+    assert list(c) == ["t.sigmoid", "t.held", "t.zero"]
+    assert all("us" not in e for e in c.values())
+    assert all(e["combine_rel_l2"] < 1e-3 and e["layer_rel_l2"] < 1e-3
+               for e in c.values())
+
+
 def test_serve_tiny():
     out = chip_smoke.serve(GPT, [5, 40, 70, 90], new_tokens=6)
     c = out["checked"]

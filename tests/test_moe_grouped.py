@@ -161,6 +161,55 @@ def test_dropless_plan_of_the_held_experts(held):
         assert (np.asarray(plain[0]).reshape(topk.shape) == rows).all()
 
 
+@pytest.mark.parametrize("identity", [None, 12])
+@pytest.mark.parametrize("carried", [False, True])
+@pytest.mark.parametrize("held", [None, (2, 6)])
+@pytest.mark.parametrize("top_k", [1, 8, 12])
+def test_gathers_match_the_scatter_form(top_k, held, carried, identity):
+    """The grouped buffer the gathers fill is the scattered one bit for
+    bit (the same rows in the same places, padding zero); the combine
+    and the layer agree with the einsum form within float32 rounding.
+    The scatter and einsum forms are `chip_smoke`'s.  Choices among 16
+    experts, of which 12-15 are zero-compute ones where ``identity`` is
+    12."""
+    from chip_smoke import _einsum_combine, _scatter_dispatch
+    rng = np.random.RandomState(9 + top_k)
+    N, D, W = 40, 16, 8
+    lo, hi = held or (0, identity or 16)        # every routed expert
+    E = hi - lo
+    idx = jnp.asarray(np.stack([rng.permutation(16)[:top_k]
+                                for _ in range(N)]), jnp.int32)
+    live = jnp.asarray(rng.rand(N) < 0.7) if carried else None
+    x = jnp.asarray(rng.randn(N, D), jnp.bfloat16)
+    bm, nb, R = pg.grouped_layout(N * top_k, E, x.dtype)
+    rows, gid, _ = md.dropless_plan(idx, E, bm, nb, live, (lo, hi))
+    want = np.asarray(_scatter_dispatch(x, rows, top_k, R).view(jnp.uint16))
+    got = md.dropless_dispatch(x, rows, top_k, R)
+    assert got.shape == (R, D)
+    assert (np.asarray(got.view(jnp.uint16)) == want).all()
+    w = jnp.asarray(rng.rand(N, top_k), jnp.float32)
+    y_rows = jnp.asarray(rng.randn(R, D), jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(md.dropless_combine(y_rows, rows, w)),
+        np.asarray(_einsum_combine(y_rows, rows, w)), rtol=1e-6, atol=1e-6)
+    # the whole layer in float32 against the scatter-and-einsum form
+    xf = x.astype(jnp.float32)
+    gate_up = jnp.asarray(rng.randn(E, D, 2 * W) * 0.3, jnp.float32)
+    down = jnp.asarray(rng.randn(E, W, D) * 0.3, jnp.float32)
+    y, _ = md.gated_experts(xf, idx, w, gate_up, down, live, held=(lo, hi),
+                            identity=identity)
+    h = pg.grouped_gated_act_ref(_scatter_dispatch(xf, rows, top_k, R),
+                                 gate_up, block_group=gid, act="silu")
+    ref = _einsum_combine(pg.grouped_linear_act_ref(h, down, block_group=gid),
+                          rows, w)
+    if identity is not None:
+        zero = (idx >= identity) & (True if live is None
+                                    else live[:, None])
+        ref = ref + jnp.where(zero, w, 0.0).sum(-1)[:, None] * xf
+    np.testing.assert_allclose(np.asarray(y), np.asarray(ref), rtol=1e-5,
+                               atol=1e-5)
+
+
 def test_gated_experts_shares_add_up():
     """The parts of the result that the holders of experts 0-1, 2-5 and
     6-7 give sum to the layer's with every expert held, and the counters

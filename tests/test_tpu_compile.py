@@ -558,6 +558,82 @@ def test_qwen3_next_kernel_compiles_for_v5e(one_chip, name):
     assert not moved, moved
 
 
+# -- LongCat-Flash's expert layer (benchmarks/traffic/longctx16k-closed32)
+# 8 held experts of 6144 x 2048, top-12 of 768 (512 routed, 256
+# zero-compute) over the 1,520-row budget: the widest rows any cell's
+# dispatch moves
+LC_T, LC_D, LC_K = 1520, 6144, 12
+
+
+def _longcat_experts():
+    from paddle_tpu.distributed.auto_parallel import moe_dispatch as md
+    return (lambda x, idx, w, gate_up, down, carried: md.gated_experts(
+                x, idx, w, gate_up, down, carried, use_pallas=True,
+                held=(0, 8), identity=512),
+            [((LC_T, LC_D), bf16), ((LC_T, LC_K), i32), ((LC_T, LC_K), f32),
+             ((8, LC_D, 4096), bf16), ((8, 2048, LC_D), bf16),
+             ((LC_T,), jnp.bool_)])
+
+
+def _buffers(text):
+    """Instruction lines of the computations that are no fusion's body:
+    each writes a buffer of its own."""
+    bodies = set(re.findall(r"\bcalls=(%[\w.\-]+)", text))
+    lines, keep = [], False
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) ", line)
+        if head:
+            keep = head.group(1) not in bodies
+        elif keep and line.startswith(" "):
+            lines.append(line.strip())
+    return lines
+
+
+def _row_scatters(text, lanes):
+    """Scatters, fused or not, whose rows are ``lanes`` wide."""
+    return re.findall(rf"\w+\[\d+,{lanes}\]\{{[^}}]*\}} scatter\(", text)
+
+
+def test_longcat_experts_move_rows_by_gathers(one_chip):
+    """`gated_experts` at the LongCat-Flash cell's shapes: the grouped
+    kernel pair under its name; the 6,144-lane rows enter and leave the
+    grouped buffer by gathers (no scatter of them), no float32 ``[N, k,
+    D]`` buffer is written, and nothing contracts over the choices (the
+    lowered program has no ``dot_general``)."""
+    import chip_smoke
+    fn, args = _longcat_experts()
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+             for s, d in args]
+    with jax.enable_x64(False):
+        lowered = jax.jit(fn).lower(*avals)
+        text = lowered.compile().as_text()
+    assert "dot_general" not in lowered.as_text()
+    assert chip_smoke.mosaic_kernels(text) == {"grouped_matmul_fwd": 2}
+    assert not _row_scatters(text, LC_D)
+    wide = [line for line in _buffers(text) if re.search(
+        rf"= f32\[(?:{LC_T},{LC_K},{LC_D}|{LC_T * LC_K},{LC_D})\]", line)]
+    assert not wide, wide
+
+
+def test_row_scatter_guard_sees_the_scatter_form(one_chip):
+    """The guard's own control: the dispatch the gathers replaced
+    (`chip_smoke._scatter_dispatch`, tokens repeated and scattered into
+    a zeroed buffer) compiles to a scatter of 6,144-lane rows, and the
+    einsum combine lowers to a ``dot_general``."""
+    import chip_smoke
+    avals = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((LC_T, LC_D), bf16), ((LC_T * LC_K,), i32), ((LC_T, LC_K), f32))]
+    with jax.enable_x64(False):
+        text = jax.jit(lambda x, rows: chip_smoke._scatter_dispatch(
+            x, rows, LC_K, 19328)).lower(*avals[:2]).compile().as_text()
+        combine = jax.jit(lambda y, rows, w: chip_smoke._einsum_combine(
+            y, rows, w)).lower(
+            jax.ShapeDtypeStruct((19328, LC_D), bf16, sharding=one_chip),
+            *avals[1:]).as_text()
+    assert _row_scatters(text, LC_D)
+    assert "dot_general" in combine
+
+
 # -- a layer's scatter and attention on donated pools, a serving cell ---
 # The pools are [num_blocks, block_size, H_kv * D]: the scatter writes
 # rows of them in place and the ragged kernel copies lane windows out of
